@@ -45,11 +45,6 @@ from . import jaxpr_tools as JT
 
 __all__ = ["extract", "collective_bytes"]
 
-#: Collective primitive aliases folded onto their canonical family name
-#: (legacy shard_map lowers psum as ``psum2``).
-_COLLECTIVE_ALIASES = {"psum2": "psum"}
-
-
 def _mesh_devices(handle) -> int:
     mesh = getattr(handle, "mesh", None)
     size = getattr(getattr(mesh, "devices", None), "size", None)
@@ -65,22 +60,13 @@ def collective_bytes(handle, closed=None) -> dict:
     devices = _mesh_devices(handle)
     out: dict = {}
     for eqn in JT.iter_eqns(closed):
-        prim = eqn.primitive.name
-        if prim not in JT.COLLECTIVE_PRIMS:
+        name = JT.COLLECTIVE_FAMILY.get(eqn.primitive.name)
+        if name is None:
             continue
-        name = _COLLECTIVE_ALIASES.get(prim, prim)
         nb = sum(JT._nbytes(getattr(v, "aval", None))
                  for v in eqn.invars if not hasattr(v, "val"))
         out[name] = out.get(name, 0) + nb * devices
     return out
-
-
-def _first_module(ca) -> dict:
-    """``Compiled.cost_analysis()`` returns a flat dict on modern jax
-    and a one-element list of dicts on 0.4.x — normalize to the dict."""
-    if isinstance(ca, (list, tuple)):
-        return dict(ca[0]) if ca else {}
-    return dict(ca or {})
 
 
 def extract(handle) -> Optional[dict]:
@@ -100,7 +86,7 @@ def extract(handle) -> Optional[dict]:
 
     lowered = jax.jit(fn).lower(*handle.args)
     compiled = lowered.compile()
-    ca = _first_module(compiled.cost_analysis())
+    ca = compiled.cost_analysis() or {}
     doc = {
         "flops": float(ca.get("flops", 0.0)),
         "transcendentals": float(ca.get("transcendentals", 0.0)),

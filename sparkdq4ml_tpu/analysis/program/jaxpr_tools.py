@@ -33,22 +33,30 @@ import numpy as np
 __all__ = [
     "trace", "structural_signature", "peak_bytes", "iter_eqns",
     "collective_eqns", "callback_eqns",
-    "COLLECTIVE_PRIMS", "CALLBACK_PRIMS",
+    "COLLECTIVE_FAMILY", "CALLBACK_PRIMS",
 ]
 
-#: Cross-device communication primitives — every one must resolve its
-#: axis names against the installed mesh (collective-topology detector).
-COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmin", "pmax", "pmean", "all_gather",
-    "all_to_all", "reduce_scatter", "ppermute", "pbroadcast",
-})
+#: Cross-device communication primitives of the installed JAX — every one
+#: must resolve its axis names against the installed mesh
+#: (collective-topology detector) — mapped to the family name the
+#: detectors and cost tables report. Inside ``jax.shard_map``
+#: (``check_vma=True``) ``lax.psum`` binds ``psum_invariant`` and
+#: ``lax.all_gather`` binds ``all_gather_invariant``.
+COLLECTIVE_FAMILY = {
+    "psum": "psum", "psum_invariant": "psum",
+    "pmin": "pmin", "pmax": "pmax",
+    "all_gather": "all_gather", "all_gather_invariant": "all_gather",
+    "all_to_all": "all_to_all", "ragged_all_to_all": "all_to_all",
+    "reduce_scatter": "reduce_scatter",
+    "ppermute": "ppermute", "pbroadcast": "pbroadcast",
+}
 
-#: Host-callback primitives — a hidden host round-trip inside a jitted
-#: body (hidden-sync detector). ``debug_callback`` is what
-#: ``jax.debug.print`` lowers to.
+#: Host-callback primitives of the installed JAX — a hidden host
+#: round-trip inside a jitted body (hidden-sync detector).
+#: ``jax.debug.print`` binds ``debug_print``, ``jax.debug.callback``
+#: binds ``debug_callback``.
 CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "outside_call", "host_callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
 })
 
 
@@ -94,18 +102,19 @@ def iter_eqns(closed) -> Iterator:
 
 
 def collective_eqns(closed) -> list:
-    """``(primitive_name, axis_names)`` per collective eqn. Axis names
+    """``(family_name, axis_names)`` per collective eqn. Axis names
     come from the ``axes``/``axis_name`` params; integer (positional)
     axes are dropped — only named axes bind to a mesh."""
     out = []
     for eqn in iter_eqns(closed):
-        if eqn.primitive.name not in COLLECTIVE_PRIMS:
+        family = COLLECTIVE_FAMILY.get(eqn.primitive.name)
+        if family is None:
             continue
         axes = eqn.params.get("axes", eqn.params.get("axis_name", ()))
         if isinstance(axes, str):
             axes = (axes,)
         names = tuple(a for a in (axes or ()) if isinstance(a, str))
-        out.append((eqn.primitive.name, names))
+        out.append((family, names))
     return out
 
 

@@ -98,10 +98,7 @@ CONF_KEYS = {
     "spark.faults": "init",
     "spark.faults.seed": "init",
     "spark.recovery.validate": "init",
-    "spark.backend.probe": "init",
-    "spark.backend.probeTimeout": "init",
     "spark.compilation.cache": "init",
-    "spark.compilation.cacheDir": "init",
     "spark.distributed.coordinator": "init",
     "spark.distributed.numProcesses": "init",
     "spark.distributed.processId": "init",

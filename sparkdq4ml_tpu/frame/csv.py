@@ -227,8 +227,10 @@ def read_csv(path: str, header: bool = False, infer_schema: bool = True,
     """Load a CSV file into a Frame.
 
     ``engine``: "python" (pure host parser), "native" (C++ tokenizer), or
-    "auto" (native when the shared library is built and the column set is
-    numeric-friendly, else python).
+    "auto" (native when the column set is numeric-friendly, else python —
+    counted as ``ingest.python_fallback``). The native library builds
+    itself from ``native/csvparse.cpp`` on first use; a toolchain failure
+    raises for "native" and "auto" alike.
 
     ``mode`` (Spark's malformed-record policy): ``PERMISSIVE`` (default —
     short rows null-fill, long rows truncate), ``DROPMALFORMED`` (rows with
@@ -282,11 +284,12 @@ def read_csv(path: str, header: bool = False, infer_schema: bool = True,
                 frame, degraded = None, True
             if frame is not None:
                 return frame
-            if native_csv.available() and not degraded:
-                # native was eligible and declined (non-numeric content,
-                # ragged header, multibyte delimiter...): the ingest
-                # telemetry counts the demotion so a fleet-wide scrape can
-                # see what share of reads misses the fast path
+            if not degraded:
+                # native declined (non-numeric content, ragged header,
+                # multibyte delimiter...) or this install carries no
+                # native source: the ingest telemetry counts the demotion
+                # so a fleet-wide scrape can see what share of reads
+                # misses the fast path
                 from ..utils.profiling import counters
 
                 counters.increment("ingest.python_fallback")

@@ -5,8 +5,9 @@ Spark's CSV source (SURVEY.md §2.2 "CSV reader"). The native tokenizer handles
 the common all-numeric case (which is what feature matrices are), with or
 without a header record (names are read host-side, the body is skipped
 C-side); anything else returns ``None`` here and the pure-Python reader takes
-over, so the framework works identically whether or not the shared library is
-built (``make -C native``).
+over. The shared library is built on first use from the ``csvparse.cpp`` of
+this checkout (see :func:`_load`), so the engine a read goes through never
+depends on who built what beforehand.
 
 Two native paths, selected by ``spark.ingest.*`` conf (see ``config``):
 
@@ -57,101 +58,140 @@ class NativeIngestError(RuntimeError):
     rung of the ingest degradation ladder."""
 
 
+class NativeBuildError(RuntimeError):
+    """``native/libdqcsv.so`` could not be built from this checkout's
+    ``csvparse.cpp`` (no ``make``/``g++``, or a compile error). Raised to
+    the caller for ``engine="native"`` and ``engine="auto"`` alike: a
+    broken toolchain must not silently turn every read into a
+    python-engine read."""
+
+
 _LIB = None
 _LIB_TRIED = False
+_LOAD_LOCK = threading.Lock()
 
-_SO_PATHS = [
-    os.path.join(os.path.dirname(__file__), "..", "..", "native", "libdqcsv.so"),
-    os.path.join(os.path.dirname(__file__), "_native", "libdqcsv.so"),
-]
+_NATIVE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "native"))
+_SO_PATH = os.path.join(_NATIVE_DIR, "libdqcsv.so")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "csvparse.cpp")
 
 _SIMD_CONF = {"auto": -1, "off": 0, "scalar": 0, "avx2": 1, "avx512": 2}
 _SIMD_NAMES = {0: "scalar", 1: "avx2", 2: "avx512"}
 
 
+def _build_if_stale() -> bool:
+    """Make ``_SO_PATH`` current with ``_SRC_PATH``: build it when it is
+    missing or older than its source. Returns False only when this
+    install carries no native source at all (then there is nothing to
+    tie a library to, and none is loaded)."""
+    try:
+        src_mtime = os.path.getmtime(_SRC_PATH)
+    except OSError:
+        return False
+    try:
+        if os.path.getmtime(_SO_PATH) >= src_mtime:
+            return True
+    except OSError:
+        pass
+    import subprocess
+
+    try:
+        proc = subprocess.run(
+            ["make", "-C", _NATIVE_DIR, "libdqcsv.so"],
+            capture_output=True, text=True, timeout=600)
+    except (OSError, subprocess.SubprocessError) as e:
+        raise NativeBuildError(
+            f"cannot build {_SO_PATH}: {type(e).__name__}: {e}") from e
+    if proc.returncode != 0:
+        raise NativeBuildError(
+            f"building {_SO_PATH} failed (rc={proc.returncode}):\n"
+            f"{proc.stderr[-2000:]}")
+    return True
+
+
 def _load():
+    """The bound library, built first when stale (:func:`_build_if_stale`).
+    ``None`` when this install has no native source; raises
+    :class:`NativeBuildError` when the build fails."""
     global _LIB, _LIB_TRIED
     if _LIB_TRIED:
         return _LIB
-    _LIB_TRIED = True
-    for p in _SO_PATHS:
-        p = os.path.abspath(p)
-        if os.path.exists(p):
+    with _LOAD_LOCK:
+        if _LIB_TRIED:
+            return _LIB
+        if _build_if_stale():
             try:
-                lib = ctypes.CDLL(p)
-            except OSError:
-                continue
-            pd = ctypes.POINTER(ctypes.c_double)
-            lib.dq_parse_numeric_csv.restype = ctypes.c_longlong
-            lib.dq_parse_numeric_csv.argtypes = [
-                ctypes.c_char_p,                      # path
-                ctypes.c_char,                        # delimiter
-                ctypes.c_char,                        # quote
-                ctypes.c_int,                         # skip_header
-                ctypes.POINTER(pd),                   # out data
-                ctypes.POINTER(ctypes.c_longlong),    # out ncols
-                ctypes.POINTER(ctypes.POINTER(ctypes.c_char)),  # out int_flags
-            ]
-            lib.dq_free.restype = None
-            lib.dq_free.argtypes = [ctypes.c_void_p]
-            if hasattr(lib, "dq_stream_open"):  # v2 + streaming ABI
-                lib.dq_parse_numeric_csv_v2.restype = ctypes.c_longlong
-                lib.dq_parse_numeric_csv_v2.argtypes = (
-                    lib.dq_parse_numeric_csv.argtypes[:4]
-                    + [ctypes.c_int, ctypes.c_int]        # simd, threads
-                    + lib.dq_parse_numeric_csv.argtypes[4:])
-                lib.dq_effective_simd.restype = ctypes.c_int
-                lib.dq_effective_simd.argtypes = [ctypes.c_int]
-                lib.dq_stream_open.restype = ctypes.c_void_p
-                lib.dq_stream_open.argtypes = [
-                    ctypes.c_char_p, ctypes.c_char, ctypes.c_char,
-                    ctypes.c_int,                     # skip_header
-                    ctypes.c_longlong,                # chunk_bytes
-                    ctypes.c_int, ctypes.c_int,       # threads, simd
-                ]
-                lib.dq_stream_ncols.restype = ctypes.c_longlong
-                lib.dq_stream_ncols.argtypes = [ctypes.c_void_p]
-                lib.dq_stream_simd.restype = ctypes.c_int
-                lib.dq_stream_simd.argtypes = [ctypes.c_void_p]
-                lib.dq_stream_next.restype = ctypes.c_longlong
-                lib.dq_stream_next.argtypes = [ctypes.c_void_p,
-                                               ctypes.POINTER(pd)]
-                lib.dq_stream_int_flags.restype = None
-                lib.dq_stream_int_flags.argtypes = [ctypes.c_void_p,
-                                                    ctypes.c_char_p]
-                lib.dq_stream_close.restype = None
-                lib.dq_stream_close.argtypes = [ctypes.c_void_p]
-            if hasattr(lib, "dq_stream_bind"):  # zero-stitch bind ABI
-                lib.dq_stream_total_rows.restype = ctypes.c_longlong
-                lib.dq_stream_total_rows.argtypes = [ctypes.c_void_p]
-                lib.dq_stream_bind.restype = ctypes.c_int
-                lib.dq_stream_bind.argtypes = [
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_longlong, ctypes.c_int,
-                ]
-                lib.dq_stream_next_into.restype = ctypes.c_longlong
-                lib.dq_stream_next_into.argtypes = [
-                    ctypes.c_void_p, ctypes.POINTER(ctypes.c_longlong)]
-            _LIB = lib
-            break
+                _LIB = _bind(ctypes.CDLL(_SO_PATH))
+            except OSError as e:
+                raise NativeBuildError(
+                    f"cannot load {_SO_PATH}: {e}") from e
+        _LIB_TRIED = True
     return _LIB
+
+
+def _bind(lib):
+    """Declare ``argtypes``/``restype`` for every entry point."""
+    pd = ctypes.POINTER(ctypes.c_double)
+    lib.dq_parse_numeric_csv.restype = ctypes.c_longlong
+    lib.dq_parse_numeric_csv.argtypes = [
+        ctypes.c_char_p,                      # path
+        ctypes.c_char,                        # delimiter
+        ctypes.c_char,                        # quote
+        ctypes.c_int,                         # skip_header
+        ctypes.POINTER(pd),                   # out data
+        ctypes.POINTER(ctypes.c_longlong),    # out ncols
+        ctypes.POINTER(ctypes.POINTER(ctypes.c_char)),  # out int_flags
+    ]
+    lib.dq_free.restype = None
+    lib.dq_free.argtypes = [ctypes.c_void_p]
+    lib.dq_parse_numeric_csv_v2.restype = ctypes.c_longlong
+    lib.dq_parse_numeric_csv_v2.argtypes = (
+        lib.dq_parse_numeric_csv.argtypes[:4]
+        + [ctypes.c_int, ctypes.c_int]        # simd, threads
+        + lib.dq_parse_numeric_csv.argtypes[4:])
+    lib.dq_effective_simd.restype = ctypes.c_int
+    lib.dq_effective_simd.argtypes = [ctypes.c_int]
+    lib.dq_stream_open.restype = ctypes.c_void_p
+    lib.dq_stream_open.argtypes = [
+        ctypes.c_char_p, ctypes.c_char, ctypes.c_char,
+        ctypes.c_int,                     # skip_header
+        ctypes.c_longlong,                # chunk_bytes
+        ctypes.c_int, ctypes.c_int,       # threads, simd
+    ]
+    lib.dq_stream_ncols.restype = ctypes.c_longlong
+    lib.dq_stream_ncols.argtypes = [ctypes.c_void_p]
+    lib.dq_stream_simd.restype = ctypes.c_int
+    lib.dq_stream_simd.argtypes = [ctypes.c_void_p]
+    lib.dq_stream_next.restype = ctypes.c_longlong
+    lib.dq_stream_next.argtypes = [ctypes.c_void_p, ctypes.POINTER(pd)]
+    lib.dq_stream_int_flags.restype = None
+    lib.dq_stream_int_flags.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
+    lib.dq_stream_close.restype = None
+    lib.dq_stream_close.argtypes = [ctypes.c_void_p]
+    lib.dq_stream_total_rows.restype = ctypes.c_longlong
+    lib.dq_stream_total_rows.argtypes = [ctypes.c_void_p]
+    lib.dq_stream_bind.restype = ctypes.c_int
+    lib.dq_stream_bind.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_longlong, ctypes.c_int,
+    ]
+    lib.dq_stream_next_into.restype = ctypes.c_longlong
+    lib.dq_stream_next_into.argtypes = [
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_longlong)]
+    return lib
 
 
 def available() -> bool:
     return _load() is not None
 
 
-def streaming_available() -> bool:
-    """True when the built library carries the dq_stream/v2 ABI."""
-    lib = _load()
-    return lib is not None and hasattr(lib, "dq_stream_open")
 
 
 def simd_level(requested: Optional[str] = None) -> str:
     """Effective SIMD tier name for a conf request (default: the session
     conf) — the simd-vs-scalar verdict the ``frame.ingest`` span reports."""
     lib = _load()
-    if lib is None or not hasattr(lib, "dq_effective_simd"):
+    if lib is None:
         return "unavailable"
     req = _SIMD_CONF.get((requested or config.ingest_simd).lower(), -1)
     return _SIMD_NAMES.get(int(lib.dq_effective_simd(req)), "scalar")
@@ -164,8 +204,8 @@ def try_read_csv(path: str, header: bool, infer_schema: bool, delimiter: str,
     if lib is None:
         if required:
             raise RuntimeError(
-                "native CSV engine requested but native/libdqcsv.so is not "
-                "built (run `make -C native`)")
+                "native CSV engine requested but this install carries no "
+                "native/csvparse.cpp to build it from")
         return None
     if len(delimiter.encode("utf-8")) != 1 or len(quote.encode("utf-8")) != 1:
         return None  # ctypes c_char needs exactly one BYTE → python engine
@@ -190,7 +230,7 @@ def try_read_csv(path: str, header: bool, infer_schema: bool, delimiter: str,
     # InjectedIOError here — the flaky-disk model — and frame/csv.py
     # degrades the read to the python engine.
     _faults.inject("ingest_native")
-    if config.ingest_streaming and hasattr(lib, "dq_stream_open"):
+    if config.ingest_streaming:
         try:
             size = os.path.getsize(path)
         except OSError:
@@ -283,70 +323,28 @@ def _finish_oneshot(lib, path, nrows, data_p, ncols, intf_p, names):
 
 def _aligned_empty(n: int, dtype, align: int = 64) -> np.ndarray:
     """Uninitialized 1-D array whose data pointer is ``align``-byte
-    aligned — the alignment XLA requires to adopt a host buffer zero-copy
-    when the runtime supports adoption (``_device_handoff_mode() ==
-    "alias"``), and a cache-line-aligned store target for the native
-    column writes either way."""
+    aligned — the alignment at which XLA:CPU adopts a host buffer
+    zero-copy on ``device_put``, and a cache-line-aligned store target
+    for the native column writes either way."""
     dt = np.dtype(dtype)
     raw = np.empty(n * dt.itemsize + align, dtype=np.uint8)
     off = (-raw.ctypes.data) % align
     return raw[off:off + n * dt.itemsize].view(dt)
 
 
-# ---- device handoff + bind-buffer pool -------------------------------------
-# How a finished host column becomes a jax.Array is probed ONCE per
-# process, because jax's import behavior differs by version/backend:
-#   "alias"  dlpack import aliases host memory (true zero-copy): fastest,
-#            but the buffer now belongs to the engine — never reuse it.
-#   "copy"   dlpack import copies (jax 0.4.x on CPU). The copy runs ~3x
-#            faster than device_put's path, and since the engine owns a
-#            copy, the parse buffers can be POOLED: reused bind buffers
-#            have warm (already-faulted) pages, and on fault-throttled
-#            hosts (gVisor-class sandboxes, small VMs) first-touch faults
-#            on a couple hundred MB of fresh columns otherwise cost more
-#            than the parse itself.
-#   "put"    no usable dlpack: plain device_put (also a copy → pool too).
-_HANDOFF_MODE: Optional[str] = None
+# ---- bind-buffer pool ------------------------------------------------------
+# Reused bind buffers have warm (already-faulted) pages; on fault-throttled
+# hosts first-touch faults on a couple hundred MB of fresh columns otherwise
+# cost more than the parse itself. A buffer may come back here only when the
+# engine holds no reference into it: after an accelerator read (device_put
+# COPIED the columns into device memory) or after a read that handed no
+# column over. On the CPU backend device_put ADOPTS these 64-byte-aligned
+# buffers zero-copy — the columns ARE the buffers, so a successful CPU read
+# never returns them.
 _POOL_LOCK = threading.Lock()
-_POOL: list = []  # (fbuf, ibuf) pairs checked in after the engine copied
+_POOL: list = []  # (fbuf, ibuf) pairs no live column refers to
 _POOL_MAX_ENTRIES = 2
 _POOL_CAP_BYTES = 1 << 30
-
-
-def _device_handoff_mode() -> str:
-    global _HANDOFF_MODE
-    if _HANDOFF_MODE is None:
-        try:
-            import warnings
-
-            import jax.dlpack
-
-            probe = _aligned_empty(16, np.float64)
-            probe[:] = 1.0
-            with warnings.catch_warnings():
-                warnings.simplefilter("ignore")
-                d = jax.dlpack.from_dlpack(probe.__dlpack__())
-            d.block_until_ready()
-            probe[0] = 2.0
-            _HANDOFF_MODE = "alias" if float(d[0]) == 2.0 else "copy"
-        except Exception:
-            _HANDOFF_MODE = "put"
-    return _HANDOFF_MODE
-
-
-def _to_device(arr: np.ndarray):
-    """Host column -> jax.Array via the probed fastest path."""
-    if _device_handoff_mode() in ("alias", "copy"):
-        import warnings
-
-        import jax.dlpack
-
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            return jax.dlpack.from_dlpack(arr.__dlpack__())
-    import jax
-
-    return jax.device_put(arr)
 
 
 def _pool_checkout(nf: int, fdtype, ni: int):
@@ -359,10 +357,6 @@ def _pool_checkout(nf: int, fdtype, ni: int):
 
 
 def _pool_checkin(fbuf: np.ndarray, ibuf: np.ndarray) -> None:
-    """Return bind buffers for reuse — only when the engine holds COPIES
-    of the columns (alias mode hands the memory itself to the engine)."""
-    if _device_handoff_mode() == "alias":
-        return
     if fbuf.nbytes + ibuf.nbytes > _POOL_CAP_BYTES:
         return
     with _POOL_LOCK:
@@ -379,16 +373,16 @@ def _stream_read(lib, path, size, names, header, delimiter, quote):
       sweep bounds the row count, the final engine-dtype column buffers
       (float32/float64 + int32 staging) come 64-byte aligned from a
       process-level pool (warm pages — see the pool note above
-      ``_device_handoff_mode``), and every chunk parses STRAIGHT into its
+      ``_pool_checkout``), and every chunk parses STRAIGHT into its
       final rows inside ``dq_stream_next_into`` (typed stores in the
-      native walk — no per-chunk malloc, no astype, no concatenate). At
-      EOF each column hands to JAX through the probed fastest path
-      (``_to_device``): dlpack adoption where the runtime aliases host
-      buffers, else one bulk dlpack/device_put copy per column. Bit
-      parity: the native (float)/(int32) casts are the same IEEE
+      native walk — no per-chunk malloc, no astype, no concatenate). On
+      the CPU backend each column hands to JAX at EOF with one
+      ``device_put`` (which adopts the aligned buffer); on an
+      accelerator float rows ship per chunk and concatenate on device.
+      Bit parity: the native (float)/(int32) casts are the same IEEE
       elementwise conversions as the one-shot path's numpy ``astype``.
-    * **chunked** (quoted files, or a pre-bind libdqcsv build): the
-      original per-chunk f64 blocks + host-side ``astype`` staging.
+    * **chunked** (quoted files): the original per-chunk f64 blocks +
+      host-side ``astype`` staging.
 
     In both modes a producer thread blocks in the native parse (GIL
     released) up to ``spark.ingest.prefetch`` chunks ahead of the
@@ -416,16 +410,12 @@ def _stream_read(lib, path, size, names, header, delimiter, quote):
             from .frame import Frame
             return Frame({})
         verdict = _SIMD_NAMES.get(int(lib.dq_stream_simd(h)), "scalar")
-        pinned = hasattr(lib, "dq_stream_bind")
         with span("frame.ingest", cat="frame", path=os.path.basename(path),
                   mode="stream") as sp:
-            if pinned:
-                # _stream_pinned falls back to the chunked body itself if
-                # the bind is refused; a None from either body is
-                # DEFINITIVE (non-numeric content) — never retried.
-                out = _stream_pinned(lib, h, nc, names, size)
-            else:
-                out = _stream_chunked(lib, h, nc, names)
+            # _stream_pinned falls back to the chunked body itself if the
+            # bind is refused; a None from either body is DEFINITIVE
+            # (non-numeric content) — never retried.
+            out = _stream_pinned(lib, h, nc, names, size)
             if out is None:
                 return None  # non-numeric mid-file → python engine
             data, total_rows, nchunks = out
@@ -437,7 +427,7 @@ def _stream_read(lib, path, size, names, header, delimiter, quote):
             counters.increment("ingest.chunks", nchunks)
             sp.set(bytes=size, rows=total_rows, chunks=nchunks,
                    threads=config.ingest_threads or 0, simd=verdict,
-                   prefetch=config.ingest_prefetch, pinned=pinned,
+                   prefetch=config.ingest_prefetch,
                    gb_s=round(size / el / 1e9, 4) if el > 0 else 0.0)
     finally:
         lib.dq_stream_close(h)
@@ -495,19 +485,23 @@ def _stream_pinned(lib, h, nc, names, size):
     stride = ((max(total_cap, 1) + 15) // 16) * 16
     fbuf, ibuf = _pool_checkout(
         nc * stride, np.float64 if want_f64 else np.float32, nc * stride)
-    # Release-ONCE discipline: the buffers return to the pool on EVERY
-    # exit — success (after the engine finished reading them), the
+    # Release-ONCE discipline: the buffers return to the pool on every
+    # exit that leaves no column referring to them — an accelerator
+    # success (after the engine finished copying them), the
     # definitive-None parse failure, the alloc-failure raise, a dead
     # prefetch producer — via the finally below. The flag stops a double
     # checkin (two pool entries aliasing one buffer would hand the same
-    # memory to two concurrent readers).
+    # memory to two concurrent readers); ``adopted`` keeps buffers the
+    # CPU backend's columns alias out of the pool for good.
     released = False
+    adopted = False
 
     def _release():
         nonlocal released
         if not released:
             released = True
-            _pool_checkin(fbuf, ibuf)
+            if not adopted:
+                _pool_checkin(fbuf, ibuf)
 
     rc = int(lib.dq_stream_bind(
         h, fbuf.ctypes.data_as(ctypes.c_void_p),
@@ -528,8 +522,8 @@ def _stream_pinned(lib, h, nc, names, size):
     # No transferred region is ever rewritten: backfill only targets
     # columns transitioning alive->dead, which by construction have no
     # prior float transfers. On the CPU backend there is no DMA to
-    # overlap — columns hand over whole at EOF through the probed
-    # fastest path (_to_device: dlpack adoption or bulk copy).
+    # overlap — columns hand over whole at EOF, and device_put adopts the
+    # aligned buffer instead of copying it.
     cpu_backend = jax.default_backend() == "cpu"
     chunks = _bind_chunk_iter(lib, h, nc)
     try:
@@ -554,6 +548,7 @@ def _stream_pinned(lib, h, nc, names, size):
                                             dev_rows[j]]))
                     dev_rows[j] = total_rows
         flags = _stream_flags(lib, h, nc)
+        adopted = cpu_backend
         data = {}
         for j in range(nc):
             name = names[j] if names is not None else f"_c{j}"
@@ -561,23 +556,16 @@ def _stream_pinned(lib, h, nc, names, size):
             if flags[j]:
                 col = ibuf[base:base + total_rows]
                 col = col if idt == np.dtype(np.int32) else col.astype(idt)
-                # dlpack commits to the HOST device — correct on the CPU
-                # backend, but on an accelerator it would strand int
-                # columns on the CPU next to float columns living on the
-                # accelerator (mixed-device Frames fail on first use):
-                # device_put instead.
-                data[name] = (_to_device(col) if cpu_backend
-                              else jax.device_put(col))
+                data[name] = jax.device_put(col)
             elif cpu_backend:
-                data[name] = _to_device(fbuf[base:base + total_rows])
+                data[name] = jax.device_put(fbuf[base:base + total_rows])
             else:
                 import jax.numpy as jnp
 
                 data[name] = (dev_chunks[j][0] if len(dev_chunks[j]) == 1
                               else jnp.concatenate(dev_chunks[j]))
-        # The engine must be done reading the bind buffers before they
-        # can be pooled for the next read (checkin is a no-op in alias
-        # mode, where the columns ARE these buffers).
+        # The engine must be done copying out of the bind buffers before
+        # they can be pooled for the next read.
         jax.block_until_ready(list(data.values()))
         return data, total_rows, nchunks
     finally:
@@ -592,8 +580,9 @@ def _stream_pinned(lib, h, nc, names, size):
 
 
 def _stream_chunked(lib, h, nc, names):
-    """Per-chunk f64 blocks + host astype staging — quoted files and
-    pre-bind libdqcsv builds. Returns ``(data, rows, chunks)`` or None."""
+    """Per-chunk f64 blocks + host astype staging — quoted files (the
+    bind is refused) and the ``pool_exhaust`` rung. Returns
+    ``(data, rows, chunks)`` or None."""
     import jax
 
     fdt = np.dtype(float_dtype())

@@ -56,9 +56,8 @@ def _fm_core(X, y, mask, n, *, factor_size, loss, reg_param, max_iter, lr,
                if axis is not None else jnp.asarray(1.0, dt))
 
     def objective(params):
-        # LOCAL share of the loss: psum_value_and_grad sums value+grad
-        # over the mesh (grad through a psum is unreliable on legacy
-        # shard_map; see solvers.psum_value_and_grad)
+        # LOCAL share of the loss: solvers.psum_value_and_grad
+        # differentiates its psum over the mesh
         b, w, V = params
         pred = fm_forward(Xm, b, w, V)
         if loss == "squared":

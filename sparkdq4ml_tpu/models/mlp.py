@@ -52,9 +52,8 @@ def _mlp_fit_fn(mesh, layers: tuple, max_iter: int, lr: float, seed: int):
         def objective(params):
             # invalid rows arrive zeroed (host-side) and pads are zero by
             # construction — no per-iteration re-masking needed. LOCAL
-            # share only: psum_value_and_grad sums value+grad over the
-            # mesh (grad *through* a psum is unreliable on legacy
-            # shard_map; see solvers.psum_value_and_grad).
+            # share only: solvers.psum_value_and_grad differentiates its
+            # psum over the mesh.
             logits = _mlp_forward(params, X)
             lse = jax.nn.logsumexp(logits, axis=1)
             ll = jnp.where(mask,

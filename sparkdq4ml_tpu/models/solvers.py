@@ -158,11 +158,8 @@ def fista_solve(A: jnp.ndarray, reg_param, elastic_net_param,
     that solve many throwaway cells (the fused CV grid) skip the wasted
     stacking. The trace itself is accumulated in the scan CARRY with an
     explicit int32 ``dynamic_update_index_in_dim`` rather than as a
-    stacked scan output: the stacking machinery's update indices come
-    out mixed s64/s32 under x64, which the jax-0.4.x SPMD partitioner
-    rejects whenever the solve lands inside a sharded program (the fused
-    CV refit). Identical trace, partitioner-safe on every jax this
-    framework supports.
+    stacked scan output, so ``record_history=False`` carries a
+    zero-length buffer instead of stacking ``max_iter`` values.
     """
     m = unpack_moments(A, fit_intercept=fit_intercept)
     dt = A.dtype
@@ -421,16 +418,15 @@ _register_cache_stats()
 
 
 def psum_value_and_grad(local_objective, axis):
-    """``value_and_grad`` for a data-parallel objective inside shard_map:
-    differentiate the LOCAL objective, then explicitly ``psum`` both the
-    value and every gradient leaf.
+    """``value_and_grad`` of a data-parallel objective inside
+    ``jax.shard_map(check_vma=True)``: differentiate the psum of the
+    LOCAL objective.
 
-    Mathematically identical to ``value_and_grad(psum(local))`` — grad is
-    linear — but robust across shard_map implementations: differentiating
-    *through* a psum relies on replication tracking that the legacy
-    ``check_rep`` machinery gets silently wrong when the check is off
-    (which it must be: the old checker cannot traverse the while/scan
-    loops every solver here uses; see ``parallel.mesh.shard_map``). Any
+    ``params`` enter the manual region replicated and the local objective
+    is device-varying, so autodiff already reduces the cotangent of the
+    replicated params over ``axis`` — the gradient that comes back is the
+    full-data gradient, identical on every device, and must NOT be
+    ``psum``'d again (that would scale it by the device count). Any
     replicated term in the local objective (regularizers on replicated
     params) must be pre-divided by the shard count so the psum restores
     it exactly once.
@@ -438,15 +434,10 @@ def psum_value_and_grad(local_objective, axis):
     ``axis=None`` returns plain ``jax.value_and_grad`` — the single-device
     path pays nothing.
     """
-    vg = jax.value_and_grad(local_objective)
     if axis is None:
-        return vg
-
-    def vg_psum(params):
-        v, g = vg(params)
-        return (jax.lax.psum(v, axis),
-                jax.tree_util.tree_map(lambda t: jax.lax.psum(t, axis), g))
-    return vg_psum
+        return jax.value_and_grad(local_objective)
+    return jax.value_and_grad(
+        lambda params: jax.lax.psum(local_objective(params), axis))
 
 
 def adam_scan(value_and_grad, params0, max_iter: int, lr: float,

@@ -58,9 +58,8 @@ def _aft_core(X, logt, censor, mask, n, std, max_iter, lr, axis=None):
         return jax.lax.psum(v, axis) if axis is not None else v
 
     def neg_ll(params):
-        # LOCAL share of the likelihood: psum_value_and_grad reduces
-        # value+grad over the mesh (grad through a psum is unreliable on
-        # legacy shard_map; see solvers.psum_value_and_grad)
+        # LOCAL share of the likelihood: solvers.psum_value_and_grad
+        # differentiates its psum over the mesh
         beta, b0, logsig = params[:d], params[d], params[d + 1]
         sig = jnp.exp(logsig)
         eps = (lt - b0 * wm - Xs @ beta) / sig

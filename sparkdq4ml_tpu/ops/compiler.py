@@ -3,8 +3,8 @@
 The frame engine is eager by design (frame.py docstring: Spark's lazy DAG is
 deliberately not replicated) — but in eager JAX every ``with_column`` /
 ``filter`` node dispatches as its *own* XLA computation, and the fusion the
-design banks on only happens **inside** ``jax.jit``. BENCH_r05 showed the op
-sweep pinned at interpreter-dispatch cost, not FLOPs. This module is the
+design banks on only happens **inside** ``jax.jit``; without it the op
+sweep is pinned at interpreter-dispatch cost, not FLOPs. This module is the
 missing compilation layer: chains of compilable frame ops coalesce (see
 ``Frame._defer``) and materialize as ONE jitted XLA program per *plan shape*.
 
@@ -710,16 +710,24 @@ class _Plan:
         self.mesh = None
         self.guarded = None
 
-        # Buffer donation (replaced columns + mask) only pays on
+        # Buffer donation of the replaced columns only pays on
         # accelerators, where the donated HBM buffer is reused for the
         # output; on XLA:CPU (unified memory) aliasing buys nothing and
         # measurably slows the call (~25% on the 20-op bench chain), so
-        # the CPU path keeps the plain signature.
-        if jax.default_backend() == "cpu":
-            self.fn = jax.jit(program)
+        # the CPU path keeps the plain signature. The mask is never
+        # donated: the dq-profile hook reads the flush's INPUT mask after
+        # the dispatch (run_pipeline), and a donated array is deleted.
+        self.donates = _donates()
+        if self.donates:
+            self.fn = jax.jit(program, donate_argnums=(1,))
         else:
-            self.fn = jax.jit(program, donate_argnums=(1, 2))
-        self.donates = jax.default_backend() != "cpu"
+            self.fn = jax.jit(program)
+
+
+def _donates() -> bool:
+    """Whether fused single-device plans donate their replaced-column
+    inputs (see ``_Plan.__init__``)."""
+    return jax.default_backend() != "cpu"
 
 
 _CACHE: "OrderedDict[str, _Plan]" = OrderedDict()
@@ -945,8 +953,7 @@ def _run_chunked(plan, lit_values, data: dict, mask, n: int,
             donated = tuple(_pad(data[name][start:start + rows], cb,
                                  fresh=plan.donates)
                             for name in plan.donated)
-            mask_in = _pad(mask[start:start + rows], cb,
-                           fresh=plan.donates)
+            mask_in = _pad(mask[start:start + rows], cb, fresh=False)
             if plan.example is None:
                 # same idempotent recording as the unchunked path — a
                 # plan whose FIRST execution is chunked must still be
@@ -1258,7 +1265,7 @@ def run_pipeline(data: dict, mask, n: int, steps, extra=(), shard=None):
         # so the padded mask tail is invalid by construction
         donated = tuple(_pad(data[name], b, fresh=plan.donates)
                         for name in plan.donated)
-        mask_in = _pad(jnp.asarray(mask, jnp.bool_), b, fresh=plan.donates)
+        mask_in = _pad(jnp.asarray(mask, jnp.bool_), b, fresh=False)
         if plan.example is None:
             # Abstract specs only (shape/dtype metadata, no device read);
             # idempotent, so the benign cross-thread race needs no lock.

@@ -84,19 +84,10 @@ def _unsupported_trace(*operands) -> bool:
     """
     from jax._src.interpreters import batching
 
-    # jax.typeof is the modern name; 0.4.x spells it get_aval (and its
-    # avals carry no vma field — old shard_map tracks replication
-    # elsewhere, so the getattr default covers it)
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        from jax.core import get_aval as typeof
-
     for op in operands:
         if isinstance(op, batching.BatchTracer):
             return True
-        if "ShardMap" in type(op).__name__:   # 0.4.x shard_map tracer
-            return True
-        if getattr(typeof(op), "vma", frozenset()):
+        if jax.typeof(op).vma:
             return True
     return False
 

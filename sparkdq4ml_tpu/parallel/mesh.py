@@ -85,40 +85,16 @@ def serialize_collectives(fn, mesh):
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compat ``shard_map`` — every sharded program in the
-    framework routes through here. Newer jax exports it as
-    ``jax.shard_map`` (with the replication check spelled ``check_vma``);
-    0.4.x ships only ``jax.experimental.shard_map`` with the older
-    ``check_rep`` spelling. Without this shim the whole sharded execution
-    layer (Gramian psum, clustering/tree/ALS statistics) crashes with
-    ``AttributeError`` on a 0.4.x runtime — a version skew is an
-    environment fault and gets the same graceful treatment as a device
-    fault.
-
-    Wherever the kwarg is spelled ``check_rep`` (the pre-``check_vma``
-    checker), it is forced **off**: that checker has no replication rule
-    for ``while``/``scan`` — the primitives every solver loop here is
-    built on — and aborts compilation with ``NotImplementedError``. The
-    check is a static lint, not a semantics change; the modern
-    ``check_vma`` checker (which does infer through loops) still honors
-    the caller's flag."""
+    """``jax.shard_map`` plus the build counter — every sharded program
+    in the framework routes through here."""
     from ..utils.profiling import counters
 
     # One sharded-program BUILD (trace-time, not per-dispatch): the
     # collective-shape signal the observability layer surfaces as
     # ``parallel.shard_map_builds``.
     counters.increment("parallel.shard_map_builds")
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        except TypeError:   # public export, pre-check_vma kwarg naming
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def parse_master(master: Optional[str]) -> Optional[int]:

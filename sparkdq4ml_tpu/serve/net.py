@@ -779,7 +779,16 @@ class NetServer:
             raise _Abort()
         finally:
             if watch is not None and not watch.done():
+                # The cancelled read must have RUN its cancellation (it
+                # owns the StreamReader's one waiter slot) before the
+                # keep-alive loop reads the next request: a reply small
+                # enough to drain without suspending would otherwise
+                # reach that read first, fail it with "read() called
+                # while another coroutine is already waiting" and drop
+                # the connection after every response. wait() does not
+                # re-raise the watch's own CancelledError.
                 watch.cancel()
+                await asyncio.wait({watch})
 
     def _wait_result(self, fut: QueryFuture, bound: float) -> QueryResult:
         try:

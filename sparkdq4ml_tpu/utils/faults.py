@@ -12,7 +12,7 @@ Failure classes (``kind``):
 
 * ``device_error`` — raises :class:`InjectedDeviceError`, a
   ``jax.errors.JaxRuntimeError`` subclass, i.e. exactly the exception type
-  a real XLA device fault (OOM, interconnect reset, preempted tunnel)
+  a real XLA device fault (OOM, interconnect reset, preempted device)
   surfaces as. The production catch paths cannot tell the difference,
   which is the point.
 * ``nan`` — poisons one leaf of a result pytree with NaN (a diverged
@@ -154,7 +154,7 @@ def injected_device_error_class():
     if _INJECTED_DEVICE_ERROR is None:
         class InjectedDeviceError(_jax_runtime_error_base()):
             """Simulated ``XlaRuntimeError`` (device OOM / interconnect
-            reset / preempted tunnel) raised by the fault plan."""
+            reset / preempted device) raised by the fault plan."""
 
         _INJECTED_DEVICE_ERROR = InjectedDeviceError
     return _INJECTED_DEVICE_ERROR

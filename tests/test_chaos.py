@@ -347,7 +347,7 @@ def _write_csv(tmp_path, name="big.csv", rows=4000):
 
 
 needs_stream = pytest.mark.skipif(
-    not native_csv.streaming_available(),
+    not native_csv.available(),
     reason="native streaming library not built")
 
 
@@ -427,11 +427,8 @@ class TestIngestChaos:
                                                     monkeypatch):
         """The pooled bind-mode buffers return to the pool on a
         mid-stream parse failure (the old code leaked them on every
-        non-success exit). Forced into "copy" handoff mode — alias mode
-        never pools, and on this failure path no column is ever handed
-        to the engine, so the mode only gates the checkin."""
-        monkeypatch.setattr(native_csv, "_device_handoff_mode",
-                            lambda: "copy")
+        non-success exit). No column is handed to the engine on this
+        path, so the buffers pool on every backend."""
         path = str(tmp_path / "bad.csv")
         with open(path, "w") as f:
             for i in range(2500):

@@ -515,6 +515,33 @@ class TestRuleAccounting:
         assert obs.METRICS.get_gauge(
             "dq.violation_rate.minimumPriceRule") == pytest.approx(0.25)
 
+    def test_rule_rows_recorded_when_the_flush_donates(self, session,
+                                                       monkeypatch):
+        # On an accelerator the fused flush donates input buffers — a
+        # branch no CPU test ran. The reference app's rule-bearing path
+        # must come out the same with donation on: same rows, exact rule
+        # tallies, no swallowed profile failure. (The profile hook is
+        # handed the flush's INPUT mask after the dispatch, which is why
+        # the mask is never among the donated buffers.)
+        from sparkdq4ml_tpu.ops import compiler
+
+        config.dq_profile_enabled = True
+        monkeypatch.setattr(compiler, "_donates", lambda: True)
+        compiler.clear_cache()
+        try:
+            df = run_dq_pipeline(session, dataset_path("abstract"))
+            assert df.count() == 24
+            assert compiler._CACHE and all(
+                p.donates for p in compiler._CACHE.values())
+        finally:
+            compiler.clear_cache()
+        rules = {r["rule"]: r for r in dqprof.report()["rules"]}
+        assert (rules["minimumPriceRule"]["rows"],
+                rules["minimumPriceRule"]["violations"]) == (40, 6)
+        assert rules["priceCorrelationRule"]["rows"] > 0
+        assert profiling.counters.get("dq.profile_failed") == 0
+        assert RECOVERY_LOG.count(site="dq_profile") == 0
+
     def test_violation_spike_captures_incident(self, session):
         config.dq_profile_enabled = True
         obs.enable()

@@ -1,4 +1,4 @@
-"""Example scripts run end-to-end (subprocess, CPU-pinned, short probe):
+"""Example scripts run end-to-end (subprocess, CPU-pinned):
 each example asserts its own results internally, so rc==0 + the final OK
 banner is a real integration check, not a smoke-only pass."""
 
@@ -13,7 +13,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(script: str, timeout: int = 240):
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["JAX_PLATFORMS"] = "cpu"
-    env["SPARKDQ4ML_PROBE_TIMEOUT"] = "3"
     return subprocess.run(
         [sys.executable, os.path.join(REPO, "examples", script)],
         capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)

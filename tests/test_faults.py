@@ -220,8 +220,7 @@ class TestRetryPolicy:
     def test_per_site_policy_overrides(self):
         from sparkdq4ml_tpu.session import TpuSession
 
-        s = TpuSession(conf={"spark.backend.probe": "off",
-                             "spark.compilation.cache": "off",
+        s = TpuSession(conf={"spark.compilation.cache": "off",
                              "spark.recovery.maxAttempts": "5",
                              "spark.recovery.gram_sharded.maxAttempts": "2"})
         import sparkdq4ml_tpu.session as sess_mod
@@ -452,7 +451,6 @@ class TestDeviceDrop:
 
         full = make_mesh().devices.size
         s = TpuSession(conf={"spark.faults": "mesh:device_drop:n=1",
-                             "spark.backend.probe": "off",
                              "spark.compilation.cache": "off"})
         try:
             assert s.mesh.devices.size == max(1, full - 1)
@@ -465,7 +463,6 @@ class TestDeviceDrop:
         from sparkdq4ml_tpu.session import TpuSession
 
         s = TpuSession(conf={"spark.faults": "solver:device_error:1,2,3",
-                             "spark.backend.probe": "off",
                              "spark.compilation.cache": "off"})
         assert faults.active() is not None
         s.stop()
@@ -479,7 +476,6 @@ class TestDeviceDrop:
         sess_mod._ACTIVE = None
         try:
             s = TpuSession.builder() \
-                .config("spark.backend.probe", "off") \
                 .config("spark.compilation.cache", "off").get_or_create()
             assert faults.active() is None
             TpuSession.builder() \
@@ -545,8 +541,7 @@ class TestTelemetrySurface:
     def test_session_exposes_the_log(self):
         from sparkdq4ml_tpu.session import TpuSession
 
-        s = TpuSession(conf={"spark.backend.probe": "off",
-                             "spark.compilation.cache": "off"})
+        s = TpuSession(conf={"spark.compilation.cache": "off"})
         assert s.recovery_log is RECOVERY_LOG
 
     def test_log_is_bounded(self):

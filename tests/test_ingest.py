@@ -44,9 +44,7 @@ REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 needs_native = pytest.mark.skipif(
     not native_csv.available(), reason="native/libdqcsv.so not built")
-needs_streaming = pytest.mark.skipif(
-    not native_csv.streaming_available(),
-    reason="libdqcsv.so lacks the dq_stream ABI (rebuild native/)")
+needs_streaming = needs_native
 
 _INGEST_DEFAULTS = ("ingest_streaming", "ingest_threads",
                     "ingest_chunk_bytes", "ingest_prefetch", "ingest_simd")
@@ -538,6 +536,97 @@ def test_conf_boolean_vocabulary():
     finally:
         s.stop()
     assert config.ingest_streaming is True
+
+
+# ---------------------------------------------------------------------------
+# The library is tied to this checkout's csvparse.cpp (build on first use)
+# ---------------------------------------------------------------------------
+
+class TestBuildOnFirstUse:
+    @pytest.fixture
+    def fake_native(self, tmp_path, monkeypatch):
+        """A native/ directory with a source and an OLDER library, and a
+        ``make`` that records its command line and touches the target."""
+        src = tmp_path / "csvparse.cpp"
+        so = tmp_path / "libdqcsv.so"
+        src.write_text("// source")
+        so.write_bytes(b"stale")
+        os.utime(so, (1_000, 1_000))
+        os.utime(src, (2_000, 2_000))
+        monkeypatch.setattr(native_csv, "_NATIVE_DIR", str(tmp_path))
+        monkeypatch.setattr(native_csv, "_SRC_PATH", str(src))
+        monkeypatch.setattr(native_csv, "_SO_PATH", str(so))
+        calls = []
+
+        def fake_make(cmd, **kw):
+            calls.append(cmd)
+            if fake_make.rc == 0:
+                so.write_bytes(b"rebuilt")
+            return subprocess.CompletedProcess(cmd, fake_make.rc, "",
+                                               "g++: boom")
+
+        fake_make.rc = 0
+        monkeypatch.setattr(subprocess, "run", fake_make)
+        return so, calls, fake_make
+
+    def test_rebuilt_when_source_is_newer_then_left_alone(self, fake_native):
+        so, calls, _ = fake_native
+        assert native_csv._build_if_stale() is True
+        assert calls == [["make", "-C", os.path.dirname(str(so)),
+                          "libdqcsv.so"]]
+        assert so.read_bytes() == b"rebuilt"
+        assert native_csv._build_if_stale() is True      # now current
+        assert len(calls) == 1
+
+    def test_missing_library_is_built(self, fake_native):
+        so, calls, _ = fake_native
+        so.unlink()
+        assert native_csv._build_if_stale() is True
+        assert len(calls) == 1 and so.exists()
+
+    def test_toolchain_failure_raises_for_auto_and_native(self, fake_native,
+                                                          monkeypatch):
+        _, _, fake_make = fake_native
+        fake_make.rc = 2
+        monkeypatch.setattr(native_csv, "_LIB", None)
+        monkeypatch.setattr(native_csv, "_LIB_TRIED", False)
+        for engine in ("auto", "native"):
+            with pytest.raises(native_csv.NativeBuildError, match="boom"):
+                read_csv(dataset_path("small"), engine=engine,
+                         infer_schema=True)
+
+    def test_absent_library_under_auto_is_counted(self, monkeypatch):
+        monkeypatch.setattr(native_csv, "_LIB", None)
+        monkeypatch.setattr(native_csv, "_LIB_TRIED", True)
+        frame = read_csv(dataset_path("small"), engine="auto",
+                         infer_schema=True)
+        assert frame.count() == 27
+        assert counters.get("ingest.python_fallback") == 1
+
+
+@needs_native
+def test_streamed_frame_survives_the_next_read(tmp_path):
+    # On the CPU backend device_put ADOPTS the 64-byte-aligned bind
+    # buffers, so a finished read's columns alias them: handing such a
+    # buffer back to the pool let the NEXT streamed read overwrite the
+    # previous frame's data.
+    def write(name, offset):
+        p = tmp_path / name
+        p.write_text("".join(f"{i % 97},{i + offset}.5\n"
+                             for i in range(5000)))
+        return str(p)
+
+    a, b = write("a.csv", 0), write("b.csv", 100_000)
+    _set(chunk_bytes=1024)
+    with native_csv._POOL_LOCK:
+        native_csv._POOL.clear()
+    fa = read_csv(a, engine="native")
+    fb = read_csv(b, engine="native")
+    assert counters.get("ingest.streamed") == 2
+    np.testing.assert_array_equal(np.asarray(fa._data["_c1"][:3]),
+                                  [0.5, 1.5, 2.5])
+    np.testing.assert_array_equal(np.asarray(fb._data["_c1"][:3]),
+                                  [100000.5, 100001.5, 100002.5])
 
 
 # ---------------------------------------------------------------------------

@@ -109,11 +109,17 @@ class TestWireProtocol:
     def test_frame_connection_is_keepalive(self, served):
         srv, net = served
         net.register_job("n", lambda ctx: 1)
+        accepted = profiling.counters.get("net.accept")
         with ResilientClient("127.0.0.1", net.port,
                              transport="frame") as c:
             for _ in range(3):
-                assert c.call_job("n").value == 1
+                r = c.call_job("n")
+                # a retry would silently reconnect: the server dropping
+                # the connection after each response must not pass
+                assert r.value == 1 and r.attempts == 1
             assert c._sock is not None    # one persistent connection
+        assert profiling.counters.get("net.accept") == accepted + 1
+        assert len(RECOVERY_LOG) == 0
 
     def test_sql_streams_frame_pages(self, session, served):
         """A Frame-valued SELECT streams as row pages (page_rows rows

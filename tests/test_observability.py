@@ -551,8 +551,7 @@ class TestParallelSpans:
 
 
 class TestSessionSurface:
-    CONF = {"spark.backend.probe": "off",
-            "spark.compilation.cache": "off"}
+    CONF = {"spark.compilation.cache": "off"}
 
     def _session(self, **conf):
         from sparkdq4ml_tpu import TpuSession
@@ -634,7 +633,6 @@ class TestHeadlineAcceptance:
         RECOVERY_LOG.record("obs_test", "retry")  # recovery.* pre-seeded
         session = (TpuSession.builder().app_name("headline")
                    .master("local[*]")
-                   .config("spark.backend.probe", "off")
                    .config("spark.compilation.cache", "off")
                    .config("spark.observability.enabled", "true")
                    .get_or_create())

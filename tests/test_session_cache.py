@@ -15,6 +15,7 @@ def _restore_jax_cache_config():
     of the suite compiles with its original cache behavior."""
     saved = {k: getattr(jax.config, k) for k in (
         "jax_compilation_cache_dir",
+        "jax_enable_compilation_cache",
         "jax_persistent_cache_min_compile_time_secs",
         "jax_persistent_cache_min_entry_size_bytes")}
     yield
@@ -25,159 +26,86 @@ def _restore_jax_cache_config():
     cc.reset_cache()
 
 
-def test_cache_dir_created_and_configured(tmp_path, monkeypatch):
-    # conftest sets SPARKDQ4ML_CACHE_EVERYTHING for suite speed; this test
-    # verifies the production CPU policy, so drop it.
-    monkeypatch.delenv("SPARKDQ4ML_CACHE_EVERYTHING", raising=False)
-    cache = os.path.join(str(tmp_path), "xla-cache")
-    s = (TpuSession.builder().app_name("t")
-         .config("spark.compilation.cacheDir", cache).get_or_create())
-    backend_dir = os.path.join(cache, jax.default_backend())
+def _cache_entries(path):
+    return sorted(n for n in os.listdir(path) if n.endswith("-cache"))
+
+
+def test_unset_env_uses_the_fixed_in_checkout_dir(monkeypatch):
+    from sparkdq4ml_tpu import session as sess_mod
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert sess_mod.COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", None)
+    s = TpuSession.builder().app_name("t").get_or_create()
     try:
-        assert os.path.isdir(backend_dir)
-        assert jax.config.jax_compilation_cache_dir == backend_dir
-        # On CPU the session keeps the stock "long compiles only"
-        # thresholds (persisting every tiny kernel floods AOT reload
-        # warnings); pin the threshold to 0 here to verify the DIR wiring
-        # with a fast compile.
-        assert jax.config.jax_persistent_cache_min_compile_time_secs == 1.0
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.jit(lambda x: x * 3.0 + 1.0)(np.arange(8.0)).block_until_ready()
-        assert len(os.listdir(backend_dir)) >= 1
+        assert jax.config.jax_compilation_cache_dir \
+            == sess_mod.COMPILE_CACHE_DIR
+        assert os.path.isdir(sess_mod.COMPILE_CACHE_DIR)
     finally:
         s.stop()
 
 
-def test_cache_everything_env_forces_aggressive(tmp_path, monkeypatch):
-    monkeypatch.setenv("SPARKDQ4ML_CACHE_EVERYTHING", "1")
-    cache = os.path.join(str(tmp_path), "xla-agg")
-    s = (TpuSession.builder().app_name("t")
-         .config("spark.compilation.cacheDir", cache).get_or_create())
+def test_env_dir_is_left_alone_and_is_the_one_that_fills(tmp_path,
+                                                         monkeypatch):
+    from sparkdq4ml_tpu import session as sess_mod
+
+    placed = tmp_path / "placed-from-outside"
+    placed.mkdir()
+    # a cache the driver put there: unstamped entries and a foreign file
+    (placed / "jit_foreign-entry-cache").write_bytes(b"\x00foreign")
+    (placed / "notes.txt").write_text("not a cache entry")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(placed))
+    # what jax itself does with the variable at import time
+    jax.config.update("jax_compilation_cache_dir", str(placed))
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append(k), real_update(k, v))[1])
+    default_before = (_cache_entries(sess_mod.COMPILE_CACHE_DIR)
+                      if os.path.isdir(sess_mod.COMPILE_CACHE_DIR) else [])
+    s = TpuSession.builder().app_name("t").get_or_create()
     try:
-        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        assert "jax_compilation_cache_dir" not in updates
+        assert jax.config.jax_compilation_cache_dir == str(placed)
+        jax.jit(lambda x: x * 3.0 + 41.5)(np.arange(8.0)).block_until_ready()
+        assert (placed / "jit_foreign-entry-cache").read_bytes() \
+            == b"\x00foreign"
+        assert (placed / "notes.txt").exists()
+        assert not (placed / "host_key.json").exists()
+        assert len(_cache_entries(placed)) >= 2      # gained an entry
+        default_after = (_cache_entries(sess_mod.COMPILE_CACHE_DIR)
+                         if os.path.isdir(sess_mod.COMPILE_CACHE_DIR)
+                         else [])
+        assert default_after == default_before
     finally:
         s.stop()
 
 
-def test_cache_opt_out(tmp_path):
-    cache = os.path.join(str(tmp_path), "unused")
+def test_cache_opt_out_and_back_on():
+    before = jax.config.jax_compilation_cache_dir
     s = (TpuSession.builder().app_name("t")
-         .config("spark.compilation.cache", "off")
-         .config("spark.compilation.cacheDir", cache).get_or_create())
+         .config("spark.compilation.cache", "off").get_or_create())
     try:
-        assert not os.path.exists(cache)
-        # Opt-out actively disables caching, including a dir left over from
-        # an earlier session in the same process.
-        assert jax.config.jax_compilation_cache_dir is None
-    finally:
-        s.stop()
-
-
-def test_cache_reconfigured_on_get_or_create(tmp_path):
-    first = os.path.join(str(tmp_path), "a")
-    second = os.path.join(str(tmp_path), "b")
-    s = (TpuSession.builder().app_name("t")
-         .config("spark.compilation.cacheDir", first).get_or_create())
-    be = jax.default_backend()
-    try:
-        assert jax.config.jax_compilation_cache_dir == os.path.join(first, be)
+        assert jax.config.jax_enable_compilation_cache is False
+        # the directory is never cleared — opting out flips the switch
+        assert jax.config.jax_compilation_cache_dir == before
         s2 = (TpuSession.builder()
-              .config("spark.compilation.cacheDir", second).get_or_create())
+              .config("spark.compilation.cache", "on").get_or_create())
         assert s2 is s
-        assert jax.config.jax_compilation_cache_dir == os.path.join(second, be)
-        assert os.path.isdir(os.path.join(second, be))
+        assert jax.config.jax_enable_compilation_cache is True
     finally:
         s.stop()
+        jax.config.update("jax_enable_compilation_cache", True)
 
 
-class TestCacheHostKey:
-    """Load-side AOT-mismatch guard (VERDICT r4 item 4): entries written
-    by another host/jaxlib must be invalidated before XLA reloads them."""
-
-    def test_poisoned_entries_invalidated(self, tmp_path):
-        import json
-
-        cache = tmp_path / "xla-poisoned" / jax.default_backend()
-        cache.mkdir(parents=True)
-        (cache / "host_key.json").write_text(json.dumps({"tag": "deadbeef"}))
-        (cache / "jit_foreign-entry").write_bytes(b"\x00AOT-from-elsewhere")
-        s = (TpuSession.builder().app_name("t")
-             .config("spark.compilation.cacheDir", str(cache.parent))
-             .get_or_create())
-        try:
-            from sparkdq4ml_tpu.session import host_cache_tag
-
-            assert not (cache / "jit_foreign-entry").exists()
-            assert (json.loads((cache / "host_key.json").read_text())["tag"]
-                    == host_cache_tag())
-            assert jax.config.jax_compilation_cache_dir == str(cache)
-        finally:
-            s.stop()
-
-    def test_unstamped_nonempty_dir_invalidated(self, tmp_path):
-        # No provenance stamp + existing entries = exactly the round-4
-        # error-spam scenario (a dir inherited from an older build).
-        cache = tmp_path / "xla-legacy" / jax.default_backend()
-        cache.mkdir(parents=True)
-        (cache / "jit_old-entry").write_bytes(b"\x00old")
-        s = (TpuSession.builder().app_name("t")
-             .config("spark.compilation.cacheDir", str(cache.parent))
-             .get_or_create())
-        try:
-            assert not (cache / "jit_old-entry").exists()
-            assert (cache / "host_key.json").exists()
-        finally:
-            s.stop()
-
-    def test_non_cache_files_never_deleted(self, tmp_path):
-        # Provenance hygiene must not become data loss: a user can point
-        # cacheDir at a directory holding OTHER files; only names that
-        # look like XLA cache entries (jit_*/pjit_*/*-cache) may go.
-        import json
-
-        cache = tmp_path / "xla-shared" / jax.default_backend()
-        cache.mkdir(parents=True)
-        (cache / "host_key.json").write_text(json.dumps({"tag": "deadbeef"}))
-        (cache / "jit_foreign-entry").write_bytes(b"\x00foreign")
-        (cache / "notes.txt").write_text("user data, not a cache entry")
-        (cache / "results.json").write_text("{}")
-        s = (TpuSession.builder().app_name("t")
-             .config("spark.compilation.cacheDir", str(cache.parent))
-             .get_or_create())
-        try:
-            assert not (cache / "jit_foreign-entry").exists()
-            assert (cache / "notes.txt").exists()
-            assert (cache / "results.json").exists()
-        finally:
-            s.stop()
-
-    def test_matching_stamp_preserves_entries(self, tmp_path):
-        import json
-
-        from sparkdq4ml_tpu.session import host_cache_tag
-
-        cache = tmp_path / "xla-ours" / jax.default_backend()
-        cache.mkdir(parents=True)
-        (cache / "host_key.json").write_text(
-            json.dumps({"tag": host_cache_tag()}))
-        (cache / "jit_our-entry").write_bytes(b"\x00ours")
-        s = (TpuSession.builder().app_name("t")
-             .config("spark.compilation.cacheDir", str(cache.parent))
-             .get_or_create())
-        try:
-            assert (cache / "jit_our-entry").exists()
-        finally:
-            s.stop()
-
-    def test_tag_includes_jaxlib_version(self, monkeypatch):
-        import jaxlib
-
-        from sparkdq4ml_tpu.session import host_cache_tag
-
-        before = host_cache_tag()
-        monkeypatch.setattr(jaxlib, "__version__", "0.0.0-other")
-        assert host_cache_tag() != before
+def test_master_tpu_raises_on_another_backend():
+    # no probe, no fallback: the accelerator was demanded, the default
+    # backend is the CPU, so the session refuses instead of degrading
+    with pytest.raises(RuntimeError, match="default backend here is 'cpu'"):
+        TpuSession.builder().master("tpu[*]").get_or_create()
+    assert TpuSession.active() is None
 
 
 class TestDistributedInit:

@@ -2,6 +2,7 @@
 reference datasets): FISTA↔OWLQN agreement, L-BFGS history wrap-around,
 constant features, and moment unpacking."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -102,3 +103,42 @@ class TestResolveSolver:
     def test_normal_with_l1_rejected(self):
         with pytest.raises(ValueError):
             resolve_solver("normal", 1.0, 1.0)
+
+
+class TestPsumValueAndGrad:
+    """``psum_value_and_grad`` under ``jax.shard_map(check_vma=True)``:
+    autodiff already reduces the cotangent of the replicated params, so
+    the sharded gradient IS the single-device gradient — checked on the
+    gradient itself, not through Adam (whose scale invariance hides a
+    device-count factor to 1e-5)."""
+
+    def test_sharded_gradient_equals_single_device_exactly(self):
+        from jax.sharding import PartitionSpec as P
+
+        from conftest import assert_devices
+        from sparkdq4ml_tpu.models.solvers import psum_value_and_grad
+        from sparkdq4ml_tpu.parallel.mesh import (DATA_AXIS, make_mesh,
+                                                  shard_map)
+
+        assert_devices(4)
+        mesh = make_mesh(4)
+        # small integers: every product and partial sum is exact, so the
+        # two reduction orders agree to the last bit
+        rng = np.random.default_rng(0)
+        X = jnp.asarray(rng.integers(-3, 4, (32, 3)), jnp.float64)
+        y = jnp.asarray(rng.integers(-3, 4, (32,)), jnp.float64)
+        w0 = jnp.asarray([1.0, -2.0, 3.0])
+
+        def value_and_grad(Xs, ys, axis, nshards):
+            def local(w):     # data term + a replicated (pre-divided) term
+                return jnp.sum((Xs @ w - ys) ** 2) + jnp.sum(w * w) / nshards
+            return psum_value_and_grad(local, axis)(w0)
+
+        v1, g1 = value_and_grad(X, y, None, 1.0)
+        v4, g4 = jax.jit(shard_map(
+            lambda Xs, ys: value_and_grad(Xs, ys, DATA_AXIS, 4.0),
+            mesh=mesh, in_specs=(P(DATA_AXIS, None), P(DATA_AXIS)),
+            out_specs=P()))(X, y)
+        np.testing.assert_array_equal(np.asarray(g4), np.asarray(g1))
+        assert float(v4) == float(v1)
+        assert float(jnp.max(jnp.abs(g1))) > 0

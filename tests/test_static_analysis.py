@@ -291,7 +291,7 @@ CONF_CONFIG = {"config.py": """
     CONF_TRUE = ("true", "on", "1", "yes")
     CONF_KEYS = {
         "spark.pipeline.enabled": "session",
-        "spark.backend.probe": "init",
+        "spark.compilation.cache": "init",
     }
     CONF_KEY_PREFIXES = ("spark.serve.",)
     """,
@@ -327,8 +327,8 @@ class TestConfKeyRule:
     def test_session_key_must_be_in_init_pipeline(self, tmp_path):
         files = dict(CONF_CONFIG)
         files["config.py"] = files["config.py"].replace(
-            '"spark.backend.probe": "init",',
-            '"spark.backend.probe": "init",\n'
+            '"spark.compilation.cache": "init",',
+            '"spark.compilation.cache": "init",\n'
             '        "spark.orphan.enabled": "session",')
         f = findings_for(tmp_path, files, ["conf-key"])
         assert len(f) == 1 and "spark.orphan.enabled" in f[0].message \
@@ -350,7 +350,7 @@ class TestConfKeyRule:
         files = dict(CONF_CONFIG)
         files["frame/reader.py"] = """
             def f(conf):
-                return str(conf.get("spark.backend.probe")) in ("true", "1")
+                return str(conf.get("spark.compilation.cache")) in ("true", "1")
             """
         f = findings_for(tmp_path, files, ["conf-key"])
         assert len(f) == 1 and "CONF_TRUE" in f[0].message
@@ -361,7 +361,7 @@ class TestConfKeyRule:
             from ..config import CONF_TRUE
 
             def f(conf):
-                return str(conf.get("spark.backend.probe")) in CONF_TRUE
+                return str(conf.get("spark.compilation.cache")) in CONF_TRUE
             """
         f = findings_for(tmp_path, files, ["conf-key"])
         assert f == []
