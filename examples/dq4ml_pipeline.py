@@ -16,28 +16,24 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import sparkdq4ml_tpu as dq
 from sparkdq4ml_tpu.models import LinearRegression, Vectors, VectorAssembler
-from sparkdq4ml_tpu.utils import PhaseTimer, configure_logging
+from sparkdq4ml_tpu.utils import configure_logging
 
 
 def start(filename: str) -> None:
-    timer = PhaseTimer()
-
     # Session init (`App.java:38-41`): device discovery + mesh construction
-    # replaces the driver JVM / executor pool.
-    spark = dq.TpuSession.builder().app_name("DQ4ML").master("local[*]").get_or_create()
+    # replaces the driver JVM / executor pool. The span tracer is on, so
+    # the run ends with the tree of what it did and how long each part took.
+    spark = (dq.TpuSession.builder().app_name("DQ4ML").master("local[*]")
+             .config("spark.observability.enabled", "true").get_or_create())
 
     # DQ Section (`App.java:44-95`)
     # ----------
     spark.udf.register("minimumPriceRule", dq.minimum_price_rule, "double")
     spark.udf.register("priceCorrelationRule", dq.price_correlation_rule, "double")
 
-    def load_phase():
-        return (spark.read.format("csv")
-                .option("inferSchema", "true").option("header", "false")
-                .load(filename))
-
-    with timer.phase("load"):
-        df = load_phase()
+    df = (spark.read.format("csv")
+          .option("inferSchema", "true").option("header", "false")
+          .load(filename))
 
     df = df.with_column_renamed("_c0", "guest")
     df = df.with_column_renamed("_c1", "price")
@@ -74,9 +70,7 @@ def start(filename: str) -> None:
         return spark.sql("SELECT guest, price_correct_correl AS price "
                          "FROM price WHERE price_correct_correl > 0")
 
-    df_loaded = df
-    with timer.phase("dq_rules"):
-        df = dq_phase(df_loaded, show=True)
+    df = dq_phase(df, show=True)
 
     print("----")
     print("2nd DQ rule")
@@ -94,17 +88,7 @@ def start(filename: str) -> None:
 
     lr = LinearRegression().setMaxIter(40).setRegParam(1).setElasticNetParam(1)
 
-    with timer.phase("fit"):
-        model = lr.fit(df)
-
-    # Steady-state re-runs against the XLA compile cache (the cold numbers
-    # above are compile-dominated; conflating the two misleads). "fit" here
-    # is the full API call — it materializes the model, so it INCLUDES
-    # device→host fetches; bench.py reports the device-only dispatch figure.
-    timer.steady("load", load_phase, sync=lambda f: f.mask)
-    timer.steady("dq_rules", lambda: dq_phase(df_loaded),
-                 sync=lambda f: f.mask)
-    timer.steady("fit", lambda: lr.fit(df))
+    model = lr.fit(df)
 
     model.transform(df).show()
 
@@ -127,10 +111,10 @@ def start(filename: str) -> None:
     p = model.predict(features)
     print(f"Prediction for {feature} guests is {p}")
 
-    pairs = timer.report_pairs()
-    print("phase wall-clock (s, cold = first run incl. XLA compile):",
-          {k: {m: (round(v, 4) if v is not None else None)
-               for m, v in p.items()} for k, p in pairs.items()})
+    # The program's own spans (the first run of each program includes its
+    # XLA compile: the fit root says `compile=miss` then).
+    print("span tree (ms):")
+    print(spark.trace_report())
 
     # Pipeline-compiler telemetry (README § "Pipeline compiler & jit
     # cache"): steady-state reruns should show `compile` frozen while

@@ -15,8 +15,8 @@ exempt — it owns the gate):
   span) whose argument contains eager string formatting (f-string with a
   hole, ``%`` / ``+`` on a string literal, ``.format(...)``, or
   ``", ".join(...)``), unless the call is statically guarded by an
-  enclosing ``if ... enabled ...`` branch (or a preceding
-  ``if not ... enabled ...: return`` early-out);
+  enclosing ``if ... enabled ...`` / ``if ... recording ...`` branch (or a
+  preceding ``if not ... enabled ...: return`` early-out);
 * direct ``Span(...)`` construction outside the tracer.
 """
 
@@ -32,11 +32,16 @@ _EXEMPT = ("sparkdq4ml_tpu/utils/observability.py",)
 _SINK_NAMES = frozenset({"span", "fit_span", "begin"})
 
 
+#: The tracer's gate: the explicit flag, and the one predicate that also
+#: follows a jax profiler session (``Tracer.recording``).
+_GATE_NAMES = frozenset({"enabled", "recording"})
+
+
 def _mentions_enabled(node: ast.AST) -> bool:
     for n in ast.walk(node):
-        if isinstance(n, ast.Attribute) and n.attr == "enabled":
+        if isinstance(n, ast.Attribute) and n.attr in _GATE_NAMES:
             return True
-        if isinstance(n, ast.Name) and n.id == "enabled":
+        if isinstance(n, ast.Name) and n.id in _GATE_NAMES:
             return True
     return False
 

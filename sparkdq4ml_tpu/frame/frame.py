@@ -37,8 +37,8 @@ import numpy as np
 
 from ..config import config, float_dtype, int_dtype
 from ..ops.expressions import Col, Expr, spark_type_name
-from ..utils.observability import op_span
-from ..utils.profiling import counters
+from ..utils.observability import op_span, span
+from ..utils.profiling import counters, host_read
 
 logger = logging.getLogger("sparkdq4ml_tpu.frame")
 
@@ -282,6 +282,15 @@ def _as_column(values, n: Optional[int] = None):
     if n is not None and arr.shape[0] != n:
         raise ValueError(f"column length {arr.shape[0]} != frame length {n}")
     return arr
+
+
+def _count_pull(device: dict, pulled: dict) -> None:
+    """One batched ``jax.device_get`` of ``device`` came back as ``pulled``:
+    a counted frame host boundary, and a host read of the bytes that were
+    on the device (host columns pass through ``device_get`` unread)."""
+    counters.increment("frame.host_sync")
+    host_read(sum(pulled[k].nbytes for k, v in device.items()
+                  if isinstance(v, jax.Array)))
 
 
 class Frame:
@@ -1511,15 +1520,24 @@ class Frame:
         # dqlint: ok(host-sync): deliberately NOT a counted frame host
         # boundary — the seed contract, pinned by test_explain
         # TestDisabledModeNoOp (count() is the no-op-path probe there;
-        # counting it would make the probe self-invalidating)
-        return int(jnp.sum(self._mask))
+        # counting it would make the probe self-invalidating). It IS a
+        # blocking device->host read, so host.reads / host.read_bytes
+        # count it and the frame.count span shows how long the host waited.
+        with span("frame.count", cat="action", rows_in=self._n) as s:
+            total = jnp.sum(self._mask)
+            n = int(total)
+            host_read(total.dtype.itemsize)
+            s.set(host_read_bytes=total.dtype.itemsize)
+        return n
 
     def is_empty(self) -> bool:
         return self.count() == 0
 
     def _host_mask(self) -> np.ndarray:
         counters.increment("frame.host_sync")
-        return np.asarray(self._mask)
+        m = np.asarray(self._mask)
+        host_read(m.nbytes)
+        return m
 
     @op_span("frame.to_pydict", cat="action")
     def to_pydict(self, limit: Optional[int] = None) -> dict[str, np.ndarray]:
@@ -1548,7 +1566,7 @@ class Frame:
                       if not _is_string_col(arr)}
             pulled = jax.device_get(device) if device else {}
             if device:
-                counters.increment("frame.host_sync")
+                _count_pull(device, pulled)
         else:
             mask_key = "__mask__"
             while mask_key in self._data:       # paranoid name collision
@@ -1557,7 +1575,7 @@ class Frame:
                       if not _is_string_col(arr)}
             device[mask_key] = self._mask
             pulled = jax.device_get(device)     # ONE batched transfer
-            counters.increment("frame.host_sync")
+            _count_pull(device, pulled)
             m = np.asarray(pulled.pop(mask_key), bool)
         out = {}
         for name, arr in self._data.items():

@@ -32,10 +32,12 @@ def host_fetch(x) -> np.ndarray:
     is that it be *counted*, so EXPLAIN ANALYZE and the span layer's
     per-op sync deltas see it (dqlint's ``host-sync`` rule pins the
     discipline statically)."""
-    from ..utils.profiling import counters
+    from ..utils.profiling import counters, host_read
 
     counters.increment("frame.host_sync")
-    return np.asarray(x)
+    out = np.asarray(x)
+    host_read(out.nbytes)
+    return out
 
 
 def persistable(cls):

@@ -37,6 +37,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..config import float_dtype
 from ..frame.frame import Frame
 from ..parallel.mesh import DATA_AXIS, serialize_collectives, shard_map
+from ..utils import observability as _obs
 from .base import (Estimator, Model, host_fetch, persistable, read_json,
                    write_json)
 from .regression import _extract_xy
@@ -172,11 +173,12 @@ def _logistic_newton_core(X, y, mask, reg_param, alpha, n, std,
     d = X.shape[1]
     valid = std > 0
     sx = jnp.where(valid, std, 1.0)
-    wm = mask.astype(dt)
-    Xs = (X / sx) * wm[:, None]
-    yv = y.astype(dt) * wm
-    wv = wm if weights is None else weights.astype(dt)
-    Za = jnp.concatenate([Xs, wm[:, None]], axis=1)   # intercept column
+    with _obs.scope("fit.pack"):       # standardise + intercept column
+        wm = mask.astype(dt)
+        Xs = (X / sx) * wm[:, None]
+        yv = y.astype(dt) * wm
+        wv = wm if weights is None else weights.astype(dt)
+        Za = jnp.concatenate([Xs, wm[:, None]], axis=1)
 
     u1 = jnp.ones((d,), dt) if standardization \
         else jnp.where(valid, 1.0 / sx, 0.0)
@@ -195,12 +197,17 @@ def _logistic_newton_core(X, y, mask, reg_param, alpha, n, std,
         is NOT computed here: the driver reads objectives only through
         ``batched_objective``, so packing a loss scalar would be dead
         O(n) work the psum forbids XLA from eliminating.)"""
-        margin = Za @ wb
-        p = jax.nn.sigmoid(margin)
-        resid = (p - yv) * wv
-        g = Za.T @ resid                                   # (m,)
-        s = wv * p * (1.0 - p)
-        H = (Za * s[:, None]).T @ Za                       # (m, m)
+        # the iteration's data pass, one scope a part (ROADMAP S3 asks
+        # which of them the time goes to; XLA may still fuse across them)
+        with _obs.scope("fit.newton.margin"):
+            margin = Za @ wb
+            p = jax.nn.sigmoid(margin)
+        with _obs.scope("fit.newton.gradient"):
+            resid = (p - yv) * wv
+            g = Za.T @ resid                               # (m,)
+        with _obs.scope("fit.newton.hessian"):
+            s = wv * p * (1.0 - p)
+            H = (Za * s[:, None]).T @ Za                   # (m, m)
         packed = reduce_(jnp.concatenate([H.ravel(), g]))
         H = packed[:m * m].reshape(m, m) / n
         g = packed[m * m:] / n
@@ -213,11 +220,13 @@ def _logistic_newton_core(X, y, mask, reg_param, alpha, n, std,
 
     def batched_objective(C):
         """Objectives of a (4, m) candidate stack in one fused pass."""
-        margins = Za @ C.T                                 # (n, 4)
-        z = (2.0 * yv - wm)[:, None] * margins
-        ll = jnp.sum(wv[:, None] * jnp.logaddexp(0.0, -z), axis=0)  # (4,)
-        ll = reduce_(ll) / n
-        return ll + 0.5 * jnp.sum(lam2_full[None, :] * C * C, axis=1)
+        with _obs.scope("fit.newton.line_search"):
+            margins = Za @ C.T                             # (n, 4)
+            z = (2.0 * yv - wm)[:, None] * margins
+            ll = jnp.sum(wv[:, None] * jnp.logaddexp(0.0, -z),
+                         axis=0)                           # (4,)
+            ll = reduce_(ll) / n
+            return ll + 0.5 * jnp.sum(lam2_full[None, :] * C * C, axis=1)
 
     wb, ok, iters, history = _newton_drive(stats, batched_objective, m,
                                            valid_full, dt, max_iter, tol)
@@ -244,6 +253,12 @@ def _fista_drive(loss_grad, objective, prox, step, M, dt, max_iter, tol):
     Returns ``(wb, converged, iterations, history)`` with ``history`` of
     length ``max_iter + 1`` (entry 0 = objective at zero).
     """
+    smooth_pass = loss_grad
+
+    def loss_grad(wb):      # the O(n·d) data pass, named in a trace
+        with _obs.scope("fit.fista.loss_grad"):
+            return smooth_pass(wb)
+
     wb0 = jnp.zeros((M,), dt)
     loss0, _ = loss_grad(wb0)
     obj0 = objective(wb0, loss0)
@@ -312,10 +327,11 @@ def _newton_drive(stats, batched_objective, M, valid_full, dt,
         # (e.g. the unpenalized-softmax shift degeneracy). Scale by the
         # dtype's eps: an absolute 1e-9 is BELOW half-ulp of a float32
         # diagonal (~1e-8 at O(1) entries) and would be bit-for-bit inert.
-        jitter = 100.0 * jnp.asarray(jnp.finfo(dt).eps, dt) * \
-            (1.0 + jnp.max(jnp.abs(jnp.diag(H))))
-        delta = jnp.linalg.solve(H + jitter * jnp.eye(M, dtype=dt), g)
-        delta = jnp.where(valid_full, delta, 0.0)
+        with _obs.scope("fit.solve"):
+            jitter = 100.0 * jnp.asarray(jnp.finfo(dt).eps, dt) * \
+                (1.0 + jnp.max(jnp.abs(jnp.diag(H))))
+            delta = jnp.linalg.solve(H + jitter * jnp.eye(M, dtype=dt), g)
+            delta = jnp.where(valid_full, delta, 0.0)
         C = wb[None, :] - steps[:, None] * delta[None, :]  # (4, M)
         objs = batched_objective(C)
         objs = jnp.where(jnp.isfinite(objs), objs, jnp.inf)
@@ -584,15 +600,18 @@ def fused_logistic_fit_packed(mesh: Optional[Mesh], max_iter: int, tol: float,
 
     if mesh is None or mesh.devices.size <= 1:
         def fit(Z, hyper):
-            X, y, mask, w = split(Z)
-            n, std = _feature_stats(X, y, mask if w is None else w)
+            with _obs.scope("fit.pack"):    # unpack + the moments pass
+                X, y, mask, w = split(Z)
+                n, std = _feature_stats(X, y, mask if w is None else w)
             return _pack_logistic_result(core(
                 X, y, mask, hyper[0], hyper[1], n, std, max_iter,
                 tol, fit_intercept, standardization, weights=w))
     else:
         def local(Z, hyper):
-            X, y, mask, w = split(Z)
-            n, std = _sharded_feature_stats(X, mask if w is None else w)
+            with _obs.scope("fit.pack"):
+                X, y, mask, w = split(Z)
+                n, std = _sharded_feature_stats(X,
+                                                mask if w is None else w)
             return _pack_logistic_result(core(
                 X, y, mask, hyper[0], hyper[1], n, std, max_iter,
                 tol, fit_intercept, standardization, axis=DATA_AXIS,
@@ -682,15 +701,17 @@ def fused_svc_fit_packed(mesh: Optional[Mesh], max_iter: int, tol: float,
 
     if mesh is None or mesh.devices.size <= 1:
         def fit(Z, hyper):
-            X, y, mask = _unpack_z(Z)
-            n, std = _feature_stats(X, y, mask)
+            with _obs.scope("fit.pack"):
+                X, y, mask = _unpack_z(Z)
+                n, std = _feature_stats(X, y, mask)
             return _pack_logistic_result(_svc_core(
                 X, y, mask, hyper[0], n, std, max_iter, tol,
                 fit_intercept, standardization))
     else:
         def local(Z, hyper):
-            X, y, mask = _unpack_z(Z)
-            n, std = _sharded_feature_stats(X, mask)
+            with _obs.scope("fit.pack"):
+                X, y, mask = _unpack_z(Z)
+                n, std = _sharded_feature_stats(X, mask)
             return _pack_logistic_result(_svc_core(
                 X, y, mask, hyper[0], n, std, max_iter, tol,
                 fit_intercept, standardization, axis=DATA_AXIS))
@@ -713,8 +734,12 @@ def _pack_softmax_result(r: "SoftmaxFitResult"):
 
 
 def unpack_softmax_result(flat, num_classes: int, d: int):
-    """Host-side decode of the packed softmax fit output."""
+    """Host-side decode of the packed softmax fit output (one counted
+    blocking read of the packed buffer)."""
+    from ..utils.profiling import host_read
+
     flat = np.asarray(flat)
+    host_read(flat.nbytes)
     m = num_classes * d
     return SoftmaxFitResult(
         coefficient_matrix=flat[:m].reshape(num_classes, d),
@@ -745,15 +770,18 @@ def fused_softmax_fit_packed(mesh: Optional[Mesh], num_classes: int,
 
     if mesh is None or mesh.devices.size <= 1:
         def fit(Z, hyper):
-            X, y, mask, w = split(Z)
-            n, std = _feature_stats(X, y, mask if w is None else w)
+            with _obs.scope("fit.pack"):
+                X, y, mask, w = split(Z)
+                n, std = _feature_stats(X, y, mask if w is None else w)
             return _pack_softmax_result(core(
                 X, y, mask, hyper[0], hyper[1], n, std, num_classes,
                 max_iter, tol, fit_intercept, standardization, weights=w))
     else:
         def local(Z, hyper):
-            X, y, mask, w = split(Z)
-            n, std = _sharded_feature_stats(X, mask if w is None else w)
+            with _obs.scope("fit.pack"):
+                X, y, mask, w = split(Z)
+                n, std = _sharded_feature_stats(X,
+                                                mask if w is None else w)
             return _pack_softmax_result(core(
                 X, y, mask, hyper[0], hyper[1], n, std, num_classes,
                 max_iter, tol, fit_intercept, standardization,
@@ -853,6 +881,24 @@ class LogisticRegression(Estimator):
             "raw_prediction_col", "weight_col")}
 
     def fit(self, frame: Frame, mesh=None) -> "LogisticRegressionModel":
+
+        # ONE root span per fit, opened where fit begins: fit.prepare
+        # (extract, validate, pack) and fit.solve are its children, so its
+        # self time is what neither explains.
+        with _obs.fit_span("fit.logistic_regression",
+                           fused_logistic_fit_packed,
+                           fused_softmax_fit_packed,
+                           max_iter=self.max_iter) as root:
+            return self._fit(frame, mesh, root)
+
+    def _fit(self, frame: Frame, mesh, root) -> "LogisticRegressionModel":
+        from ..config import float_dtype
+        from ..parallel.distributed import (pack_design,
+                                            pack_design_weighted,
+                                            place_packed, unpack_fit_result)
+        from ..utils.profiling import counters as _counters
+        from ..utils.profiling import host_read
+
         if mesh is None:
             from ..session import TpuSession
 
@@ -860,64 +906,78 @@ class LogisticRegression(Estimator):
             mesh = active.mesh if active is not None else None
         if mesh is not None and mesh.devices.size <= 1:
             mesh = None
-        X, y, mask = _extract_xy(frame, self.features_col, self.label_col)
-
-        yv = np.asarray(y)[np.asarray(mask)]
-        if len(yv) == 0:
-            raise ValueError("LogisticRegression: no valid rows")
-        if np.any(yv < 0) or np.any(yv != np.floor(yv)):
-            raise ValueError("labels must be nonnegative integers 0..k-1")
-        num_classes = int(yv.max()) + 1
-        family = self.family
-        if family == "auto":
-            family = "binomial" if num_classes <= 2 else "multinomial"
-        if family == "binomial" and num_classes > 2:
-            raise ValueError(
-                f"binomial family requires binary labels, found "
-                f"{num_classes} classes; use family='multinomial'")
-
-        from ..config import float_dtype
-        from ..parallel.distributed import (pack_design,
-                                            pack_design_weighted,
-                                            place_packed, unpack_fit_result)
-
         weighted = self.weight_col is not None
-        if weighted:
-            # masked rows' weight values never participate (see the
-            # LinearRegression weightCol note): validate valid rows only,
-            # zero the rest so a NaN payload cannot poison the packing
-            w = frame._column_values(self.weight_col)
-            # NaN fails >= too (silent NaN poisoning must raise instead)
-            if not bool(np.all(np.asarray(w)[np.asarray(mask)] >= 0)):
-                raise ValueError("weights must be nonnegative")
-            w = jnp.where(mask, jnp.asarray(w, float_dtype()), 0.0)
-            Zd = place_packed(pack_design_weighted(X, y, mask, w), mesh)
-        else:
-            Zd = place_packed(pack_design(X, y, mask), mesh)
-        hyper = jnp.asarray([self.reg_param, self.elastic_net_param],
-                            float_dtype())
+        with _obs.span("fit.prepare", cat="fit") as prep:
+            with _obs.span("fit.extract", cat="fit"):
+                X, y, mask = _extract_xy(frame, self.features_col,
+                                         self.label_col)
+            prep.set(rows=int(X.shape[0]), features=int(X.shape[1]))
+            with _obs.span("fit.validate", cat="fit") as val:
+                # the labels and the mask come to the host to be checked:
+                # two blocking reads of n rows each
+                y_host, mask_host = np.asarray(y), np.asarray(mask)
+                host_read(y_host.nbytes)
+                host_read(mask_host.nbytes)
+                pulled = y_host.nbytes + mask_host.nbytes
+                yv = y_host[mask_host]
+                if len(yv) == 0:
+                    raise ValueError("LogisticRegression: no valid rows")
+                if np.any(yv < 0) or np.any(yv != np.floor(yv)):
+                    raise ValueError(
+                        "labels must be nonnegative integers 0..k-1")
+                num_classes = int(yv.max()) + 1
+                family = self.family
+                if family == "auto":
+                    family = "binomial" if num_classes <= 2 \
+                        else "multinomial"
+                if family == "binomial" and num_classes > 2:
+                    raise ValueError(
+                        f"binomial family requires binary labels, found "
+                        f"{num_classes} classes; use family='multinomial'")
+                if weighted:
+                    # masked rows' weight values never participate (see the
+                    # LinearRegression weightCol note): validate valid rows
+                    # only, zero the rest so a NaN payload cannot poison
+                    # the packing
+                    w = frame._column_values(self.weight_col)
+                    w_host = np.asarray(w)
+                    if isinstance(w, jax.Array):
+                        host_read(w_host.nbytes)
+                        pulled += w_host.nbytes
+                    # NaN fails >= too (silent NaN poisoning must raise)
+                    if not bool(np.all(w_host[mask_host] >= 0)):
+                        raise ValueError("weights must be nonnegative")
+                    del w_host
+                val.set(host_read_bytes=pulled)
+                # the host copies (n labels, n mask bytes) go now, not
+                # when fit returns: they are not held through the solve
+                del y_host, mask_host, yv
+            with _obs.span("fit.pack", cat="fit"):
+                if weighted:
+                    w = jnp.where(mask, jnp.asarray(w, float_dtype()), 0.0)
+                    Zd = place_packed(pack_design_weighted(X, y, mask, w),
+                                      mesh)
+                else:
+                    Zd = place_packed(pack_design(X, y, mask), mesh)
+                hyper = jnp.asarray([self.reg_param, self.elastic_net_param],
+                                    float_dtype())
+        shards = mesh.devices.size if mesh is not None else 1
+        l1_free = (self.elastic_net_param == 0.0 or self.reg_param == 0.0)
 
         if family == "multinomial":
             K = max(num_classes, 2)
             # Same routing as the binary path: L1-free penalties take the
             # block-Hessian Newton solver; the K(d+1) cap keeps the
             # on-device solve trivial next to the per-iteration data pass.
-            l1_free = (self.elastic_net_param == 0.0
-                       or self.reg_param == 0.0)
             sm_solver = "newton" if (l1_free
                                      and K * (X.shape[1] + 1) <= 256) \
                 else "fista"
-            from ..utils import observability as _obs
-            from ..utils.profiling import counters as _counters
-
-            with _obs.fit_span("fit.logistic_regression",
-                               fused_softmax_fit_packed,
-                               family="multinomial", classes=K,
-                               rows=int(X.shape[0]),
-                               features=int(X.shape[1]),
-                               solver=sm_solver, max_iter=self.max_iter,
-                               shards=(mesh.devices.size if mesh is not None
-                                       else 1)) as s:
+            root.set(family="multinomial", classes=K, rows=int(X.shape[0]),
+                     features=int(X.shape[1]), solver=sm_solver,
+                     shards=shards)
+            # dispatch of the compiled fit to its result on the host (the
+            # decode reads the one packed output buffer)
+            with _obs.span("fit.solve", cat="solver", solver=sm_solver) as sv:
                 fit_fn = fused_softmax_fit_packed(mesh, K, self.max_iter,
                                                   self.tol,
                                                   self.fit_intercept,
@@ -926,11 +986,12 @@ class LogisticRegression(Estimator):
                                                   solver=sm_solver)
                 result = unpack_softmax_result(fit_fn(Zd, hyper), K,
                                                X.shape[1])
-                _counters.increment("solver.fits")
-                _counters.increment("solver.iterations",
-                                    int(result.iterations))
-                s.set(iterations=int(result.iterations),
-                      converged=bool(result.converged))
+                sv.set(iterations=int(result.iterations),
+                       converged=bool(result.converged))
+            _counters.increment("solver.fits")
+            _counters.increment("solver.iterations", int(result.iterations))
+            root.set(iterations=int(result.iterations),
+                     converged=bool(result.converged))
             W = np.asarray(result.coefficient_matrix, np.float64)
             b = np.asarray(result.intercept_vector, np.float64)
             # Identifiability pivot (MLlib convention): the softmax loss is
@@ -956,18 +1017,11 @@ class LogisticRegression(Estimator):
         # runs damped Newton/IRLS, which converges in ~5-10 fused
         # iterations instead of FISTA's O(100). Capped at d<=256 so the
         # per-iteration (d+1)^2 Hessian psum + host-free solve stays cheap.
-        l1_free = (self.elastic_net_param == 0.0 or self.reg_param == 0.0)
         solver = "newton" if (l1_free and X.shape[1] <= 256) else "fista"
-        from ..utils import observability as _obs
-        from ..utils.profiling import counters as _counters
-
-        with _obs.fit_span("fit.logistic_regression",
-                           fused_logistic_fit_packed,
-                           family="binomial", classes=num_classes,
-                           rows=int(X.shape[0]), features=int(X.shape[1]),
-                           solver=solver, max_iter=self.max_iter,
-                           shards=(mesh.devices.size if mesh is not None
-                                   else 1)) as s:
+        root.set(family="binomial", classes=num_classes,
+                 rows=int(X.shape[0]), features=int(X.shape[1]),
+                 solver=solver, shards=shards)
+        with _obs.span("fit.solve", cat="solver", solver=solver) as sv:
             fit_fn = fused_logistic_fit_packed(mesh, self.max_iter, self.tol,
                                                self.fit_intercept,
                                                self.standardization,
@@ -975,10 +1029,12 @@ class LogisticRegression(Estimator):
                                                solver=solver)
             result = LogisticFitResult(
                 *unpack_fit_result(fit_fn(Zd, hyper), X.shape[1]))
-            _counters.increment("solver.fits")
-            _counters.increment("solver.iterations", int(result.iterations))
-            s.set(iterations=int(result.iterations),
-                  converged=bool(result.converged))
+            sv.set(iterations=int(result.iterations),
+                   converged=bool(result.converged))
+        _counters.increment("solver.fits")
+        _counters.increment("solver.iterations", int(result.iterations))
+        root.set(iterations=int(result.iterations),
+                 converged=bool(result.converged))
         model = LogisticRegressionModel(
             coefficients=np.asarray(result.coefficients),
             intercept=float(result.intercept),
@@ -1073,27 +1129,29 @@ class LogisticRegressionModel(Model):
     def transform(self, frame: Frame) -> Frame:
         """Append rawPrediction (margin), probability, and prediction columns
         — MLlib's classifier transform contract."""
-        p = self._params
-        X = jnp.asarray(frame._column_values(p.get("features_col", "features")),
-                        float_dtype())
-        if X.ndim == 1:
-            X = X[:, None]
-        if not self._binary:
-            raw = self._margins_multi(X)
-            prob = jax.nn.softmax(raw, axis=1)
-            pred = jnp.argmax(raw, axis=1).astype(float_dtype())
-            out = frame.with_column(
-                p.get("raw_prediction_col", "rawPrediction"), raw)
-            out = out.with_column(p.get("probability_col", "probability"),
-                                  prob)
-            return out.with_column(p.get("prediction_col", "prediction"),
-                                   pred)
-        margin = self._margin(X)
-        prob = jax.nn.sigmoid(margin)
-        pred = (prob > self.threshold).astype(float_dtype())
-        out = frame.with_column(p.get("raw_prediction_col", "rawPrediction"), margin)
-        out = out.with_column(p.get("probability_col", "probability"), prob)
-        return out.with_column(p.get("prediction_col", "prediction"), pred)
+        with _obs.span("model.transform", cat="model",
+                       model="logistic_regression", rows=frame.num_slots):
+            p = self._params
+            X = jnp.asarray(frame._column_values(p.get("features_col", "features")),
+                            float_dtype())
+            if X.ndim == 1:
+                X = X[:, None]
+            if not self._binary:
+                raw = self._margins_multi(X)
+                prob = jax.nn.softmax(raw, axis=1)
+                pred = jnp.argmax(raw, axis=1).astype(float_dtype())
+                out = frame.with_column(
+                    p.get("raw_prediction_col", "rawPrediction"), raw)
+                out = out.with_column(p.get("probability_col", "probability"),
+                                      prob)
+                return out.with_column(p.get("prediction_col", "prediction"),
+                                       pred)
+            margin = self._margin(X)
+            prob = jax.nn.sigmoid(margin)
+            pred = (prob > self.threshold).astype(float_dtype())
+            out = frame.with_column(p.get("raw_prediction_col", "rawPrediction"), margin)
+            out = out.with_column(p.get("probability_col", "probability"), prob)
+            return out.with_column(p.get("prediction_col", "prediction"), pred)
 
     def predict_raw(self, features):
         v = np.asarray(features, np.float64).reshape(-1)
@@ -1111,9 +1169,12 @@ class LogisticRegressionModel(Model):
     predictProbability = predict_probability
 
     def predict(self, features) -> float:
-        if not self._binary:
-            return float(np.argmax(self.predict_raw(features)))
-        return 1.0 if self.predict_probability(features) > self.threshold else 0.0
+        with _obs.span("model.predict", cat="model",
+                       model="logistic_regression", rows=1):
+            if not self._binary:
+                return float(np.argmax(self.predict_raw(features)))
+            return (1.0 if self.predict_probability(features)
+                    > self.threshold else 0.0)
 
     @property
     def summary(self):
@@ -1455,7 +1516,15 @@ class LinearSVC(Estimator):
     setLabelCol = set_label_col
 
     def fit(self, frame: Frame, mesh=None) -> "LinearSVCModel":
+        with _obs.fit_span("fit.linear_svc", fused_svc_fit_packed,
+                           max_iter=self.max_iter) as root:
+            return self._fit(frame, mesh, root)
+
+    def _fit(self, frame: Frame, mesh, root) -> "LinearSVCModel":
+        from ..parallel.distributed import (pack_design, place_packed,
+                                            unpack_fit_result)
         from ..parallel.mesh import normalize_mesh
+        from ..utils.profiling import host_read
 
         if mesh is None:
             from ..session import TpuSession
@@ -1463,22 +1532,35 @@ class LinearSVC(Estimator):
             active = TpuSession.active()
             mesh = active.mesh if active is not None else None
         mesh = normalize_mesh(mesh)
-        X, y, mask = _extract_xy(frame, self.features_col, self.label_col)
-        yv = np.asarray(y)[np.asarray(mask)]
-        if len(yv) == 0:
-            raise ValueError("LinearSVC: no valid rows")
-        if not np.all((yv == 0) | (yv == 1)):
-            raise ValueError("LinearSVC requires binary 0/1 labels")
-
-        from ..parallel.distributed import (pack_design, place_packed,
-                                            unpack_fit_result)
-
-        Zd = place_packed(pack_design(X, y, mask), mesh)
-        fit_fn = fused_svc_fit_packed(mesh, self.max_iter, self.tol,
-                                      self.fit_intercept,
-                                      self.standardization)
-        hyper = jnp.asarray([self.reg_param, 0.0], float_dtype())
-        r = unpack_fit_result(fit_fn(Zd, hyper), X.shape[1])
+        with _obs.span("fit.prepare", cat="fit") as prep:
+            with _obs.span("fit.extract", cat="fit"):
+                X, y, mask = _extract_xy(frame, self.features_col,
+                                         self.label_col)
+            prep.set(rows=int(X.shape[0]), features=int(X.shape[1]))
+            with _obs.span("fit.validate", cat="fit") as val:
+                y_host, mask_host = np.asarray(y), np.asarray(mask)
+                host_read(y_host.nbytes)
+                host_read(mask_host.nbytes)
+                val.set(host_read_bytes=y_host.nbytes + mask_host.nbytes)
+                yv = y_host[mask_host]
+                if len(yv) == 0:
+                    raise ValueError("LinearSVC: no valid rows")
+                if not np.all((yv == 0) | (yv == 1)):
+                    raise ValueError("LinearSVC requires binary 0/1 labels")
+                del y_host, mask_host, yv
+            with _obs.span("fit.pack", cat="fit"):
+                Zd = place_packed(pack_design(X, y, mask), mesh)
+                hyper = jnp.asarray([self.reg_param, 0.0], float_dtype())
+        root.set(rows=int(X.shape[0]), features=int(X.shape[1]),
+                 solver="fista")
+        with _obs.span("fit.solve", cat="solver", solver="fista") as sv:
+            fit_fn = fused_svc_fit_packed(mesh, self.max_iter, self.tol,
+                                          self.fit_intercept,
+                                          self.standardization)
+            r = unpack_fit_result(fit_fn(Zd, hyper), X.shape[1])
+            sv.set(iterations=int(r.iterations),
+                   converged=bool(r.converged))
+        root.set(iterations=int(r.iterations), converged=bool(r.converged))
         iters = int(r.iterations)
         # truncate the scan's padded tail (post-convergence repeats), the
         # LogisticRegressionTrainingSummary convention

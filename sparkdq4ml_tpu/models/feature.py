@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import float_dtype, int_dtype
+from ..utils import observability as _obs
 from .base import Estimator, Model, Transformer, host_fetch, persistable
 
 
@@ -62,11 +63,16 @@ class VectorAssembler(Transformer):
         if not self.input_cols:
             raise ValueError("VectorAssembler: input_cols not set")
         dt = float_dtype()
-        parts = []
-        for name in self.input_cols:
-            arr = jnp.asarray(frame._column_values(name), dt)
-            parts.append(arr[:, None] if arr.ndim == 1 else arr)
-        return frame.with_column(self.output_col, jnp.concatenate(parts, axis=1))
+        with _obs.span("feature.assemble", cat="feature",
+                       columns=len(self.input_cols),
+                       rows=frame.num_slots) as s:
+            parts = []
+            for name in self.input_cols:
+                arr = jnp.asarray(frame._column_values(name), dt)
+                parts.append(arr[:, None] if arr.ndim == 1 else arr)
+            out = jnp.concatenate(parts, axis=1)
+            s.set(width=int(out.shape[1]))      # static shape, no read
+            return frame.with_column(self.output_col, out)
 
 
 @persistable

@@ -23,6 +23,7 @@ import numpy as np
 from ..config import float_dtype
 from ..frame.frame import Frame
 from ..ops.expressions import col
+from ..utils import observability as _obs
 from .base import Estimator, Model, persistable, read_json, write_json
 from .solvers import FitResult, resolve_solver
 
@@ -157,55 +158,79 @@ class LinearRegression(Estimator):
     def fit(self, frame: Frame, mesh=None) -> "LinearRegressionModel":
         """Fit on the frame's valid rows. ``mesh`` defaults to the active
         session's device mesh (row-sharded psum path when >1 device)."""
+        # Imported here, not at module top: parallel.distributed imports
+        # models.solvers, so a top-level import would make package init
+        # order-sensitive (importing parallel first used to crash).
+        from ..parallel.distributed import fused_linear_fit_packed
+
+        # Observability: ONE root span per fit, opened where fit begins,
+        # with the cold-compile vs steady split (trace-cache probe on the
+        # lru-cached jit factory) and any retry/fallback the resilience
+        # layer took; fit.prepare and fit.solve are its children, so its
+        # self time is what neither explains.
+        with _obs.fit_span("fit.linear_regression", fused_linear_fit_packed,
+                           max_iter=self.max_iter) as root:
+            return self._fit(frame, mesh, root)
+
+    def _fit(self, frame: Frame, mesh, root) -> "LinearRegressionModel":
+        from ..parallel.distributed import (fused_linear_fit_packed,
+                                            pack_design, place_packed,
+                                            unpack_fit_result)
+        from ..utils import faults as _faults
+        from ..utils import recovery as _recovery
+        from ..utils.profiling import counters, host_read
+        from .solvers import downgrade_solver
+
         if mesh is None:
             from ..session import TpuSession
 
             active = TpuSession.active()
             mesh = active.mesh if active is not None else None
-        # Imported here, not at module top: parallel.distributed imports
-        # models.solvers, so a top-level import would make package init
-        # order-sensitive (importing parallel first used to crash).
-        from ..parallel.distributed import (fused_linear_fit_packed,
-                                            pack_design, place_packed,
-                                            unpack_fit_result)
-
-        X, y, mask = _extract_xy(frame, self.features_col, self.label_col)
-        if self.weight_col is not None:
-            # Instance weights (MLlib weightCol): scaling packed rows by
-            # sqrt(w) makes the Gramian ZᵀZ = Σ w·zzᵀ — every moment the
-            # solver unpacks (n = Σw, weighted mean/std, Gram, correlation)
-            # becomes its weighted form, so an integer weight k is EXACTLY
-            # a row repeated k times (the regression test for this path).
-            # Summary metrics remain unweighted row statistics.
-            # Masked rows' weight VALUES never participate: validation
-            # only inspects valid rows, and sqrt() sees 0 there (a NaN/
-            # negative payload in a filtered slot must not poison Z).
-            # Validating costs one host read — a weighted-fit-only price.
-            w = frame._column_values(self.weight_col)
-            w_host = np.asarray(w)
-            # NaN fails >= too: a NaN weight on a valid row must raise,
-            # not silently poison the Gramian
-            if not bool(np.all(w_host[np.asarray(mask)] >= 0)):
-                raise ValueError("weights must be nonnegative")
-            mask_b = mask
-            mask = mask.astype(float_dtype()) * jnp.sqrt(
-                jnp.where(mask_b, jnp.asarray(w, float_dtype()), 0.0))
-        if self.loss == "huber":
-            return self._fit_huber(frame, X, y, mask)
+        with _obs.span("fit.prepare", cat="fit") as prep:
+            with _obs.span("fit.extract", cat="fit"):
+                X, y, mask = _extract_xy(frame, self.features_col,
+                                         self.label_col)
+            d = X.shape[1]
+            prep.set(rows=int(X.shape[0]), features=int(d))
+            if self.weight_col is not None:
+                # Instance weights (MLlib weightCol): scaling packed rows
+                # by sqrt(w) makes the Gramian ZᵀZ = Σ w·zzᵀ — every
+                # moment the solver unpacks (n = Σw, weighted mean/std,
+                # Gram, correlation) becomes its weighted form, so an
+                # integer weight k is EXACTLY a row repeated k times (the
+                # regression test for this path). Summary metrics remain
+                # unweighted row statistics.
+                # Masked rows' weight VALUES never participate: validation
+                # only inspects valid rows, and sqrt() sees 0 there (a
+                # NaN/negative payload in a filtered slot must not poison
+                # Z). Validating costs host reads — a weighted-fit-only
+                # price.
+                with _obs.span("fit.validate", cat="fit") as val:
+                    w = frame._column_values(self.weight_col)
+                    w_host, mask_host = np.asarray(w), np.asarray(mask)
+                    pulled = mask_host.nbytes
+                    host_read(mask_host.nbytes)
+                    if not isinstance(w, np.ndarray):
+                        host_read(w_host.nbytes)
+                        pulled += w_host.nbytes
+                    val.set(host_read_bytes=pulled)
+                    # NaN fails >= too: a NaN weight on a valid row must
+                    # raise, not silently poison the Gramian
+                    if not bool(np.all(w_host[mask_host] >= 0)):
+                        raise ValueError("weights must be nonnegative")
+                mask_b = mask
+                mask = mask.astype(float_dtype()) * jnp.sqrt(
+                    jnp.where(mask_b, jnp.asarray(w, float_dtype()), 0.0))
+            if self.loss == "huber":
+                return self._fit_huber(frame, X, y, mask)
+            with _obs.span("fit.pack", cat="fit"):
+                Z = pack_design(X, y, mask)
+                hyper = jnp.asarray([self.reg_param,
+                                     self.elastic_net_param], float_dtype())
         solver_name = resolve_solver(self.solver, self.reg_param,
                                      self.elastic_net_param)
         if mesh is not None and mesh.devices.size <= 1:
             mesh = None  # unify the single-device cache key
-        from ..utils import faults as _faults
-        from ..utils import observability as _obs
-        from ..utils import recovery as _recovery
-        from ..utils.profiling import counters
-        from .solvers import downgrade_solver
-
-        Z = pack_design(X, y, mask)
-        hyper = jnp.asarray([self.reg_param, self.elastic_net_param],
-                            float_dtype())
-        d = X.shape[1]
 
         def make_call(m, sname):
             # Everything stays inside the closure: fallback rungs must
@@ -232,38 +257,33 @@ class LinearRegression(Estimator):
         if downgraded is not None:
             fallbacks.append((f"solver_{downgraded}",
                               make_call(None, downgraded)))
-        # Observability: the fit span records the cold-compile vs steady
-        # split (trace-cache probe on the lru-cached jit factory), the
-        # solver trajectory (iterations/objective — read from the packed
-        # result, which unpack_fit_result already materialized on host, so
-        # no added sync), and any retry/fallback the resilience layer took.
-        with _obs.fit_span("fit.linear_regression", fused_linear_fit_packed,
-                           rows=int(X.shape[0]), features=d,
-                           solver=solver_name,
-                           shards=(mesh.devices.size if mesh is not None
-                                   else 1),
-                           max_iter=self.max_iter) as s:
-            with _obs.span("fit.solve", cat="solver", solver=solver_name):
-                result = _recovery.resilient_call(
-                    make_call(mesh, solver_name), site="fit_packed",
-                    policy=_recovery.active_policy("fit_packed"),
-                    validate=_recovery.result_validator(),
-                    fallbacks=fallbacks, breaker=_recovery.DEVICE_BREAKER)
+        root.set(rows=int(X.shape[0]), features=int(d), solver=solver_name,
+                 shards=(mesh.devices.size if mesh is not None else 1))
+        # fit.solve: dispatch of the compiled fit to its result on the
+        # host (unpack_fit_result reads the one packed buffer — a read the
+        # code makes anyway, so the solver trajectory below adds no sync)
+        with _obs.span("fit.solve", cat="solver", solver=solver_name) as sv:
+            result = _recovery.resilient_call(
+                make_call(mesh, solver_name), site="fit_packed",
+                policy=_recovery.active_policy("fit_packed"),
+                validate=_recovery.result_validator(),
+                fallbacks=fallbacks, breaker=_recovery.DEVICE_BREAKER)
             iters = int(result.iterations)
-            counters.increment("solver.fits")
-            counters.increment("solver.iterations", iters)
-            if s is not _obs._NOOP:
-                from ..utils import meminfo as _meminfo
+            sv.set(iterations=iters, converged=bool(result.converged))
+        counters.increment("solver.fits")
+        counters.increment("solver.iterations", iters)
+        if root is not _obs._NOOP:
+            from ..utils import meminfo as _meminfo
 
-                hist = np.asarray(result.objective_history, np.float64)
-                # input_bytes: static-shape estimate of the packed design
-                # the fit dispatched (the fit-node device-memory figure
-                # EXPLAIN/memory_report cross-reference) — metadata only,
-                # never a device read.
-                s.set(iterations=iters, converged=bool(result.converged),
-                      objective_final=float(
-                          hist[min(iters, hist.shape[0] - 1)]),
-                      input_bytes=_meminfo.estimated_bytes(Z))
+            hist = np.asarray(result.objective_history, np.float64)
+            # input_bytes: static-shape estimate of the packed design
+            # the fit dispatched (the fit-node device-memory figure
+            # EXPLAIN/memory_report cross-reference) — metadata only,
+            # never a device read.
+            root.set(iterations=iters, converged=bool(result.converged),
+                     objective_final=float(
+                         hist[min(iters, hist.shape[0] - 1)]),
+                     input_bytes=_meminfo.estimated_bytes(Z))
         model = LinearRegressionModel(
             coefficients=np.asarray(result.coefficients),
             intercept=float(result.intercept),
@@ -366,17 +386,24 @@ class LinearRegressionModel(Model):
     def transform(self, frame: Frame) -> Frame:
         """Append the prediction column (batch inference, one fused matvec —
         `App.java:129`)."""
-        X = jnp.asarray(frame._column_values(self.features_col), float_dtype())
-        if X.ndim == 1:
-            X = X[:, None]
-        pred = X @ jnp.asarray(self.coefficients, X.dtype) + self.intercept
-        return frame.with_column(self.prediction_col, pred)
+        with _obs.span("model.transform", cat="model",
+                       model="linear_regression", rows=frame.num_slots):
+            X = jnp.asarray(frame._column_values(self.features_col),
+                            float_dtype())
+            if X.ndim == 1:
+                X = X[:, None]
+            pred = X @ jnp.asarray(self.coefficients, X.dtype) \
+                + self.intercept
+            return frame.with_column(self.prediction_col, pred)
 
     def predict(self, features) -> float:
         """Host-side single-point inference (`App.java:149-151`) — a dot+add
         with no device round-trip, like MLlib's driver-local predict."""
-        v = np.asarray(features, dtype=np.float64).reshape(-1)
-        return float(v @ self.coefficients.astype(np.float64) + self.intercept)
+        with _obs.span("model.predict", cat="model",
+                       model="linear_regression", rows=1):
+            v = np.asarray(features, dtype=np.float64).reshape(-1)
+            return float(v @ self.coefficients.astype(np.float64)
+                         + self.intercept)
 
     # -- summaries -----------------------------------------------------------
     @property

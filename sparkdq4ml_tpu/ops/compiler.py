@@ -624,7 +624,7 @@ class _Plan:
         # surface (observability.ProgramHandle). None until first run.
         self.example: Optional[tuple] = None
 
-        def body(kept, donated, mask, lit_args):
+        def steps_and_extras(kept, donated, mask, lit_args):
             # The pure program logic — shared by the jitted entry below
             # and the auditor's abstract re-trace (which must not count
             # as a compile nor bump the replay-verdict trace counter).
@@ -655,6 +655,12 @@ class _Plan:
                 return changed, new_mask, extras
             finally:
                 _RUNTIME_LITS.lits = ()
+
+        def body(kept, donated, mask, lit_args):
+            # every operation of the fused flush carries dq.flush in its
+            # op metadata (the layer's name in a trace)
+            with _obs.scope("flush"):
+                return steps_and_extras(kept, donated, mask, lit_args)
 
         if shard is not None:
             # ONE shard_map-wrapped program per flush: rows partition
@@ -1293,14 +1299,15 @@ def run_pipeline(data: dict, mask, n: int, steps, extra=(), shard=None):
             # harmless, and the warning would spam every compile
             warnings.filterwarnings(
                 "ignore", message=".*[Dd]onated.*", category=UserWarning)
-            span_cm = (_obs.TRACER.span(
+            span_cm = _obs.TRACER.span(
                 "frame.pipeline.flush", cat="frame", steps=len(steps),
                 outputs=len(extra), rows=n, bucket=b,
                 # the cost-observatory join handle: EXPLAIN ANALYZE maps
                 # this span's operator node to its cached CostProfile by
                 # plan key (an attribute read, never formatting)
                 plan_key=plan.key)
-                if _obs.TRACER.enabled else None)
+            if span_cm is _obs._NOOP:   # the gate, read once, was off
+                span_cm = None
             # chaos hook at the dispatch boundary (one None check without
             # a plan): a due device_error raises HERE — inside the flush
             # span, so EXPLAIN ANALYZE attributes the fault to the

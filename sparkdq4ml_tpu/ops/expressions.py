@@ -592,19 +592,27 @@ class UdfCall(Expr):
             if key in _BUILTIN_FNS:
                 return Func(key, self.args).eval(frame)
             raise
-        vals = [a.eval(frame) for a in self.args]
-        out = fn(*vals)
-        if return_dtype is not None:
-            out = jnp.asarray(out, return_dtype)
-        # Data-quality observatory gate (utils/dqprof.py): ONE flag
-        # read; record_eval skips tracers itself, so a traced flush
-        # accounts through the compiler hook instead — never twice.
         from ..config import config as _cfg
+        from ..utils import observability as _obs
 
-        if _cfg.dq_profile_enabled:
-            from ..utils import dqprof as _dqprof
+        # One span per evaluation of a registered rule, beside the
+        # dq.rule_evals counter. (No named scope here: a scope opened on
+        # the host does not reach the metadata of the eager one-operation
+        # programs a rule runs — PERF.md section 3; the dispatching span
+        # is what tells them apart in a capture.)
+        with _obs.span("dq.rule", cat="dq", rule=self.udf_name,
+                       rows=frame.num_slots):
+            vals = [a.eval(frame) for a in self.args]
+            out = fn(*vals)
+            if return_dtype is not None:
+                out = jnp.asarray(out, return_dtype)
+            # Data-quality observatory gate (utils/dqprof.py): ONE flag
+            # read; record_eval skips tracers itself, so a traced flush
+            # accounts through the compiler hook instead — never twice.
+            if _cfg.dq_profile_enabled:
+                from ..utils import dqprof as _dqprof
 
-            _dqprof.record_eval(self.udf_name, out)
+                _dqprof.record_eval(self.udf_name, out)
         return out
 
     @property

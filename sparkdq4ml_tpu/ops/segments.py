@@ -264,7 +264,13 @@ class _PlanEntry:
                  "key", "stats_key")
 
     def __init__(self, raw, mesh=None):
-        self.trace_body = raw
+        def scoped(*args):
+            # every operation of a grouped/sort/unique program carries
+            # dq.grouped in its op metadata (the layer's name in a trace)
+            with _obs.scope("grouped"):
+                return raw(*args)
+
+        self.trace_body = scoped
         self.mesh = mesh
         # full cache key (namespace-prefixed) — set by _cached_plan;
         # the cost observatory's join handle (flush spans carry it)
@@ -280,7 +286,7 @@ class _PlanEntry:
             # Runs at trace time only → counts XLA compiles (the single
             # home of the increment the four program builders shared).
             counters.increment("grouped.compile")
-            return raw(*args)
+            return scoped(*args)
 
         jitted = jax.jit(counted)
         if mesh is not None:
@@ -740,8 +746,9 @@ def _build_dense_agg_program(key_kinds, agg_ops, val_kinds, S: int,
             # of the program computes replicated
             _merge = {"ai": lax.psum, "af": lax.psum, "mf": lax.pmin,
                       "mi": lax.pmin, "xi": lax.pmax}
-            reduced = {stack: _merge[stack](r, axis)
-                       for stack, r in reduced.items()}
+            with _obs.scope("exchange"):
+                reduced = {stack: _merge[stack](r, axis)
+                           for stack, r in reduced.items()}
 
         def table(name):
             stack, j = index[name]
@@ -924,8 +931,9 @@ def _build_sharded_unique_program(mesh, key_kinds):
             def xchg(blocked):     # (D*b, …): block d → shard d
                 # dqlint: ok(collective-guard): dispatch is guarded by
                 # _PlanEntry(mesh=...) via serialize_collectives
-                return lax.all_to_all(blocked, DATA_AXIS, split_axis=0,
-                                      concat_axis=0, tiled=True)
+                with _obs.scope("exchange"):
+                    return lax.all_to_all(blocked, DATA_AXIS, split_axis=0,
+                                          concat_axis=0, tiled=True)
 
             def rep(x):            # every destination gets the full rows
                 return jnp.broadcast_to(

@@ -29,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.solvers import augmented_gram
 from ..ops.segments import abstract_specs
+from ..utils import observability as _obs
 from .mesh import DATA_AXIS, serialize_collectives, shard_map
 
 logger = logging.getLogger("sparkdq4ml_tpu.distributed")
@@ -263,13 +264,18 @@ def fused_linear_fit_packed(mesh: Optional[Mesh], solver: str, max_iter: int,
             mesh=mesh, in_specs=(P(DATA_AXIS),), out_specs=P())
 
     def fit(Z, hyper):
-        r = solve_A(gram(Z), hyper[0], hyper[1])
-        dt = r.coefficients.dtype
-        scalars = jnp.stack([r.intercept.astype(dt),
-                             r.iterations.astype(dt),
-                             r.converged.astype(dt)])
-        return jnp.concatenate(
-            [r.coefficients, scalars, r.objective_history.astype(dt)])
+        # the two layers of the program, named in its op metadata: the
+        # one data pass, then the solver on the (d+2)^2 moments
+        with _obs.scope("fit.gram"):
+            A = gram(Z)
+        with _obs.scope("fit.solve"):
+            r = solve_A(A, hyper[0], hyper[1])
+            dt = r.coefficients.dtype
+            scalars = jnp.stack([r.intercept.astype(dt),
+                                 r.iterations.astype(dt),
+                                 r.converged.astype(dt)])
+            return jnp.concatenate(
+                [r.coefficients, scalars, r.objective_history.astype(dt)])
 
     # Multi-device programs serialize dispatch-to-completion on the
     # process-wide collective guard (mesh.serialize_collectives): two
@@ -324,7 +330,6 @@ def fit_program_handles() -> list:
     routes dispatch through ``mesh.serialize_collectives`` — so the
     collective-topology detector can cross-check the jaxpr's collectives
     against the mesh AND the guard wrapping in one place."""
-    from ..utils import observability as _obs
 
     out = []
     for name, factory in (("fused_linear_fit_packed",
@@ -366,7 +371,6 @@ def fit_program_handles() -> list:
 
 
 def _register_cache_stats() -> None:
-    from ..utils import observability as _obs
 
     _obs.CACHES.register("fit.factories", fit_factory_cache_stats)
     _obs.CACHES.register_programs("fit.factories", fit_program_handles)
@@ -376,10 +380,13 @@ _register_cache_stats()
 
 
 def unpack_fit_result(flat, d: int):
-    """Decode the packed fit output (host side) into a ``FitResult``."""
+    """Decode the packed fit output (host side) into a ``FitResult``: the
+    one blocking read of the fit's result, counted as a host read."""
     from ..models.solvers import FitResult
+    from ..utils.profiling import host_read
 
     flat = np.asarray(flat)
+    host_read(flat.nbytes)
     return FitResult(
         coefficients=flat[:d],
         intercept=flat[d],
@@ -493,7 +500,6 @@ def compute_gram(X, y, mask, mesh: Optional[Mesh] = None):
         return _gram_single(jnp.asarray(X), jnp.asarray(y),
                             jnp.asarray(mask, jnp.bool_))
     from ..utils import faults as _faults
-    from ..utils import observability as _obs
     from ..utils import recovery as _recovery
     from ..utils.profiling import counters
 
@@ -514,10 +520,11 @@ def compute_gram(X, y, mask, mesh: Optional[Mesh] = None):
     def sharded():
         _faults.inject("gram_sharded")
         counters.increment("parallel.psum_dispatches")
-        # Per-shard Gramian timing: with tracing ON the span blocks on the
-        # result so the duration covers the actual collective, not just
-        # the async enqueue — an enabled-mode-only sync, per the
-        # observability cost contract (disabled mode adds no host work).
+        # Per-shard Gramian timing: with the tracer switched ON the span
+        # blocks on the result so the duration covers the actual
+        # collective, not just the async enqueue — an explicit-flag-only
+        # sync, per the observability cost contract (off, and recording
+        # for a profiler, add no device wait).
         with _obs.span("parallel.gram_shard", cat="parallel",
                        shards=nshards, rows=int(Xp.shape[0]),
                        rows_per_shard=int(Xp.shape[0]) // nshards,
@@ -526,7 +533,9 @@ def compute_gram(X, y, mask, mesh: Optional[Mesh] = None):
             yd = yp if pre else jax.device_put(yp, shard)
             md = mp if pre else jax.device_put(mp, shard)
             A = _gram_sharded_fn(mesh)(Xd, yd, md)
-            if s is not _obs._NOOP:
+            if _obs.TRACER.enabled:
+                # the explicit flag only: a span recorded because a
+                # profiler is on never adds a device wait
                 jax.block_until_ready(A)
             return A
 

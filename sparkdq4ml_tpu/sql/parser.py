@@ -2401,14 +2401,16 @@ def execute(sql: str, catalog=None):
     [IF EXISTS] name``. Both return an empty no-column Frame like
     Spark's DDL commands.
 
-    When observability is enabled, each statement runs inside an
-    ``sql.query`` span carrying the query text, the plan summary
-    (:func:`plan_summary`), and the output row count.
+    While the tracer records, each statement runs inside an ``sql.query``
+    span carrying the query text, the plan summary (:func:`plan_summary`),
+    and the output row count; its children ``sql.parse``, ``sql.optimize``
+    (rewrites applied) and ``sql.execute`` tell the statement's host cost
+    (text to plan) from its execution.
     """
-    if not _obs.TRACER.enabled:
+    if not _obs.TRACER.recording:
         return _execute_statement(sql, catalog)
-    with _obs.TRACER.span("sql.query", cat="sql",
-                          query=" ".join(sql.split())[:300]) as s:
+    with _obs.span("sql.query", cat="sql",
+                   query=" ".join(sql.split())[:300]) as s:
         out = _execute_statement(sql, catalog)
         n = getattr(out, "_n", None)
         if n is not None:
@@ -2427,8 +2429,18 @@ def _maybe_optimize(q: Query, cat):
         return q
     from . import optimizer as _optimizer
 
-    q2, _rewrites = _optimizer.optimize_or_fallback(q, cat)
+    with _obs.span("sql.optimize", cat="sql") as s:
+        q2, rewrites = _optimizer.optimize_or_fallback(q, cat)
+        s.set(rewrites=len(rewrites))
     return q2
+
+
+def _execute_optimized(q, cat):
+    """Optimize, then execute, one set expression (the ``sql.optimize``
+    and ``sql.execute`` children of the statement's ``sql.query``)."""
+    q = _maybe_optimize(q, cat)
+    with _obs.span("sql.execute", cat="sql"):
+        return _execute_set(q, cat)
 
 
 def _run_parsed(q: Query, cat):
@@ -2441,9 +2453,8 @@ def _run_parsed(q: Query, cat):
         cat = _OverlayCatalog(cat)
         for name, sub in q.ctes:
             # Later CTEs may reference earlier ones (executed in order).
-            cat.register(name, _execute_set(_maybe_optimize(sub, cat),
-                                            cat))
-    return _execute_set(_maybe_optimize(q, cat), cat)
+            cat.register(name, _execute_optimized(sub, cat))
+    return _execute_optimized(q, cat)
 
 
 def _execute_statement(sql: str, catalog=None):
@@ -2456,8 +2467,8 @@ def _execute_statement(sql: str, catalog=None):
     m = _DDL_RE.match(sql)
     if m:
         name, body = m.group(1), m.group(2)
-        if _obs.TRACER.enabled:
-            # format only when the span is live (disabled-mode no-op)
+        if _obs.TRACER.recording:
+            # format only when the span is live (off-mode no-op)
             _obs.current_span().set(plan=f"CreateView[{name}]")
         frame = execute(body, cat)
         cat.register(name, frame)
@@ -2467,8 +2478,8 @@ def _execute_statement(sql: str, catalog=None):
     m = _DROP_RE.match(sql)
     if m:
         if_exists, name = bool(m.group(1)), m.group(2)
-        if _obs.TRACER.enabled:
-            # format only when the span is live (disabled-mode no-op)
+        if _obs.TRACER.recording:
+            # format only when the span is live (off-mode no-op)
             _obs.current_span().set(plan=f"DropView[{name}]")
         existed = cat.drop(name)
         if not existed and not if_exists:
@@ -2476,8 +2487,9 @@ def _execute_statement(sql: str, catalog=None):
         from ..frame.frame import Frame
 
         return Frame({"__one_row__": [0.0]}).drop("__one_row__").limit(0)
-    q = parse(sql)
-    if _obs.TRACER.enabled:
+    with _obs.span("sql.parse", cat="sql"):
+        q = parse(sql)
+    if _obs.TRACER.recording:
         # plan_summary walks the WHERE/projection trees — skip the build
         # entirely when the span is a no-op (the SQL hot path)
         _obs.current_span().set(plan=plan_summary(q))

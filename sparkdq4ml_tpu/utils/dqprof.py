@@ -52,6 +52,7 @@ ledgers — use the statstore for exact row accounting.
 
 from __future__ import annotations
 
+import functools
 import logging
 import math
 import threading
@@ -62,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import config
+from . import observability as _obs
 from .profiling import counters
 
 logger = logging.getLogger("sparkdq4ml_tpu.dqprof")
@@ -376,7 +378,13 @@ def _program(kind: str, b: int, dtype, shard):
         entry = _PROGRAMS.get(key)
     if entry is not None:
         return entry
-    body = _sketch_body(bins) if kind == "sketch" else _rule_body()
+    raw = _sketch_body(bins) if kind == "sketch" else _rule_body()
+
+    @functools.wraps(raw)       # the program keeps its name (jit_sketch)
+    def body(col, mask):
+        # the dq profile's device work, named in a trace's op metadata
+        with _obs.scope("sketch"):
+            return raw(col, mask)
     if shard is not None:
         wrap = _sharded if kind == "sketch" else _sharded_rule
         fn, traced = wrap(body, shard.mesh)
@@ -402,8 +410,6 @@ def program_handles() -> list:
     program, so dqaudit statically bounds sketch peak bytes the same
     way it bounds every other enumerable program. ``fn`` is the
     un-counted trace body."""
-    from . import observability as _obs
-
     with _PROG_LOCK:
         items = list(_PROGRAMS.items())
     return [_obs.ProgramHandle(
@@ -615,8 +621,6 @@ def _check_drift(col: str, prof: ColumnProfile) -> None:
     score = drift_score(baseline, prof)
     if score is None:
         return
-    from . import observability as _obs
-
     with _LOCK:
         _DRIFT[col] = score
     _obs.METRICS.set_gauge(f"dq.drift.{col}", score)
@@ -653,8 +657,6 @@ def _apply_rule(name: str, rows: int, passed: int, window: dict) -> None:
     w[1] += violations
     if violations:
         counters.increment(f"dq.violations.{name}", violations)
-    from . import observability as _obs
-
     rate = (total_viol / total_rows) if total_rows else 0.0
     _obs.METRICS.set_gauge(f"dq.violation_rate.{name}", round(rate, 6))
 
@@ -833,8 +835,6 @@ def explain_lines(marks) -> list:
 # sketch cache is registry-enumerable like every other compiled-program
 # cache (peak-byte bounding rides dqaudit's existing machinery).
 def _register() -> None:
-    from . import observability as _obs
-
     _obs.CACHES.register_programs("dqprof", program_handles)
 
 

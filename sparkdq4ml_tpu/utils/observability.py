@@ -17,26 +17,72 @@ Exporters (all host-side, on demand — never on the hot path):
   counter, gauge, and histogram in one scrape,
 * :func:`trace_report` — a human-readable span tree.
 
-Cost contract: **disabled mode is a near-zero no-op** — every instrumented
-site guards on one ``TRACER.enabled`` flag read and allocates nothing (the
-shared :data:`_NOOP` context manager is returned, no Span object exists),
-so the fused device paths keep their "no host reads" hygiene. Enabling
-observability MAY add host syncs (honest span timing blocks on the traced
-dispatch where noted); that is the explicit price of turning it on.
+One gate, and it follows the profiler: a site records when the tracer was
+switched on explicitly (``spark.observability.enabled`` / ``SPARKDQ4ML_OBS``
+/ :func:`enable` / :func:`query_stats` — the ``Tracer.enabled`` flag) **or
+while a jax profiler session is active**, whoever started it (a benchmark's
+``--trace 1``, ``profiling.start_capture`` behind ``/profile/trace``,
+TensorBoard). :attr:`Tracer.recording` is that one predicate. While a
+profiler session is active every ``with``-style span also opens
+``jax.profiler.TraceAnnotation("dq.<name>", sid=..., parent=...)``, so the
+program's spans lie on the capture's host thread lines, on the device's
+clock, nested as the spans nest.
 
-Wired through the framework:
+Cost contract: **off (no flag, no session) is a near-zero no-op** — every
+instrumented site reads :attr:`Tracer.recording` once (the flag plus one
+``TraceAnnotation.is_enabled()`` call) and allocates nothing (the shared
+:data:`_NOOP` context manager is returned, no Span object exists), so the
+fused device paths keep their "no host reads" hygiene. A span recorded
+because a profiler is on costs an object, two clock reads and a TraceMe and
+**never adds a device wait or a host read**. Only the explicit flag MAY add
+host syncs (honest span timing blocks on the traced dispatch where noted,
+the live-array census feeds the chrome-trace counter tracks); that is the
+explicit price of turning it on.
+
+Wired through the framework (span names are a contract: the benchmark's
+``layer_metrics`` and ``benchmarks/tools/scopes.py`` read them):
 
 * ``frame/frame.py`` — op spans (:func:`op_span` decorator; rows in/out),
-* ``sql/parser.py`` — per-query span with the query text and an
-  ``explain()``-style plan summary,
-* ``models/solvers.py`` / ``regression.py`` / ``classification.py`` — fit
-  spans with cold-compile vs steady split (jit trace-cache hit/miss),
-  iteration counts, final objective, retry/fallback annotations pulled from
-  ``utils.recovery.RECOVERY_LOG``,
-* ``parallel/distributed.py`` / ``mesh.py`` — per-shard Gramian timing,
-  collective/shard_map build counters, mesh-size gauge,
+  ``frame.count`` (the scalar pull, ``host_read_bytes``),
+* ``frame/native_csv.py`` — ``frame.ingest``,
+* ``ops/compiler.py`` / ``ops/segments.py`` — ``frame.pipeline.flush`` and
+  ``frame.grouped.flush`` around the fused programs,
+* ``ops/expressions.py`` — ``dq.rule`` around a registered UDF rule's
+  evaluation (rule name, rows),
+* ``sql/parser.py`` — ``sql.query`` with the query text and an
+  ``explain()``-style plan summary, and its children ``sql.parse``,
+  ``sql.optimize`` (rewrites applied), ``sql.execute``,
+* ``models/feature.py`` — ``feature.assemble`` (columns in, output width),
+* ``models/regression.py`` / ``classification.py`` — one root per fit
+  (``fit.linear_regression`` / ``fit.logistic_regression`` /
+  ``fit.linear_svc``, opened where ``fit`` begins: cold-compile vs steady
+  split, iteration counts, retry/fallback annotations pulled from
+  ``utils.recovery.RECOVERY_LOG``) holding ``fit.prepare`` (children
+  ``fit.extract``, ``fit.validate`` with ``host_read_bytes``, ``fit.pack``)
+  and ``fit.solve`` (dispatch of the compiled fit to its result on the
+  host); ``model.transform`` / ``model.predict`` on both model classes,
+* ``models/solvers.py`` — ``solver.solve``,
+* ``parallel/distributed.py`` / ``mesh.py`` — per-shard Gramian timing
+  (blocks under the explicit flag only), collective/shard_map build
+  counters, mesh-size gauge,
+* ``serve/`` — ``serve.query/admit/queue/stream`` request trees (explicit
+  flag only: they feed the tail sampler),
 * ``session.py`` — ``spark.observability.*`` conf + ``SPARKDQ4ML_OBS`` env
   gating, ``session.metrics()`` / ``trace_report()`` / ``dump_trace(path)``.
+
+Inside the compiled programs :func:`scope` (``jax.named_scope`` under the
+``dq.`` prefix) names the layer a device operation belongs to in its op
+metadata: ``dq.flush``, ``dq.sketch``, ``dq.grouped``, ``dq.exchange``,
+``dq.fit.pack``, ``dq.fit.gram``, ``dq.fit.newton.margin`` / ``.gradient``
+/ ``.hessian`` / ``.line_search``, ``dq.fit.fista.loss_grad``,
+``dq.fit.solve``. Metadata only: the operations' HLO names and the
+compiled code are unchanged. (A scope opened on the host around eager
+``jnp`` calls does not reach their one-operation programs' metadata —
+measured on the chip, PERF.md section 3 — so none is opened there.)
+
+Where the host reads from the device the counters ``host.reads`` and
+``host.read_bytes`` count it (:func:`host_read`), beside
+``frame.host_sync``, which keeps its meaning.
 """
 
 from __future__ import annotations
@@ -51,12 +97,22 @@ import threading
 import time
 from typing import Callable, Optional
 
+import jax
+
 from . import profiling
 from .logging import format_kv
 
 logger = logging.getLogger("sparkdq4ml_tpu.observability")
 
 ENV_VAR = "SPARKDQ4ML_OBS"
+
+#: Prefix of everything the program writes into a profiler capture: the
+#: ``TraceAnnotation`` of a span and the ``named_scope`` of a compiled
+#: program's layer. Never ``bench.``: that is the benchmark's own.
+TRACE_PREFIX = "dq."
+
+#: True while a jax profiler session is active, whoever started it.
+profiler_active = jax.profiler.TraceAnnotation.is_enabled
 
 # ---------------------------------------------------------------------------
 # Metrics: gauges + fixed-bucket histograms (counters live in
@@ -81,6 +137,10 @@ METRIC_NAMES = {
     # frame engine
     "frame.host_sync": ("counter", "counted device->host boundary pulls"),
     "frame.cache": ("counter", "Frame.cache()/persist() materializations"),
+    # every blocking device->host read on the job paths (host_read)
+    "host.reads": ("counter", "blocking device->host reads"),
+    "host.read_bytes": ("counter", "bytes pulled by blocking "
+                                   "device->host reads"),
     # fused expression pipeline (ops/compiler.py)
     "pipeline.flush": ("counter", "pending-pipeline materializations"),
     "pipeline.compile": ("counter", "fused programs traced+compiled"),
@@ -455,7 +515,7 @@ class Span:
 
     __slots__ = ("name", "cat", "attrs", "sid", "parent_id", "trace_id",
                  "tid", "ts_us", "dur_us", "_t0", "_token", "_tracer",
-                 "_mem")
+                 "_mem", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, attrs: dict):
         self._tracer = tracer
@@ -485,6 +545,12 @@ class Span:
         self._t0 = 0.0
         self._token: Optional[contextvars.Token] = None
         self._mem = None              # meminfo.SpanSampler when sampling
+        self._annotation = None       # the TraceMe while a profiler is on
+
+    @property
+    def start_s(self) -> float:
+        """Start on ``time.perf_counter``'s clock (0.0 before entry)."""
+        return self._t0
 
     def set(self, **attrs) -> "Span":
         # Copy-on-write, never in-place: exporters snapshot ``self.attrs``
@@ -502,9 +568,21 @@ class Span:
             self._mem = meminfo.span_sampler()
         self.ts_us = self._tracer._now_us()
         self._t0 = time.perf_counter()
+        if profiler_active():
+            # the same span on the capture's host line, on the device's
+            # clock: an event named dq.<name> with the ids as stats
+            ids = {"sid": self.sid}
+            if self.parent_id is not None:
+                ids["parent"] = self.parent_id
+            self._annotation = jax.profiler.TraceAnnotation(
+                TRACE_PREFIX + self.name, **ids)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(et, ev, tb)
+            self._annotation = None
         self.dur_us = int((time.perf_counter() - self._t0) * 1e6)
         if self._mem is not None:
             self.attrs = {**self.attrs, **self._mem.finish()}
@@ -522,15 +600,20 @@ class Span:
 
 
 class Tracer:
-    """Span recorder. ``enabled`` is THE hot-path gate: every instrumented
-    site reads it once and returns :data:`_NOOP` when off. Finished spans
-    land in a bounded buffer (oldest dropped) and their durations feed the
-    ``span_ms.<category>`` histograms."""
+    """Span recorder. :attr:`recording` is THE hot-path gate: every
+    instrumented site reads it once and returns :data:`_NOOP` when off.
+    It is true under the explicit ``enabled`` flag or while a jax profiler
+    session is active; the two stay separate facts, so that
+    :func:`query_stats`' save-and-restore and ``session.stop()`` only ever
+    touch the flag. Finished spans land in a bounded buffer (oldest
+    dropped) and their durations feed the ``span_ms.<category>``
+    histograms."""
 
     #: Minimum spacing of the resource-counter samples the Chrome-trace
     #: exporter renders as ``"ph": "C"`` tracks (microseconds). Sampling
-    #: is activity-driven (taken at span completion, throttled to this
-    #: interval) so an idle process records nothing.
+    #: is activity-driven (taken at span completion under the explicit
+    #: flag, throttled to this interval) so an idle process records
+    #: nothing, and a span recorded for a profiler never pays the census.
     counter_sample_us = 20_000
     #: Bounded counter-sample history (oldest dropped).
     max_counter_samples = 4096
@@ -551,6 +634,11 @@ class Tracer:
         self._id = 0
         self._epoch_s = time.time()
         self._pc0 = time.perf_counter()
+
+    @property
+    def recording(self) -> bool:
+        """The one gate: the explicit flag, or a jax profiler session."""
+        return self.enabled or profiler_active()
 
     # -- internals --------------------------------------------------------
     def _next_id(self) -> int:
@@ -581,7 +669,8 @@ class Tracer:
                 sink(s)
             except Exception:   # a broken collector must not break the op
                 logger.debug("span sink failed", exc_info=True)
-        self._maybe_sample_counters()
+        if self.enabled:
+            self._maybe_sample_counters()
         METRICS.observe(f"span_ms.{s.cat or 'other'}",
                         (s.dur_us or 0) / 1e3)
         if self.log_spans:
@@ -597,9 +686,9 @@ class Tracer:
         tracks (Perfetto renders them as graphs under the span
         timeline): the live-bytes census, serving queue depth, and the
         pipeline hit/compile counters, taken at span completion and
-        throttled to :data:`counter_sample_us`. Runs only while tracing
-        is enabled (we are in ``_finish``) — the disabled path never
-        reaches here."""
+        throttled to :data:`counter_sample_us`. Runs only under the
+        explicit flag: the census walks ``jax.live_arrays()``, which a
+        span recorded because a profiler is on must never pay."""
         now = self._now_us()
         with self._lock:
             if now - self._last_csample_us < self.counter_sample_us:
@@ -627,8 +716,8 @@ class Tracer:
     # -- recording --------------------------------------------------------
     def span(self, name: str, cat: str = "", **attrs):
         """Context manager for one traced operation. Returns the shared
-        no-op when disabled — one flag check, zero allocation."""
-        if not self.enabled:
+        no-op when off — one gate read, zero allocation."""
+        if not self.recording:
             return _NOOP
         return Span(self, name, cat, attrs)
 
@@ -636,8 +725,10 @@ class Tracer:
         """Open a long-lived span (e.g. the session root) that outlives the
         calling frame. Pair with :meth:`end`. Child spans nest under it via
         the context AND the ambient-root fallback (so spans from worker
-        threads or sibling contexts still parent correctly)."""
-        if not self.enabled:
+        threads or sibling contexts still parent correctly). Not written
+        into a profiler capture: a TraceMe must close on the thread, and
+        inside the session, that opened it."""
+        if not self.recording:
             return _NOOP
         s = Span(self, name, cat, attrs)
         s.ts_us = self._now_us()
@@ -680,12 +771,13 @@ class Tracer:
             self.dropped = 0
 
 
-#: Process-global tracer. Disabled by default; ``session`` conf/env turn it
-#: on (or call :func:`enable` directly).
+#: Process-global tracer. Off by default; ``session`` conf/env turn it on
+#: (or call :func:`enable` directly), and so does any jax profiler session.
 TRACER = Tracer()
 
 
 def enabled() -> bool:
+    """The explicit flag alone (see :attr:`Tracer.recording`)."""
     return TRACER.enabled
 
 
@@ -717,8 +809,6 @@ def reset() -> None:
 
 def span(name: str, cat: str = "", **attrs):
     """Module-level convenience: ``with observability.span("x"): ...``."""
-    if not TRACER.enabled:
-        return _NOOP
     return TRACER.span(name, cat, **attrs)
 
 
@@ -727,7 +817,7 @@ def current_span():
     singleton when disabled or outside any span) — instrumented sites use
     it to attach attributes computed mid-operation without re-plumbing the
     span object."""
-    if not TRACER.enabled:
+    if not TRACER.recording:
         return _NOOP
     s = _CURRENT.get()
     return s if s is not None else _NOOP
@@ -738,7 +828,7 @@ def current_ids() -> tuple:
     None)`` when tracing is off or no span is open. Recovery events attach
     these so a retry/fallback line in the structured log can be cross-
     referenced into the logfmt span stream and the Perfetto view."""
-    if not TRACER.enabled:
+    if not TRACER.recording:
         return (None, None)
     s = _CURRENT.get()
     if s is None:
@@ -1101,9 +1191,11 @@ def emit_span(name: str, cat: str = "", dur_ms: float = 0.0,
     by ``dur_ms``. The serving layer's admission/queue/stream stages run
     outside the execute context (caller thread, asyncio thread) — this is
     how they still land in the request tree: ``ctx`` parents the span
-    under the adopted request root."""
+    under the adopted request root. A back-dated span cannot be written
+    into a profiler capture (a TraceMe starts when it is opened): it
+    exists in the tracer's buffer only."""
     t = TRACER
-    if not t.enabled:
+    if not t.recording:
         return
     s = Span(t, name, cat, attrs)
     if ctx is not None and getattr(ctx, "root_sid", None) is not None:
@@ -1119,13 +1211,13 @@ def op_span(name: str, cat: str = "frame"):
     the call in a span carrying rows in/out (``num_slots`` — static shape
     info, never a device read) and the number of ``frame.host_sync``
     events the op (and anything nested under it) performed — the per-
-    operator sync attribution EXPLAIN ANALYZE reads. Disabled cost: one
-    attribute read and a branch."""
+    operator sync attribution EXPLAIN ANALYZE reads. Off cost: one gate
+    read and a branch."""
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(self, *args, **kwargs):
             t = TRACER
-            if not t.enabled:
+            if not t.recording:
                 return fn(self, *args, **kwargs)
             sync0 = profiling.counters.get("frame.host_sync")
             with Span(t, name, cat, {"rows_in": getattr(self, "_n", None)}) \
@@ -1182,8 +1274,9 @@ def jit_cache_probe(cached_factory) -> Callable[[], str]:
     """Cold-compile vs steady detection for an ``lru_cache``-ed jit-factory
     (``fused_linear_fit_packed`` et al.): snapshot ``cache_info()`` now,
     and the returned thunk reports ``"miss"`` (a new trace+compile was
-    built since) or ``"hit"`` (served from cache). Also mirrors into the
-    ``jit.trace_miss`` / ``jit.trace_hit`` counters."""
+    built since) or ``"hit"`` (served from cache). Under the explicit flag
+    it also mirrors into the ``jit.trace_miss`` / ``jit.trace_hit``
+    counters."""
     try:
         before = cached_factory.cache_info().misses
     except AttributeError:        # not an lru_cache — report unknown
@@ -1194,32 +1287,50 @@ def jit_cache_probe(cached_factory) -> Callable[[], str]:
             missed = cached_factory.cache_info().misses > before
         except AttributeError:
             return "unknown"
-        profiling.counters.increment(
-            "jit.trace_miss" if missed else "jit.trace_hit")
+        if TRACER.enabled:
+            # the counter mirror belongs to the explicit flag: a profiler
+            # session must not change what a job's counters read
+            profiling.counters.increment(
+                "jit.trace_miss" if missed else "jit.trace_hit")
         return "miss" if missed else "hit"
     return verdict
 
 
 @contextlib.contextmanager
-def fit_span(name: str, jit_factory, **attrs):
+def fit_span(name: str, *jit_factories, **attrs):
     """The shared fit-instrumentation shape (LinearRegression /
-    LogisticRegression both families): one span carrying the fit attrs,
-    the cold-compile vs steady verdict from :func:`jit_cache_probe` on the
-    lru-cached jit factory, and recovery retry/fallback annotations for
-    anything the resilience layer did inside. Yields the span (the no-op
-    when disabled) — the caller sets result attrs (iterations, converged)
-    on it. The enabled flag is read ONCE here, so a concurrent enable
-    mid-fit cannot desync the probe from the span."""
+    LogisticRegression both families, LinearSVC): ONE root span per fit,
+    opened where ``fit`` begins, carrying the fit attrs, the cold-compile
+    vs steady verdict from :func:`jit_cache_probe` on the lru-cached jit
+    factories the fit may build from (``miss`` when any of them traced a
+    new program), and recovery retry/fallback annotations for anything the
+    resilience layer did inside. Yields the span (the no-op when off) —
+    the caller opens ``fit.prepare`` / ``fit.solve`` under it and sets
+    result attrs (iterations, converged) on it. The gate is read ONCE
+    here, so a profiler that starts or stops mid-fit cannot desync the
+    probe from the span."""
     t = TRACER
-    if not t.enabled:
+    if not t.recording:
         yield _NOOP
         return
-    verdict = jit_cache_probe(jit_factory)
+    verdicts = [jit_cache_probe(f) for f in jit_factories]
     mark = recovery_mark()
-    with t.span(name, cat="fit", **attrs) as s:
+    with Span(t, name, "fit", attrs) as s:
         yield s
-        s.set(compile=verdict())
+        seen = [v() for v in verdicts]
+        s.set(compile="miss" if "miss" in seen
+              else (seen[0] if seen else "unknown"))
         annotate_recovery(s, mark)
+
+
+def scope(name: str):
+    """``jax.named_scope`` under :data:`TRACE_PREFIX`, for the bodies of
+    compiled programs: every operation traced inside carries
+    ``dq.<name>`` in its op metadata, which XProf/Perfetto show beside the
+    device operation. Metadata only — HLO instruction names, fusion and
+    the compile-cache key are unchanged. Costs nothing at run time (it
+    exists while the program is traced)."""
+    return jax.named_scope(TRACE_PREFIX + name)
 
 
 _jax_listener_installed = False

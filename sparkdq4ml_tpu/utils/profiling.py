@@ -3,21 +3,21 @@
 The reference exposes nothing beyond post-hoc ``objectiveHistory`` prints
 (`DataQuality4MachineLearningApp.java:133-136`). Here:
 
-* :class:`PhaseTimer` — per-phase wall-clock for the pipeline runner (the
-  observability the reference approximates with stdout banners),
-* :func:`trace` — context manager around ``jax.profiler`` emitting an XLA
-  trace viewable in TensorBoard/Perfetto, for the fit hot loop,
 * :func:`block_until_ready` — honest timing helper (JAX dispatch is async;
   timings without a sync measure nothing),
 * :data:`counters` — process-global named counters; the recovery layer
   (``utils.recovery.RECOVERY_LOG``) mirrors every retry/fallback/breaker
   event here as ``recovery.<action>``, so resilience activity shows up in
-  the same place as performance telemetry.
+  the same place as performance telemetry; :func:`host_read` counts the
+  blocking device->host reads and their bytes,
+* :func:`start_capture` / :func:`stop_capture` — managed ``jax.profiler``
+  captures (the ``/profile/trace`` surface). While one runs the span
+  tracer records by itself and writes its spans into the capture
+  (``utils.observability``): per-phase wall-clock is a span's job now.
 """
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import threading
 import time
@@ -71,87 +71,24 @@ class Counters:
 counters = Counters()
 
 
-class PhaseTimer:
-    """Collects named phase durations; ``report()`` returns a dict.
-
-    A first (cold) run through a jitted phase is dominated by XLA
-    compilation; :meth:`steady` re-runs the phase against the compile
-    cache so :meth:`report_pairs` can show (cold, steady) side by side —
-    reading the cold number as throughput would be off by orders of
-    magnitude (bench.py measures the same split).
-    """
-
-    def __init__(self):
-        self.phases: dict[str, float] = {}
-        self.steadies: dict[str, float] = {}
-
-    @contextlib.contextmanager
-    def phase(self, name: str, sync=None):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            if sync is not None:
-                jax.block_until_ready(sync)
-            dt = time.perf_counter() - t0
-            self.phases[name] = self.phases.get(name, 0.0) + dt
-            logger.debug("phase %-20s %8.3f ms", name, dt * 1e3)
-
-    def steady(self, name: str, fn, reps: int = 3, sync=None):
-        """Median steady-state wall-clock of ``fn()`` over ``reps`` calls
-        (run it AFTER the cold :meth:`phase` so compiles are cached);
-        returns the last result.
-
-        ``jax.block_until_ready`` only syncs jax pytrees — an opaque object
-        (a Frame, a fitted model) passes through WITHOUT waiting for its
-        pending dispatch. Pass ``sync`` to extract a device array from the
-        result (e.g. ``lambda f: f.mask``) so the timing includes the async
-        work; syncing is never a host read (bench.py's hygiene rule)."""
-        times = []
-        out = None
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            out = fn()
-            jax.block_until_ready(sync(out) if sync is not None else out)
-            times.append(time.perf_counter() - t0)
-        times.sort()
-        self.steadies[name] = times[len(times) // 2]
-        logger.debug("steady %-19s %8.3f ms", name,
-                     self.steadies[name] * 1e3)
-        return out
-
-    def report(self) -> dict[str, float]:
-        return dict(self.phases)
-
-    def report_pairs(self) -> dict[str, dict[str, Optional[float]]]:
-        """{phase: {"cold": s|None, "steady": s|None}} — cold includes
-        compile. Steady-only names (no matching cold phase) are reported,
-        not dropped."""
-        names = list(self.phases) + [n for n in self.steadies
-                                     if n not in self.phases]
-        return {name: {"cold": self.phases.get(name),
-                       "steady": self.steadies.get(name)}
-                for name in names}
-
-
-@contextlib.contextmanager
-def trace(log_dir: Optional[str] = None):
-    """XLA profiler trace; no-op when log_dir is None."""
-    if log_dir is None:
-        yield
-        return
-    with jax.profiler.trace(log_dir):
-        yield
+def host_read(nbytes: int) -> None:
+    """Count one blocking device->host read of ``nbytes`` (``host.reads``,
+    ``host.read_bytes``). ``nbytes`` comes from the host copy's
+    ``.nbytes`` or from static shapes — never from another device op.
+    Beside ``frame.host_sync``, which keeps its meaning (counted frame
+    boundary pulls); this pair counts every read, ``count()`` and the
+    fit's label/result pulls included, and says how large."""
+    counters.increment("host.reads")
+    counters.increment("host.read_bytes", int(nbytes))
 
 
 # ---------------------------------------------------------------------------
 # Managed jax-profiler captures (the /profile/trace surface)
 # ---------------------------------------------------------------------------
 #
-# :func:`trace` takes an explicit directory and manages nothing — fine
-# for a one-off bench run, but the on-demand capture the telemetry
-# endpoint arms (serve/http.py ``/profile/trace?seconds=N``) needs a
-# bounded, discoverable home: captures land under one base directory,
+# The on-demand capture the telemetry endpoint arms (serve/http.py
+# ``/profile/trace?seconds=N``) needs a bounded, discoverable home:
+# captures land under one base directory,
 # named ``cap-<timestamp>-<label>`` so a capture is attributable to the
 # plan/context that armed it, retention is bounded by
 # ``spark.profiling.maxCaptures`` (oldest pruned), and the newest path
@@ -280,22 +217,3 @@ def stop_capture(expected: Optional[str] = None) -> Optional[str]:
             logger.debug("profiler stop_trace failed", exc_info=True)
     prune_captures()
     return path
-
-
-@contextlib.contextmanager
-def timed(label: str = "block", sync=None):
-    """Log the wall-clock of a block.
-
-    JAX dispatch is ASYNC: without ``sync`` this measures only enqueue
-    time — pending device work is excluded, and a fused fit can "take"
-    microseconds. Pass ``sync`` (a device array / pytree, same contract
-    as ``PhaseTimer.phase``) to ``block_until_ready`` it before the clock
-    stops, making the timing honest; syncing is a device wait, never a
-    host read. A zero-arg callable ``sync`` is invoked at exit and its
-    result blocked on — use that when the array only exists after the
-    block runs (``timed("fit", sync=lambda: out["coef"])``)."""
-    t0 = time.perf_counter()
-    yield
-    if sync is not None:
-        jax.block_until_ready(sync() if callable(sync) else sync)
-    logger.info("%s took %.3f ms", label, (time.perf_counter() - t0) * 1e3)

@@ -2,7 +2,7 @@
 
 Covers the PR-2 tentpole (``utils.observability``) AND the telemetry seeds
 PR 1 left untested: ``Counters`` under threads, ``snapshot``/``clear``
-prefix semantics, ``PhaseTimer.report_pairs`` steady-only phases — plus the
+prefix semantics — plus the
 acceptance criterion: the headline Lasso fit (dataset-full.csv, maxIter=40)
 with ``spark.observability.enabled=true`` produces a valid nested Chrome
 trace, one merged metrics registry (solver + ``recovery.*``), and a
@@ -27,7 +27,7 @@ import pytest
 from sparkdq4ml_tpu.utils import observability as obs
 from sparkdq4ml_tpu.utils import profiling
 from sparkdq4ml_tpu.utils.logging import configure_logging, format_kv
-from sparkdq4ml_tpu.utils.profiling import Counters, PhaseTimer, timed
+from sparkdq4ml_tpu.utils.profiling import Counters
 
 from conftest import dataset_path, prepare_features, run_dq_pipeline
 
@@ -93,31 +93,8 @@ class TestCountersSeed:
         assert c.snapshot() == {}
 
 
-class TestPhaseTimerSeed:
-    def test_report_pairs_includes_steady_only_phases(self):
-        t = PhaseTimer()
-        with t.phase("cold_and_steady"):
-            pass
-        t.steady("cold_and_steady", lambda: jnp.zeros((2,)))
-        t.steady("steady_only", lambda: jnp.ones((2,)))
-        pairs = t.report_pairs()
-        assert pairs["cold_and_steady"]["cold"] is not None
-        assert pairs["cold_and_steady"]["steady"] is not None
-        assert pairs["steady_only"]["cold"] is None
-        assert pairs["steady_only"]["steady"] is not None
-
-    def test_phase_accumulates_across_entries(self):
-        t = PhaseTimer()
-        with t.phase("p"):
-            pass
-        first = t.report()["p"]
-        with t.phase("p"):
-            pass
-        assert t.report()["p"] >= first
-
-
 # ---------------------------------------------------------------------------
-# Satellites: format_kv zeros, timed sync, configure_logging force
+# Satellites: format_kv zeros, configure_logging force
 # ---------------------------------------------------------------------------
 
 
@@ -137,34 +114,6 @@ class TestFormatKvZeros:
         # False is a value, not an absence (bool is an int subclass — the
         # old zero-ish elision dropped it too)
         assert "ok=False" in format_kv(ok=False)
-
-
-class TestTimedSync:
-    def test_sync_object_blocked(self, monkeypatch):
-        blocked = []
-        monkeypatch.setattr(jax, "block_until_ready",
-                            lambda t: blocked.append(t) or t)
-        x = jnp.ones((4,))
-        with timed("t", sync=x):
-            pass
-        assert len(blocked) == 1
-
-    def test_sync_callable_evaluated_at_exit(self, monkeypatch):
-        blocked = []
-        monkeypatch.setattr(jax, "block_until_ready",
-                            lambda t: blocked.append(t) or t)
-        out = {}
-        with timed("t", sync=lambda: out["r"]):
-            out["r"] = jnp.zeros((2,))
-        assert blocked and blocked[0] is out["r"]
-
-    def test_no_sync_means_no_block(self, monkeypatch):
-        calls = []
-        monkeypatch.setattr(jax, "block_until_ready",
-                            lambda t: calls.append(t) or t)
-        with timed("t"):
-            jnp.ones((2,))
-        assert calls == []
 
 
 class TestConfigureLoggingForce:
@@ -509,9 +458,14 @@ class TestSqlSpans:
             "Limit[5] <- DeviceSort[1] <- FusedStage(Project[1] <- Filter) "
             "<- Scan[t]")
         assert s.attrs["rows_out"] == out.num_slots
-        # frame ops executed by the query nest under it
-        frame_children = [c for c in obs.TRACER.spans()
-                          if c.cat == "frame" and c.parent_id == s.sid]
+        # frame ops executed by the query nest under it, below its
+        # sql.execute child (sql.parse and sql.optimize are its siblings)
+        by_sid = {c.sid: c for c in obs.TRACER.spans()}
+        execute = [c for c in by_sid.values()
+                   if c.name == "sql.execute" and c.parent_id == s.sid]
+        assert len(execute) == 1
+        frame_children = [c for c in by_sid.values() if c.cat == "frame"
+                          and c.parent_id == execute[0].sid]
         assert frame_children
 
     def test_ddl_spans(self, session):
