@@ -16,9 +16,13 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Sequence
+from typing import NamedTuple, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+
+from ..utils import observability as _obs
 
 _STAGE_REGISTRY: dict[str, type] = {}
 
@@ -38,6 +42,62 @@ def host_fetch(x) -> np.ndarray:
     out = np.asarray(x)
     host_read(out.nbytes)
     return out
+
+
+@jax.jit
+def label_stats(y, mask, w=None):
+    """What a fit has to know of its labels and weights before it may
+    start, reduced ON THE DEVICE to one small vector (decode with
+    :func:`read_label_stats`): ``[rows, label_min, label_max, label_bad,
+    weight_bad]`` over the rows ``mask`` keeps. ``label_bad``: some valid
+    label is not a finite integer (``y != floor(y)`` holds for NaN);
+    ``weight_bad``: some valid weight is not ``>= 0`` (NaN fails ``>=``).
+    With a NaN among the valid labels ``label_bad`` is the verdict; min and
+    max are then whatever the backend's reduction makes of it. ``y`` or
+    ``w`` may be ``None``; its entries then read 0.
+
+    Masked rows take neutral values through ``jnp.where`` BEFORE any
+    reduction, so a NaN or negative payload in a filtered slot cannot
+    reach the result — the contract indexing host copies with the mask
+    had. Keyed by shape and dtype; sharded operands need no path of their
+    own."""
+    with _obs.scope("fit.validate"):
+        valid = jnp.asarray(mask, jnp.bool_)
+        dtype = jnp.result_type(*(a for a in (y, w) if a is not None),
+                                jnp.float32)
+        # exact below 2**24 rows in float32; read for "== 0" only
+        rows = jnp.sum(valid, dtype=jnp.int32)
+        lo = hi = label_bad = weight_bad = 0
+        if y is not None:
+            lo = jnp.min(jnp.where(valid, y, jnp.inf))
+            hi = jnp.max(jnp.where(valid, y, -jnp.inf))
+            label_bad = jnp.any(jnp.where(
+                valid, (y != jnp.floor(y)) | jnp.isinf(y), False))
+        if w is not None:
+            weight_bad = jnp.any(jnp.where(valid, ~(w >= 0), False))
+        return jnp.stack([jnp.asarray(v, dtype) for v in
+                          (rows, lo, hi, label_bad, weight_bad)])
+
+
+class LabelStats(NamedTuple):
+    rows: float
+    label_min: float
+    label_max: float
+    label_bad: bool
+    weight_bad: bool
+    nbytes: int
+
+
+def read_label_stats(stats) -> LabelStats:
+    """The one blocking read of a :func:`label_stats` vector, counted as a
+    host read (a few scalars, whatever the row count)."""
+    from ..utils.profiling import host_read
+
+    out = np.asarray(stats)
+    host_read(out.nbytes)
+    rows, lo, hi, label_bad, weight_bad = out.tolist()
+    return LabelStats(rows, lo, hi, bool(label_bad), bool(weight_bad),
+                      out.nbytes)
 
 
 def persistable(cls):

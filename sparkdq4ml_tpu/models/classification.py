@@ -38,8 +38,8 @@ from ..config import float_dtype
 from ..frame.frame import Frame
 from ..parallel.mesh import DATA_AXIS, serialize_collectives, shard_map
 from ..utils import observability as _obs
-from .base import (Estimator, Model, host_fetch, persistable, read_json,
-                   write_json)
+from .base import (Estimator, Model, host_fetch, label_stats, persistable,
+                   read_json, read_label_stats, write_json)
 from .regression import _extract_xy
 from .solvers import _soft
 
@@ -897,7 +897,6 @@ class LogisticRegression(Estimator):
                                             pack_design_weighted,
                                             place_packed, unpack_fit_result)
         from ..utils.profiling import counters as _counters
-        from ..utils.profiling import host_read
 
         if mesh is None:
             from ..session import TpuSession
@@ -912,20 +911,28 @@ class LogisticRegression(Estimator):
                 X, y, mask = _extract_xy(frame, self.features_col,
                                          self.label_col)
             prep.set(rows=int(X.shape[0]), features=int(X.shape[1]))
+            w = None
+            if weighted:
+                # masked rows' weight values never participate (see the
+                # LinearRegression weightCol note): the reduction looks
+                # at valid rows only, and the packing zeroes the rest so
+                # a NaN payload cannot poison it
+                w = jnp.asarray(frame._column_values(self.weight_col),
+                                float_dtype())
             with _obs.span("fit.validate", cat="fit") as val:
-                # the labels and the mask come to the host to be checked:
-                # two blocking reads of n rows each
-                y_host, mask_host = np.asarray(y), np.asarray(mask)
-                host_read(y_host.nbytes)
-                host_read(mask_host.nbytes)
-                pulled = y_host.nbytes + mask_host.nbytes
-                yv = y_host[mask_host]
-                if len(yv) == 0:
+                # one small reduction on the device and a read of its few
+                # scalars (base.label_stats): no n-row column comes to
+                # the host. The read waits for the device to reach it —
+                # whatever the frame still had queued — and no longer.
+                stats = read_label_stats(label_stats(y, mask, w))
+                val.set(host_read_bytes=stats.nbytes)
+                if stats.rows == 0:
                     raise ValueError("LogisticRegression: no valid rows")
-                if np.any(yv < 0) or np.any(yv != np.floor(yv)):
+                # NaN and +/-inf on a valid row set label_bad
+                if stats.label_bad or stats.label_min < 0:
                     raise ValueError(
                         "labels must be nonnegative integers 0..k-1")
-                num_classes = int(yv.max()) + 1
+                num_classes = int(stats.label_max) + 1
                 family = self.family
                 if family == "auto":
                     family = "binomial" if num_classes <= 2 \
@@ -934,29 +941,14 @@ class LogisticRegression(Estimator):
                     raise ValueError(
                         f"binomial family requires binary labels, found "
                         f"{num_classes} classes; use family='multinomial'")
-                if weighted:
-                    # masked rows' weight values never participate (see the
-                    # LinearRegression weightCol note): validate valid rows
-                    # only, zero the rest so a NaN payload cannot poison
-                    # the packing
-                    w = frame._column_values(self.weight_col)
-                    w_host = np.asarray(w)
-                    if isinstance(w, jax.Array):
-                        host_read(w_host.nbytes)
-                        pulled += w_host.nbytes
-                    # NaN fails >= too (silent NaN poisoning must raise)
-                    if not bool(np.all(w_host[mask_host] >= 0)):
-                        raise ValueError("weights must be nonnegative")
-                    del w_host
-                val.set(host_read_bytes=pulled)
-                # the host copies (n labels, n mask bytes) go now, not
-                # when fit returns: they are not held through the solve
-                del y_host, mask_host, yv
+                # NaN fails >= too (silent NaN poisoning must raise)
+                if stats.weight_bad:
+                    raise ValueError("weights must be nonnegative")
             with _obs.span("fit.pack", cat="fit"):
                 if weighted:
-                    w = jnp.where(mask, jnp.asarray(w, float_dtype()), 0.0)
-                    Zd = place_packed(pack_design_weighted(X, y, mask, w),
-                                      mesh)
+                    Zd = place_packed(
+                        pack_design_weighted(X, y, mask,
+                                             jnp.where(mask, w, 0.0)), mesh)
                 else:
                     Zd = place_packed(pack_design(X, y, mask), mesh)
                 hyper = jnp.asarray([self.reg_param, self.elastic_net_param],
@@ -1524,7 +1516,6 @@ class LinearSVC(Estimator):
         from ..parallel.distributed import (pack_design, place_packed,
                                             unpack_fit_result)
         from ..parallel.mesh import normalize_mesh
-        from ..utils.profiling import host_read
 
         if mesh is None:
             from ..session import TpuSession
@@ -1538,16 +1529,14 @@ class LinearSVC(Estimator):
                                          self.label_col)
             prep.set(rows=int(X.shape[0]), features=int(X.shape[1]))
             with _obs.span("fit.validate", cat="fit") as val:
-                y_host, mask_host = np.asarray(y), np.asarray(mask)
-                host_read(y_host.nbytes)
-                host_read(mask_host.nbytes)
-                val.set(host_read_bytes=y_host.nbytes + mask_host.nbytes)
-                yv = y_host[mask_host]
-                if len(yv) == 0:
+                stats = read_label_stats(label_stats(y, mask))
+                val.set(host_read_bytes=stats.nbytes)
+                if stats.rows == 0:
                     raise ValueError("LinearSVC: no valid rows")
-                if not np.all((yv == 0) | (yv == 1)):
+                # integers within [0, 1] are 0 and 1
+                if stats.label_bad or stats.label_min < 0 \
+                        or stats.label_max > 1:
                     raise ValueError("LinearSVC requires binary 0/1 labels")
-                del y_host, mask_host, yv
             with _obs.span("fit.pack", cat="fit"):
                 Zd = place_packed(pack_design(X, y, mask), mesh)
                 hyper = jnp.asarray([self.reg_param, 0.0], float_dtype())

@@ -24,7 +24,8 @@ from ..config import float_dtype
 from ..frame.frame import Frame
 from ..ops.expressions import col
 from ..utils import observability as _obs
-from .base import Estimator, Model, persistable, read_json, write_json
+from .base import (Estimator, Model, label_stats, persistable, read_json,
+                   read_label_stats, write_json)
 from .solvers import FitResult, resolve_solver
 
 
@@ -178,7 +179,7 @@ class LinearRegression(Estimator):
                                             unpack_fit_result)
         from ..utils import faults as _faults
         from ..utils import recovery as _recovery
-        from ..utils.profiling import counters, host_read
+        from ..utils.profiling import counters
         from .solvers import downgrade_solver
 
         if mesh is None:
@@ -200,27 +201,23 @@ class LinearRegression(Estimator):
                 # integer weight k is EXACTLY a row repeated k times (the
                 # regression test for this path). Summary metrics remain
                 # unweighted row statistics.
-                # Masked rows' weight VALUES never participate: validation
-                # only inspects valid rows, and sqrt() sees 0 there (a
-                # NaN/negative payload in a filtered slot must not poison
-                # Z). Validating costs host reads — a weighted-fit-only
-                # price.
+                # Masked rows' weight VALUES never participate: the
+                # validation looks at valid rows only, and sqrt() sees 0
+                # there (a NaN/negative payload in a filtered slot must
+                # not poison Z). Validating is one small reduction on the
+                # device and a read of its few scalars (base.label_stats).
+                w = jnp.asarray(frame._column_values(self.weight_col),
+                                float_dtype())
                 with _obs.span("fit.validate", cat="fit") as val:
-                    w = frame._column_values(self.weight_col)
-                    w_host, mask_host = np.asarray(w), np.asarray(mask)
-                    pulled = mask_host.nbytes
-                    host_read(mask_host.nbytes)
-                    if not isinstance(w, np.ndarray):
-                        host_read(w_host.nbytes)
-                        pulled += w_host.nbytes
-                    val.set(host_read_bytes=pulled)
+                    stats = read_label_stats(label_stats(None, mask, w))
+                    val.set(host_read_bytes=stats.nbytes)
                     # NaN fails >= too: a NaN weight on a valid row must
                     # raise, not silently poison the Gramian
-                    if not bool(np.all(w_host[mask_host] >= 0)):
+                    if stats.weight_bad:
                         raise ValueError("weights must be nonnegative")
                 mask_b = mask
                 mask = mask.astype(float_dtype()) * jnp.sqrt(
-                    jnp.where(mask_b, jnp.asarray(w, float_dtype()), 0.0))
+                    jnp.where(mask_b, w, 0.0))
             if self.loss == "huber":
                 return self._fit_huber(frame, X, y, mask)
             with _obs.span("fit.pack", cat="fit"):

@@ -58,7 +58,8 @@ Wired through the framework (span names are a contract: the benchmark's
   ``fit.linear_svc``, opened where ``fit`` begins: cold-compile vs steady
   split, iteration counts, retry/fallback annotations pulled from
   ``utils.recovery.RECOVERY_LOG``) holding ``fit.prepare`` (children
-  ``fit.extract``, ``fit.validate`` with ``host_read_bytes``, ``fit.pack``)
+  ``fit.extract``, ``fit.validate`` — ``base.label_stats`` and the read
+  of its few scalars, with ``host_read_bytes`` — and ``fit.pack``)
   and ``fit.solve`` (dispatch of the compiled fit to its result on the
   host); ``model.transform`` / ``model.predict`` on both model classes,
 * ``models/solvers.py`` — ``solver.solve``,
@@ -73,12 +74,13 @@ Wired through the framework (span names are a contract: the benchmark's
 Inside the compiled programs :func:`scope` (``jax.named_scope`` under the
 ``dq.`` prefix) names the layer a device operation belongs to in its op
 metadata: ``dq.flush``, ``dq.sketch``, ``dq.grouped``, ``dq.exchange``,
-``dq.fit.pack``, ``dq.fit.gram``, ``dq.fit.newton.margin`` / ``.gradient``
-/ ``.hessian`` / ``.line_search``, ``dq.fit.fista.loss_grad``,
-``dq.fit.solve``. Metadata only: the operations' HLO names and the
-compiled code are unchanged. (A scope opened on the host around eager
-``jnp`` calls does not reach their one-operation programs' metadata —
-measured on the chip, PERF.md section 3 — so none is opened there.)
+``dq.fit.validate``, ``dq.fit.pack``, ``dq.fit.gram``,
+``dq.fit.newton.margin`` / ``.gradient`` / ``.hessian`` /
+``.line_search``, ``dq.fit.fista.loss_grad``, ``dq.fit.solve``. Metadata
+only: the operations' HLO names and the compiled code are unchanged. (A
+scope opened on the host around eager ``jnp`` calls does not reach their
+one-operation programs' metadata — measured on the chip, PERF.md section 3
+— so none is opened there.)
 
 Where the host reads from the device the counters ``host.reads`` and
 ``host.read_bytes`` count it (:func:`host_read`), beside

@@ -77,7 +77,7 @@ def host_read(nbytes: int) -> None:
     ``.nbytes`` or from static shapes — never from another device op.
     Beside ``frame.host_sync``, which keeps its meaning (counted frame
     boundary pulls); this pair counts every read, ``count()`` and the
-    fit's label/result pulls included, and says how large."""
+    fit's validation-stats and result reads included, and says how large."""
     counters.increment("host.reads")
     counters.increment("host.read_bytes", int(nbytes))
 

@@ -461,8 +461,9 @@ def test_span_attributes_carry_the_counts(flow_spans):
                                     "rows": ROWS}
         assert attrs["frame.count"]["host_read_bytes"] in (4, 8)
     if "fit.validate" in attrs:
-        # float64 labels here (conftest), float32 on the chip; bool mask
-        assert attrs["fit.validate"]["host_read_bytes"] == ROWS * 8 + ROWS
+        # the stats vector of base.label_stats, whatever the row count:
+        # 5 scalars, float64 here (conftest), float32 on the chip
+        assert attrs["fit.validate"]["host_read_bytes"] == 5 * 8
 
 
 @pytest.mark.parametrize("flow_spans", ["catering", "higgs"],
@@ -515,11 +516,14 @@ def test_to_pydict_counts_its_one_batched_pull():
     assert moved["host.read_bytes"] == 100 * 4 + 100 * 4 + 100   # + mask
 
 
-def test_logistic_fit_pulls_labels_mask_and_result():
+@pytest.mark.parametrize("n", [300, 30_000])
+def test_logistic_fit_reads_the_stats_vector_and_the_result(n):
+    """Two counted reads a fit, and their bytes do not depend on n: the
+    labels are validated on the device (base.label_stats)."""
     from sparkdq4ml_tpu.frame.frame import Frame
     from sparkdq4ml_tpu.models import LogisticRegression
 
-    n, d, iters = 300, 3, 20
+    d, iters = 3, 20
     rng = np.random.default_rng(1)
     X = rng.normal(size=(n, d))
     y = (rng.random(n) < 0.5).astype(np.float64)
@@ -528,9 +532,10 @@ def test_logistic_fit_pulls_labels_mask_and_result():
     LogisticRegression(max_iter=iters).fit(f, mesh=None)
     moved = _moved(before)
     item = jnp.asarray(y).dtype.itemsize
+    stats = 5 * item                        # rows, min, max, two flags
     flat = (d + 3 + iters + 1) * item       # coef, 3 scalars, history
-    assert moved["host.reads"] == 3         # labels, mask, packed result
-    assert moved["host.read_bytes"] == n * item + n + flat
+    assert moved["host.reads"] == 2         # stats vector, packed result
+    assert moved["host.read_bytes"] == stats + flat
     assert moved["frame.host_sync"] == 0
 
 
