@@ -311,6 +311,7 @@ class Frame:
     _alias: Optional[str] = None  # set by .alias(); not inherited by _with
     _pending: tuple = ()          # deferred pipeline steps (see _defer)
     _flush_lock = None            # per-frame flush serializer (see _lock)
+    _ones = None                  # the all-valid mask __init__ made, if any
     # Row-shard layout descriptor (parallel/shard.py ShardedStore), or
     # None for the single-device layout. A sharded frame's columns/mask
     # are global arrays padded to devices×bucket slots with a False mask
@@ -354,7 +355,7 @@ class Frame:
             self._data[name] = arr
         self._n = 0 if n is None else int(n)
         if mask is None:
-            self._mask = jnp.ones((self._n,), dtype=jnp.bool_)
+            self._mask = self._ones = jnp.ones((self._n,), dtype=jnp.bool_)
         else:
             self._mask = jnp.asarray(mask, jnp.bool_)
             if self._mask.shape != (self._n,):
@@ -367,10 +368,17 @@ class Frame:
         cols = list(zip(*rows)) if rows else [[] for _ in names]
         return cls({name: list(vals) for name, vals in zip(names, cols)})
 
+    def _every_slot_valid(self) -> bool:
+        """True when the HOST knows no slot is masked: the frame still
+        carries the all-ones mask it was constructed with (a compact
+        result — groupBy, sort, join output). False says nothing."""
+        return self._ones is not None and self._mask is self._ones
+
     def _with(self, data=None, mask=None) -> "Frame":
         f = Frame.__new__(Frame)
         f._data = dict(self._data if data is None else data)
         f._mask = self._mask if mask is None else mask
+        f._ones = self._ones      # counts only while the mask is that one
         f._n = self._n
         f._shard = self._shard
         return f

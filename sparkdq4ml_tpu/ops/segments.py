@@ -11,12 +11,17 @@ pipeline compiler (``ops/compiler.py``) removed it for expression chains:
 
   - the **dense** program (the common case: integer-valued keys whose
     packed range fits a bounded table) maps each row's key tuple straight
-    to a dense lexicographic slot — NO row sort at all — and computes
-    every aggregate with ``jax.ops.segment_*`` reductions whose additive
-    members stack into one ``(n, C)`` scatter (per-element scatter
-    overhead amortizes across aggregates). Table→group compaction is
-    gather-based (``searchsorted`` over the presence prefix-sum), because
-    gathers are fast on every backend while scatters are not.
+    to a dense lexicographic slot — NO row sort at all. Two tiers share
+    the program and are chosen inside it from the traced key range: a
+    range of at most 128 slots (one lane tile) reduces by blocked masked
+    passes over the rows (``_tile_tables``: no n-sized operand per
+    aggregate, float sums as a pairwise tree over block sums); a wider
+    range by one ``jax.ops.segment_*`` scatter per aggregate member, block
+    of rows by block (``_scatter_tables``; members are separate 1-D
+    operands — stacked as ``(n, C)`` their minor dimension pads to 128
+    lanes on the TPU). Table→group compaction is gather-based
+    (``searchsorted`` over the presence prefix-sum), because gathers are
+    fast on every backend while scatters are not.
   - the **sorted** program (arbitrary float keys, and any plan containing
     ``count_distinct``/``sum_distinct``, which need sorted-run counting)
     does an on-device lexicographic sort (``jax.lax.sort`` over null-flag/
@@ -28,7 +33,9 @@ pipeline compiler (``ops/compiler.py``) removed it for expression chains:
   "did the range fit" verdict) — leaves the device as ONE scalar sync at
   the very end; outputs are computed at static length and sliced on the
   way out. A dense-range miss costs one extra sync (the verdict) before
-  the sorted program runs.
+  the sorted program runs. What surrounds a plan is one launch each, not
+  one per column: the inputs' pad (``_padded``), the outputs' slice
+  (``compiler._unpad_tree``), a sort's payload gather (``_take_tree``).
 
 * **Plan-keyed jit cache.** Programs cache under a structural key (key
   dtypes, aggregate set with value-column slots, engine dtype tag) in a
@@ -73,6 +80,7 @@ CPU-backend sort permutation).
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import time
@@ -86,8 +94,9 @@ from jax import lax
 from ..config import config, float_dtype, int_dtype
 from ..utils import faults as _faults
 from ..utils import observability as _obs
-from ..utils.profiling import counters
-from .compiler import bucket_size, dtype_tag, pad_rows, plan_namespace_tag
+from ..utils.profiling import counters, host_read
+from .compiler import (_unpad_tree, bucket_size, dtype_tag, pad_rows,
+                       plan_namespace_tag)
 
 logger = logging.getLogger("sparkdq4ml_tpu.ops.segments")
 
@@ -213,6 +222,14 @@ def agg_lowerable(agg) -> bool:
 # slots or the plan reroutes to the sorted program. 2^17 keeps the table
 # comfortably cache/VMEM-sized while covering the 100k-group regime.
 _DENSE_MAX = 1 << 17
+# Small-table tier of the dense lowering: a packed key range that fits one
+# lane tile of slots reduces by blocked masked passes over the rows
+# (_tile_tables) instead of an S-slot scatter — no n-sized operand per
+# aggregate, whatever the row count.
+_TILE = 128
+_TILE_CHUNK = 8          # slots reduced per pass over the rows
+_TILE_BLOCK = 1 << 21    # rows per block of a pass
+_SCATTER_BLOCK = 1 << 22  # rows per block of the S-slot scatters
 
 
 # ---------------------------------------------------------------------------
@@ -508,10 +525,15 @@ def _group_scaffold(keys, key_kinds, mask):
 def _dense_slots(keys, key_kinds, valid, S: int, axis=None):
     """Per-row dense slot ids + the fit verdict.
 
-    Each key contributes a digit ``0`` for NULL (NaN) else ``k - lo + 1``
-    — ascending slot order IS the host lexsort's group order (key 1
-    major, nulls first). Returns ``(slot, ok, decoders)`` where
-    ``decoders`` rebuilds per-key group values from a slot index.
+    Each float key contributes a digit ``0`` for NULL (NaN) else
+    ``k - lo + 1``, each int or bool key ``k - lo`` — ascending slot order
+    IS the host lexsort's group order (key 1 major, nulls first). Returns
+    ``(slots, ok, decoders, total)``:
+    ``slots(keys)`` gives the per-row slot ids of any rows (the whole
+    columns, or one block of them) from the layout fixed here,
+    ``decoders`` rebuilds per-key group values from a slot index and
+    ``total`` is the traced size of the packed range (what picks the tile
+    tier inside the program).
     ``ok`` is a traced scalar: every float key integer-valued and the
     packed size within ``S``; when False the slot ids are garbage and the
     caller reroutes to the sorted program.
@@ -545,7 +567,12 @@ def _dense_slots(keys, key_kinds, valid, S: int, axis=None):
             any_nn = jnp.any(nonnull)
         lo = jnp.where(any_nn, lo, jnp.zeros((), acc))
         hi = jnp.where(any_nn, hi, jnp.zeros((), acc) - 1)
-        size = hi - lo + 2           # +1 digit offset, +1 null slot
+        if kind == "f":
+            size = hi - lo + 2       # +1 digit offset, +1 null slot
+        else:
+            # an int or bool key is never NULL: no digit is kept for it
+            # (two flags of 3 and 2 values pack to 6 slots, not 12)
+            size = jnp.maximum(hi - lo + 1, 1)
         sizes.append(size)
         infos.append((kind, lo, a.dtype))
         # digits are computed in the float accumulator: key magnitudes
@@ -565,7 +592,6 @@ def _dense_slots(keys, key_kinds, valid, S: int, axis=None):
     ok = jnp.logical_and(ok, jnp.isfinite(total))
     ok = jnp.logical_and(ok, total <= S)
 
-    slot = jnp.zeros(valid.shape, jnp.int32)
     stride = jnp.asarray(1.0, acc)
     # build strides minor→major (last key = fastest digit)
     strides = [None] * len(keys)
@@ -573,26 +599,30 @@ def _dense_slots(keys, key_kinds, valid, S: int, axis=None):
         strides[i] = stride
         stride = stride * sizes[i]
     safe = jnp.where(ok, jnp.asarray(1.0, acc), jnp.zeros((), acc))
-    for (kind, lo, _dt), st, k in zip(infos, strides, keys):
-        a = jnp.asarray(k)
-        af = (a.astype(jnp.int8) if kind == "b" else a).astype(acc)
-        if kind == "f":
-            digit = jnp.where(jnp.isnan(af), jnp.zeros((), acc),
-                              af - lo + 1)
-        else:
-            digit = af - lo + 1
-        # ok=False ⇒ clamp contributions to 0 so the int32 cast can't
-        # overflow into UB before the verdict reroutes the plan
-        slot = slot + (digit * st * safe).astype(jnp.int32)
+
+    def slots(rows):
+        slot = jnp.zeros(jnp.shape(rows[0]), jnp.int32)
+        for (kind, lo, _dt), st, k in zip(infos, strides, rows):
+            a = jnp.asarray(k)
+            af = (a.astype(jnp.int8) if kind == "b" else a).astype(acc)
+            if kind == "f":
+                digit = jnp.where(jnp.isnan(af), jnp.zeros((), acc),
+                                  af - lo + 1)
+            else:
+                digit = af - lo
+            # ok=False ⇒ clamp contributions to 0 so the int32 cast
+            # can't overflow into UB before the verdict reroutes the plan
+            slot = slot + (digit * st * safe).astype(jnp.int32)
+        return slot
 
     def make_decoder(kind, lo, dt, st, size):
         def decode(t_idx):
             tf = t_idx.astype(acc)
             digit = jnp.floor(tf / st) % size
-            val = lo + digit - 1
             if kind == "f":
-                return jnp.where(digit == 0,
-                                 jnp.asarray(jnp.nan, acc), val).astype(dt)
+                return jnp.where(digit == 0, jnp.asarray(jnp.nan, acc),
+                                 lo + digit - 1).astype(dt)
+            val = lo + digit
             if kind == "b":
                 return val.astype(jnp.int8).astype(dt)
             return val.astype(dt)
@@ -600,7 +630,7 @@ def _dense_slots(keys, key_kinds, valid, S: int, axis=None):
 
     decoders = [make_decoder(kind, lo, dt, st, size)
                 for (kind, lo, dt), st, size in zip(infos, strides, sizes)]
-    return slot, ok, decoders
+    return slots, ok, decoders, total
 
 
 def _compact_index(present, S: int):
@@ -611,10 +641,209 @@ def _compact_index(present, S: int):
     return jnp.searchsorted(cs, lax.iota(jnp.int32, S) + 1, side="left")
 
 
+# Per stack of the dense program: how two partials combine, how a member
+# scatters into the S-slot table, and (``_stack_identity``) the value a row
+# contributes when it is not in the slot being reduced.
+_STACK_OPS = {"ai": jnp.add, "af": jnp.add, "mf": jnp.minimum,
+              "mi": jnp.minimum, "xi": jnp.maximum}
+_STACK_SCATTER = {"ai": jax.ops.segment_sum, "af": jax.ops.segment_sum,
+                  "mf": jax.ops.segment_min, "mi": jax.ops.segment_min,
+                  "xi": jax.ops.segment_max}
+_STACK_MERGE = {"ai": lax.psum, "af": lax.psum, "mf": lax.pmin,
+                "mi": lax.pmin, "xi": lax.pmax}
+
+
+def _stack_identity(stack: str, dtype):
+    if stack in ("ai", "af"):
+        return jnp.zeros((), dtype)
+    if stack == "mf":
+        return jnp.asarray(jnp.inf, dtype)
+    info = jnp.iinfo(dtype)
+    return jnp.asarray(info.max if stack == "mi" else info.min, dtype)
+
+
+def _contribution(member, rows):
+    """A member's per-row operand: its value where its gate holds, its
+    stack's identity on every other row (masked, NULL)."""
+    stack, dtype, gate, value = member
+    v = jnp.asarray(value(rows))
+    if v.dtype == jnp.bool_:
+        v = v.astype(jnp.int8)
+    return jnp.where(gate(rows), v.astype(dtype),
+                     _stack_identity(stack, dtype))
+
+
+class _Rows:
+    """The rows a reduction reads — the whole columns or one block of
+    them: key columns, value columns, validity, and on demand the original
+    row index (``idx``) and the slot ids those keys pack to (``seg``, from
+    ``slots`` of ``_dense_slots``; invalid rows get ``drop``, a slot no
+    table has)."""
+
+    __slots__ = ("keys", "vals", "valid", "_slots", "_drop", "_start")
+
+    def __init__(self, keys, vals, valid, slots, drop: int, start=0):
+        self.keys, self.vals, self.valid = keys, vals, valid
+        self._slots, self._drop, self._start = slots, drop, start
+
+    @property
+    def rows(self) -> int:
+        return self.valid.shape[0]
+
+    @property
+    def idx(self):
+        return self._start + lax.iota(jnp.int32, self.rows)
+
+    @property
+    def seg(self):
+        return jnp.where(self.valid, self._slots(self.keys), self._drop)
+
+    def block(self, start, size: int):
+        """The ``size`` rows from ``start`` (traced or static)."""
+        if size == self.rows:
+            return self
+
+        def cut(a):
+            return lax.dynamic_slice(a, (start,), (size,))
+
+        return _Rows(tuple(cut(k) for k in self.keys),
+                     tuple(cut(v) for v in self.vals), cut(self.valid),
+                     self._slots, self._drop, start)
+
+    def fold(self, block: int, carry, step):
+        """``step(carry, rows)`` over the whole blocks of ``block`` rows in
+        turn (one loop), then over the shorter rest."""
+        whole = self.rows // block
+        if whole:
+            carry = lax.fori_loop(
+                0, whole, lambda i, c: step(c, self.block(i * block, block)),
+                carry)
+        if self.rows > whole * block:
+            carry = step(carry, self.block(whole * block,
+                                           self.rows - whole * block))
+        return carry
+
+
+def _tile_blocks(n: int) -> tuple[int, int]:
+    """(rows per block, whole blocks >= 1) of the tile tier's row blocking:
+    a block is a power of two of at most ``_TILE_BLOCK`` rows; the rows
+    past the last whole block reduce as one short block of their own."""
+    block = min(_TILE_BLOCK, 1 << (n.bit_length() - 1))
+    return block, n // block
+
+
+def _pairwise(x, op, identity):
+    """Balanced-tree combine of the minor axis (padded to a power of two
+    with ``identity``): the rounding error of a float sum grows with log2
+    of the length, not with the length."""
+    size = 1 << max(x.shape[-1] - 1, 0).bit_length()
+    if size > x.shape[-1]:
+        x = jnp.concatenate([x, jnp.full(
+            x.shape[:-1] + (size - x.shape[-1],), identity, x.dtype)], -1)
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        x = op(x[..., :h], x[..., h:])
+    return x[..., 0]
+
+
+def _tile_tables(plan, rows: _Rows, passes, tile: int, vary):
+    """The tile tier's reduction: one ``(tile,)`` table per member of
+    ``plan`` (``[(stack, dtype, gate, value)]``, see ``_contribution``),
+    with no n-sized operand per aggregate.
+
+    Rows are read in blocks of ``_TILE_BLOCK``. A pass reduces
+    ``_TILE_CHUNK`` slots at once: every (slot, member) pair is one operand
+    of ONE variadic reduce over the block, whose elementwise producer
+    (``where(seg == slot, contribution, identity)``, from the block's slice
+    of the program's own parameters) fuses into it — so a pass reads each
+    input column once and writes one partial per (slot, member) and block,
+    and nothing n-sized is ever built. The block partials then combine
+    pairwise. A float sum is therefore a balanced tree over block sums of
+    at most 2^21 terms — a few float32 ulps at 1.8e8 rows (1e-7 read on the
+    chip), where one running total would drift; integer members are exact.
+    ``passes`` (traced) is how many chunks the packed key range spans: a
+    6-slot range costs one pass over the rows, a 128-slot range sixteen."""
+    n = rows.rows
+    block, blocks = _tile_blocks(n)
+    whole = block * blocks
+    stacks = [m[0] for m in plan]
+    dtypes = [m[1] for m in plan]
+    ident = [_stack_identity(st, dt) for st, dt in zip(stacks, dtypes)]
+    ops = [_STACK_OPS[st] for st in stacks] * _TILE_CHUNK
+    inits = tuple(ident) * _TILE_CHUNK
+    # partials travel as one (chunk * members-of-the-kind,) vector per
+    # (stack, dtype): a handful of small arrays a block, not one scalar
+    # per operand
+    kinds = list(dict.fromkeys(zip(stacks, dtypes)))
+    of_kind = [[c for c, k in enumerate(zip(stacks, dtypes)) if k == kind]
+               for kind in kinds]
+
+    def combine(xs, ys):
+        return tuple(op(x, y) for op, x, y in zip(ops, xs, ys))
+
+    def partials(view, base):
+        """The block's (chunk, member) partials, grouped by kind."""
+        members = [_contribution(m, view) for m in plan]
+        seg = view.seg
+        operands = []
+        for t in range(_TILE_CHUNK):
+            hit = seg == base + t
+            operands.extend(jnp.where(hit, m, e)
+                            for m, e in zip(members, ident))
+        part = lax.reduce(tuple(operands), inits, combine, (0,))
+        return tuple(jnp.stack([part[t * len(plan) + c]
+                                for t in range(_TILE_CHUNK) for c in cs])
+                     for cs in of_kind)
+
+    def one_pass(j, tables):
+        base = j * _TILE_CHUNK
+        _, per_block = lax.scan(
+            lambda i, _: (i + 1, partials(rows.block(i * block, block),
+                                          base)),
+            jnp.zeros((), jnp.int32), None, length=blocks)
+        got = [_pairwise(p.T, _STACK_OPS[st], _stack_identity(st, dt))
+               for p, (st, dt) in zip(per_block, kinds)]
+        if n > whole:
+            rest = partials(rows.block(whole, n - whole), base)
+            got = [_STACK_OPS[st](g, r)
+                   for (st, _), g, r in zip(kinds, got, rest)]
+        out = list(tables)
+        for cs, g in zip(of_kind, got):
+            g = g.reshape(_TILE_CHUNK, len(cs))
+            for k, c in enumerate(cs):
+                out[c] = lax.dynamic_update_slice(out[c], g[:, k], (base,))
+        return tuple(out)
+
+    empty = tuple(vary(jnp.full((tile,), e, e.dtype)) for e in ident)
+    return lax.fori_loop(0, passes, one_pass, empty)
+
+
+def _scatter_tables(plan, rows: _Rows, S: int, vary):
+    """The scatter tier's reduction: one ``(S,)`` table per member of
+    ``plan``, one scatter per member. Members are separate 1-D operands,
+    built where they are reduced: stacked as ``(n, C)`` their minor
+    dimension pads to 128 lanes on the TPU — 512 B a row, 61 GB at 1.2e8
+    rows. Block by block too: a scatter cannot fuse its update's producer,
+    so a member exists as a buffer while it scatters — of one block of
+    rows, not of n."""
+    def step(tables, view):
+        seg = view.seg
+        return tuple(_STACK_OPS[m[0]](t, _STACK_SCATTER[m[0]](
+            _contribution(m, view), seg, num_segments=S))
+            for m, t in zip(plan, tables))
+
+    return rows.fold(_SCATTER_BLOCK, tuple(
+        vary(jnp.full((S,), _stack_identity(st, dt), dt))
+        for st, dt, _, _ in plan), step)
+
+
 def _build_dense_agg_program(key_kinds, agg_ops, val_kinds, S: int,
                              axis=None, world: int = 1):
     """The sort-free grouped lowering (see module docstring): dense slot
-    ids, stacked segment reductions, gather compaction.
+    ids, one reduction per member, gather compaction. Two tiers share the
+    program and are chosen inside it from the traced key range: the tile
+    tier (``_tile_tables``) where the packed range fits ``_TILE`` slots,
+    S-slot scatters above.
 
     Integer quantities — counts, integer sums, min/max over int columns,
     and the first/last row indices — reduce in INTEGER stacks: the float
@@ -633,220 +862,234 @@ def _build_dense_agg_program(key_kinds, agg_ops, val_kinds, S: int,
     if axis is not None and any(fn in ("first", "last")
                                 for fn, _, _ in agg_ops):
         raise AssertionError("first/last are not sharded-lowerable")
+    def nonnull(rows, s_i):
+        if val_kinds[s_i] != "f":
+            return rows.valid
+        return jnp.logical_and(
+            rows.valid, jnp.logical_not(jnp.isnan(rows.vals[s_i])))
 
-    def program(keys, vals, mask):
-        n = mask.shape[0]
-        idx = lax.iota(jnp.int32, n)
-        valid = mask
-        slot, ok, decoders = _dense_slots(keys, key_kinds, valid, S, axis)
-        seg = jnp.where(valid, slot, S)          # invalid → dropped
+    def member_plan(n):
+        """The members of the reduction, by domain (int/float) and
+        combiner: ``{name: (stack, dtype, gate, value)}`` — a row
+        contributes ``value`` where ``gate`` holds (``_contribution``).
+        Counts and row indices are bounded by the STATIC n, so whenever n
+        sits inside the accumulator's exact-integer window (2^53 / 2^24)
+        they ride the float stacks exactly; the integer stacks exist for
+        unbounded int VALUES (sums, min/max), which must never round."""
+        plan: dict = {}
 
-        nonnull = {}
+        def want(stack, name, dtype, gate, value):
+            plan.setdefault(name, (stack, dtype, gate, value))
 
-        def vwide(s_i):
-            a = jnp.asarray(vals[s_i])
-            return (a.astype(jnp.int8) if a.dtype == jnp.bool_
-                    else a).astype(wide)
-
-        for s_i, v in enumerate(vals):
-            a = jnp.asarray(v)
-            if val_kinds[s_i] == "f":
-                nonnull[s_i] = jnp.logical_and(
-                    valid, jnp.logical_not(jnp.isnan(a)))
-            else:
-                nonnull[s_i] = valid
-
-        # ---- stacked additive scatters: every sum-like member in ONE
-        # (n, C) segment_sum per domain (int/float) — scatter overhead
-        # amortizes across the stacked columns. Counts and row indices
-        # are bounded by the STATIC n, so whenever n sits inside the
-        # accumulator's exact-integer window (2^53 / 2^24) they ride the
-        # float stacks exactly — the common all-float plan then needs
-        # only two scatters; the integer stacks exist for unbounded int
-        # VALUES (sums, min/max), which must never round.
-        stacks = {"ai": [], "af": [], "mf": [], "mi": [], "xi": []}
-        index: dict[str, tuple[str, int]] = {}
-
-        def want(stack, name, arr):
-            if name not in index:
-                index[name] = (stack, len(stacks[stack]))
-                stacks[stack].append(arr)
+        def valid(r):
+            return r.valid
 
         # counts/indices are bounded by the GLOBAL row count (n per shard
         # × world shards) — the exactness window must hold for the merged
         # totals, not just one shard's partials
         small_n = n * world < (1 << (53 if acc == jnp.float64 else 24))
-        cstk = "af" if small_n else "ai"
-        cdt = acc if small_n else wide
-        want(cstk, "present", valid.astype(cdt))
-        big_f = jnp.asarray(jnp.inf, acc)
-        big_i = jnp.asarray(jnp.iinfo(wide).max, wide)
-        small_i = jnp.asarray(jnp.iinfo(wide).min, wide)
+        cstk, cdt = ("af", acc) if small_n else ("ai", wide)
+        want(cstk, "present", cdt, valid, lambda r: 1)
         for fn, s_i, ig in agg_ops:
             if s_i < 0:
                 continue
-            nn = nonnull[s_i]
+            is_float = val_kinds[s_i] == "f"
+
+            def nn(r, s_i=s_i):
+                return nonnull(r, s_i)
+
+            def v(r, s_i=s_i):
+                return r.vals[s_i]
+
             # every referenced slot carries its non-null count: the
             # empty→NULL rule (all-null float groups) needs it for
-            # min/max/first/last too, and one more stacked column is free
-            want(cstk, f"cnt{s_i}", nn.astype(cdt))
+            # min/max/first/last too
+            want(cstk, f"cnt{s_i}", cdt, nn, lambda r: 1)
             if fn in ("sum", "avg", "stddev", "variance", "stddev_pop",
                       "var_pop"):
-                if val_kinds[s_i] != "f":
-                    want("ai", f"sum{s_i}",
-                         jnp.where(valid, vwide(s_i), jnp.zeros((), wide)))
+                if is_float:
+                    want("af", f"sum{s_i}", acc, nn, v)
                 else:
-                    vf = jnp.asarray(vals[s_i]).astype(acc)
-                    want("af", f"sum{s_i}",
-                         jnp.where(nn, vf, jnp.zeros((), acc)))
+                    want("ai", f"sum{s_i}", wide, valid, v)
             elif fn in ("min", "max"):
-                if val_kinds[s_i] == "f":
-                    vf = jnp.asarray(vals[s_i]).astype(acc)
-                    arr = (jnp.where(nn, vf, big_f) if fn == "min"
-                           else jnp.where(nn, -vf, big_f))
-                    want("mf", f"{fn}{s_i}", arr)
-                elif fn == "min":
-                    want("mi", f"min{s_i}",
-                         jnp.where(valid, vwide(s_i), big_i))
+                if is_float:     # max rides the min stack via negation
+                    want("mf", f"{fn}{s_i}", acc, nn,
+                         v if fn == "min" else lambda r, v=v: -v(r))
                 else:
-                    want("xi", f"max{s_i}",
-                         jnp.where(valid, vwide(s_i), small_i))
-            elif fn == "first":
-                gate = nn if ig else valid
-                if small_n:
-                    want("mf", f"fst{s_i}{ig}",
-                         jnp.where(gate, idx.astype(acc), big_f))
-                else:
-                    want("mi", f"fst{s_i}{ig}",
-                         jnp.where(gate, idx.astype(wide), big_i))
-            elif fn == "last":
-                gate = nn if ig else valid
-                if small_n:
-                    # ride the min stack via negation (indices are exact)
-                    want("mf", f"lst{s_i}{ig}",
-                         jnp.where(gate, -idx.astype(acc), big_f))
-                else:
-                    want("xi", f"lst{s_i}{ig}",
-                         jnp.where(gate, idx.astype(wide),
-                                   jnp.asarray(-1, wide)))
-
-        reduced = {}
-        for stack, red in (("ai", jax.ops.segment_sum),
-                           ("af", jax.ops.segment_sum),
-                           ("mf", jax.ops.segment_min),
-                           ("mi", jax.ops.segment_min),
-                           ("xi", jax.ops.segment_max)):
-            if stacks[stack]:
-                reduced[stack] = red(jnp.stack(stacks[stack], axis=1),
-                                     seg, num_segments=S)
-        if axis is not None:
-            # THE cross-shard merge: one collective per populated stack
-            # (additive → psum, min → pmin, max → pmax); after it every
-            # shard holds the identical global slot tables and the rest
-            # of the program computes replicated
-            _merge = {"ai": lax.psum, "af": lax.psum, "mf": lax.pmin,
-                      "mi": lax.pmin, "xi": lax.pmax}
-            with _obs.scope("exchange"):
-                reduced = {stack: _merge[stack](r, axis)
-                           for stack, r in reduced.items()}
-
-        def table(name):
-            stack, j = index[name]
-            return reduced[stack][:, j]
-
-        present = table("present") > 0
-        groups = jnp.sum(present.astype(jnp.int32))
-
-        def fsum(s_i):
-            s = table(f"sum{s_i}")
-            return s if val_kinds[s_i] == "f" else s.astype(acc)
-
-        # ---- variance family second pass (only when requested): the
-        # same two-pass Σ(v-μ)² the host path computes
-        var_cols = []
-        var_index = {}
-        need_var = [s_i for fn, s_i, _ in agg_ops
-                    if fn in ("stddev", "variance", "stddev_pop",
-                              "var_pop")]
-        if need_var:
-            seg_c = jnp.clip(seg, 0, S - 1)
-            for s_i in dict.fromkeys(need_var):
-                nn = nonnull[s_i]
-                vf = jnp.asarray(vals[s_i]).astype(acc)
-                mu = fsum(s_i) / table(f"cnt{s_i}").astype(acc)
-                d = jnp.where(nn, vf - jnp.take(mu, seg_c),
-                              jnp.zeros((), acc))
-                var_index[s_i] = len(var_cols)
-                var_cols.append(d * d)
-            ssd = jax.ops.segment_sum(
-                jnp.stack(var_cols, axis=1), seg, num_segments=S)
-            if axis is not None:
-                # decomposable variance: the per-shard Σ(v-μ)² partials
-                # (μ already global from the merged sum/count tables)
-                # psum into the global second moment
-                ssd = lax.psum(ssd, axis)
-
-        comp = _compact_index(present, S)
-        nan = jnp.asarray(jnp.nan, acc)
-
-        key_outs = tuple(dec(comp) for dec in decoders)
-
-        agg_outs = []
-        for fn, s_i, ig in agg_ops:
-            if fn == "count" and s_i < 0:
-                agg_outs.append(jnp.take(table("present"), comp)
-                                .astype(int_dtype()))
-                continue
-            vs = jnp.asarray(vals[s_i])
-            cnt = jnp.take(table(f"cnt{s_i}"), comp)
-            if fn == "count":
-                agg_outs.append(cnt.astype(int_dtype()))
-            elif fn == "sum":
-                s = jnp.take(table(f"sum{s_i}"), comp)
-                if val_kinds[s_i] != "f":
-                    agg_outs.append(s.astype(int_dtype()))
-                else:
-                    agg_outs.append(jnp.where(cnt > 0, s, nan)
-                                    .astype(vs.dtype))
-            elif fn == "avg":
-                agg_outs.append((jnp.take(fsum(s_i), comp)
-                                 / cnt.astype(acc)).astype(float_dtype()))
-            elif fn in ("stddev", "variance", "stddev_pop", "var_pop"):
-                sd = jnp.take(ssd[:, var_index[s_i]], comp)
-                cf = cnt.astype(acc)
-                if fn in ("stddev", "variance"):
-                    var = jnp.where(cnt > 1,
-                                    sd / jnp.maximum(cf - 1, 1), nan)
-                else:
-                    var = jnp.where(cnt > 0, sd / jnp.maximum(cf, 1),
-                                    nan)
-                out = var if fn in ("variance", "var_pop") \
-                    else jnp.sqrt(var)
-                agg_outs.append(out.astype(float_dtype()))
-            elif fn in ("min", "max"):
-                m = jnp.take(table(f"{fn}{s_i}"), comp)
-                if val_kinds[s_i] == "f":
-                    if fn == "max":
-                        m = -m
-                    agg_outs.append(jnp.where(cnt > 0, m, nan)
-                                    .astype(vs.dtype))
-                else:
-                    agg_outs.append(m.astype(vs.dtype))
+                    want("mi" if fn == "min" else "xi", f"{fn}{s_i}", wide,
+                         valid, v)
             elif fn in ("first", "last"):
                 tag = "fst" if fn == "first" else "lst"
-                pos = jnp.take(table(f"{tag}{s_i}{ig}"), comp)
-                if fn == "last" and index[f"{tag}{s_i}{ig}"][0] == "mf":
-                    pos = -pos         # small-n: last rode the min stack
-                pi = jnp.clip(pos, 0, n - 1).astype(jnp.int32)
-                picked = jnp.take(vs, pi)
-                if ig and val_kinds[s_i] == "f":
-                    agg_outs.append(jnp.where(
-                        cnt > 0, picked, jnp.asarray(jnp.nan, vs.dtype)))
+                gate = nn if ig else valid
+                if small_n:      # last rides the min stack via negation
+                    want("mf", f"{tag}{s_i}{ig}", acc, gate,
+                         (lambda r: r.idx) if fn == "first"
+                         else (lambda r: -r.idx))
                 else:
-                    agg_outs.append(picked)
-            else:  # pragma: no cover - distinct aggs never lower dense
-                raise AssertionError(fn)
-        return key_outs, tuple(agg_outs), groups, ok
+                    want("mi" if fn == "first" else "xi",
+                         f"{tag}{s_i}{ig}", wide, gate, lambda r: r.idx)
+        return plan
+
+    def program(keys, vals, mask):
+        n = mask.shape[0]
+        keys = tuple(jnp.asarray(k) for k in keys)
+        vals = tuple(jnp.asarray(v) for v in vals)
+        with _obs.scope("grouped.slots"):
+            slots, ok, decoders, total = _dense_slots(
+                keys, key_kinds, mask, S, axis)
+            # the tile tier, chosen from the traced key range: a plan
+            # whose verdict already failed takes it with no pass at all
+            tiled = jnp.logical_and(ok, total <= min(_TILE, S))
+            passes = jnp.where(
+                tiled, jnp.ceil(total / _TILE_CHUNK), 0).astype(jnp.int32)
+            use_tile = jnp.logical_or(tiled, jnp.logical_not(ok))
+
+        rows = _Rows(keys, vals, mask, slots, S)     # invalid → dropped
+        plan = member_plan(n)
+        tile = min(_TILE, S)
+
+        def vary(x):
+            # inside shard_map a loop's carry is per-shard from its first
+            # step
+            return x if axis is None \
+                else lax.pcast(x, (axis,), to="varying")
+
+        def tier(size, reduce):
+            """Rows to result columns of static length ``size``: the
+            tables of ``size`` slots by ``reduce``, their cross-shard
+            merge, the variance pass, compaction and the outputs. The two
+            tiers differ in ``reduce`` and in ``size`` only — compacting a
+            128-slot table costs nothing, compacting it padded to S slots
+            cost 25 ms a job on the chip."""
+            def reduce_plan(plan):
+                """{name: (size,) table} of {name: member}."""
+                with _obs.scope("grouped.reduce"):
+                    tables = dict(zip(plan, reduce(list(plan.values()))))
+                if axis is not None:
+                    # THE cross-shard merge: one collective per populated
+                    # stack (additive → psum, min → pmin, max → pmax);
+                    # after it every shard holds the identical global slot
+                    # tables and the rest of the program computes replicated
+                    with _obs.scope("exchange"):
+                        for st in _STACK_OPS:
+                            names = [k for k, m in plan.items()
+                                     if m[0] == st]
+                            if names:
+                                merged = _STACK_MERGE[st](jnp.stack(
+                                    [tables[k] for k in names]), axis)
+                                tables.update(zip(names, merged))
+                return tables
+
+            tables = reduce_plan(plan)
+            table = tables.__getitem__
+
+            present = table("present") > 0
+            groups = jnp.sum(present.astype(jnp.int32))
+
+            def fsum(s_i):
+                s = table(f"sum{s_i}")
+                return s if val_kinds[s_i] == "f" else s.astype(acc)
+
+            # ---- variance family second pass (only when requested): the
+            # same two-pass Σ(v-μ)² the host path computes; decomposable, so
+            # the per-shard partials (μ already global from the merged
+            # sum/count tables) psum into the global second moment
+            need_var = [s_i for fn, s_i, _ in agg_ops
+                        if fn in ("stddev", "variance", "stddev_pop",
+                                  "var_pop")]
+            if need_var:
+                def squared_gap(r, s_i):
+                    mu = fsum(s_i) / table(f"cnt{s_i}").astype(acc)
+                    d = r.vals[s_i].astype(acc) - jnp.take(
+                        mu, jnp.clip(r.seg, 0, size - 1))
+                    return d * d
+
+                ssd = reduce_plan({
+                    s_i: ("af", acc, lambda r, s_i=s_i: nonnull(r, s_i),
+                          lambda r, s_i=s_i: squared_gap(r, s_i))
+                    for s_i in dict.fromkeys(need_var)})
+
+            with _obs.scope("grouped.compact"):
+                comp = _compact_index(present, size)
+                key_outs = tuple(dec(comp) for dec in decoders)
+            nan = jnp.asarray(jnp.nan, acc)
+
+            agg_outs = []
+            for fn, s_i, ig in agg_ops:
+                if fn == "count" and s_i < 0:
+                    agg_outs.append(jnp.take(table("present"), comp)
+                                    .astype(int_dtype()))
+                    continue
+                vs = vals[s_i]
+                cnt = jnp.take(table(f"cnt{s_i}"), comp)
+                if fn == "count":
+                    agg_outs.append(cnt.astype(int_dtype()))
+                elif fn == "sum":
+                    s = jnp.take(table(f"sum{s_i}"), comp)
+                    if val_kinds[s_i] != "f":
+                        agg_outs.append(s.astype(int_dtype()))
+                    else:
+                        agg_outs.append(jnp.where(cnt > 0, s, nan)
+                                        .astype(vs.dtype))
+                elif fn == "avg":
+                    agg_outs.append((jnp.take(fsum(s_i), comp)
+                                     / cnt.astype(acc)).astype(float_dtype()))
+                elif fn in ("stddev", "variance", "stddev_pop", "var_pop"):
+                    sd = jnp.take(ssd[s_i], comp)
+                    cf = cnt.astype(acc)
+                    if fn in ("stddev", "variance"):
+                        var = jnp.where(cnt > 1,
+                                        sd / jnp.maximum(cf - 1, 1), nan)
+                    else:
+                        var = jnp.where(cnt > 0, sd / jnp.maximum(cf, 1),
+                                        nan)
+                    out = var if fn in ("variance", "var_pop") \
+                        else jnp.sqrt(var)
+                    agg_outs.append(out.astype(float_dtype()))
+                elif fn in ("min", "max"):
+                    m = jnp.take(table(f"{fn}{s_i}"), comp)
+                    if val_kinds[s_i] == "f":
+                        if fn == "max":
+                            m = -m
+                        agg_outs.append(jnp.where(cnt > 0, m, nan)
+                                        .astype(vs.dtype))
+                    else:
+                        agg_outs.append(m.astype(vs.dtype))
+                elif fn in ("first", "last"):
+                    tag = "fst" if fn == "first" else "lst"
+                    pos = jnp.take(table(f"{tag}{s_i}{ig}"), comp)
+                    if fn == "last" and plan[f"{tag}{s_i}{ig}"][0] == "mf":
+                        pos = -pos         # small-n: last rode the min stack
+                    pi = jnp.clip(pos, 0, n - 1).astype(jnp.int32)
+                    picked = jnp.take(vs, pi)
+                    if ig and val_kinds[s_i] == "f":
+                        agg_outs.append(jnp.where(
+                            cnt > 0, picked, jnp.asarray(jnp.nan, vs.dtype)))
+                    else:
+                        agg_outs.append(picked)
+                else:  # pragma: no cover - distinct aggs never lower dense
+                    raise AssertionError(fn)
+            return key_outs, tuple(agg_outs), groups
+
+        def padded(outs):
+            """The tile tier's columns at the program's static length."""
+            key_outs, agg_outs, groups = outs
+
+            def grow(a):
+                return jnp.concatenate([a, jnp.zeros((S - tile,), a.dtype)])
+
+            if S == tile:
+                return outs
+            return (tuple(grow(a) for a in key_outs),
+                    tuple(grow(a) for a in agg_outs), groups)
+
+        key_outs, agg_outs, groups = lax.cond(
+            use_tile,
+            lambda: padded(tier(tile, lambda members: _tile_tables(
+                members, rows, passes, tile, vary))),
+            lambda: tier(S, lambda members: _scatter_tables(
+                members, rows, S, vary)))
+        return key_outs, agg_outs, groups, ok, tiled
 
     return lambda: program
 
@@ -1168,7 +1411,32 @@ def _build_sorted_agg_program(key_kinds, agg_ops, val_kinds):
 # Grouped aggregation entry point
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnums=1)
+def _pad_tree(tree, b: int):
+    return jax.tree_util.tree_map(
+        lambda a: pad_rows(a, b, fresh=False), tree)
+
+
+def _padded(tree, n: int, b: int):
+    """The ``n``-row plan inputs of ``tree`` at ``b`` row slots in ONE
+    dispatch (per-array eager pads cost three dispatches each); as they
+    are when the bucket IS ``n`` — no copy of an n-row column."""
+    if n == b:
+        return jax.tree_util.tree_map(jnp.asarray, tree)
+    return _pad_tree(tree, b)
+
+
+def _read_verdict(tree):
+    """THE blocking read of a grouped/sort plan: its few scalars (group
+    count, fit verdict) in one counted frame-boundary sync."""
+    counters.increment("frame.host_sync")
+    host = jax.device_get(tree)
+    host_read(sum(a.nbytes for a in jax.tree_util.tree_leaves(host)))
+    return host
+
+
 def _run_plan(fn, args, before, sp):
+    counters.increment("grouped.rows", int(args[-1].shape[0]))
     out = fn(*args)
     compiled = counters.get("grouped.compile") > before
     # plan_key: the cost-observatory join handle (attribute read, no
@@ -1265,10 +1533,9 @@ def grouped_agg(frame, keys, agg_list):
         shard = None
 
     b = n if sharded else bucket_size(n)
-    keys_in = tuple(pad_rows(a, b, fresh=False) for a in key_arrs)
-    vals_in = tuple(pad_rows(a, b, fresh=False) for a in val_arrs)
-    mask_in = pad_rows(jnp.asarray(mask, jnp.bool_), b, fresh=False)
-    args = (keys_in, vals_in, mask_in)
+    args = keys_in, vals_in, mask_in = _padded(
+        (tuple(key_arrs), tuple(val_arrs), jnp.asarray(mask, jnp.bool_)),
+        n, b)
 
     S = min(_DENSE_MAX, max(2 * b, 16))
 
@@ -1342,12 +1609,11 @@ def grouped_agg(frame, keys, agg_list):
             fn.stats_key = stats_key
             try:
                 _faults.inject("shard_merge")
-                key_outs, agg_outs, groups, fit = _run_plan(
+                key_outs, agg_outs, groups, fit, tiled = _run_plan(
                     fn, args, before, sp)
                 # ONE host sync: fit verdict + group count together
-                counters.increment("frame.host_sync")
                 syncs += 1
-                fit_h, g_h = jax.device_get((fit, groups))
+                fit_h, g_h, tiled_h = _read_verdict((fit, groups, tiled))
             except jax.errors.JaxRuntimeError as e:
                 # shard_merge ladder: a device fault in the sharded
                 # merge gathers to single-device grouped execution —
@@ -1369,7 +1635,7 @@ def grouped_agg(frame, keys, agg_list):
                 if bool(fit_h):
                     g = int(g_h)
                     sp.set(groups=g, lowering="sharded-dense",
-                           shards=shard.devices)
+                           shards=shard.devices, tile=bool(tiled_h))
                     if config.costprof_enabled:
                         # exchange-volume accounting (device-cost
                         # observatory): the merge collective reduces the
@@ -1399,15 +1665,19 @@ def grouped_agg(frame, keys, agg_list):
             fn = _cached_plan(f"GD{S}|{struct}", _build_dense_agg_program(
                 tuple(key_kinds), tuple(agg_ops), tuple(val_kinds), S))
             fn.stats_key = stats_key
-            key_outs, agg_outs, groups, fit = _run_plan(
+            key_outs, agg_outs, groups, fit, tiled = _run_plan(
                 fn, args, before, sp)
             # ONE host sync: the fit verdict + group count together
-            counters.increment("frame.host_sync")
             syncs += 1
-            fit_h, g_h = jax.device_get((fit, groups))
+            fit_h, g_h, tiled_h = _read_verdict((fit, groups, tiled))
             if bool(fit_h):
                 g = int(g_h)
-                sp.set(groups=g, lowering="dense")
+                if bool(tiled_h):
+                    counters.increment("grouped.tile")
+                    sp.set(groups=g, lowering="dense-tile",
+                           blocks=-(-b // _tile_blocks(b)[0]))
+                else:
+                    sp.set(groups=g, lowering="dense")
             else:
                 counters.increment("grouped.dense_miss")
                 if stats_on:
@@ -1425,9 +1695,8 @@ def grouped_agg(frame, keys, agg_list):
                 tuple(key_kinds), tuple(agg_ops), tuple(val_kinds)))
             fn.stats_key = stats_key
             key_outs, agg_outs, groups = _run_plan(fn, args, before, sp)
-            counters.increment("frame.host_sync")
             syncs += 1
-            g = int(groups)
+            g = int(_read_verdict(groups))
             sp.set(groups=g, lowering="sorted")
     if stats_on:
         _record_grouped_stats(
@@ -1435,16 +1704,12 @@ def grouped_agg(frame, keys, agg_list):
             counters.get("grouped.compile") - c_stats, syncs,
             card_key=cardinality_history_key("g", keys, key_arrs))
 
-    # per-column eager slices, deliberately NOT compiler._unpad_tree: that
-    # helper retraces per static slice length, which for the pipeline is
-    # the (few-valued) frame length but here would be the DATA-DEPENDENT
-    # group count — a retrace per distinct g costs far more than k+m
-    # trivial slice dispatches
-    out = {}
-    for name, arr in zip(keys, key_outs):
-        out[name] = arr[:g]
-    for a, arr in zip(agg_list, agg_outs):
-        out[a.name] = arr[:g]
+    # one slice program for all k+m outputs: it retraces per distinct
+    # group count, as each eager ``arr[:g]`` would (the slice length is
+    # static either way), and costs one dispatch instead of k+m
+    key_outs, agg_outs = _unpad_tree((tuple(key_outs), tuple(agg_outs)), g)
+    out = dict(zip(keys, key_outs))
+    out.update((a.name, arr) for a, arr in zip(agg_list, agg_outs))
     return Frame(out)
 
 
@@ -1481,7 +1746,8 @@ def device_sort(frame, names, ascending, nulls_first):
     gathered with ``jnp.take`` so device columns never round-trip.
 
     On accelerators the permutation comes from one jitted ``lax.sort``
-    program (one host sync: the valid-row count). On XLA:CPU — whose
+    program (one host sync: the valid-row count; none when the input is
+    compact — a groupBy result — and its n rows are all valid). On XLA:CPU — whose
     variadic sort is a scalar comparator loop several times slower than
     numpy's — the permutation is planned host-side from one batched pull
     of just the key columns + mask (the ``Frame.join`` "plan on host,
@@ -1509,7 +1775,7 @@ def device_sort(frame, names, ascending, nulls_first):
     if jax.default_backend() == "cpu":
         counters.increment("frame.host_sync")
         take = _host_sort_plan(key_arrs, specs, mask)
-        return Frame(_gather_columns(data, jnp.asarray(take),
+        return Frame(_gather_columns(data, jnp.asarray(take), len(take),
                                      host_idx=take))
 
     if getattr(frame, "_shard", None) is not None:
@@ -1533,33 +1799,41 @@ def device_sort(frame, names, ascending, nulls_first):
     b = bucket_size(n)
     before = counters.get("grouped.compile")
     fn = _cached_plan(key, _build_sort_program(tuple(specs)))
-    keys_in = tuple(pad_rows(a, b, fresh=False) for a in key_arrs)
-    mask_in = pad_rows(jnp.asarray(mask, jnp.bool_), b, fresh=False)
+    args = _padded((tuple(key_arrs), jnp.asarray(mask, jnp.bool_)), n, b)
 
     with _obs.TRACER.span(
             "frame.grouped.flush", cat="frame", op="sort",
             keys=len(names), rows=n, bucket=b) as sp:
-        perm, nvalid = _run_plan(fn, (keys_in, mask_in), before, sp)
-        counters.increment("frame.host_sync")
-        nv = int(nvalid)
-    return Frame(_gather_columns(data, perm[:nv]))
+        perm, nvalid = _run_plan(fn, args, before, sp)
+        # a compact input (a groupBy result) has n valid rows: no read
+        nv = (n if frame._every_slot_valid()
+              else int(_read_verdict(nvalid)))
+    return Frame(_gather_columns(data, perm, nv))
 
 
-def _gather_columns(data, take_dev, host_idx=None):
-    """Materialize every column at the device index vector ``take_dev``.
-    Host (string) columns need the indices host-side — one extra sync,
-    only paid when such columns exist (or free when the caller already
-    planned host-side)."""
-    out = {}
+@functools.partial(jax.jit, static_argnums=2)
+def _take_tree(cols, take, head: int):
+    idx = take[:head]
+    return tuple(jnp.take(c, idx, axis=0) for c in cols)
+
+
+def _gather_columns(data, take_dev, head: int, host_idx=None):
+    """Materialize every column at the first ``head`` entries of the
+    device index vector ``take_dev``: the device columns in ONE dispatch
+    (it retraces per distinct ``head``, as the eager slice and takes
+    would). Host (string) columns need the indices host-side — one extra
+    sync, only paid when such columns exist (or free when the caller
+    already planned host-side)."""
+    dev = [name for name, arr in data.items() if not _is_host_col(arr)]
+    out = dict(zip(dev, _take_tree(
+        tuple(jnp.asarray(data[name]) for name in dev), take_dev, head)))
     for name, arr in data.items():
         if _is_host_col(arr):
             if host_idx is None:
                 counters.increment("frame.host_sync")
-                host_idx = _host_index(take_dev)
+                host_idx = _host_index(take_dev[:head])
             out[name] = _host_gather(arr, host_idx)
-        else:
-            out[name] = jnp.take(jnp.asarray(arr), take_dev, axis=0)
-    return out
+    return {name: out[name] for name in data}
 
 
 # ---------------------------------------------------------------------------
@@ -1654,15 +1928,14 @@ def device_unique(frame, key_names):
     before = counters.get("grouped.compile")
     fn = _cached_plan(key, _build_unique_program(tuple(key_kinds)))
     fn.stats_key = key
-    keys_in = tuple(pad_rows(a, b, fresh=False) for a in key_arrs)
-    mask_in = pad_rows(jnp.asarray(mask, jnp.bool_), b, fresh=False)
+    args = _padded((tuple(key_arrs), jnp.asarray(mask, jnp.bool_)), n, b)
 
     stats_on = config.stats_enabled
     t_stats = time.perf_counter() if stats_on else 0.0
     with _obs.TRACER.span(
             "frame.grouped.flush", cat="frame", op="distinct",
             keys=len(key_arrs), rows=n, bucket=b) as sp:
-        keep, groups = _run_plan(fn, (keys_in, mask_in), before, sp)
+        keep, groups = _run_plan(fn, args, before, sp)
         counters.increment("frame.host_sync")
         g = int(groups)
         sp.set(groups=g)
@@ -1671,7 +1944,7 @@ def device_unique(frame, key_names):
             key, n, g, (time.perf_counter() - t_stats) * 1e3,
             counters.get("grouped.compile") - before, 1,
             card_key=card_key)
-    return Frame(_gather_columns(data, keep[:g]))
+    return Frame(_gather_columns(data, keep, g))
 
 
 # --- BEGIN HOST FALLBACK (numpy allowed: object-array gathers + the -------
@@ -1700,6 +1973,7 @@ def _host_sort_plan(key_arrs, specs, mask):
     # dqlint: ok(host-sync): counted by the device-sort entry — the CPU
     # branch increments frame.host_sync immediately before planning here
     pulled = jax.device_get(tuple(key_arrs) + (mask,))
+    host_read(sum(np.asarray(a).nbytes for a in pulled))
     m = np.asarray(pulled[-1], bool)
     vi = np.nonzero(m)[0]
     arrays = [np.asarray(k)[vi] for k in pulled[:-1]]
@@ -1764,5 +2038,6 @@ def _sharded_unique(frame, data, key_arrs, key_kinds, store,
             key, n, g, (time.perf_counter() - t_stats) * 1e3,
             counters.get("grouped.compile") - before, 1,
             card_key=card_key)
-    return Frame(_gather_columns(data, jnp.asarray(keep), host_idx=keep))
+    return Frame(_gather_columns(data, jnp.asarray(keep), len(keep),
+                                 host_idx=keep))
 # --- END HOST FALLBACK ----------------------------------------------------

@@ -165,6 +165,10 @@ METRIC_NAMES = {
                                "grouped ops host-degraded by the fault "
                                "ladder"),
     "grouped.dense_miss": ("counter", "dense lowering misfits rerouted"),
+    "grouped.rows": ("counter", "row slots handed to grouped/sort/"
+                                "unique programs"),
+    "grouped.tile": ("counter", "grouped plans reduced by the dense "
+                                "lowering's tile tier"),
     "grouped.evict": ("counter", "grouped plan-cache LRU evictions"),
     "grouped.shard_gather": ("counter",
                              "sharded grouped/distinct programs gathered "

@@ -579,7 +579,7 @@ def test_grouped_flush_span(session):
         assert spans
         s = spans[-1]
         assert s.attrs["op"] == "group_by"
-        assert s.attrs["lowering"] in ("dense", "sorted")
+        assert s.attrs["lowering"] in ("dense", "dense-tile", "sorted")
         assert s.attrs["cache"] in ("compile", "hit")
         assert s.attrs["groups"] >= 1
     finally:

@@ -777,3 +777,106 @@ class TestTracingConf:
             assert "tail" in rep and "ring_size" in rep["tail"]
         finally:
             s.stop()
+
+
+# ---------------------------------------------------------------------------
+# Grouped execution's spans, scopes and counters (ISSUE 28)
+# ---------------------------------------------------------------------------
+
+class TestGroupedExecutionNames:
+    """``frame.grouped.flush`` names the tier that ran, the grouped program
+    carries its three inner scopes, and the grouped verdict and the sort
+    pull are counted host reads."""
+
+    @pytest.fixture
+    def grouped_view(self, session):
+        import jax.numpy as jnp
+        import numpy as np
+
+        from sparkdq4ml_tpu.ops import segments
+
+        segments.clear_cache()
+        frame = session.create_data_frame(
+            {"k": jnp.asarray(np.arange(4_000) % 5, jnp.int32),
+             "wide": jnp.asarray(np.arange(4_000) % 900, jnp.int32),
+             "v": jnp.arange(4_000.0)})
+        frame.create_or_replace_temp_view("g")
+        yield session
+        segments.clear_cache()
+
+    def _flushes(self, session, query):
+        obs.enable()
+        before = profiling.counters.snapshot()
+        session.sql(query).to_pydict()
+        moved = {k: v - before.get(k, 0)
+                 for k, v in profiling.counters.snapshot().items()
+                 if v != before.get(k, 0)}
+        spans = [s for s in obs.TRACER.spans()
+                 if s.name == "frame.grouped.flush"]
+        obs.disable()
+        return spans, moved
+
+    def test_flush_span_names_the_tile_tier(self, grouped_view):
+        spans, moved = self._flushes(
+            grouped_view, "SELECT k, sum(v) AS s, count(*) AS n FROM g "
+                          "GROUP BY k")
+        (span,) = [s for s in spans if s.attrs["op"] == "group_by"]
+        assert span.attrs["lowering"] == "dense-tile"
+        assert span.attrs["groups"] == 5 and span.attrs["rows"] == 4_000
+        assert span.attrs["blocks"] == 1
+        assert moved["grouped.tile"] == 1
+        assert moved["grouped.rows"] == 4_096           # the plan's bucket
+        assert "grouped.fallback" not in moved
+
+    def test_flush_span_names_the_scatter_tier_above_the_tile(
+            self, grouped_view):
+        spans, moved = self._flushes(
+            grouped_view, "SELECT wide, sum(v) AS s FROM g GROUP BY wide")
+        (span,) = [s for s in spans if s.attrs["op"] == "group_by"]
+        assert span.attrs["lowering"] == "dense"
+        assert span.attrs["groups"] == 900 and "blocks" not in span.attrs
+        assert "grouped.tile" not in moved
+        assert moved["grouped.rows"] == 4_096
+
+    def test_grouped_program_carries_its_three_scopes(self, grouped_view):
+        import jax
+
+        from sparkdq4ml_tpu.ops import segments
+
+        grouped_view.sql("SELECT k, avg(v) AS m FROM g GROUP BY k") \
+            .to_pydict()
+        (h,) = [h for h in segments.program_handles()]
+        text = jax.jit(h.fn).lower(*h.args, **h.kwargs).as_text(
+            debug_info=True)
+        assert "dq.grouped/dq.grouped.slots/" in text
+        # the tier is chosen inside the program: each branch of the cond
+        # reduces and compacts under its own scopes
+        for branch in ("branch_0_fun", "branch_1_fun"):
+            for scope in ("grouped.reduce", "grouped.compact"):
+                assert f"dq.grouped/cond/{branch}/dq.{scope}/" in text, \
+                    (branch, scope)
+
+    def test_new_counters_are_declared(self):
+        assert obs.METRIC_NAMES["grouped.rows"][0] == "counter"
+        assert obs.METRIC_NAMES["grouped.tile"][0] == "counter"
+
+    def test_grouped_verdict_and_sort_pull_are_counted_reads(
+            self, grouped_view):
+        import jax.numpy as jnp
+
+        frame = grouped_view.table("g")
+        profiling.counters.clear()
+        out = frame.group_by("k").agg({"v": "sum"})
+        # the verdict: fit, group count and tier, three scalars in one pull
+        assert profiling.counters.get("host.reads") == 1
+        assert profiling.counters.get("frame.host_sync") == 1
+        assert 0 < profiling.counters.get("host.read_bytes") <= 3 * 8
+        verdict = profiling.counters.get("host.read_bytes")
+        out = out.sort("k")
+        assert profiling.counters.get("host.reads") == 2
+        assert profiling.counters.get("frame.host_sync") == 2
+        # on the CPU backend the sort plans on the host from the key and
+        # the mask: 5 keys and 5 flags
+        pulled = profiling.counters.get("host.read_bytes") - verdict
+        key_bytes = jnp.asarray(out.to_pydict()["k"]).dtype.itemsize
+        assert pulled == 5 * key_bytes + 5
