@@ -53,9 +53,13 @@ class LogisticFitResult(NamedTuple):
 
 
 def _feature_stats(X, y, mask):
-    """Masked n, feature std (sample), for standardization — one pass."""
+    """Masked n, feature std (sample), for standardization — one pass.
+    ``mask`` is the boolean mask or the instance weights (0 on masked
+    rows); a row it zeroes is not read (``where``: a NaN there stays out
+    of the sums)."""
     w = mask.astype(X.dtype)
     n = jnp.sum(w)
+    X = jnp.where((w != 0)[:, None], X, 0)
     mean = (w @ X) / n
     var = (w @ (X * X)) / n - mean * mean
     denom = jnp.maximum(n - 1.0, 1.0)
@@ -92,7 +96,7 @@ def _logistic_core(X, y, mask, reg_param, alpha, n, std,
     d = X.shape[1]
     valid = std > 0
     sx = jnp.where(valid, std, 1.0)
-    Xs = (X / sx) * mask.astype(dt)[:, None]   # standardized, masked rows
+    Xs = jnp.where(mask[:, None], X / sx, 0)   # standardized, masked rows
     yv = y.astype(dt) * mask.astype(dt)
     wm = mask.astype(dt)
     wv = wm if weights is None else weights.astype(dt)
@@ -175,10 +179,20 @@ def _logistic_newton_core(X, y, mask, reg_param, alpha, n, std,
     sx = jnp.where(valid, std, 1.0)
     with _obs.scope("fit.pack"):       # standardise + intercept column
         wm = mask.astype(dt)
-        Xs = (X / sx) * wm[:, None]
         yv = y.astype(dt) * wm
         wv = wm if weights is None else weights.astype(dt)
-        Za = jnp.concatenate([Xs, wm[:, None]], axis=1)
+        # Za = [X / sx, 1]·mask is the one (n, d+1) array the pack writes
+        # and every pass of the loop re-reads. Written as a pad (the ones
+        # column) under one select, it is one fusion over X; as
+        # concatenate([Xs, wm[:, None]]) XLA wrote the standardised Xs as
+        # a second n-row copy and relaid wm out for the concatenation.
+        # The barrier keeps it from splitting Za @ v back into
+        # Xs @ v[:d] + wm * v[d], which needs that copy again.
+        Za = jax.lax.optimization_barrier(jnp.where(
+            mask[:, None],
+            jnp.pad(X, ((0, 0), (0, 1)), constant_values=1.0)
+            / jnp.concatenate([sx, jnp.ones((1,), dt)]),
+            jnp.zeros((), dt)))
 
     u1 = jnp.ones((d,), dt) if standardization \
         else jnp.where(valid, 1.0 / sx, 0.0)
@@ -388,7 +402,7 @@ def _softmax_core(X, y, mask, reg_param, alpha, n, std, num_classes,
     K = num_classes
     valid = std > 0
     sx = jnp.where(valid, std, 1.0)
-    Xs = (X / sx) * mask.astype(dt)[:, None]   # standardized, masked rows
+    Xs = jnp.where(mask[:, None], X / sx, 0)   # standardized, masked rows
     wm = mask.astype(dt)
     wv = wm if weights is None else weights.astype(dt)
     Y1 = jax.nn.one_hot(y.astype(jnp.int32), K, dtype=dt) * wm[:, None]
@@ -471,7 +485,7 @@ def _softmax_newton_core(X, y, mask, reg_param, alpha, n, std, num_classes,
     valid = std > 0
     sx = jnp.where(valid, std, 1.0)
     wm = mask.astype(dt)
-    Xs = (X / sx) * wm[:, None]
+    Xs = jnp.where(mask[:, None], X / sx, 0)
     wv = wm if weights is None else weights.astype(dt)
     Y1 = jax.nn.one_hot(y.astype(jnp.int32), K, dtype=dt) * wm[:, None]
     Za = jnp.concatenate([Xs, wm[:, None]], axis=1)      # (n, d+1)
@@ -537,7 +551,8 @@ def _softmax_newton_core(X, y, mask, reg_param, alpha, n, std, num_classes,
 
 
 def _unpack_z(Z):
-    """Split the packed design ``Z = [X, y, 1]·mask`` (pack_design layout).
+    """Split the packed design ``Z = [X, y, 1]·mask`` (pack_design layout):
+    the packed entry of the compiled fits (the columns entry skips this).
 
     The pre-masked columns are exactly what the logistic core consumes —
     it only ever reads X, y masked, and ``w² = w`` for a boolean mask, so
@@ -561,6 +576,48 @@ def _unpack_zw(Z):
     return Z[:, :d], Z[:, d], w > 0, w
 
 
+def _split_design(design, weighted: bool = False):
+    """``(X, y, mask, w)`` of a compiled fit's first argument, whichever
+    entry it came through: the frame's columns (``DesignColumns``) or a
+    packed ``Z`` (``pack_design`` / ``pack_design_weighted``, told apart
+    by ``weighted``), sliced apart. ``w`` is ``None`` unweighted.
+
+    Of the columns, ``y`` and ``w`` come back zeroed where the mask drops
+    the row; ``X`` comes back as it is — the moments and the cores select
+    by the mask where they read it (``where``, not ``x * 0``: a NaN in a
+    filtered slot reaches no sum), and a cleaned ``X`` shared between
+    them would be written out as an ``(n, d)`` copy."""
+    from ..parallel.distributed import DesignColumns
+
+    if isinstance(design, DesignColumns):
+        X, y, mask, w = design
+        return (X, jnp.where(mask, y, 0), mask,
+                None if w is None else jnp.where(mask, w, 0))
+    if weighted:
+        return _unpack_zw(design)
+    return _unpack_z(design) + (None,)
+
+
+def _fit_design(X, y, mask, w, mesh):
+    """What the estimators hand a compiled fit, inside the ``fit.pack``
+    span: on one device the columns as they are (the program packs them:
+    ``lowering="in-program"``, counter ``fit.pack_in_program``); for a
+    mesh a packed ``Z`` placed row-sharded (``lowering="eager"``, counter
+    ``fit.pack_eager``: one pack program and one ``device_put``)."""
+    from ..parallel.distributed import (DesignColumns, pack_design,
+                                        pack_design_weighted, place_packed)
+    from ..utils.profiling import counters
+
+    if mesh is None:
+        with _obs.span("fit.pack", cat="fit", lowering="in-program"):
+            counters.increment("fit.pack_in_program")
+            return DesignColumns(X, y, mask, w)
+    with _obs.span("fit.pack", cat="fit", lowering="eager"):
+        Z = pack_design(X, y, mask) if w is None \
+            else pack_design_weighted(X, y, mask, w)
+        return place_packed(Z, mesh)
+
+
 def _pack_logistic_result(r: "LogisticFitResult"):
     """One output buffer: [coef(d) | intercept | iters | converged | history]
     (same layout as the linear path; decode with
@@ -577,14 +634,24 @@ def fused_logistic_fit_packed(mesh: Optional[Mesh], max_iter: int, tol: float,
                               fit_intercept: bool, standardization: bool,
                               weighted: bool = False,
                               solver: str = "fista"):
-    """One jitted program: stats pass + solver scan (+ per-iteration psum
+    """One jitted program: stats pass + solver loop (+ per-iteration psum
     when sharded). Mirrors the linear path's ``fused_linear_fit_packed``,
-    including its single-input/single-output dispatch discipline:
-    ``fit(Z, hyper) -> flat`` with ``Z = pack_design(X, y, mask)`` and
-    ``hyper = [regParam, elasticNetParam]``. With ``weighted=True`` the
-    input is ``pack_design_weighted(X, y, mask, w)`` — the last column
-    carries real instance weights (MLlib weightCol), and n/std/loss/grad
-    are their weighted forms.
+    including its two entries: ``fit(design, hyper) -> flat`` with
+    ``hyper = [regParam, elasticNetParam]`` and ``design`` either
+
+    * the frame's columns, ``DesignColumns(X, y, mask, w)`` — what the
+      estimator hands over on one device: the program masks, takes the
+      moments and standardises under the scope ``dq.fit.pack``, and the
+      only n-row matrix it writes is the one its loop re-reads; or
+    * a packed ``Z = pack_design(X, y, mask)`` — callers that hold one,
+      and the sharded path (row-sharded ``Z``), which slice it apart
+      (``_unpack_z``) and run the same core. With ``weighted=True`` the
+      packed input is ``pack_design_weighted(X, y, mask, w)`` — the last
+      column carries real instance weights (MLlib weightCol), and
+      n/std/loss/grad are their weighted forms; the columns entry carries
+      the weights as ``w``.
+
+    Which entry runs follows from the form of ``design`` alone.
 
     ``solver``: "fista" (the general elastic-net path) or "newton" (damped
     IRLS — L1-free penalties only; ``LogisticRegression.fit`` routes to it
@@ -592,16 +659,10 @@ def fused_logistic_fit_packed(mesh: Optional[Mesh], max_iter: int, tol: float,
     core = {"fista": _logistic_core,
             "newton": _logistic_newton_core}[solver]
 
-    def split(Z):
-        if weighted:
-            return _unpack_zw(Z)
-        X, y, mask = _unpack_z(Z)
-        return X, y, mask, None
-
     if mesh is None or mesh.devices.size <= 1:
-        def fit(Z, hyper):
-            with _obs.scope("fit.pack"):    # unpack + the moments pass
-                X, y, mask, w = split(Z)
+        def fit(design, hyper):
+            with _obs.scope("fit.pack"):    # split + the moments pass
+                X, y, mask, w = _split_design(design, weighted)
                 n, std = _feature_stats(X, y, mask if w is None else w)
             return _pack_logistic_result(core(
                 X, y, mask, hyper[0], hyper[1], n, std, max_iter,
@@ -609,7 +670,7 @@ def fused_logistic_fit_packed(mesh: Optional[Mesh], max_iter: int, tol: float,
     else:
         def local(Z, hyper):
             with _obs.scope("fit.pack"):
-                X, y, mask, w = split(Z)
+                X, y, mask, w = _split_design(Z, weighted)
                 n, std = _sharded_feature_stats(X,
                                                 mask if w is None else w)
             return _pack_logistic_result(core(
@@ -643,7 +704,7 @@ def _svc_core(X, y, mask, reg_param, n, std, max_iter, tol,
     d = X.shape[1]
     valid = std > 0
     sx = jnp.where(valid, std, 1.0)
-    Xs = (X / sx) * mask.astype(dt)[:, None]
+    Xs = jnp.where(mask[:, None], X / sx, 0)
     wm = mask.astype(dt)
     z = (2.0 * y.astype(dt) - 1.0) * wm         # ±1 labels, masked
 
@@ -694,15 +755,16 @@ def _svc_core(X, y, mask, reg_param, n, std, max_iter, tol,
 @functools.lru_cache(maxsize=None)
 def fused_svc_fit_packed(mesh: Optional[Mesh], max_iter: int, tol: float,
                          fit_intercept: bool, standardization: bool):
-    """One jitted program for LinearSVC: stats pass + Nesterov scan
-    (+ per-iteration psum when sharded); same single-input/single-output
-    dispatch discipline as the logistic path. ``hyper = [regParam, 0]``
-    (second slot reserved — the SVC penalty is L2-only, like MLlib)."""
+    """One jitted program for LinearSVC: stats pass + Nesterov loop
+    (+ per-iteration psum when sharded); the same two entries as the
+    logistic path (``DesignColumns`` on one device, a packed ``Z``
+    otherwise). ``hyper = [regParam, 0]`` (second slot reserved — the SVC
+    penalty is L2-only, like MLlib)."""
 
     if mesh is None or mesh.devices.size <= 1:
-        def fit(Z, hyper):
+        def fit(design, hyper):
             with _obs.scope("fit.pack"):
-                X, y, mask = _unpack_z(Z)
+                X, y, mask, _ = _split_design(design)
                 n, std = _feature_stats(X, y, mask)
             return _pack_logistic_result(_svc_core(
                 X, y, mask, hyper[0], n, std, max_iter, tol,
@@ -755,23 +817,18 @@ def fused_softmax_fit_packed(mesh: Optional[Mesh], num_classes: int,
                              fit_intercept: bool, standardization: bool,
                              weighted: bool = False,
                              solver: str = "fista"):
-    """Multinomial analogue of ``fused_logistic_fit_packed`` — same
-    single-input/single-output dispatch discipline and per-iteration psum
-    (and the same ``weighted`` / ``solver`` contracts; "newton" is the
-    L1-free block-Hessian IRLS, see ``_softmax_newton_core``)."""
+    """Multinomial analogue of ``fused_logistic_fit_packed`` — the same
+    two entries (``DesignColumns`` or a packed ``Z``), one output buffer
+    and per-iteration psum (and the same ``weighted`` / ``solver``
+    contracts; "newton" is the L1-free block-Hessian IRLS, see
+    ``_softmax_newton_core``)."""
     core = {"fista": _softmax_core,
             "newton": _softmax_newton_core}[solver]
 
-    def split(Z):
-        if weighted:
-            return _unpack_zw(Z)
-        X, y, mask = _unpack_z(Z)
-        return X, y, mask, None
-
     if mesh is None or mesh.devices.size <= 1:
-        def fit(Z, hyper):
+        def fit(design, hyper):
             with _obs.scope("fit.pack"):
-                X, y, mask, w = split(Z)
+                X, y, mask, w = _split_design(design, weighted)
                 n, std = _feature_stats(X, y, mask if w is None else w)
             return _pack_softmax_result(core(
                 X, y, mask, hyper[0], hyper[1], n, std, num_classes,
@@ -779,7 +836,7 @@ def fused_softmax_fit_packed(mesh: Optional[Mesh], num_classes: int,
     else:
         def local(Z, hyper):
             with _obs.scope("fit.pack"):
-                X, y, mask, w = split(Z)
+                X, y, mask, w = _split_design(Z, weighted)
                 n, std = _sharded_feature_stats(X,
                                                 mask if w is None else w)
             return _pack_softmax_result(core(
@@ -893,9 +950,7 @@ class LogisticRegression(Estimator):
 
     def _fit(self, frame: Frame, mesh, root) -> "LogisticRegressionModel":
         from ..config import float_dtype
-        from ..parallel.distributed import (pack_design,
-                                            pack_design_weighted,
-                                            place_packed, unpack_fit_result)
+        from ..parallel.distributed import unpack_fit_result
         from ..utils.profiling import counters as _counters
 
         if mesh is None:
@@ -944,15 +999,9 @@ class LogisticRegression(Estimator):
                 # NaN fails >= too (silent NaN poisoning must raise)
                 if stats.weight_bad:
                     raise ValueError("weights must be nonnegative")
-            with _obs.span("fit.pack", cat="fit"):
-                if weighted:
-                    Zd = place_packed(
-                        pack_design_weighted(X, y, mask,
-                                             jnp.where(mask, w, 0.0)), mesh)
-                else:
-                    Zd = place_packed(pack_design(X, y, mask), mesh)
-                hyper = jnp.asarray([self.reg_param, self.elastic_net_param],
-                                    float_dtype())
+            design = _fit_design(X, y, mask, w, mesh)
+            hyper = jnp.asarray([self.reg_param, self.elastic_net_param],
+                                float_dtype())
         shards = mesh.devices.size if mesh is not None else 1
         l1_free = (self.elastic_net_param == 0.0 or self.reg_param == 0.0)
 
@@ -976,7 +1025,7 @@ class LogisticRegression(Estimator):
                                                   self.standardization,
                                                   weighted=weighted,
                                                   solver=sm_solver)
-                result = unpack_softmax_result(fit_fn(Zd, hyper), K,
+                result = unpack_softmax_result(fit_fn(design, hyper), K,
                                                X.shape[1])
                 sv.set(iterations=int(result.iterations),
                        converged=bool(result.converged))
@@ -1020,7 +1069,7 @@ class LogisticRegression(Estimator):
                                                weighted=weighted,
                                                solver=solver)
             result = LogisticFitResult(
-                *unpack_fit_result(fit_fn(Zd, hyper), X.shape[1]))
+                *unpack_fit_result(fit_fn(design, hyper), X.shape[1]))
             sv.set(iterations=int(result.iterations),
                    converged=bool(result.converged))
         _counters.increment("solver.fits")
@@ -1513,8 +1562,7 @@ class LinearSVC(Estimator):
             return self._fit(frame, mesh, root)
 
     def _fit(self, frame: Frame, mesh, root) -> "LinearSVCModel":
-        from ..parallel.distributed import (pack_design, place_packed,
-                                            unpack_fit_result)
+        from ..parallel.distributed import unpack_fit_result
         from ..parallel.mesh import normalize_mesh
 
         if mesh is None:
@@ -1537,16 +1585,15 @@ class LinearSVC(Estimator):
                 if stats.label_bad or stats.label_min < 0 \
                         or stats.label_max > 1:
                     raise ValueError("LinearSVC requires binary 0/1 labels")
-            with _obs.span("fit.pack", cat="fit"):
-                Zd = place_packed(pack_design(X, y, mask), mesh)
-                hyper = jnp.asarray([self.reg_param, 0.0], float_dtype())
+            design = _fit_design(X, y, mask, None, mesh)
+            hyper = jnp.asarray([self.reg_param, 0.0], float_dtype())
         root.set(rows=int(X.shape[0]), features=int(X.shape[1]),
                  solver="fista")
         with _obs.span("fit.solve", cat="solver", solver="fista") as sv:
             fit_fn = fused_svc_fit_packed(mesh, self.max_iter, self.tol,
                                           self.fit_intercept,
                                           self.standardization)
-            r = unpack_fit_result(fit_fn(Zd, hyper), X.shape[1])
+            r = unpack_fit_result(fit_fn(design, hyper), X.shape[1])
             sv.set(iterations=int(r.iterations),
                    converged=bool(r.converged))
         root.set(iterations=int(r.iterations), converged=bool(r.converged))

@@ -4,6 +4,9 @@
 column (`DataQuality4MachineLearningApp.java:110-113`). TPU-first: the
 "vector column" is literally the feature matrix in HBM, laid out densely so
 the fit's Gramian is a single MXU matmul — there is no per-row vector object.
+``transform`` is one compiled program (``_assemble``): every input column
+in, the matrix out, one launch. The matrix is materialised — the fitted
+model's ``transform`` reads it again in the score stage.
 
 ``StandardScaler`` / ``MinMaxScaler`` / ``MaxAbsScaler`` are the adjacent
 MLlib feature estimators (same ``spark.ml.feature`` package the reference's
@@ -27,6 +30,59 @@ import numpy as np
 from ..config import float_dtype, int_dtype
 from ..utils import observability as _obs
 from .base import Estimator, Model, Transformer, host_fetch, persistable
+
+
+#: 1-D columns gathered by one chain of selects; a longer run is cut into
+#: blocks of this many (a chain does ``k`` selects an element).
+_CHAIN = 32
+
+
+def _scalar_block(columns, dtype):
+    """``k`` 1-D columns as one ``(n, k)`` block: column ``i`` broadcast
+    along the row and selected where the column index is ``i``. On the TPU
+    that is ONE loop fusion that reads the columns and writes the block;
+    ``concatenate([c[:, None] ...])`` relays every column out from its 1-D
+    tiling into an ``(n, 1)`` array first — a ``while`` loop a column —
+    and took 14.2 ms for 28 columns of 1.1e7 rows where this takes 8.6
+    (PERF.md section 6, PR 29)."""
+    n, k = columns[0].shape[0], len(columns)
+    wide = [jax.lax.broadcast_in_dim(c.astype(dtype), (n, k), (0,))
+            for c in columns]
+    index = jax.lax.broadcasted_iota(jnp.int32, (n, k), 1)
+    out = wide[-1]
+    for i in reversed(range(k - 1)):
+        out = jax.lax.select(index == i, wide[i], out)
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _assemble(columns, dtype):
+    """Every input column in, the ``(n, d)`` matrix out: ONE program a
+    tuple of column shapes and dtypes (each eager convert, ``[:, None]``
+    and the concatenate retraced per shape too, and was a launch and a
+    full pass of its own). A 1-D column is one feature, a 2-D vector
+    column its width; runs of 1-D columns are gathered by
+    :func:`_scalar_block`, and blocks meet in one concatenate."""
+    with _obs.scope("feature.assemble"):
+        blocks, run = [], []
+
+        def close_run():
+            if run:
+                blocks.append(_scalar_block(tuple(run), dtype))
+                run.clear()
+
+        for c in columns:
+            if c.ndim == 1:
+                run.append(c)
+                if len(run) == _CHAIN:
+                    close_run()
+            else:
+                close_run()
+                blocks.append(c.astype(dtype))
+        close_run()
+        if len(blocks) == 1:
+            return blocks[0]
+        return jax.lax.concatenate(blocks, 1)
 
 
 @persistable
@@ -65,12 +121,14 @@ class VectorAssembler(Transformer):
         dt = float_dtype()
         with _obs.span("feature.assemble", cat="feature",
                        columns=len(self.input_cols),
-                       rows=frame.num_slots) as s:
-            parts = []
-            for name in self.input_cols:
-                arr = jnp.asarray(frame._column_values(name), dt)
-                parts.append(arr[:, None] if arr.ndim == 1 else arr)
-            out = jnp.concatenate(parts, axis=1)
+                       rows=frame.num_slots, programs=1) as s:
+            # a host column is converted on the host, as jnp.asarray(a, dt)
+            # converted it (a 64-bit host column must not pass through
+            # jit's 32-bit canonical form on its way to dt)
+            parts = tuple(
+                a if isinstance(a, jax.Array) else np.asarray(a, dt)
+                for a in map(frame._column_values, self.input_cols))
+            out = _assemble(parts, dt)
             s.set(width=int(out.shape[1]))      # static shape, no read
             return frame.with_column(self.output_col, out)
 

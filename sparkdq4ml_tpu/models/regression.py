@@ -174,9 +174,10 @@ class LinearRegression(Estimator):
             return self._fit(frame, mesh, root)
 
     def _fit(self, frame: Frame, mesh, root) -> "LinearRegressionModel":
-        from ..parallel.distributed import (fused_linear_fit_packed,
+        from ..parallel.distributed import (DesignColumns,
+                                            fused_linear_fit_packed,
                                             pack_design, place_packed,
-                                            unpack_fit_result)
+                                            row_scale, unpack_fit_result)
         from ..utils import faults as _faults
         from ..utils import recovery as _recovery
         from ..utils.profiling import counters
@@ -187,12 +188,15 @@ class LinearRegression(Estimator):
 
             active = TpuSession.active()
             mesh = active.mesh if active is not None else None
+        if mesh is not None and mesh.devices.size <= 1:
+            mesh = None  # unify the single-device cache key
         with _obs.span("fit.prepare", cat="fit") as prep:
             with _obs.span("fit.extract", cat="fit"):
                 X, y, mask = _extract_xy(frame, self.features_col,
                                          self.label_col)
             d = X.shape[1]
             prep.set(rows=int(X.shape[0]), features=int(d))
+            w = None
             if self.weight_col is not None:
                 # Instance weights (MLlib weightCol): scaling packed rows
                 # by sqrt(w) makes the Gramian ZᵀZ = Σ w·zzᵀ — every
@@ -215,19 +219,23 @@ class LinearRegression(Estimator):
                     # raise, not silently poison the Gramian
                     if stats.weight_bad:
                         raise ValueError("weights must be nonnegative")
-                mask_b = mask
-                mask = mask.astype(float_dtype()) * jnp.sqrt(
-                    jnp.where(mask_b, w, 0.0))
             if self.loss == "huber":
-                return self._fit_huber(frame, X, y, mask)
-            with _obs.span("fit.pack", cat="fit"):
-                Z = pack_design(X, y, mask)
+                return self._fit_huber(frame, X, y, row_scale(mask, w))
+            # fit.pack: what stands between the columns and the compiled
+            # fit. On one device nothing does — the program takes the
+            # columns and packs them itself (lowering="in-program"); a
+            # mesh is handed a packed Z, placed row-sharded by the rung
+            # that runs on it (lowering="eager": one pack program).
+            with _obs.span("fit.pack", cat="fit",
+                           lowering="eager" if mesh is not None
+                           else "in-program"):
+                columns = DesignColumns(X, y, mask, w)
+                Z = None if mesh is None else \
+                    pack_design(X, y, row_scale(mask, w))
                 hyper = jnp.asarray([self.reg_param,
                                      self.elastic_net_param], float_dtype())
         solver_name = resolve_solver(self.solver, self.reg_param,
                                      self.elastic_net_param)
-        if mesh is not None and mesh.devices.size <= 1:
-            mesh = None  # unify the single-device cache key
 
         def make_call(m, sname):
             # Everything stays inside the closure: fallback rungs must
@@ -237,9 +245,13 @@ class LinearRegression(Estimator):
                 fit_fn = fused_linear_fit_packed(
                     m, sname, self.max_iter, self.tol, self.fit_intercept,
                     self.standardization)
-                Zd = place_packed(Z, m)
+                if m is None:
+                    counters.increment("fit.pack_in_program")
+                    design = columns
+                else:
+                    design = place_packed(Z, m)
                 return _faults.corrupt(
-                    "solver", unpack_fit_result(fit_fn(Zd, hyper), d))
+                    "solver", unpack_fit_result(fit_fn(design, hyper), d))
             return call
 
         # Fallback ladder: sharded fit → single-device fit → closed-form
@@ -273,14 +285,14 @@ class LinearRegression(Estimator):
             from ..utils import meminfo as _meminfo
 
             hist = np.asarray(result.objective_history, np.float64)
-            # input_bytes: static-shape estimate of the packed design
-            # the fit dispatched (the fit-node device-memory figure
+            # input_bytes: static-shape estimate of the columns the fit
+            # dispatched (the fit-node device-memory figure
             # EXPLAIN/memory_report cross-reference) — metadata only,
             # never a device read.
             root.set(iterations=iters, converged=bool(result.converged),
                      objective_final=float(
                          hist[min(iters, hist.shape[0] - 1)]),
-                     input_bytes=_meminfo.estimated_bytes(Z))
+                     input_bytes=_meminfo.estimated_bytes(columns))
         model = LinearRegressionModel(
             coefficients=np.asarray(result.coefficients),
             intercept=float(result.intercept),

@@ -20,7 +20,7 @@ import functools
 import logging
 import threading
 from collections import namedtuple
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -51,21 +51,24 @@ class _RecordedProgram:
     re-trace surface for the program auditor (``observability.
     ProgramHandle``); this wrapper is that surface."""
 
-    __slots__ = ("dispatch", "trace_body", "jit_fn", "mesh", "example")
+    __slots__ = ("dispatch", "trace_body", "jit_fn", "mesh", "examples")
 
     def __init__(self, dispatch, trace_body, jit_fn, mesh):
         self.dispatch = dispatch
         self.trace_body = trace_body
         self.jit_fn = jit_fn
         self.mesh = mesh
-        self.example = None
+        # entry name -> example: a fit program has two entries, the
+        # frame's columns and a packed ``Z`` (see DesignColumns)
+        self.examples: dict = {}
 
     def __call__(self, *args):
-        # One None-check per dispatch on the steady path — this wrapper
-        # sits on the dispatch-lean packed-fit hot loop, so recording
-        # happens exactly once (shape/dtype metadata, no device read).
-        if self.example is None:
-            self.example = abstract_specs(args)
+        # One dict probe per dispatch on the steady path — this wrapper
+        # sits on the dispatch-lean fit hot loop, so recording happens
+        # once an entry (shape/dtype metadata, no device read).
+        entry = "columns" if isinstance(args[0], DesignColumns) else "packed"
+        if entry not in self.examples:
+            self.examples[entry] = abstract_specs(args)
         return self.dispatch(*args)
 
 
@@ -170,31 +173,75 @@ def _resolve_solve_A(solver: str, max_iter: int, tol: float,
     return solve_A
 
 
-def pack_design(X, y, mask) -> np.ndarray:
-    """Pack ``Z = [X, y, 1]·mask`` into ONE array — the single transfer unit
-    of the packed fit path.
+class DesignColumns(NamedTuple):
+    """What a fit reads of a frame, as ``_extract_xy`` returned it: the
+    **columns entry** of the compiled fits. ``X`` ``(n, d)`` and ``y``
+    ``(n,)`` in ``float_dtype()``, ``mask`` the frame's boolean mask,
+    ``w`` the raw weight column or ``None``. A pytree, so a fit program
+    sees which entry it was called through — these columns, or a packed
+    ``Z`` (:func:`pack_design`) — from the form of its first argument."""
 
-    Why packing matters here: every device argument of a dispatch costs a
-    fixed per-buffer overhead (not measured on the chip this round). The
-    masked augmented Gramian only ever consumes ``Z`` (``A = ZᵀZ``,
-    solvers.augmented_gram), so pre-masking on the host collapses
-    (X, y, mask) into one buffer with zero information loss: the mask
-    column *is* the masked ones-column, and all-zero padding rows
-    contribute nothing to ``ZᵀZ`` — no mask bookkeeping needed.
+    X: jax.Array
+    y: jax.Array
+    mask: jax.Array
+    w: Optional[jax.Array] = None
 
-    Device arrays are packed ON DEVICE (jnp ops, async): ``np.asarray`` on a
-    device array is a blocking device→host read, and the pack must not
-    stall the dispatch queue in front of the fit.
-    """
-    xp = jnp if any(isinstance(a, jax.Array) for a in (X, y, mask)) else np
+
+def row_scale(mask, w):
+    """The per-row scale of a linear fit's design: the mask, and
+    ``sqrt(w)`` where the fit is weighted (``ZᵀZ = Σ w·zzᵀ``; a masked
+    slot's weight is never read: ``where`` first)."""
+    if w is None:
+        return mask
+    return jnp.sqrt(jnp.where(mask, w, 0))
+
+
+def _pack(xp, X, y, scale, last):
+    """``[X, y, last]`` with row ``i`` scaled by ``scale[i]``; a row whose
+    scale is 0 is written as zeros whatever it holds (NaN included)."""
     X = xp.asarray(X)
     if X.ndim == 1:
         X = X[:, None]
     y = xp.asarray(y, X.dtype)
-    w = xp.asarray(mask, X.dtype)
-    Z = xp.concatenate([X, y[:, None], xp.ones_like(y)[:, None]], axis=1)
-    return Z * w[:, None]
+    scale = xp.asarray(scale, X.dtype)[:, None]
+    last = xp.ones_like(y) if last is None else xp.asarray(last, X.dtype)
+    Z = xp.concatenate([X, y[:, None], last[:, None]], axis=1)
+    return xp.where(scale != 0, Z, 0) * scale
 
+
+_pack_program = jax.jit(functools.partial(_pack, jnp))
+
+
+def _pack_eager(X, y, scale, last=None):
+    from ..utils.profiling import counters
+
+    counters.increment("fit.pack_eager")
+    if any(isinstance(a, jax.Array) for a in (X, y, scale, last)):
+        return _pack_program(X, y, scale, last)
+    return _pack(np, X, y, scale, last)
+
+
+def pack_design(X, y, mask):
+    """Pack ``Z = [X, y, 1]·mask`` into ONE array: the **packed entry** of
+    the compiled fits, for callers that hold a design matrix of their own
+    (``bench.py``, ``chip_smoke.py``, the graft entry) and for the sharded
+    path, which places ``Z`` row-sharded with one ``device_put``
+    (:func:`place_packed`). A fit on one device does not come here: it
+    hands the frame's columns to the program (:class:`DesignColumns`),
+    which then never writes ``Z`` (ISSUE 29: the eager pack was 21 ms of
+    a 93 ms ``catering_dq_lasso`` job on the chip, PERF.md section 6).
+
+    The masked augmented Gramian only ever consumes ``Z`` (``A = ZᵀZ``,
+    solvers.augmented_gram): the mask column *is* the masked ones-column,
+    and all-zero rows contribute nothing to ``ZᵀZ``. ``mask`` may carry a
+    per-row scale instead of 0/1 (the weighted linear fit passes
+    ``sqrt(w)``).
+
+    Device arrays are packed by ONE compiled program (one launch, ``Z``
+    written once, no device→host read); host arrays in numpy. Counted as
+    ``fit.pack_eager``.
+    """
+    return _pack_eager(X, y, mask)
 
 
 def pack_design_weighted(X, y, mask, w):
@@ -202,16 +249,9 @@ def pack_design_weighted(X, y, mask, w):
     zeroes invalid rows (boolean, exactly like :func:`pack_design`) while
     the last column carries the real instance weights, so one buffer still
     ships everything the weighted logistic/softmax cores consume
-    (``classification._unpack_zw``)."""
-    xp = jnp if any(isinstance(a, jax.Array) for a in (X, y, mask, w)) else np
-    X = xp.asarray(X)
-    if X.ndim == 1:
-        X = X[:, None]
-    y = xp.asarray(y, X.dtype)
-    m = xp.asarray(mask, X.dtype)
-    wv = xp.asarray(w, X.dtype)
-    Z = xp.concatenate([X, y[:, None], wv[:, None]], axis=1)
-    return Z * m[:, None]
+    (``classification._unpack_zw``). One compiled program on device
+    arrays, like :func:`pack_design`."""
+    return _pack_eager(X, y, mask, w)
 
 
 def place_packed(Z, mesh: Optional[Mesh]):
@@ -231,17 +271,24 @@ def place_packed(Z, mesh: Optional[Mesh]):
 def fused_linear_fit_packed(mesh: Optional[Mesh], solver: str, max_iter: int,
                             tol: float, fit_intercept: bool,
                             standardization: bool):
-    """Packed-I/O variant of :func:`fused_linear_fit_fn` — the dispatch-lean
-    hot path ``LinearRegression.fit`` and ``bench.py`` use.
+    """The compiled linear fit — the hot path ``LinearRegression.fit`` and
+    ``bench.py`` use: one data pass (``A = ZᵀZ`` on the MXU, ``psum`` over
+    ICI when sharded), then the solver loop on the replicated moments.
 
-    Signature: ``fit(Z, hyper) -> flat`` where ``Z = pack_design(X, y, mask)``
-    (row-sharded over the mesh), ``hyper = [regParam, elasticNetParam]`` as a
-    device array, and ``flat`` is one buffer:
+    Signature: ``fit(design, hyper) -> flat``. ``design`` is one of two
+    entries, told apart by its form (a pytree the program sees; no option):
+
+    * :class:`DesignColumns` — the frame's columns as ``_extract_xy``
+      returned them (one device only). The program masks, scales and
+      augments them under the scope ``dq.fit.pack`` as part of the
+      Gramian's read; no ``(n, d+2)`` array is an argument of it.
+    * a packed ``Z = pack_design(X, y, mask)`` (row-sharded over the mesh
+      when there is one), for callers that hold one.
+
+    Both run the same Gramian and the same solver. ``hyper = [regParam,
+    elasticNetParam]`` as a device array, and ``flat`` is one buffer:
     ``[coef(d) | intercept | iterations | converged | objective_history]``
-    (decode with :func:`unpack_fit_result`). One input buffer + one output
-    buffer ≈ the minimum possible dispatch cost; the compute is identical to
-    the unpacked path (local ``ZᵀZ`` on the MXU, ``psum`` over ICI, solver
-    loop on replicated statistics).
+    (decode with :func:`unpack_fit_result`).
     """
     solve_A = _resolve_solve_A(solver, max_iter, tol, fit_intercept,
                                standardization)
@@ -257,17 +304,24 @@ def fused_linear_fit_packed(mesh: Optional[Mesh], solver: str, max_iter: int,
         return Z.T @ Z
 
     if mesh is None or mesh.devices.size <= 1:
-        gram = local_gram
+        def gram(design):
+            if isinstance(design, DesignColumns):
+                # [X, y, 1] scaled row by row: a producer of the
+                # contraction, which XLA fuses into it — Z is not written
+                with _obs.scope("fit.pack"):
+                    X, y, mask, w = design
+                    design = _pack(jnp, X, y, row_scale(mask, w), None)
+            return local_gram(design)
     else:
         gram = shard_map(
             lambda Zs: jax.lax.psum(local_gram(Zs), DATA_AXIS),
             mesh=mesh, in_specs=(P(DATA_AXIS),), out_specs=P())
 
-    def fit(Z, hyper):
+    def fit(design, hyper):
         # the two layers of the program, named in its op metadata: the
         # one data pass, then the solver on the (d+2)^2 moments
         with _obs.scope("fit.gram"):
-            A = gram(Z)
+            A = gram(design)
         with _obs.scope("fit.solve"):
             r = solve_A(A, hyper[0], hyper[1])
             dt = r.coefficients.dtype
@@ -316,16 +370,53 @@ def fit_factory_cache_stats() -> dict:
             out[name] = {"size": info.currsize, "hits": info.hits,
                          "misses": info.misses,
                          "entries": [
-                             {"program_key": _factory_program_key(name, k)}
-                             for k, _ in factory.entries()]}
+                             {"program_key": _factory_program_key(name, k),
+                              "called_through": sorted(rec.examples)}
+                             for k, rec in factory.entries()]}
         except Exception as e:
             out[name] = {"error": str(e)}
     return out
 
 
+def _fit_handle(name, key, rec, entry, example):
+    """The handle of one entry (``packed`` or ``columns``) of one cached
+    fit program, re-traceable at the calling convention it ran with."""
+    # Scale only the ROW-indexed inputs (the widest leading dim
+    # = the shared row count): hyperparameter vectors and other
+    # small fixed-shape args keep their calling convention.
+    # Two factors (x2/x4) give the retrace detector a pair of
+    # FRESH traces — jax may serve the recorded shape from a
+    # trace cache predating a config flip (pallas mode).
+    leaves = [s for s in jax.tree_util.tree_leaves(example)
+              if hasattr(s, "shape") and s.shape]
+    rows = max((s.shape[0] for s in leaves), default=0)
+
+    def scaled(factor):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(
+                (s.shape[0] * factor,) + tuple(s.shape[1:]), s.dtype)
+            if hasattr(s, "shape") and s.shape
+            and s.shape[0] == rows else s, example)
+    # NO expected/observed trace accounting here: the jit entry
+    # legitimately retraces on input SHARDING layout (row-sharded
+    # vs replicated placements of the same shapes — exactly what
+    # the resilience fallback rungs produce), which the
+    # shape-signature recorder cannot observe. The retrace
+    # detector's variant re-trace still covers shape stability.
+    program_key = _factory_program_key(name, key)
+    if entry != "packed":
+        program_key += f"[{entry}]"
+    return _obs.ProgramHandle(
+        "fit.factories", program_key, rec.trace_body, args=example,
+        variants={"bucket": [(scaled(2), {}), (scaled(4), {})]},
+        mesh=rec.mesh, guarded=True, meta={})
+
+
 def fit_program_handles() -> list:
     """Registry callback (CACHES.register_programs): one traceable
-    handle per cached packed/sharded fit program that has executed.
+    handle per cached fit program and entry that has executed (the packed
+    ``Z`` entry under the factory's key, the columns entry under
+    ``<key>[columns]``).
     ``guarded=True`` by construction — every product of these factories
     routes dispatch through ``mesh.serialize_collectives`` — so the
     collective-topology detector can cross-check the jaxpr's collectives
@@ -336,37 +427,8 @@ def fit_program_handles() -> list:
                            fused_linear_fit_packed),
                           ("gram_sharded", _gram_sharded_fn)):
         for key, rec in factory.entries():
-            if rec.example is None:
-                continue
-            # Scale only the ROW-indexed inputs (the widest leading dim
-            # = the shared row count): hyperparameter vectors and other
-            # small fixed-shape args keep their calling convention.
-            # Two factors (x2/x4) give the retrace detector a pair of
-            # FRESH traces — jax may serve the recorded shape from a
-            # trace cache predating a config flip (pallas mode).
-            leaves = [s for s in jax.tree_util.tree_leaves(rec.example)
-                      if hasattr(s, "shape") and s.shape]
-            rows = max((s.shape[0] for s in leaves), default=0)
-
-            def scaled(factor):
-                return jax.tree_util.tree_map(
-                    lambda s: jax.ShapeDtypeStruct(
-                        (s.shape[0] * factor,) + tuple(s.shape[1:]),
-                        s.dtype)
-                    if hasattr(s, "shape") and s.shape
-                    and s.shape[0] == rows else s, rec.example)
-            # NO expected/observed trace accounting here: the jit entry
-            # legitimately retraces on input SHARDING layout (row-sharded
-            # vs replicated placements of the same shapes — exactly what
-            # the resilience fallback rungs produce), which the
-            # shape-signature recorder cannot observe. The retrace
-            # detector's variant re-trace still covers shape stability.
-            meta: dict = {}
-            out.append(_obs.ProgramHandle(
-                "fit.factories", _factory_program_key(name, key),
-                rec.trace_body, args=rec.example,
-                variants={"bucket": [(scaled(2), {}), (scaled(4), {})]},
-                mesh=rec.mesh, guarded=True, meta=meta))
+            for entry, example in list(rec.examples.items()):
+                out.append(_fit_handle(name, key, rec, entry, example))
     return out
 
 

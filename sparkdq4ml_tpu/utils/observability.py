@@ -52,16 +52,22 @@ Wired through the framework (span names are a contract: the benchmark's
 * ``sql/parser.py`` — ``sql.query`` with the query text and an
   ``explain()``-style plan summary, and its children ``sql.parse``,
   ``sql.optimize`` (rewrites applied), ``sql.execute``,
-* ``models/feature.py`` — ``feature.assemble`` (columns in, output width),
+* ``models/feature.py`` — ``feature.assemble`` (columns in, output width,
+  ``programs=1``: the assembler is one launch),
 * ``models/regression.py`` / ``classification.py`` — one root per fit
   (``fit.linear_regression`` / ``fit.logistic_regression`` /
   ``fit.linear_svc``, opened where ``fit`` begins: cold-compile vs steady
   split, iteration counts, retry/fallback annotations pulled from
   ``utils.recovery.RECOVERY_LOG``) holding ``fit.prepare`` (children
   ``fit.extract``, ``fit.validate`` — ``base.label_stats`` and the read
-  of its few scalars, with ``host_read_bytes`` — and ``fit.pack``)
-  and ``fit.solve`` (dispatch of the compiled fit to its result on the
-  host); ``model.transform`` / ``model.predict`` on both model classes,
+  of its few scalars, with ``host_read_bytes`` — and ``fit.pack``, which
+  says how the design reaches the program: ``lowering="in-program"`` on
+  one device, where the compiled fit takes the frame's columns and packs
+  them itself, counter ``fit.pack_in_program``; ``lowering="eager"``
+  where ``pack_design`` writes ``Z`` first — the sharded path — counter
+  ``fit.pack_eager``) and ``fit.solve`` (dispatch of the compiled fit to
+  its result on the host); ``model.transform`` / ``model.predict`` on
+  both model classes,
 * ``models/solvers.py`` — ``solver.solve``,
 * ``parallel/distributed.py`` / ``mesh.py`` — per-shard Gramian timing
   (blocks under the explicit flag only), collective/shard_map build
@@ -74,7 +80,10 @@ Wired through the framework (span names are a contract: the benchmark's
 Inside the compiled programs :func:`scope` (``jax.named_scope`` under the
 ``dq.`` prefix) names the layer a device operation belongs to in its op
 metadata: ``dq.flush``, ``dq.sketch``, ``dq.grouped``, ``dq.exchange``,
-``dq.fit.validate``, ``dq.fit.pack``, ``dq.fit.gram``,
+``dq.feature.assemble`` (the assembler's one program),
+``dq.fit.validate``, ``dq.fit.pack`` (what a fit makes of its columns
+before its passes: mask, scale, moments, the standardised design),
+``dq.fit.gram``,
 ``dq.fit.newton.margin`` / ``.gradient`` / ``.hessian`` /
 ``.line_search``, ``dq.fit.fista.loss_grad``, ``dq.fit.solve``. Metadata
 only: the operations' HLO names and the compiled code are unchanged. (A
@@ -198,6 +207,12 @@ METRIC_NAMES = {
     # solver / jit layers
     "solver.fits": ("counter", "model fits dispatched"),
     "solver.iterations": ("counter", "solver iterations run"),
+    "fit.pack_in_program": ("counter", "fits handed the frame's columns: "
+                                       "the design packed inside the "
+                                       "compiled fit"),
+    "fit.pack_eager": ("counter", "designs packed into Z before a fit "
+                                  "(pack_design: the sharded path, "
+                                  "callers that hold a Z)"),
     "jit.trace_miss": ("counter", "jit-factory cache misses (new trace)"),
     "jit.trace_hit": ("counter", "jit-factory cache hits"),
     # parallel / mesh
