@@ -223,7 +223,7 @@ def build_schedule(seed: int, transport: str = "inproc") -> str:
 
 def headline_job(data_path: str):
     """The reference app's DQ+Lasso flow as a tenant-scoped server job
-    (the bench/test_serve workload): CSV ingest, two DQ rules with SQL
+    (the test_serve workload): CSV ingest, two DQ rules with SQL
     filters, vector assembly, Lasso fit — touches ingest, the fused
     pipeline, SQL, and the packed-fit ladder in one query."""
     import sparkdq4ml_tpu as dq

@@ -2,7 +2,7 @@
 
 Counters, gauges, and histograms are matched BY NAME at runtime: a
 typo'd ``counters.increment("pipleine.hit")`` compiles, runs, and
-silently creates a ghost series no dashboard, bench gate, or test ever
+silently creates a ghost series no dashboard, benchmark or test ever
 reads — while the real series quietly stops moving. The registry is
 ``utils/observability.py::METRIC_NAMES`` (name → (type, help)) plus
 ``METRIC_NAME_PREFIXES`` for the dynamic per-site/per-tenant families

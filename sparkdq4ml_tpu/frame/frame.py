@@ -1412,7 +1412,7 @@ class Frame:
         ``block_until_ready`` every device column and the validity mask.
         JAX dispatch is async — without the block, timing code around
         ``cache()`` would measure enqueue, not compute; this makes
-        ``cache()`` the honest timing boundary bench.py treats it as
+        ``cache()`` an honest timing boundary
         (Spark parity: after ``cache().count()`` the data IS resident)."""
         arrs = [jnp.asarray(arr) for arr in self._data.values()
                 if not _is_string_col(arr)]

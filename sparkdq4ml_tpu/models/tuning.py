@@ -287,9 +287,8 @@ def cv_device_program(frame: Frame, estimator: LinearRegression,
                       param_maps: list[dict], metric: str, num_folds: int,
                       seed: int, mesh, larger_better: bool):
     """Build the fused CV program and its device arguments WITHOUT running
-    it. Used by ``_linear_cv_fast`` and by the benchmark harness (which
-    times the device-complete program under async dispatch, like every
-    other packed fit)."""
+    it. Used by ``_linear_cv_fast`` and by the graft entry, which runs the
+    device-complete program itself."""
     # _extract_xy already returns float-dtype device arrays with X 2-D
     X, y, mask = _extract_xy(frame, estimator.features_col, estimator.label_col)
     fold = _fold_ids_device(X.shape[0], num_folds, seed)

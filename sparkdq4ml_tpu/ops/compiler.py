@@ -719,7 +719,7 @@ class _Plan:
         # Buffer donation of the replaced columns only pays on
         # accelerators, where the donated HBM buffer is reused for the
         # output; on XLA:CPU (unified memory) aliasing buys nothing and
-        # measurably slows the call (~25% on the 20-op bench chain), so
+        # measurably slows the call (~25% on a 20-op chain), so
         # the CPU path keeps the plain signature. The mask is never
         # donated: the dq-profile hook reads the flush's INPUT mask after
         # the dispatch (run_pipeline), and a donated array is deleted.
@@ -748,9 +748,9 @@ _CACHE_LOCK = threading.Lock()
 #: structural keys make cross-tenant reuse safe by construction, so this
 #: is the production configuration. The serving layer
 #: (``serve/server.py``) sets a per-tenant namespace only when its
-#: shared-plan-cache mode is OFF, which partitions the cache by tenant —
-#: the control arm of the serving bench's shared-on vs shared-off
-#: comparison. A contextvar, not a global: each worker thread/context
+#: shared-plan-cache mode is OFF, which partitions the cache by tenant
+#: (only tests turn it off, to pin that isolated tenants compile their
+#: own programs). A contextvar, not a global: each worker thread/context
 #: scopes its own queries without affecting concurrent ones.
 _PLAN_NS: contextvars.ContextVar[str] = contextvars.ContextVar(
     "sparkdq4ml_plan_namespace", default="")
@@ -852,7 +852,7 @@ pad_rows = _pad
 def _unpad_tree(tree, n: int):
     """Slice every padded output back to ``n`` rows in ONE dispatch —
     un-jitted per-array ``a[:n]`` slices cost a dispatch each (~1 ms × 11
-    outputs on the 20-op bench chain, dominating the flush). A trivial
+    outputs on a 20-op chain, dominating the flush). A trivial
     memcpy program; its per-(shapes, n) retrace is not a pipeline
     compile."""
     return jax.tree_util.tree_map(lambda a: a[:n], tree)

@@ -224,8 +224,8 @@ def _pack_eager(X, y, scale, last=None):
 def pack_design(X, y, mask):
     """Pack ``Z = [X, y, 1]·mask`` into ONE array: the **packed entry** of
     the compiled fits, for callers that hold a design matrix of their own
-    (``bench.py``, ``chip_smoke.py``, the graft entry) and for the sharded
-    path, which places ``Z`` row-sharded with one ``device_put``
+    (``chip_smoke.py``, the graft entry) and for the sharded path, which
+    places ``Z`` row-sharded with one ``device_put``
     (:func:`place_packed`). A fit on one device does not come here: it
     hands the frame's columns to the program (:class:`DesignColumns`),
     which then never writes ``Z`` (ISSUE 29: the eager pack was 21 ms of
@@ -271,8 +271,8 @@ def place_packed(Z, mesh: Optional[Mesh]):
 def fused_linear_fit_packed(mesh: Optional[Mesh], solver: str, max_iter: int,
                             tol: float, fit_intercept: bool,
                             standardization: bool):
-    """The compiled linear fit — the hot path ``LinearRegression.fit`` and
-    ``bench.py`` use: one data pass (``A = ZᵀZ`` on the MXU, ``psum`` over
+    """The compiled linear fit — the hot path ``LinearRegression.fit``
+    uses: one data pass (``A = ZᵀZ`` on the MXU, ``psum`` over
     ICI when sharded), then the solver loop on the replicated moments.
 
     Signature: ``fit(design, hyper) -> flat``. ``design`` is one of two

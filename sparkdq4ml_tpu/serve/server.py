@@ -29,8 +29,8 @@ Architecture::
   compiled programs with zero new compiles (test-pinned via
   ``cache_report`` diffs). ``shared_plan_cache=False`` partitions the
   pipeline + grouped caches per tenant via
-  :func:`ops.compiler.plan_namespace` — the control arm of the serving
-  bench. (Solver/fit jit factories key on model params only and stay
+  :func:`ops.compiler.plan_namespace`; only tests turn it off.
+  (Solver/fit jit factories key on model params only and stay
   shared in both modes; they carry no per-tenant state.)
 * **Admission control** — see :mod:`serve.admission`: breaker shedding,
   global + per-tenant queue bounds, device-memory gate.

@@ -69,7 +69,7 @@ from .config import CONF_TRUE as _CONF_TRUE  # noqa: E402
 #: Where the persistent XLA compile cache lives when the environment names
 #: no ``JAX_COMPILATION_CACHE_DIR``: one git-ignored directory at the root
 #: of this checkout. The path is part of every cache key, so it is the
-#: same for tests, examples, bench and ``chip_smoke.py`` and never moves.
+#: same for tests, examples and ``chip_smoke.py`` and never moves.
 COMPILE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     ".jax_cache")

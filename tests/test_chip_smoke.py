@@ -43,3 +43,12 @@ def test_command_line_demands_a_tpu_and_prints_no_result(capsys):
     out = capsys.readouterr()
     assert out.out == ""
     assert "no TPU" in out.err
+
+
+def test_peak_comes_from_the_benchmarks_table_and_an_unknown_kind_raises():
+    with open(os.path.join(chip_smoke.REPO, "benchmarks", "peaks.json")) as f:
+        table = json.load(f)
+    assert (chip_smoke.peak_bf16_flops_per_s("TPU v5 lite")
+            == table["TPU v5 lite"]["bf16_flops_per_s"])
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_smoke.peak_bf16_flops_per_s("TPU v0 none")

@@ -723,32 +723,3 @@ class TestProgramHandleRule:
         names = [c.name for c in ALL_RULES]
         assert "program-handle" in names
         assert get_rules(["program-handle"])
-
-
-# ---------------------------------------------------------------------------
-# Bench-gate recognition
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.bench_regress
-class TestBenchGate:
-    def test_costprof_section_recognized_and_gated(self, tmp_path):
-        import subprocess
-        import sys
-
-        script = os.path.join(os.path.dirname(__file__), "..",
-                              "scripts", "check_bench_regress.py")
-        old = {"costprof": {"report_ms": 10.0, "disabled_flush_ms": 1.0}}
-        new_ok = {"costprof": {"report_ms": 10.5,
-                               "disabled_flush_ms": 1.05}}
-        new_bad = {"costprof": {"report_ms": 20.0,
-                                "disabled_flush_ms": 1.0}}
-        p_old = tmp_path / "old.json"
-        p_old.write_text(json.dumps(old))
-        for doc, want in ((new_ok, 0), (new_bad, 1)):
-            p_new = tmp_path / "new.json"
-            p_new.write_text(json.dumps(doc))
-            r = subprocess.run(
-                [sys.executable, script, "--old", str(p_old),
-                 "--new", str(p_new)], capture_output=True, text=True)
-            assert r.returncode == want, r.stdout + r.stderr

@@ -4,17 +4,12 @@ PR-5 tentpole: per-operator runtime plan profiles (``sql/parser.py`` plan
 tree + ``observability.query_stats``), device-memory accounting
 (``utils.meminfo``), unified jit-cache introspection
 (``observability.CACHES``), plus the satellites: trace-buffer overflow
-accounting, stable trace/span ids across exporters, the host-sync audit
-(window/stat/evaluation), and the bench-regression gate
-(``scripts/check_bench_regress.py``).
+accounting, stable trace/span ids across exporters, and the host-sync
+audit (window/stat/evaluation).
 """
 
-import json
 import logging
-import os
 import re
-import subprocess
-import sys
 
 import jax.numpy as jnp
 import numpy as np
@@ -688,119 +683,3 @@ class TestDisabledModeNoOp:
         assert results["slow"] == ["slow.op"]     # no cross-pollution
         assert results["slow_enabled_mid"] is True  # fast exit ≠ disable
         assert not obs.TRACER.enabled             # last one out restores
-
-
-# ---------------------------------------------------------------------------
-# Satellite: bench-regression gate
-# ---------------------------------------------------------------------------
-
-REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-SCRIPT = os.path.join(REPO, "scripts", "check_bench_regress.py")
-
-
-def _run_script(*args):
-    return subprocess.run([sys.executable, SCRIPT, *args],
-                          capture_output=True, text=True, timeout=60)
-
-
-def _write(path, doc):
-    with open(path, "w") as f:
-        json.dump(doc, f)
-
-
-@pytest.mark.bench_regress
-class TestBenchRegress:
-    OLD = {"configs": [{"config": "a_lasso", "device_ms": 1.0,
-                        "vs_baseline": 10.0, "rows": 100}],
-           "sweep": [{"rows": 1000, "features": 16,
-                      "xla_ms": 2.0, "xla_gbps": 3.0}]}
-
-    def test_pass_within_threshold(self, tmp_path):
-        new = {"configs": [{"config": "a_lasso", "device_ms": 1.1,
-                            "vs_baseline": 9.0, "rows": 100}],
-               "sweep": [{"rows": 1000, "features": 16,
-                          "xla_ms": 2.2, "xla_gbps": 2.7}]}
-        _write(tmp_path / "o.json", self.OLD)
-        _write(tmp_path / "n.json", new)
-        p = _run_script("--old", str(tmp_path / "o.json"),
-                        "--new", str(tmp_path / "n.json"))
-        assert p.returncode == 0, p.stdout
-        assert "PASS" in p.stdout
-
-    def test_fail_on_time_regression(self, tmp_path):
-        new = {"configs": [{"config": "a_lasso", "device_ms": 1.3,
-                            "vs_baseline": 10.0, "rows": 100}],
-               "sweep": [{"rows": 1000, "features": 16,
-                          "xla_ms": 2.0, "xla_gbps": 3.0}]}
-        _write(tmp_path / "o.json", self.OLD)
-        _write(tmp_path / "n.json", new)
-        p = _run_script("--old", str(tmp_path / "o.json"),
-                        "--new", str(tmp_path / "n.json"))
-        assert p.returncode == 1
-        assert "configs/a_lasso/device_ms" in p.stdout
-
-    def test_fail_on_throughput_regression(self, tmp_path):
-        new = {"configs": [{"config": "a_lasso", "device_ms": 1.0,
-                            "vs_baseline": 10.0, "rows": 100}],
-               "sweep": [{"rows": 1000, "features": 16,
-                          "xla_ms": 2.0, "xla_gbps": 2.0}]}
-        _write(tmp_path / "o.json", self.OLD)
-        _write(tmp_path / "n.json", new)
-        p = _run_script("--old", str(tmp_path / "o.json"),
-                        "--new", str(tmp_path / "n.json"))
-        assert p.returncode == 1
-        assert "xla_gbps" in p.stdout
-
-    def test_new_metrics_do_not_gate(self, tmp_path):
-        new = dict(self.OLD)
-        new["grouped_ops"] = {"agg_ms": 99.0}   # new section: not shared
-        _write(tmp_path / "o.json", self.OLD)
-        _write(tmp_path / "n.json", new)
-        p = _run_script("--old", str(tmp_path / "o.json"),
-                        "--new", str(tmp_path / "n.json"))
-        assert p.returncode == 0
-
-    def test_wrapper_with_parsed_field(self, tmp_path):
-        _write(tmp_path / "o.json", {"n": 1, "rc": 0, "parsed": self.OLD})
-        _write(tmp_path / "n.json", self.OLD)
-        p = _run_script("--old", str(tmp_path / "o.json"),
-                        "--new", str(tmp_path / "n.json"))
-        assert p.returncode == 0
-        assert "PASS" in p.stdout
-
-    def test_unparseable_skips_clean(self, tmp_path):
-        _write(tmp_path / "o.json", {"n": 1, "rc": 0,
-                                     "tail": "…truncated nonsense"})
-        _write(tmp_path / "n.json", self.OLD)
-        p = _run_script("--old", str(tmp_path / "o.json"),
-                        "--new", str(tmp_path / "n.json"))
-        assert p.returncode == 0
-        assert "SKIP" in p.stdout
-
-    def test_auto_discovery_pairs_latest_rounds(self, tmp_path):
-        worse = {"configs": [{"config": "a_lasso", "device_ms": 5.0,
-                              "vs_baseline": 10.0, "rows": 100}],
-                 "sweep": []}
-        _write(tmp_path / "BENCH_r01.json", self.OLD)
-        _write(tmp_path / "BENCH_r02.json", self.OLD)
-        _write(tmp_path / "BENCH_r03.json", worse)
-        p = _run_script("--dir", str(tmp_path))
-        assert p.returncode == 1
-        assert "BENCH_r02.json -> BENCH_r03.json" in p.stdout
-
-    def test_repo_gate_runs(self):
-        # on the real repo this must never crash; truncated captures skip
-        p = _run_script("--dir", REPO)
-        assert p.returncode in (0, 1), p.stdout + p.stderr
-
-    def test_direction_inference(self):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location("cbr", SCRIPT)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        assert mod.metric_direction("configs/a/device_ms") == "lower"
-        assert mod.metric_direction("sweep/r1000x16/xla_gbps") == "higher"
-        assert mod.metric_direction("configs/a/vs_baseline") == "higher"
-        assert mod.metric_direction("configs/a/rows") is None
-        assert mod.metric_direction("configs/a/iterations") is None

@@ -9,14 +9,9 @@ cache control mode, every admission gate (global queue, per-tenant
 quota, memory, breaker shedding), structured deadline errors that never
 hang, per-tenant metric isolation + the Prometheus scrape, concurrent
 ``query_stats`` collectors at server scale, the 16-thread jit-cache
-hammer, the thread-safe session singleton, and the serving extensions of
-the bench-regression gate.
+hammer, and the thread-safe session singleton.
 """
 
-import json
-import os
-import subprocess
-import sys
 import threading
 import time
 
@@ -896,69 +891,3 @@ class TestDisabledMode:
         assert counters.snapshot("serve.") == {}
         assert not any(k.startswith("serve.")
                        for k in obs.METRICS.snapshot())
-
-
-# ---------------------------------------------------------------------------
-# Satellite: bench-regression gate covers the serving metrics
-# ---------------------------------------------------------------------------
-
-REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-SCRIPT = os.path.join(REPO, "scripts", "check_bench_regress.py")
-
-
-def _run_script(*args):
-    return subprocess.run([sys.executable, SCRIPT, *args],
-                          capture_output=True, text=True, timeout=60)
-
-
-def _write(path, doc):
-    with open(path, "w") as f:
-        json.dump(doc, f)
-
-
-@pytest.mark.bench_regress
-class TestBenchRegressServing:
-    OLD = {"serving": {"config": "serving", "clients": 32,
-                       "shared_cache": {"qps": 100.0, "p50_ms": 8.0,
-                                        "p99_ms": 40.0},
-                       "isolated_cache": {"qps": 20.0, "p99_ms": 300.0},
-                       "shared_vs_isolated_qps": 5.0}}
-
-    def test_qps_drop_fails(self, tmp_path):
-        new = json.loads(json.dumps(self.OLD))
-        new["serving"]["shared_cache"]["qps"] = 50.0   # -50%
-        _write(tmp_path / "o.json", self.OLD)
-        _write(tmp_path / "n.json", new)
-        p = _run_script("--old", str(tmp_path / "o.json"),
-                        "--new", str(tmp_path / "n.json"))
-        assert p.returncode == 1
-        assert "serving/shared_cache/qps" in p.stdout
-
-    def test_p99_rise_fails(self, tmp_path):
-        new = json.loads(json.dumps(self.OLD))
-        new["serving"]["shared_cache"]["p99_ms"] = 80.0  # +100%
-        _write(tmp_path / "o.json", self.OLD)
-        _write(tmp_path / "n.json", new)
-        p = _run_script("--old", str(tmp_path / "o.json"),
-                        "--new", str(tmp_path / "n.json"))
-        assert p.returncode == 1
-        assert "serving/shared_cache/p99_ms" in p.stdout
-
-    def test_improvement_passes(self, tmp_path):
-        new = json.loads(json.dumps(self.OLD))
-        new["serving"]["shared_cache"]["qps"] = 200.0
-        new["serving"]["shared_cache"]["p99_ms"] = 20.0
-        _write(tmp_path / "o.json", self.OLD)
-        _write(tmp_path / "n.json", new)
-        p = _run_script("--old", str(tmp_path / "o.json"),
-                        "--new", str(tmp_path / "n.json"))
-        assert p.returncode == 0
-        assert "PASS" in p.stdout
-
-    def test_serving_only_doc_is_parseable(self, tmp_path):
-        _write(tmp_path / "o.json", self.OLD)
-        _write(tmp_path / "n.json", self.OLD)
-        p = _run_script("--old", str(tmp_path / "o.json"),
-                        "--new", str(tmp_path / "n.json"))
-        assert p.returncode == 0
-        assert "PASS" in p.stdout

@@ -17,8 +17,7 @@ The acceptance surface of the sharded-frames refactor:
 * **integration** — session conf save/restore, sharded ingest hand-off,
   EXPLAIN's ``ShardedStage``/``Exchange`` operators, statstore keys,
   program-audit handles (mesh + guard declared), the fit-packing
-  pass-through, serving under concurrency, and the bench-regression
-  gate recognizing the ``sharded`` section.
+  pass-through, and serving under concurrency.
 
 The golden workload (dataset-abstract: count 24 / RMSE 2.809940;
 dataset-full: RMSE 1.805140) is pinned with sharding ON.
@@ -661,45 +660,6 @@ class TestFitPassthrough:
             assert profiling.counters.get("shard.fit_passthrough") \
                 == before + 1
             assert Xo is X and yo is y and mo is m
-
-
-class TestBenchGate:
-    def test_regress_gate_sees_sharded_metrics(self):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "cbr", os.path.join(os.path.dirname(__file__), "..",
-                                "scripts", "check_bench_regress.py"))
-        cbr = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(cbr)
-
-        def doc(pipe_ms, speedup):
-            return {"sharded": {"pipeline": [
-                {"config": "pipeline_r1000000_d8", "rows": 1000000,
-                 "devices": 8, "pipeline_ms": pipe_ms,
-                 "speedup_vs_1dev": speedup}]}}
-
-        old = cbr.flatten_metrics(doc(100.0, 2.0))
-        new = cbr.flatten_metrics(doc(200.0, 0.9))
-        assert old, "sharded metrics were not recognized"
-        regressions = cbr.compare(old, new, 0.15)
-        names = {r["metric"] for r in regressions}
-        assert any("pipeline_ms" in m for m in names)
-        assert any("speedup_vs_1dev" in m for m in names)
-        assert cbr.load_bench_doc.__doc__  # module loaded intact
-
-    def test_load_bench_doc_accepts_sharded_only(self, tmp_path):
-        import importlib.util
-        import json
-
-        spec = importlib.util.spec_from_file_location(
-            "cbr2", os.path.join(os.path.dirname(__file__), "..",
-                                 "scripts", "check_bench_regress.py"))
-        cbr = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(cbr)
-        p = tmp_path / "BENCH_r99.json"
-        p.write_text(json.dumps({"sharded": {"pipeline": []}}))
-        assert cbr.load_bench_doc(str(p)) is not None
 
 
 class TestChaosSmoke:
