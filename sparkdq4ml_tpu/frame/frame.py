@@ -430,9 +430,9 @@ class Frame:
     def _pipe_schema(self):
         # lazy: only columns the checked expression references get a
         # dtype probe — deferral stays O(expr), not O(frame width)
-        from ..ops.compiler import LazySchema
+        from ..ops.compiler import pending_schema
 
-        return LazySchema(self._data_store, self._pending_names())
+        return pending_schema(self._data_store, self._pending)
 
     def _can_defer(self, *exprs) -> bool:
         if not config.pipeline or self._n == 0:

@@ -40,7 +40,11 @@ Semantics are bit-identical to eager evaluation: the compiled program runs
 the *same* ``Expr.eval`` methods (against a :class:`_TraceFrame` shim whose
 columns are tracers), so every null rule, dtype promotion, and division
 corner is the one the eager path implements. Anything outside the compilable
-subset (strings, UDFs, row generators, array cells) never defers.
+subset (strings, row generators, array cells) never defers. A registered UDF
+is inside it when its function is seen to be row-local (``ops/udf.py``: a
+probe of the traced function, no flag) — the reference app's DQ rules are —
+and outside it otherwise: a whole-column function keeps the eager path,
+because padding, row slices and shards would change what it sees.
 """
 
 from __future__ import annotations
@@ -66,6 +70,7 @@ from ..utils import faults as _faults
 from ..utils import observability as _obs
 from ..utils.profiling import counters
 from . import expressions as E
+from .udf import PROBE_ROWS
 
 __all__ = [
     "bucket_size", "pad_rows", "dtype_tag", "is_compilable",
@@ -174,21 +179,28 @@ def schema_of(data: dict, pending_names: Sequence[str] = ()) -> dict:
 
 
 class LazySchema:
-    """``get``-only schema that resolves column specs ON DEMAND — the
-    per-op ``_can_defer`` check runs once per deferred call, and eagerly
-    spec-ing every stored column made deferral O(frame width) per op on
-    wide frames; an expression only needs the handful of columns it
-    references. Not used by :func:`_linearize` (which copies and mutates
-    a real dict)."""
+    """``get``-only schema of a frame's STORED columns that resolves
+    column specs ON DEMAND — the per-op ``_can_defer`` check runs once per
+    deferred call, and eagerly spec-ing every stored column made deferral
+    O(frame width) per op on wide frames; an expression only needs the
+    handful of columns it references. Columns that pending steps produce
+    lie over it in a :class:`_SchemaOverlay` (:func:`pending_schema`)."""
 
-    def __init__(self, data: dict, pending_names: Sequence[str]):
+    def __init__(self, data: dict):
         self._data = data
-        self._pending = frozenset(pending_names)
         self._cache: dict = {}
 
+    def aval(self, name):
+        """The column as an abstract probe-length array (what a UDF
+        call's probe is given), or None for a host column."""
+        arr = self._data.get(name)
+        if arr is None or self.get(name) == "h":
+            return None
+        return jax.ShapeDtypeStruct(
+            (PROBE_ROWS,) + tuple(np.shape(arr)[1:]),
+            jax.dtypes.canonicalize_dtype(arr.dtype))
+
     def get(self, name, default=None):
-        if name in self._pending:
-            return "p"
         try:
             return self._cache[name]
         except KeyError:
@@ -220,9 +232,11 @@ dtype_tag = _dtype_tag
 def is_compilable(expr, schema: dict) -> bool:
     """True when ``expr`` evaluates entirely on device under jit: numeric
     column refs, numeric literals, arithmetic/comparison/boolean ops,
-    numeric casts, CASE WHEN, IN over literal values, and the pure-jnp
-    builtin functions. Strings, UDFs, row generators, subquery markers,
-    and array-cell functions are not (they stay on the eager path)."""
+    numeric casts, CASE WHEN, IN over literal values, the pure-jnp
+    builtin functions, and calls of registered UDFs whose function is
+    row-local (:func:`_udf_admission`). Strings, row generators, subquery
+    markers, array-cell functions and every other UDF call are not (they
+    stay on the eager path)."""
     if isinstance(expr, E.Col):
         s = schema.get(expr.name)
         return s is not None and s != "h"
@@ -266,7 +280,75 @@ def is_compilable(expr, schema: dict) -> bool:
         if expr.fn_name in _NUMERIC_FUNCS:
             return all(is_compilable(a, schema) for a in expr.args)
         return False
+    if isinstance(expr, E.UdfCall):
+        # the SQL parser builds a UdfCall for EVERY function call: names
+        # the registry lacks (abs, upper, ...) leave here at once
+        return (expr.udf_name in expr.registry() and bool(expr.args)
+                and all(is_compilable(a, schema) for a in expr.args)
+                and _udf_admission(expr, schema) is not None)
     return False
+
+
+def _walk(expr):
+    """Every node of an expression tree, parents first, children in the
+    one order all plan walks share."""
+    yield expr
+    for attr in ("left", "right", "child", "otherwise_expr"):
+        v = getattr(expr, attr, None)
+        if isinstance(v, E.Expr):
+            yield from _walk(v)
+    for v in getattr(expr, "args", None) or ():
+        yield from _walk(v)
+    for v in getattr(expr, "values", None) or ():
+        yield from _walk(v)
+    for c, v in getattr(expr, "branches", None) or ():
+        yield from _walk(c)
+        yield from _walk(v)
+
+
+def _col_aval(schema, name):
+    """A referenced column as an abstract probe-length array, or None
+    where the schema cannot tell: a host column, or a plain dict of key
+    specs (``schema_of``, the static memory estimate), which holds no
+    arrays — a UDF call checked against one is not admitted."""
+    f = getattr(schema, "aval", None)
+    return f(name) if f is not None else None
+
+
+def _udf_admission(expr, schema):
+    """``(fn, return_dtype, fingerprint)`` when this call of a registered
+    UDF may run inside a flush program, else None.
+
+    The flush compiler pads rows to a bucket, may slice them
+    (:func:`_run_chunked`) and runs shards alone — sound for row-local
+    work only. So the answer is the registry's probe of the function at
+    the dtypes this call would hand it (``ops/udf.probe_elementwise``,
+    cached on the registry entry). The dtypes come from the schema: a
+    stored column's own, or — where an argument is an expression or a
+    column that a pending step produces — from an abstract evaluation of
+    it (``jax.eval_shape``: nothing is computed)."""
+    env = {}
+    for a in expr.args:
+        for e in _walk(a):
+            if isinstance(e, E.Col) and e.name not in env:
+                av = _col_aval(schema, e.name)
+                if av is None:
+                    return None
+                env[e.name] = av
+    if all(isinstance(a, E.Col) for a in expr.args):
+        avals = [env[a.name] for a in expr.args]
+    else:
+        try:
+            avals = jax.eval_shape(
+                lambda cols: tuple(
+                    a.eval(_TraceFrame(cols, PROBE_ROWS))
+                    for a in expr.args), env)
+        except Exception:
+            return None
+    if any(len(av.shape) != 1 for av in avals):
+        return None                     # a vector column: not 1-D
+    return expr.registry().elementwise(
+        expr.udf_name, [av.dtype for av in avals])
 
 
 # ---------------------------------------------------------------------------
@@ -409,39 +491,65 @@ def _lower(expr, schema: dict, lits: list):
         return (f"W([{';'.join(parts)}],{ok})",
                 expr if not changed else E.CaseWhen(branches, oe))
     if isinstance(expr, E.Func):
-        lit_tail = expr.fn_name in _LIT_TAIL_FUNCS
-        parts = []
-        args = []
-        changed = False
-        for i, a in enumerate(expr.args):
-            if lit_tail and i > 0:
-                # host-extracted literal args (is_compilable guarantees
-                # Lits here): evaluate as host numpy, bake into the key
-                parts.append(f"V({a.value!r})")
-                args.append(_HostConstLit(a.value))
-                changed = True
-                continue
-            # numeric-builtin literal args hoist like BinOp operands:
-            # pow(x, 2)/pow(x, 3) share one program, AND the exponent
-            # stays a runtime scalar so XLA cannot strength-reduce
-            # constant forms (pow(x, 2) → x*x) into 1-ULP divergence
-            # from the eager op.
-            h = _hoistable_lit(a)
-            if h is not None:
-                idx = len(lits)
-                lits.append(h)
-                kind = _lit_kind(h.value)
-                parts.append(f"L{kind}")
-                args.append(_ArgLit(idx, kind))
-                changed = True
-                continue
-            ak, ae = _lower(a, schema, lits)
-            parts.append(ak)
-            changed = changed or ae is not a
-            args.append(ae)
+        parts, args, changed = _lower_args(
+            expr.args, schema, lits, expr.fn_name in _LIT_TAIL_FUNCS)
         return (f"F({expr.fn_name},{','.join(parts)})",
                 expr if not changed else E.Func(expr.fn_name, args))
+    if isinstance(expr, E.UdfCall):
+        # The key names the FUNCTION, not only the rule: its fingerprint
+        # is the probed program's text, literals inside it included (they
+        # are the function's own and are not hoisted). Two functions
+        # under one name — a re-registration, another registry — never
+        # share a program; one function registered twice does. The node
+        # of the rewritten plan carries the function the key was made
+        # from, so a registration that changes between the key and the
+        # trace cannot put another function behind this key.
+        admitted = _udf_admission(expr, schema)
+        if admitted is None:
+            raise PipelineError(
+                f"UDF {expr.udf_name!r} is not admitted to a flush")
+        fn, return_dtype, fingerprint = admitted
+        parts, args, _ = _lower_args(expr.args, schema, lits)
+        call = E.UdfCall(expr.udf_name, args, expr._registry)
+        call._bound = (fn, return_dtype)
+        rule = repr(expr.udf_name).replace("|", "\\x7c")   # keys split on |
+        rt = "_" if return_dtype is None else np.dtype(return_dtype).name
+        return f"R({rule}#{fingerprint}:{rt},{','.join(parts)})", call
     raise PipelineError(f"non-compilable node reached _lower: {expr!r}")
+
+
+def _lower_args(call_args, schema, lits: list, lit_tail: bool = False):
+    """Lower the arguments of a builtin or UDF call: ``(key fragments,
+    rewritten args, whether any changed)``."""
+    parts = []
+    args = []
+    changed = False
+    for i, a in enumerate(call_args):
+        if lit_tail and i > 0:
+            # host-extracted literal args (is_compilable guarantees
+            # Lits here): evaluate as host numpy, bake into the key
+            parts.append(f"V({a.value!r})")
+            args.append(_HostConstLit(a.value))
+            changed = True
+            continue
+        # literal args hoist like BinOp operands: pow(x, 2)/pow(x, 3)
+        # share one program, AND the exponent stays a runtime scalar so
+        # XLA cannot strength-reduce constant forms (pow(x, 2) → x*x)
+        # into 1-ULP divergence from the eager op.
+        h = _hoistable_lit(a)
+        if h is not None:
+            idx = len(lits)
+            lits.append(h)
+            kind = _lit_kind(h.value)
+            parts.append(f"L{kind}")
+            args.append(_ArgLit(idx, kind))
+            changed = True
+            continue
+        ak, ae = _lower(a, schema, lits)
+        parts.append(ak)
+        changed = changed or ae is not a
+        args.append(ae)
+    return parts, args, changed
 
 
 def _referenced_base_cols(expr, schema: dict, out: list) -> None:
@@ -451,21 +559,10 @@ def _referenced_base_cols(expr, schema: dict, out: list) -> None:
     before a later step replaces it resolves to base here because the
     caller marks outputs ``p`` only after lowering the step that
     produces them."""
-    if isinstance(expr, E.Col):
-        if schema.get(expr.name) not in (None, "p") and expr.name not in out:
-            out.append(expr.name)
-        return
-    for attr in ("left", "right", "child", "otherwise_expr"):
-        v = getattr(expr, attr, None)
-        if isinstance(v, E.Expr):
-            _referenced_base_cols(v, schema, out)
-    for v in getattr(expr, "args", None) or ():
-        _referenced_base_cols(v, schema, out)
-    for v in getattr(expr, "values", None) or ():
-        _referenced_base_cols(v, schema, out)
-    for c, v in getattr(expr, "branches", None) or ():
-        _referenced_base_cols(c, schema, out)
-        _referenced_base_cols(v, schema, out)
+    for e in _walk(expr):
+        if (isinstance(e, E.Col) and e.name not in out
+                and schema.get(e.name) not in (None, "p")):
+            out.append(e.name)
 
 
 # ---------------------------------------------------------------------------
@@ -497,19 +594,69 @@ class _TraceFrame:
 class _SchemaOverlay:
     """Mutable step-output overlay over a base schema (dict or
     :class:`LazySchema`) — _linearize marks produced columns ``p``
-    without copying or eagerly materializing the base."""
+    without copying or eagerly materializing the base. It keeps each
+    producing step's expressions, so that the dtype of a produced column
+    can be told when a UDF call reads one (:meth:`aval`): rarely, and by
+    one abstract replay of the steps so far."""
 
     def __init__(self, base):
         self._base = base
         self._over: dict = {}
+        self._groups: list = []     # one tuple of (name, expr) per step
+        self._avals: Optional[dict] = None
 
     def get(self, name, default=None):
         if name in self._over:
             return self._over[name]
         return self._base.get(name, default)
 
-    def __setitem__(self, name, spec) -> None:
-        self._over[name] = spec
+    def define(self, pairs) -> None:
+        """The outputs of one producing step: ``p`` for later steps."""
+        self._groups.append(tuple(pairs))
+        for name, _ in pairs:
+            self._over[name] = "p"
+        self._avals = None
+
+    def aval(self, name):
+        if name not in self._over:
+            return _col_aval(self._base, name)
+        if self._avals is None:
+            self._avals = self._replay()
+        return self._avals.get(name)
+
+    def _replay(self) -> dict:
+        base = {}
+        for group in self._groups:
+            for _, ex in group:
+                for e in _walk(ex):
+                    if isinstance(e, E.Col) and e.name not in base:
+                        av = _col_aval(self._base, e.name)
+                        if av is not None:
+                            base[e.name] = av
+
+        def run(cols):
+            env = dict(cols)
+            for group in self._groups:
+                fr = _TraceFrame(dict(env), PROBE_ROWS)   # pre-step state
+                env.update({name: ex.eval(fr) for name, ex in group})
+            return {name: env[name] for name in self._over}
+
+        try:
+            return jax.eval_shape(run, base)
+        except Exception:
+            return {}
+
+
+def pending_schema(data: dict, pending_steps=()) -> _SchemaOverlay:
+    """The schema a frame checks deferral against: its stored columns,
+    lazily, under what its pending steps produce."""
+    schema = _SchemaOverlay(LazySchema(data))
+    for s in pending_steps:
+        if s[0] == "with_column":
+            schema.define(((s[1], s[2]),))
+        elif s[0] == "with_columns":
+            schema.define(s[1])
+    return schema
 
 
 def _linearize(steps, extra, base_schema):
@@ -536,7 +683,7 @@ def _linearize(steps, extra, base_schema):
             _referenced_base_cols(step[2], schema, refs)
             key_parts.append(f"W({step[1]!r})={k}")
             lowered_steps.append(("with_column", step[1], ex))
-            schema[step[1]] = "p"
+            schema.define(((step[1], step[2]),))
         elif step[0] == "with_columns":
             pairs = []
             ks = []
@@ -547,8 +694,7 @@ def _linearize(steps, extra, base_schema):
                 pairs.append((name, ex))
             key_parts.append(f"WS({';'.join(ks)})")
             lowered_steps.append(("with_columns", tuple(pairs)))
-            for name, _ in step[1]:
-                schema[name] = "p"
+            schema.define(step[1])
         elif step[0] == "filter":
             k, ex = _lower(step[1], schema, lits)
             _referenced_base_cols(step[1], schema, refs)
@@ -578,9 +724,10 @@ class _Plan:
     side later). Sharded plans key with the store's layout tag, so
     sharded and single-device programs coexist in this cache."""
 
-    def __init__(self, steps, extra, base_schema, shard=None):
-        key, lits, lowered_steps, lowered_extra, refs = _linearize(
-            steps, extra, base_schema)
+    def __init__(self, steps, extra, walk, shard=None):
+        # ``walk``: the cache probe's own _linearize result for these
+        # steps, so the key and the program come from ONE walk
+        key, lits, lowered_steps, lowered_extra, refs = walk
         replaced = {s[1] for s in steps if s[0] == "with_column"}
         for s in steps:
             if s[0] == "with_columns":
@@ -603,6 +750,10 @@ class _Plan:
         # whether this program ANDs a filter into the mask — the flushes
         # whose output mask carries a selectivity observation (statstore)
         self.has_filter = any(s[0] == "filter" for s in lowered_steps)
+        # the registered rules this program runs: every one for the
+        # flush's span and counter, and those whose output is a column
+        # the flush hands back for the dq profile's [rows, passed] tally
+        self.rule_names, self.rule_cols = _plan_rules(steps, extra)
         # Introspection (observability.CACHES / EXPLAIN ANALYZE): per-plan
         # replay count and bucket histogram, updated under _CACHE_LOCK.
         self.hits = 0
@@ -730,6 +881,58 @@ class _Plan:
             self.fn = jax.jit(program)
 
 
+def _plan_rules(steps, extra):
+    """``(names, cols)`` of the UDF calls in a plan (every one was
+    admitted, or the plan would not exist). ``names``: one entry per
+    call, nested ones too. ``cols``: ``(rule, "changed" | "extras",
+    column)`` for each call that IS a column the flush returns — the
+    whole expression of a ``with_column`` that no later step replaces, or
+    of a projection. A call inside a larger expression has no column of
+    its own: its values exist inside the program only, so it has a span
+    and counts as run in a flush, and has no tally."""
+    names = []
+    produced: dict = {}                 # column -> rule that made it last
+
+    def top_rule(ex):
+        while isinstance(ex, E.Alias):
+            ex = ex.child
+        return ex.udf_name if isinstance(ex, E.UdfCall) else None
+
+    def note(ex):
+        names.extend(e.udf_name for e in _walk(ex)
+                     if isinstance(e, E.UdfCall))
+
+    for s in steps:
+        if s[0] == "filter":
+            note(s[1])
+            continue
+        for name, ex in (((s[1], s[2]),) if s[0] == "with_column"
+                         else s[1]):
+            note(ex)
+            produced[name] = top_rule(ex)
+    cols = [(rule, "changed", name) for name, rule in produced.items()]
+    for name, ex in extra:
+        note(ex)
+        cols.append((top_rule(ex), "extras", name))
+    return tuple(names), tuple(c for c in cols if c[0] is not None)
+
+
+def _note_rules(plan, rows: int) -> None:
+    """The host's account of the registered rules a flush program has
+    just been dispatched with: the counter that says the mechanism
+    engaged and one ``dq.rule`` span a rule, ``lowering="in-flush"`` — a
+    child of the flush span with nothing dispatched under it, since the
+    rule's operations are inside the flush's program (its scope
+    ``dq.rule`` names them in a capture)."""
+    if not plan.rule_names:
+        return
+    counters.increment("dq.rule_in_flush", len(plan.rule_names))
+    for rule in plan.rule_names:
+        with _obs.span("dq.rule", cat="dq", rule=rule, rows=rows,
+                       lowering="in-flush"):
+            pass
+
+
 def _donates() -> bool:
     """Whether fused single-device plans donate their replaced-column
     inputs (see ``_Plan.__init__``)."""
@@ -792,8 +995,10 @@ def cache_len() -> int:
 def _lookup_plan(steps, extra, base_schema, shard=None):
     # Probe via the SAME _linearize walk that builds plans: key equality
     # guarantees the probe's lit order matches the cached program's
-    # _ArgLit slots (the lowered trees are discarded on a hit).
-    key, lits, _steps, _extra, _refs = _linearize(steps, extra, base_schema)
+    # _ArgLit slots (the lowered trees are discarded on a hit, and are the
+    # new plan's on a miss).
+    walk = _linearize(steps, extra, base_schema)
+    key, lits = walk[0], walk[1]
     if shard is not None:
         key = shard.tag() + "|" + key
     key = plan_namespace_tag() + key
@@ -807,7 +1012,7 @@ def _lookup_plan(steps, extra, base_schema, shard=None):
         if plan is not None:
             _CACHE.move_to_end(key)
             return plan, lit_values
-    plan = _Plan(steps, extra, base_schema, shard)
+    plan = _Plan(steps, extra, walk, shard)
     plan.key = key                 # namespace rides the cached identity
     with _CACHE_LOCK:
         # Insert-if-absent: two threads can race past the probe and both
@@ -912,8 +1117,9 @@ def _run_chunked(plan, lit_values, data: dict, mask, n: int,
     memory BEFORE the allocator dies, instead of an OOM backtrace after.
 
     Sound because the compilable step surface is purely elementwise
-    (strings/UDFs/aggregates never defer; a filter's mask AND is
-    row-local), so slicing rows, replaying the SAME cached plan per
+    (strings and aggregates never defer, a UDF only when its function is
+    seen to be row-local; a filter's mask AND is row-local), so slicing
+    rows, replaying the SAME cached plan per
     slice, and concatenating is semantics-preserving — the chunk rows are
     a power of two, so all chunks but the tail share one compiled
     program. Counted ``pipeline.oom_chunked`` + a ``recovery.fallback``
@@ -988,6 +1194,7 @@ def _run_chunked(plan, lit_values, data: dict, mask, n: int,
             pieces_mask.append(new_mask)
             for k, v in extras.items():
                 pieces_extras.setdefault(k, []).append(v)
+        _note_rules(plan, n)
     compiled = plan.traces - before
     if nchunks > compiled:
         counters.increment("pipeline.hit", nchunks - compiled)
@@ -1004,9 +1211,15 @@ def _run_chunked(plan, lit_values, data: dict, mask, n: int,
     def cat(vs):
         return vs[0] if len(vs) == 1 else jnp.concatenate(vs)
 
+    changed = {k: cat(vs) for k, vs in pieces_changed.items()}
+    extras = {k: cat(vs) for k, vs in pieces_extras.items()}
     new_data = dict(data)
-    new_data.update({k: cat(vs) for k, vs in pieces_changed.items()})
+    new_data.update(changed)
     new_mask = cat(pieces_mask)
+    if config.dq_profile_enabled:
+        # ONE profile per flush, over the whole columns (a tally per chunk
+        # would count one evaluation of a rule as many)
+        _record_dq_profile(plan, changed, extras, new_mask, mask, n, None)
     if stats_on:
         # one record per flush (the chunked execution IS one logical
         # execution of this plan) — the heaviest plans are exactly the
@@ -1015,8 +1228,7 @@ def _run_chunked(plan, lit_values, data: dict, mask, n: int,
             plan, data, m, n,
             (time.perf_counter() - t_stats) * 1e3, compiled > 0,
             new_mask, est=est)
-    return (new_data, new_mask,
-            {k: cat(vs) for k, vs in pieces_extras.items()})
+    return new_data, new_mask, extras
 
 
 def _record_flush_stats(plan, data, b: int, n: int,
@@ -1051,13 +1263,13 @@ def _record_flush_stats(plan, data, b: int, n: int,
         logger.debug("stats hand-off failed", exc_info=True)
 
 
-def _record_dq_profile(steps, changed, new_mask, mask_in, b: int,
+def _record_dq_profile(plan, changed, extras, new_mask, mask_in, b: int,
                        shard) -> None:
     """Data-quality observatory hand-off (``utils/dqprof.py``): enqueue
-    deferred column-sketch reductions over this flush's outputs, plus
-    per-rule pass/fail reductions for every ``with_column`` step whose
-    expression is a registered DQ UDF — counted against the flush's
-    INPUT mask, because the reference app fuses ``rule`` and
+    deferred column-sketch reductions over this flush's outputs, plus a
+    ``[rows, passed]`` reduction for every output column that is a
+    registered DQ rule's (``plan.rule_cols``) — counted against the
+    flush's INPUT mask, because the reference app fuses ``rule`` and
     ``WHERE rule > 0`` into one flush and the output mask has already
     swallowed the violations. Called only when
     ``spark.dq.profile.enabled``; any failure is swallowed — profiling
@@ -1066,22 +1278,9 @@ def _record_dq_profile(steps, changed, new_mask, mask_in, b: int,
     from ..utils import dqprof as _dqprof
 
     try:
-        from . import expressions as E
-        from . import udf as _udf
-
-        registry = _udf.default_registry()
-        rules = []
-        for step in steps:
-            if step[0] == "with_column":
-                pairs = [(step[1], step[2])]
-            elif step[0] == "with_columns":
-                pairs = list(step[1])
-            else:
-                continue
-            for name, ex in pairs:
-                if (isinstance(ex, E.UdfCall) and name in changed
-                        and ex.udf_name in registry):
-                    rules.append((ex.udf_name, name))
+        outs = {"changed": changed, "extras": extras}
+        rules = [(rule, outs[where][name])
+                 for rule, where, name in plan.rule_cols]
         _dqprof.observe_flush(changed, new_mask, b, shard=shard,
                               rules=rules, mask_in=mask_in)
     except Exception:
@@ -1190,7 +1389,7 @@ def run_pipeline(data: dict, mask, n: int, steps, extra=(), shard=None):
     # BASE schema only (lazy: only referenced columns get dtype probes) —
     # _lookup_plan/_Plan evolve it step-by-step so a column read before a
     # later step replaces it stays a base input.
-    schema = LazySchema(data, ())
+    schema = LazySchema(data)
     try:
         b = n if shard is not None else bucket_size(n)
         # Stage-boundary placement (cost-based optimizer, level >= 2 —
@@ -1326,6 +1525,7 @@ def run_pipeline(data: dict, mask, n: int, steps, extra=(), shard=None):
                 else:
                     changed, new_mask, extras = plan.fn(
                         kept, donated, mask_in, lit_values)
+                _note_rules(plan, n)
                 compiled = plan.traces > before
             else:
                 with span_cm as sp:
@@ -1342,6 +1542,7 @@ def run_pipeline(data: dict, mask, n: int, steps, extra=(), shard=None):
                     else:
                         changed, new_mask, extras = plan.fn(
                             kept, donated, mask_in, lit_values)
+                    _note_rules(plan, n)
                     compiled = plan.traces > before
                     sp.set(cache="compile" if compiled else "hit")
         if not compiled:
@@ -1358,8 +1559,8 @@ def run_pipeline(data: dict, mask, n: int, steps, extra=(), shard=None):
         # arrays so sketch programs retrace per power-of-two bucket,
         # never per raw row count.
         if config.dq_profile_enabled:
-            _record_dq_profile(steps, changed, new_mask, mask_in, b,
-                               shard)
+            _record_dq_profile(plan, changed, extras, new_mask, mask_in,
+                               b, shard)
         if b != n:
             changed, new_mask, extras = _unpad_tree(
                 (changed, new_mask, extras), n)

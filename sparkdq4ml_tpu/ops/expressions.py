@@ -22,6 +22,7 @@ import math
 import re
 from typing import Any, Callable, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -569,46 +570,82 @@ class UdfCall(Expr):
 
     Resolution happens at eval time against the registry, matching Spark's
     name-based lookup (`DataQuality4MachineLearningApp.java:68-69,86-87`).
+
+    Two ways to run, chosen by what the function is seen to do
+    (``ops/udf.py``), never by a switch. A call whose function is
+    row-local — elementwise primitives only, one 1-D result, nothing
+    captured — is a compilable expression: ``with_column`` defers it like a
+    builtin and it runs inside the flush's compiled program, under the
+    named scope ``dq.rule``, with the plan's own copy of the function
+    (``_bound``: the one the plan key fingerprinted). A call whose function
+    looks at more than its own row (``x - jnp.mean(x)``, a cumulative sum)
+    or cannot be traced (numpy on its input, a Python branch on a value)
+    is evaluated eagerly on the frame's real columns, as every call was
+    before: padding, row slices and shards would change its answer.
+    Names the registry lacks resolve through the builtin function table,
+    eagerly, as before.
     """
 
     def __init__(self, udf_name: str, args: Sequence[Expr], registry=None):
         self.udf_name = udf_name
         self.args = list(args)
         self._registry = registry
+        # (fn, return_dtype), set by the flush compiler on the node of a
+        # rewritten plan so that the program and its key agree
+        self._bound = None
 
-    def eval(self, frame):
+    def registry(self):
         from .udf import default_registry
 
-        reg = self._registry if self._registry is not None else default_registry()
-        try:
-            fn, return_dtype = reg.lookup(self.udf_name)
-        except KeyError:
-            # Name-based fallback to the builtin function table, so SQL
-            # `abs(x)`, `upper(s)` etc. resolve without UDF registration
-            # (Spark's FunctionRegistry builtins behave the same way).
-            key = self.udf_name.lower()
-            if key in _ROW_FNS:     # frame-aware: need the row count
-                return _ROW_FNS[key](frame, self.args)
-            if key in _BUILTIN_FNS:
-                return Func(key, self.args).eval(frame)
-            raise
+        return (self._registry if self._registry is not None
+                else default_registry())
+
+    def eval(self, frame):
+        bound = self._bound
+        if bound is None:
+            try:
+                bound = self.registry().lookup(self.udf_name)
+            except KeyError:
+                # Name-based fallback to the builtin function table, so SQL
+                # `abs(x)`, `upper(s)` etc. resolve without UDF registration
+                # (Spark's FunctionRegistry builtins behave the same way).
+                key = self.udf_name.lower()
+                if key in _ROW_FNS:     # frame-aware: need the row count
+                    return _ROW_FNS[key](frame, self.args)
+                if key in _BUILTIN_FNS:
+                    return Func(key, self.args).eval(frame)
+                raise
+        fn, return_dtype = bound
         from ..config import config as _cfg
         from ..utils import observability as _obs
+        from ..utils.profiling import counters
 
+        def call(vals):
+            out = fn(*vals)
+            if return_dtype is not None:
+                out = jnp.asarray(out, return_dtype)
+            return out
+
+        vals = [a.eval(frame) for a in self.args]
+        if any(isinstance(v, jax.core.Tracer) for v in vals):
+            # Inside a compiled program (a flush that admitted this call):
+            # the rule's operations carry dq.rule in their op metadata, and
+            # the span, the counters and the [rows, passed] tally are the
+            # flush's to keep (ops/compiler.run_pipeline) — a trace runs
+            # once, the program many times.
+            with _obs.scope("rule"):
+                return call(vals)
         # One span per evaluation of a registered rule, beside the
         # dq.rule_evals counter. (No named scope here: a scope opened on
         # the host does not reach the metadata of the eager one-operation
         # programs a rule runs — PERF.md section 3; the dispatching span
         # is what tells them apart in a capture.)
+        counters.increment("dq.rule_eager")
         with _obs.span("dq.rule", cat="dq", rule=self.udf_name,
-                       rows=frame.num_slots):
-            vals = [a.eval(frame) for a in self.args]
-            out = fn(*vals)
-            if return_dtype is not None:
-                out = jnp.asarray(out, return_dtype)
+                       rows=frame.num_slots, lowering="eager"):
+            out = call(vals)
             # Data-quality observatory gate (utils/dqprof.py): ONE flag
-            # read; record_eval skips tracers itself, so a traced flush
-            # accounts through the compiler hook instead — never twice.
+            # read. The eager tally counts every slot the rule saw.
             if _cfg.dq_profile_enabled:
                 from ..utils import dqprof as _dqprof
 

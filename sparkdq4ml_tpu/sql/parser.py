@@ -1822,8 +1822,8 @@ def _filter_history_key(q, cat) -> Optional[str]:
         return None
     from ..ops import compiler as C
 
-    schema = C.LazySchema(frame._data_store, frame._pending_names())
-    return C.selectivity_key_for((("filter", where),), schema)
+    return C.selectivity_key_for((("filter", where),),
+                                 frame._pipe_schema())
 
 
 def _annotate_est_rows(tree: PlanNode, cat) -> None:

@@ -22,8 +22,15 @@ hot-path contracts:
   flush materializes records ``[rows, passed]`` against the flush's
   INPUT mask (the DQ convention: output > 0 = pass, so the counts
   survive the fused ``WHERE rule > 0`` filter that would otherwise
-  erase the failures). Eager UDF evaluations record through the same
-  queue (``ops/expressions.UdfCall``).
+  erase the failures). That is the path of a rule whose function is
+  row-local, the reference app's two among them: such a call defers
+  into the flush (``ops/udf.py`` says which do). A rule that stays eager
+  — a whole-column or host-only function, or any rule with the pipeline
+  off — records through the same queue from ``ops/expressions.UdfCall``,
+  and there ``rows`` is every slot the rule saw, rows an earlier filter
+  masked out included: the two denominators differ by exactly those
+  rows. A deferred call inside a larger expression (``rule(x) > 0`` as
+  a filter) has no column to tally and records nothing.
 * **drift scoring** — PSI over the fixed-bucket histograms against a
   pinned baseline (``spark.dq.baselineMode``): past
   ``spark.dq.driftThreshold`` the breach sets the ``dq.drift.<col>``
@@ -489,9 +496,11 @@ def observe_flush(changed, new_mask, bucket: int, shard=None,
     """The flush hook (``ops/compiler.run_pipeline``, gated there on ONE
     ``spark.dq.profile.enabled`` read): dispatch one sketch reduction
     per profiled output column over the PADDED bucket arrays, plus one
-    ``[rows, passed]`` reduction per registered-rule column against the
-    flush's input mask, and enqueue the device results for a later
-    batched drain — zero host syncs here.
+    ``[rows, passed]`` reduction per registered-rule column (``rules``:
+    ``(rule, values)`` pairs) against the flush's input mask, and
+    enqueue the device results for a later batched drain — zero host
+    syncs here. Each rule entry is one evaluation: ``dq.rule_evals``
+    moves here as it does in :func:`record_eval`.
 
     Rides the ``dq_profile`` fault site: ANY failure — injected or
     real — degrades this flush to unprofiled with a counted, structured
@@ -515,17 +524,20 @@ def observe_flush(changed, new_mask, bucket: int, shard=None,
                 continue
             fn = _program("sketch", b, v.dtype, shard)[0]
             entries.append(("col", str(name), 0, fn(v, new_mask)))
+        evals = 0
         if mask_in is not None:
-            for rule_name, col_name in rules:
-                v = changed.get(col_name)
-                if v is None or not _profilable(v, b):
+            for rule_name, v in rules:
+                if not _profilable(v, b):
                     continue
                 fn = _program("rule", b, v.dtype, shard)[0]
                 entries.append(("rule", str(rule_name), 0,
                                 fn(v, mask_in)))
+                evals += 1
         if not entries:
             return
         counters.increment("dq.sketches", len(entries))
+        if evals:
+            counters.increment("dq.rule_evals", evals)
         _enqueue(entries)
     except Exception as e:
         counters.increment("dq.profile_failed")

@@ -47,8 +47,13 @@ Wired through the framework (span names are a contract: the benchmark's
 * ``frame/native_csv.py`` — ``frame.ingest``,
 * ``ops/compiler.py`` / ``ops/segments.py`` — ``frame.pipeline.flush`` and
   ``frame.grouped.flush`` around the fused programs,
-* ``ops/expressions.py`` — ``dq.rule`` around a registered UDF rule's
-  evaluation (rule name, rows),
+* ``ops/expressions.py`` / ``ops/compiler.py`` — ``dq.rule``, one per
+  evaluation of a registered UDF rule (rule name, rows, and ``lowering``:
+  ``"in-flush"`` where the rule runs inside a flush's compiled program —
+  a child of ``frame.pipeline.flush`` with nothing dispatched under it,
+  counter ``dq.rule_in_flush`` — or ``"eager"`` where its function is not
+  row-local and each of its operations is a program, counter
+  ``dq.rule_eager``),
 * ``sql/parser.py`` — ``sql.query`` with the query text and an
   ``explain()``-style plan summary, and its children ``sql.parse``,
   ``sql.optimize`` (rewrites applied), ``sql.execute``,
@@ -79,7 +84,8 @@ Wired through the framework (span names are a contract: the benchmark's
 
 Inside the compiled programs :func:`scope` (``jax.named_scope`` under the
 ``dq.`` prefix) names the layer a device operation belongs to in its op
-metadata: ``dq.flush``, ``dq.sketch``, ``dq.grouped``, ``dq.exchange``,
+metadata: ``dq.flush`` (and inside it ``dq.rule``, a registered rule's
+operations), ``dq.sketch``, ``dq.grouped``, ``dq.exchange``,
 ``dq.feature.assemble`` (the assembler's one program),
 ``dq.fit.validate``, ``dq.fit.pack`` (what a fit makes of its columns
 before its passes: mask, scale, moments, the standardised design),
@@ -356,7 +362,14 @@ METRIC_NAMES = {
                           "flushes degraded to unprofiled by the "
                           "dq_profile fault ladder"),
     "dq.rule_evals": ("counter",
-                      "eager DQ-rule evaluations accounted"),
+                      "DQ-rule evaluations tallied by the dq profile, "
+                      "in a flush or eager"),
+    "dq.rule_in_flush": ("counter",
+                         "registered-rule calls run inside a flush's "
+                         "compiled program (the function is row-local)"),
+    "dq.rule_eager": ("counter",
+                      "registered-rule calls evaluated eagerly, one "
+                      "program an operation"),
     "dq.baseline_pinned": ("counter",
                            "drift baselines pinned (first drain or "
                            "persisted snapshot adoption)"),

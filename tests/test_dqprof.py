@@ -492,14 +492,21 @@ class TestDrift:
 
 
 class TestRuleAccounting:
-    def test_eager_udf_evals_accounted(self, session):
+    @pytest.mark.parametrize("path", ["in_flush", "eager_pipeline_off"])
+    def test_udf_evals_accounted(self, session, path):
         config.dq_profile_enabled = True
         dq.register_builtin_rules()
         price = np.where(np.arange(40) % 4 == 0, 5.0, 50.0)
         f = Frame({"price": price.astype(np.float64)})
-        f = f.with_column("pnm", dq.call_udf("minimumPriceRule",
-                                             dq.col("price")))
-        f.count()
+        saved = config.pipeline
+        config.pipeline = path == "in_flush"
+        try:
+            f = f.with_column("pnm", dq.call_udf("minimumPriceRule",
+                                                 dq.col("price")))
+            assert bool(f._pending) == (path == "in_flush")
+            f.count()
+        finally:
+            config.pipeline = saved
         rep = dqprof.report()
         row = next(r for r in rep["rules"]
                    if r["rule"] == "minimumPriceRule")
@@ -514,6 +521,87 @@ class TestRuleAccounting:
             "dq.violations.minimumPriceRule") == 10 * evals
         assert obs.METRICS.get_gauge(
             "dq.violation_rate.minimumPriceRule") == pytest.approx(0.25)
+
+    @pytest.mark.parametrize("path", ["in_flush", "eager_pipeline_off",
+                                      "eager_whole_column"])
+    def test_rule_tally_behind_an_earlier_filter(self, session, path):
+        """What each path counts a rule's rows against. In a flush: the
+        flush's INPUT mask — the rows an earlier, already materialised
+        filter dropped are out, and the fused ``WHERE rule > 0`` behind
+        the rule does not eat the violations. Eagerly: every slot the
+        rule saw, masked ones included. The violations the two report
+        differ by exactly the masked rows' — the rate of a rule over
+        valid rows is the flush's."""
+        from sparkdq4ml_tpu.ops import expressions as E
+        from sparkdq4ml_tpu.ops.udf import UDFRegistry
+
+        config.dq_profile_enabled = True
+        reg = UDFRegistry()
+        if path == "eager_whole_column":
+            # looks at every row (adds 0 * the column's maximum): not
+            # row-local, so it stays eager with the pipeline on
+            reg.register("floor", lambda x: jax.numpy.where(
+                x < 20.0, -1.0, x) + 0.0 * jax.numpy.max(x), "double")
+        else:
+            reg.register("floor", lambda x: jax.numpy.where(
+                x < 20.0, -1.0, x), "double")
+        price = np.where(np.arange(40) % 4 == 0, 5.0, 50.0)
+        guest = np.arange(40)
+        saved = config.pipeline
+        config.pipeline = path != "eager_pipeline_off"
+        before = {k: profiling.counters.get(k) for k in (
+            "dq.rule_evals", "dq.rule_in_flush", "dq.rule_eager",
+            "pipeline.fallback")}
+        try:
+            f = Frame({"price": price, "guest": guest})
+            f = f.filter(E.col("guest") >= 20)      # 20 rows stay
+            assert f.count() == 20                  # ... materialised
+            g = f.with_column("r", E.UdfCall("floor", [E.col("price")],
+                                             reg))
+            assert bool(g._pending) == (path == "in_flush")
+            assert g.filter(E.col("r") > 0).count() == 15
+        finally:
+            config.pipeline = saved
+        moved = {k: profiling.counters.get(k) - v
+                 for k, v in before.items()}
+        row = next(r for r in dqprof.report()["rules"]
+                   if r["rule"] == "floor")
+        if path == "in_flush":
+            assert (row["evals"], row["rows"], row["violations"]) == \
+                (1, 20, 5)
+            assert moved == {"dq.rule_evals": 1, "dq.rule_in_flush": 1,
+                             "dq.rule_eager": 0, "pipeline.fallback": 0}
+        else:
+            assert (row["evals"], row["rows"], row["violations"]) == \
+                (1, 40, 10)
+            assert moved == {"dq.rule_evals": 1, "dq.rule_in_flush": 0,
+                             "dq.rule_eager": 1, "pipeline.fallback": 0}
+
+    def test_rule_in_a_projection_is_tallied_nested_is_not(self, session):
+        """A rule that IS a column of the flush — a with_column or a
+        SELECT item — has its tally; one inside a larger expression has
+        no column to count (its values exist inside the program only):
+        it is counted as run in the flush and tallies nothing."""
+        config.dq_profile_enabled = True
+        dq.register_builtin_rules()
+        price = np.where(np.arange(40) % 4 == 0, 5.0, 50.0)
+        Frame({"price": price, "guest": np.arange(40.0)}
+              ).create_or_replace_temp_view("t")
+        before = profiling.counters.get("dq.rule_in_flush")
+        out = session.sql("SELECT minimumPriceRule(price) AS p, guest + 1 "
+                          "AS g FROM t WHERE guest >= 20")
+        assert out.count() == 20
+        assert profiling.counters.get("dq.rule_in_flush") == before + 1
+        row = next(r for r in dqprof.report()["rules"]
+                   if r["rule"] == "minimumPriceRule")
+        assert (row["evals"], row["rows"], row["violations"]) == (1, 40, 10)
+        nested = session.sql("SELECT guest FROM t WHERE "
+                             "minimumPriceRule(price) > 0")
+        assert nested.count() == 30
+        assert profiling.counters.get("dq.rule_in_flush") == before + 2
+        row = next(r for r in dqprof.report()["rules"]
+                   if r["rule"] == "minimumPriceRule")
+        assert row["evals"] == 1                     # nothing added
 
     def test_rule_rows_recorded_when_the_flush_donates(self, session,
                                                        monkeypatch):

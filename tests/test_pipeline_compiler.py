@@ -17,6 +17,7 @@ Covers the ISSUE-3 acceptance surface:
 import os
 import tempfile
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -583,3 +584,383 @@ def test_flush_span_attrs(session):
         assert spans[0].attrs["cache"] in ("compile", "hit")
     finally:
         obs.disable()
+
+
+# ---------------------------------------------------------------------------
+# Registered UDFs: a row-local function defers like a builtin (ISSUE 31)
+# ---------------------------------------------------------------------------
+
+def _registry(**fns):
+    """A registry of its own for a test: nothing leaks into the default."""
+    from sparkdq4ml_tpu.ops.udf import UDFRegistry
+
+    reg = UDFRegistry()
+    for name, fn in fns.items():
+        reg.register(name, fn, "double")
+    return reg
+
+
+def _rule_frame(n=6):
+    """Prices around both rules' thresholds, NaN rows included; any length
+    (the six of ``_base_frame`` first)."""
+    rng = np.random.default_rng(31)
+    price = np.concatenate([
+        [10.0, 25.5, 3.0, 95.0, float("nan"), 7.25],
+        rng.uniform(1.0, 120.0, max(n - 6, 0))])[:n]
+    guest = np.concatenate([[2, 5, 1, 20, 8, 3],
+                            rng.integers(1, 40, max(n - 6, 0))])[:n]
+    price[7::11] = np.nan
+    return Frame({"price": price, "guest": guest.astype(np.int64)})
+
+
+def _builtin_rule_calls(reg):
+    return {
+        "minimumPriceRule": lambda: E.UdfCall(
+            "minimumPriceRule", [E.col("price")], reg),
+        "priceCorrelationRule": lambda: E.UdfCall(
+            "priceCorrelationRule", [E.col("price"), E.col("guest")], reg),
+    }
+
+
+def _rules_registry():
+    from sparkdq4ml_tpu.ops import rules
+
+    return _registry(minimumPriceRule=rules.minimum_price_rule,
+                     priceCorrelationRule=rules.price_correlation_rule)
+
+
+@pytest.mark.parametrize("rule", ["minimumPriceRule",
+                                  "priceCorrelationRule"])
+def test_builtin_rule_defers_and_equals_eager(rule):
+    """Both rules of the reference app are row-local: the call defers, the
+    rule and the filter behind it are one program, and values and mask
+    are the eager path's bit for bit — NaN rows included (rule 1 lets a
+    NaN through, rule 2 answers -1 for one)."""
+    counters.clear("dq.")
+    call = _builtin_rule_calls(_rules_registry())[rule]
+
+    def run(f):
+        return f.with_column("r", call()).filter(E.col("r") > 0)
+
+    fused = run(_rule_frame(40))
+    assert len(fused._pending) == 2, "the rule did not defer"
+    eager = _eager(lambda: run(_rule_frame(40)))
+    assert not eager._pending
+    np.testing.assert_array_equal(np.asarray(fused._mask),
+                                  np.asarray(eager._mask))
+    np.testing.assert_array_equal(       # every slot, masked ones too
+        np.asarray(fused._data["r"]), np.asarray(eager._data["r"]))
+    _frames_equal(fused, eager)
+    assert counters.get("pipeline.flush") == 1
+    assert counters.get("pipeline.compile") == 1
+    assert counters.get("pipeline.fallback") == 0
+    assert counters.get("dq.rule_in_flush") == 1
+    assert counters.get("dq.rule_eager") == 1      # the eager twin's
+
+
+def test_rule_filter_and_cast_are_one_flush_replayed_as_a_hit(session):
+    """The reference app's first SQL statement behind its first rule: the
+    rule, ``WHERE rule > 0`` and the projection's cast are ONE flush of
+    ONE program; the same statement again replays it."""
+    import sparkdq4ml_tpu as dq
+
+    dq.register_builtin_rules()
+
+    def job():
+        rng = np.random.default_rng(3)
+        df = Frame({"guest": rng.integers(1, 40, 600).astype(np.float64),
+                    "price": rng.uniform(1.0, 120.0, 600)})
+        df = df.with_column(
+            "price_no_min", dq.call_udf("minimumPriceRule", dq.col("price")))
+        df.create_or_replace_temp_view("t")
+        out = session.sql("SELECT cast(guest as int) guest, price_no_min "
+                          "AS price FROM t WHERE price_no_min > 0")
+        return out, out.count()
+
+    counters.clear("dq.")
+    first, rows = job()
+    assert counters.get("pipeline.flush") == 1
+    assert counters.get("pipeline.compile") == 1
+    assert counters.get("dq.rule_in_flush") == 1
+    second, rows2 = job()
+    assert rows2 == rows
+    assert counters.get("pipeline.flush") == 2
+    assert counters.get("pipeline.compile") == 1     # replayed
+    assert counters.get("pipeline.hit") == 1
+    assert counters.get("pipeline.fallback") == 0
+    assert counters.get("dq.rule_eager") == 0
+    off, rows_off = _eager(job)
+    assert rows_off == rows
+    _frames_equal(first, off)
+
+
+def _np_on_input(x):
+    return jnp.asarray(np.asarray(x) * 2.0)
+
+
+_NOT_ROW_LOCAL = {
+    "whole_column_mean": lambda x: x - jnp.mean(x),
+    "cumulative_sum": lambda x: jnp.cumsum(x),
+    "numpy_on_its_input": _np_on_input,
+    "two_dimensional": lambda x: jnp.stack([x, x], axis=1),
+    "python_branch_on_a_value": lambda x: x if x[0] > 0 else -x,
+    "captured_array": lambda x, _t=np.arange(6.0): x + jnp.asarray(_t),
+    "reversed": lambda x: x[::-1],
+}
+
+@pytest.mark.parametrize("kind", sorted(_NOT_ROW_LOCAL))
+def test_udf_that_is_not_row_local_stays_eager(kind):
+    """Padding, row slices and shards would change what such a function
+    sees (or it cannot be traced at all): it keeps the eager path and the
+    eager accounting, and is no degraded path."""
+    counters.clear("dq.")
+    reg = _registry(f=_NOT_ROW_LOCAL[kind])
+    reg.register("f", _NOT_ROW_LOCAL[kind])        # its natural dtype
+    f = Frame({"price": [10.0, 25.5, 3.0, 95.0, 1.0, 7.25]})
+    g = f.with_column("r", E.UdfCall("f", [E.col("price")], reg))
+    assert not g._pending, f"{kind} deferred"
+    assert counters.get("dq.rule_eager") == 1
+    assert counters.get("dq.rule_in_flush") == 0
+    assert counters.get("pipeline.fallback") == 0
+    assert counters.get("pipeline.flush") == 0
+    want = np.asarray(_NOT_ROW_LOCAL[kind](
+        jnp.asarray(f._data["price"])))
+    np.testing.assert_array_equal(np.asarray(g._data["r"]), want)
+
+
+def test_reregistered_name_misses_the_cache_and_gives_the_new_values():
+    reg = _registry(r=lambda x: jnp.where(x < 20.0, -1.0, x))
+
+    def run():
+        return _rule_frame().with_column(
+            "r", E.UdfCall("r", [E.col("price")], reg)).to_pydict()["r"]
+
+    first = np.asarray(run())
+    assert counters.get("pipeline.compile") == 1
+    np.testing.assert_array_equal(
+        first, [-1.0, 25.5, -1.0, 95.0, np.nan, -1.0])
+    reg.register("r", lambda x: x * 2.0, "double")   # another function
+    second = np.asarray(run())
+    assert counters.get("pipeline.compile") == 2     # not the old program
+    np.testing.assert_array_equal(
+        second, [20.0, 51.0, 6.0, 190.0, np.nan, 14.5])
+    assert counters.get("pipeline.fallback") == 0
+
+
+def _udf_key(reg, name="r", args=("price",)):
+    f = _rule_frame().with_column(
+        "r", E.UdfCall(name, [E.col(a) for a in args], reg))
+    key = compiler._linearize(f._pending, (), f._pipe_schema())[0]
+    assert key.count("|") == len(f._pending), key   # no | in a fragment
+    return key
+
+
+@pytest.mark.parametrize("case", ["same_function_twice",
+                                  "another_literal_inside",
+                                  "another_return_type",
+                                  "two_registries_one_name"])
+def test_udf_plan_key_names_the_function(case):
+    """The key holds a fingerprint of what the function does: the same
+    function under one name shares a program however often it is
+    registered, and nothing else does."""
+    def floor(at):
+        return lambda x: jnp.where(x < at, -1.0, x)
+
+    reg = _registry(r=floor(20.0))
+    key = _udf_key(reg)
+    if case == "same_function_twice":
+        reg.register("r", floor(20.0), "double")
+        assert _udf_key(reg) == key
+        assert _udf_key(_registry(r=floor(20.0))) == key
+    elif case == "another_literal_inside":
+        reg.register("r", floor(21.0), "double")
+        assert _udf_key(reg) != key
+    elif case == "another_return_type":
+        reg.register("r", floor(20.0), "float")
+        assert _udf_key(reg) != key
+    else:
+        assert _udf_key(_registry(r=lambda x: x + 1.0)) != key
+
+
+@pytest.mark.parametrize("name", ["a|b", "it's", 'say "r"', "r)|W('x')=V(1"])
+def test_adversarial_rule_names_keep_the_key_whole(name):
+    reg = _registry(**{name: lambda x: x + 1.0})
+    key = _udf_key(reg, name)
+    other = _udf_key(_registry(r=lambda x: x + 1.0))
+    assert key != other
+
+
+@pytest.mark.parametrize("rows", [6, 1000, 1025])
+def test_rule_over_a_padded_tail_equals_eager(rows):
+    """A length that is no bucket size: the padded tail rides a False
+    mask and the rule never sees it in the result."""
+    calls = _builtin_rule_calls(_rules_registry())
+
+    def run():
+        f = _rule_frame(rows)
+        return (f.with_column("r1", calls["minimumPriceRule"]())
+                .filter(E.col("r1") > 0)
+                .with_column("r2", calls["priceCorrelationRule"]())
+                .filter(E.col("r2") > 0))
+
+    fused = run()
+    assert len(fused._pending) == 4
+    eager = _eager(run)
+    _frames_equal(fused, eager)
+    np.testing.assert_array_equal(np.asarray(fused._mask),
+                                  np.asarray(eager._mask))
+    assert counters.get("pipeline.flush") == 1
+    assert counters.get("pipeline.fallback") == 0
+
+
+def test_rule_in_a_row_chunked_flush_equals_eager():
+    """An over-budget flush runs in row slices: sound for a rule because
+    only a row-local function is ever in a flush."""
+    from sparkdq4ml_tpu.utils import faults
+
+    counters.clear("dq.")
+    calls = _builtin_rule_calls(_rules_registry())
+
+    def run():
+        f = _rule_frame(4096)
+        return (f.with_column("r2", calls["priceCorrelationRule"]())
+                .filter(E.col("r2") > 0))
+
+    eager = _eager(run)
+    counters.clear("dq.")
+    with faults.inject_faults("oom:oom:1:n=64", seed=3):
+        fused = run()
+        _frames_equal(fused, eager)
+    np.testing.assert_array_equal(np.asarray(fused._mask),
+                                  np.asarray(eager._mask))
+    assert counters.get("pipeline.oom_chunked") == 1
+    assert counters.get("dq.rule_in_flush") == 1     # one flush, one rule
+    assert counters.get("dq.rule_eager") == 0
+    assert counters.get("pipeline.fallback") == 0
+
+
+@pytest.mark.parametrize("shape", ["nested_in_a_filter",
+                                   "argument_is_an_expression",
+                                   "argument_is_a_pending_column",
+                                   "literal_argument",
+                                   "aliased", "with_columns"])
+def test_udf_call_shapes_defer_and_equal_eager(shape):
+    reg = _registry(
+        floor=lambda x: jnp.where(x < 20.0, -1.0, x),
+        scaled=lambda x, k: x * k)
+
+    def run():
+        f = _rule_frame(40)
+        floor = lambda a: E.UdfCall("floor", [a], reg)   # noqa: E731
+        if shape == "nested_in_a_filter":
+            return f.filter(floor(E.col("price")) > 0)
+        if shape == "argument_is_an_expression":
+            return f.with_column("r", floor(E.col("price") / 2 + 1))
+        if shape == "argument_is_a_pending_column":
+            return (f.with_column("half", E.col("guest") / 2)
+                    .with_column("r", floor(E.col("half"))))
+        if shape == "literal_argument":
+            return f.with_column(
+                "r", E.UdfCall("scaled", [E.col("price"), E.Lit(3)], reg))
+        if shape == "aliased":
+            return f.with_column("r", floor(E.col("price")).alias("x"))
+        return f.with_columns({"price": floor(E.col("price")),
+                               "was": E.col("price") + 0.0})
+
+    fused = run()
+    assert fused._pending, f"{shape} did not defer"
+    eager = _eager(run)
+    _frames_equal(fused, eager)
+    np.testing.assert_array_equal(np.asarray(fused._mask),
+                                  np.asarray(eager._mask))
+    assert counters.get("pipeline.flush") == 1
+    assert counters.get("pipeline.fallback") == 0
+
+
+def test_udf_literal_arguments_share_one_program():
+    reg = _registry(scaled=lambda x, k: x * k)
+
+    def run(k):
+        return _rule_frame().with_column(
+            "r", E.UdfCall("scaled", [E.col("price"), E.Lit(k)], reg)
+        ).to_pydict()["r"]
+
+    a, b = np.asarray(run(2.0)), np.asarray(run(3.0))
+    np.testing.assert_array_equal(b[:4], a[:4] * 1.5)
+    assert counters.get("pipeline.compile") == 1
+    assert counters.get("pipeline.hit") == 1
+
+
+@pytest.mark.parametrize("fn,sql", [
+    ("abs", "SELECT abs(price - 50) AS v, guest FROM t WHERE guest > 3"),
+    ("upper", "SELECT upper(city) AS v, guest FROM t WHERE guest > 3"),
+])
+def test_unregistered_sql_builtin_behaves_as_before(session, fn, sql):
+    """The parser builds a UdfCall for every function call; a name the
+    registry lacks is no rule: it resolves through the builtin table,
+    eagerly, and moves neither rule counter."""
+    from sparkdq4ml_tpu.ops.compiler import is_compilable
+
+    counters.clear("dq.")
+    f = _base_frame()
+    call = E.UdfCall(fn, [E.col("city" if fn == "upper" else "price")])
+    assert not is_compilable(call, f._pipe_schema())
+    assert not f.with_column("v", call)._pending
+    f.create_or_replace_temp_view("t")
+    on = session.sql(sql)
+    off = _eager(lambda: session.sql(sql))
+    _frames_equal(on, off)
+    assert counters.get("dq.rule_in_flush") == 0
+    assert counters.get("dq.rule_eager") == 0
+    assert counters.get("pipeline.fallback") == 0
+
+
+@pytest.mark.parametrize("what,fn,dtypes,admitted", [
+    ("rule_1", "minimum_price_rule", ["float32"], True),
+    ("rule_2", "price_correlation_rule", ["float32", "int32"], True),
+    ("rule_2_f64", "price_correlation_rule", ["float64", "int64"], True),
+    ("jnp_compositions", lambda x: jnp.clip(jnp.nan_to_num(x), 0, 1)
+     + jnp.sign(x) * jnp.maximum(x, 0.0) + jnp.tanh(x), ["float32"], True),
+    ("captured_scalar", lambda x, _k=np.float32(3.0): x * _k,
+     ["float32"], True),
+    ("integer_ops", lambda x: (x // 3) % 5 + (x << 1), ["int32"], True),
+    ("roll", lambda x: jnp.roll(x, 1), ["float32"], False),
+    ("sort", lambda x: jnp.sort(x), ["float32"], False),
+    ("row_number", lambda x: x + jnp.arange(x.shape[0]), ["float32"],
+     False),
+    ("scalar_result", lambda x: jnp.float32(1.0), ["float32"], False),
+    ("tuple_result", lambda x: (x, x), ["float32"], False),
+    ("argmax_gather", lambda x: x[jnp.argmax(x)] + x, ["float32"], False),
+    ("wrong_arity", lambda x, y: x + y, ["float32"], False),
+])
+def test_probe_admits_row_local_functions_only(what, fn, dtypes, admitted):
+    from sparkdq4ml_tpu.ops import rules
+    from sparkdq4ml_tpu.ops.udf import probe_elementwise
+
+    if isinstance(fn, str):
+        fn = getattr(rules, fn)
+    fp = probe_elementwise(fn, [np.dtype(d) for d in dtypes])
+    assert (fp is not None) == admitted
+    if admitted:
+        assert fp == probe_elementwise(fn, [np.dtype(d) for d in dtypes])
+        assert "|" not in fp
+
+
+def test_probe_verdict_is_cached_on_the_entry_and_goes_with_it():
+    calls = []
+
+    def fn(x):
+        calls.append(1)
+        return x + 1.0
+
+    reg = _registry(r=fn)
+    f32 = [np.dtype("float32")]
+    first = reg.elementwise("r", f32)
+    assert first is not None and first[0] is fn
+    n = len(calls)
+    assert reg.elementwise("r", f32) == first
+    assert len(calls) == n                          # not traced again
+    reg.register("r", lambda x: jnp.cumsum(x), "double")
+    assert reg.elementwise("r", f32) is None        # the new function's
+    assert reg.elementwise("missing", f32) is None
+    assert reg.lookup("r")[1] == np.dtype("float64")

@@ -111,9 +111,43 @@ SWEEP = [
     ("with_columns", lambda f: f.with_columns(
         {"o": E.col("a") + 1, "a": E.col("a") * 0.0})),
     ("chain20", lambda f: _chain20(f)),
+    # a registered rule whose function is row-local runs inside the
+    # shard_map program like a builtin (ISSUE 31)
+    ("udf_rule", lambda f: _rule_chain(f)),
+    ("udf_rule_nested", lambda f: f.filter(
+        E.UdfCall("floorRule", [E.col("c") * 3.0], _RULES) > 0)),
     ("fused_select", lambda f: f.filter(E.col("c") > 0.5).select(
         (E.col("c") * 2).alias("o"), (E.col("b") + 1).alias("p"))),
 ]
+
+
+def _floor_rule(x):
+    return jnp.where(x < 4.0, -1.0, x)
+
+
+def _pair_rule(x, k):
+    bad = jnp.logical_or(jnp.isnan(x), jnp.logical_and(k < 0, x > 5.0))
+    return jnp.where(bad, -1.0, x)
+
+
+def _make_rules():
+    from sparkdq4ml_tpu.ops.udf import UDFRegistry
+
+    reg = UDFRegistry()
+    reg.register("floorRule", _floor_rule, "double")
+    reg.register("pairRule", _pair_rule, "double")
+    return reg
+
+
+_RULES = _make_rules()
+
+
+def _rule_chain(f):
+    f = f.with_column("r1", E.UdfCall("floorRule", [E.col("c")], _RULES))
+    f = f.filter(E.col("r1") > 0)
+    f = f.with_column(
+        "r2", E.UdfCall("pairRule", [E.col("a"), E.col("b")], _RULES))
+    return f.filter(E.col("r2") > 0)
 
 
 def _chain20(f):
@@ -131,6 +165,27 @@ class TestBitParity:
         with sharding():
             out = op(shard.shard_frame(f)).to_pydict()
         _eq(ref, out)
+
+    def test_deferred_rule_on_a_sharded_frame_is_one_program(self):
+        """The rule defers on a sharded frame as on its single-device
+        twin: one shard_map program a flush, no eager evaluation, no
+        degraded path, the same rows."""
+        f = _frame(200, seed=11)
+        ref = _rule_chain(f)
+        assert len(ref._pending) == 4
+        ref = ref.to_pydict()
+        profiling.counters.clear("dq.")
+        profiling.counters.clear("pipeline")
+        with sharding():
+            g = _rule_chain(shard.shard_frame(f))
+            assert len(g._pending) == 4 and g._shard is not None
+            out = g.to_pydict()
+        _eq(ref, out)
+        assert profiling.counters.get("pipeline.flush") == 1
+        assert profiling.counters.get("dq.rule_in_flush") == 2
+        assert profiling.counters.get("dq.rule_eager") == 0
+        assert profiling.counters.get("pipeline.fallback") == 0
+        assert profiling.counters.get("pipeline.shard_gather") == 0
 
     @pytest.mark.parametrize("devices", [2, 4, 8])
     def test_device_counts(self, devices):

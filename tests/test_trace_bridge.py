@@ -380,7 +380,7 @@ def flow_spans(request, tmp_path):
 
 # (flow, span, how many a job, its parent)
 SPAN_TABLE = [
-    ("catering", "dq.rule", 2, "frame.with_column"),
+    ("catering", "dq.rule", 2, "frame.pipeline.flush"),
     ("catering", "frame.count", 2, None),
     ("catering", "sql.parse", 3, "sql.query"),
     ("catering", "sql.optimize", 3, "sql.query"),
@@ -457,8 +457,10 @@ def test_span_attributes_carry_the_counts(flow_spans):
     assert attrs["model.transform"]["rows"] == ROWS
     assert attrs["sql.optimize"]["rewrites"] >= 0
     if "dq.rule" in attrs:
+        # both rules are row-local, so each runs inside the flush of its
+        # SQL statement: the span lies under that flush's
         assert attrs["dq.rule"] == {"rule": "minimumPriceRule",
-                                    "rows": ROWS}
+                                    "rows": ROWS, "lowering": "in-flush"}
         assert attrs["frame.count"]["host_read_bytes"] in (4, 8)
     if "fit.validate" in attrs:
         # the stats vector of base.label_stats, whatever the row count:
@@ -472,6 +474,10 @@ def test_rule_evals_and_rule_spans_count_alike(flow_spans):
     tree, counters = flow_spans
     rules = sum(1 for n, _, _ in tree if n == "dq.rule")
     assert counters.get("dq.rule_evals", 0) == rules
+    # ... and every one of them ran inside a flush's program
+    assert counters.get("dq.rule_in_flush", 0) == rules
+    assert counters.get("dq.rule_eager", 0) == 0
+    assert counters.get("pipeline.fallback", 0) == 0
     assert counters["host.reads"] >= 3
     assert counters["host.read_bytes"] > 0
     # a profiler session changes no counter a job reads: the fit root's
