@@ -37,7 +37,7 @@ import numpy as np
 
 from ..config import config, float_dtype, int_dtype
 from ..ops.expressions import Col, Expr, spark_type_name
-from ..utils.observability import op_span, span
+from ..utils.observability import current_span, op_span, span
 from ..utils.profiling import counters, host_read
 
 logger = logging.getLogger("sparkdq4ml_tpu.frame")
@@ -131,9 +131,16 @@ def lexsort_keys(arrays, ascending, nulls_first):
 
 
 def _vector_join_plan(lcols, rcols, li, ri, how, build_left=False):
-    """Vectorized hash-join *plan* for all-numeric keys — (lpairs, rpairs)
-    row-index arrays, or None when ineligible (non-finite float keys, or
-    integers float64 can't hold exactly).
+    """The host's vectorized join *plan* for all-numeric keys — (lpairs,
+    rpairs) row-index arrays, or None when ineligible (non-finite float
+    keys, or integers float64 can't hold exactly; the dict plan in
+    :meth:`Frame._host_join` then answers).
+
+    Since the device plan (``ops/joins.py``) this is the plan of what stays
+    on the host with numeric keys: ``right`` / ``outer`` joins, an integer
+    key against a float one, sharded frames (per partition, through
+    ``parallel/shard.partitioned_join_plan``) and frames with host (string)
+    columns to gather. It works on the pulled keys of the valid rows.
 
     ``build_left`` (cost-based optimizer hint, inner joins only): sort
     the LEFT side instead of the right — the win when the left is the
@@ -143,20 +150,13 @@ def _vector_join_plan(lcols, rcols, li, ri, how, build_left=False):
     its right matches ascending), so the swapped plan's pairs
     re-canonicalize with one lexsort.
 
-    The Spark analogue of this step is the driver's shuffle planning; the
-    dict-based fallback in :meth:`Frame.join` is interpreter-bound at ~10⁶
-    rows, while this path is pure numpy: single-key joins use the float64
-    key values directly as sortable ids, multi-key joins assign integer
-    group ids with ONE lexsort over the concatenated rows (np.unique(axis=0)
-    would be ~5× slower), then one stable argsort of the right ids +
-    run-length-encoded binary-search group lookups — emitting pairs in
-    exactly the fallback's order (left rows in order, each with its right
-    matches in right order; unmatched right rows appended in order for
-    right/outer).
-
-    Micro-bench (this machine, 10⁶-row inner join, int keys, ~1 match/row):
-    dict plan ~2.0 s, this plan ~0.6 s (3.5×); the gap widens with match
-    multiplicity since pair emission here is ``np.repeat``, not ``list.append``.
+    Pure numpy: single-key joins use the float64 key values directly as
+    sortable ids, multi-key joins assign integer group ids with ONE
+    lexsort over the concatenated rows, then one stable argsort of the
+    right ids + run-length-encoded binary-search group lookups — emitting
+    pairs in exactly the dict plan's order (left rows in order, each with
+    its right matches in right order; unmatched right rows appended in
+    order for right/outer).
     """
     if build_left and how == "inner":
         swapped = _vector_join_plan(rcols, lcols, ri, li, "inner")
@@ -1062,6 +1062,14 @@ class Frame:
     where = filter
 
     def limit(self, n: int) -> "Frame":
+        if self._shard is None and self._every_slot_valid():
+            # a compact frame (a sort's or a GROUP BY's result): its first
+            # n rows are its first n slots — a slice, so that what follows
+            # (``to_pydict`` of ten ranked rows) reads n rows, not a mask
+            # over every group
+            if n >= self._n:
+                return self
+            return Frame({k: v[:max(n, 0)] for k, v in self._data.items()})
         keep = jnp.cumsum(self._mask.astype(jnp.int32)) <= n
         return self._with(mask=jnp.logical_and(self._mask, keep))
 
@@ -1950,37 +1958,57 @@ class Frame:
     def join(self, other: "Frame", on, how: str = "inner",
              build: Optional[str] = None,
              est: Optional[tuple] = None) -> "Frame":
-        """Relational join on key column(s) present in both frames.
+        """Relational join on key column(s).
 
         ``how``: ``inner`` | ``left`` | ``right`` | ``outer``/``full`` |
-        ``left_semi`` | ``left_anti`` | ``cross``. Key columns appear once in
-        the result (Spark's ``USING`` semantics); a non-key column name
-        present on both sides keeps the left column and surfaces the right
-        one as ``<name>_right`` (explicit, instead of Spark's ambiguous
-        duplicate).
+        ``left_semi`` | ``left_anti`` | ``cross``. ``on`` is a column name
+        or a list of them; a key is a name both frames carry (Spark's
+        ``USING``: the key column appears once in the result) or a pair
+        ``(left name, right name)`` (SQL's ``ON a = b``: the right frame's
+        column takes the left name in its column table — no copy — and an
+        inner join's result carries the right name as a second name of the
+        same column; a left/right/outer join keeps the right key as a
+        column of its own, null where its row is missing). A non-key
+        column name present on both sides keeps the left column and
+        surfaces the right one as ``<name>_right`` (explicit, instead of
+        Spark's ambiguous duplicate).
+
+        Only valid (mask=True) rows take part. **Where the plan is made**
+        follows from what the frames are: numeric keys of one kind on
+        both sides (integers with integers, floats with floats), ``how``
+        one of ``inner`` / ``left`` / ``left_semi`` / ``left_anti``,
+        neither side sharded and every column to gather on the device ->
+        the match plan, the pair order and the column gathers are one
+        compiled program (``ops/joins.py``: a sort-merge over
+        ``lax.sort``); the host reads one scalar, the result's row count,
+        and the result is a frame of a bucket of slots under a mask.
+        String keys, an integer key against a float one, ``right`` /
+        ``outer`` / ``cross``, a sharded side and host (string) columns to
+        gather keep the **host plan**: masks and key columns are pulled,
+        ``_vector_join_plan`` or the dict plan pairs the rows, and only
+        the ``jnp.take`` gathers run on the device. Every join counts
+        ``join.device`` or ``join.host`` and names its ``lowering`` in the
+        ``frame.join`` span; every blocking read on either path counts
+        ``host.reads`` / ``host.read_bytes``.
+
+        Emission order is the same on both paths: the pairs in (left row,
+        right row) order; unmatched right rows of right/outer joins
+        appended in order. Unmatched slots fill with NaN (numeric, int
+        promotes to float) or None (string).
 
         ``build="left"`` (cost-based optimizer hint, inner joins only):
-        plan the hash join building from the LEFT side — the win when
-        the left is the small side. Result is bit-identical, emission
-        order included (see ``_vector_join_plan``); any other value or
-        join type ignores the hint.
+        build from the LEFT side — the win when the left is the small
+        side; without a hint the device plan builds from the side with
+        fewer slots. The result is bit-identical either way.
 
         ``est=(left_rows, right_rows)`` (adaptive execution input,
         ``sql/adaptive.py``): the optimizer's pre-execution row
         estimates for the two sides. When ``spark.aqe.enabled`` and the
-        OBSERVED valid-row counts drift past ``spark.aqe.driftFactor``,
-        the build side re-decides mid-query and a small-enough observed
-        build side skips the hash-partition shuffle (both transforms
-        bit-identical by construction). ``None`` (or AQE off) keeps the
-        static plan.
-
-        Design: only valid (mask=True) rows participate. The match *plan*
-        (row-index pairs) is computed host-side with a hash join — the
-        analogue of Spark's driver/shuffle planning, and unavoidable for
-        host-resident string keys — while column *materialization* is device
-        gathers (``jnp.take``), so numeric data never leaves HBM. Unmatched
-        slots in outer joins fill with NaN (numeric, int promotes to float)
-        or None (string).
+        OBSERVED valid-row counts (the host plan's pulled masks; on the
+        device path two scalars read for this) drift past
+        ``spark.aqe.driftFactor``, the build side re-decides mid-query
+        and a small-enough observed build side skips the hash-partition
+        shuffle. ``None`` (or AQE off) keeps the static plan.
         """
         how = how.lower().replace("fullouter", "outer").replace("full", "outer")
         valid = ("inner", "left", "right", "outer", "left_semi", "left_anti",
@@ -1988,63 +2016,202 @@ class Frame:
         if how not in valid:
             raise ValueError(f"unknown join type {how!r}; expected one of {valid}")
         build_left = build == "left" and how == "inner"
-        keys = [on] if isinstance(on, str) else list(on or [])
-        if how != "cross":
-            if not keys:
-                raise ValueError("join requires `on` key column(s)")
-            for k in keys:
-                if k not in self.columns or k not in other.columns:
-                    raise ValueError(f"join key {k!r} must exist in both frames")
+        on = [on] if isinstance(on, str) else list(on or [])
+        keys = [k if isinstance(k, str) else k[0] for k in on]
+        pairs = [(k[0], k[1]) for k in on
+                 if not isinstance(k, str) and k[0] != k[1]]
+        if how != "cross" and not keys:
+            raise ValueError("join requires `on` key column(s)")
+        if how == "cross":
+            keys, pairs = [], []
+        for lname, rname in pairs:
+            if rname not in other.columns:
+                raise ValueError(f"join key {rname!r} must exist in the "
+                                 "right frame")
+            if lname in other.columns:
+                raise ValueError(
+                    f"join on {lname!r} = {rname!r}: {lname!r} is a shared "
+                    "column name of both frames, and the right frame's "
+                    "would be lost (rename or drop it first)")
+        if pairs:
+            # the right key takes the left name in the column table (host
+            # dict only); outer types keep it as a column of its own too
+            renames = dict((r, l) for l, r in pairs)
+            keep_right_key = how in ("left", "right", "outer")
+            table = {}
+            for name, arr in other._data.items():
+                table[renames.get(name, name)] = arr
+                if name in renames and keep_right_key:
+                    table[name] = arr
+            other = other._with(data=table)
+        for k in keys:
+            if k not in self.columns or k not in other.columns:
+                raise ValueError(f"join key {k!r} must exist in both frames")
 
+        joined = self._device_join(other, keys, how, build_left, est)
+        if joined is None:
+            joined = self._host_join(other, keys, how, build_left, est)
+        if how == "inner":
+            for lname, rname in pairs:  # one column, two names
+                out_name = rname + "_right" if rname in joined._data_store \
+                    else rname
+                joined._data_store[out_name] = joined._data_store[lname]
+        return joined
+
+    @staticmethod
+    def _join_columns(keys, left_cols, right_cols, mask):
+        """The result frame of a join from the two sides' gathered
+        columns: the left side's, then the right side's without the keys,
+        a name both sides carry suffixed ``_right``."""
+        data = dict(left_cols)
+        for name, col in right_cols.items():
+            if name in keys:
+                continue
+            data[name + "_right" if name in data else name] = col
+        return Frame(data, mask=mask)
+
+    def _aqe_build_side(self, other, build_left, est, nl, nr):
+        """Adaptive re-planning (sql/adaptive.py) from both sides' TRUE
+        valid-row counts ``nl`` / ``nr``: when either drifted past
+        spark.aqe.driftFactor from the optimizer's estimate, the build
+        side re-decides from the observed counts, and an observed build
+        side under spark.aqe.broadcastThreshold bytes skips the
+        hash-partition shuffle entirely (the partitioned plan reproduces
+        the unpartitioned emission order exactly, so skipping it is the
+        identity transform). Returns ``(build_left, skip_shuffle)``."""
+        from ..sql import adaptive as _aqe
+
+        skip_shuffle = False
+        left_est, right_est = est
+        if _aqe.drift(left_est, nl) or _aqe.drift(right_est, nr):
+            want_left = nl * _aqe.BUILD_RATIO <= nr
+            if want_left != build_left and _aqe.guard("build-flip"):
+                _aqe.record(
+                    "build-flip",
+                    f"join build={'left' if want_left else 'right'}"
+                    f" (observed {nl} vs {nr} rows)",
+                    est_before=(left_est if want_left else right_est),
+                    est_after=(int(nl) if want_left else int(nr)))
+                build_left = want_left
+            store_hint = (self._shard if self._shard is not None
+                          else other._shard)
+            if store_hint is not None and \
+                    max(nl, nr) >= int(config.shard_min_rows):
+                b_rows = int(min(nl, nr))
+                b_frame = self if nl <= nr else other
+                b_bytes = b_rows * _aqe.row_nbytes(b_frame)
+                if b_bytes <= int(config.aqe_broadcast_threshold) \
+                        and _aqe.guard("broadcast"):
+                    _aqe.record(
+                        "broadcast",
+                        "hash-partition Exchange skipped (observed"
+                        f" build side {b_rows} rows ~{b_bytes} B "
+                        "fits spark.aqe.broadcastThreshold)",
+                        est_before=(left_est if nl <= nr else right_est),
+                        est_after=b_rows)
+                    skip_shuffle = True
+        return build_left, skip_shuffle
+
+    def _device_join(self, other, keys, how, build_left, est):
+        """The join planned and gathered on the device (``ops/joins.py``),
+        or None where the host plan has to take it (see :meth:`join`)."""
+        from ..ops import joins as _joins
+
+        if how not in _joins.DEVICE_HOWS or self._shard is not None \
+                or other._shard is not None \
+                or self.num_slots == 0 or other.num_slots == 0:
+            return None
+        ldata, rdata = self._data, other._data
+        dtypes = []
+        for k in keys:
+            lk, rk = ldata[k], rdata[k]
+            if _is_string_col(lk) or _is_string_col(rk):
+                return None
+            dt = _joins.key_dtype(lk, rk)
+            if dt is None:
+                return None
+            dtypes.append(dt)
+        # a semi or anti join gathers nothing of the right side
+        right_names = [] if how in ("left_semi", "left_anti") \
+            else [n for n in rdata if n not in keys]
+        if any(_is_string_col(v) for v in list(ldata.values())
+               + [rdata[n] for n in right_names]):
+            return None
+        lmask, rmask = self._mask, other._mask
+        sp = current_span()
+        if how == "inner":
+            if est is not None and config.aqe_enabled:
+                # the observed counts the adaptive hooks compare with the
+                # optimizer's estimates: two scalars, not two masks
+                counts = jnp.stack([jnp.sum(lmask, dtype=jnp.int32),
+                                    jnp.sum(rmask, dtype=jnp.int32)])
+                # dqlint: ok(host-sync): two scalars where the host plan
+                # pulls both masks
+                nl, nr = (int(c) for c in np.asarray(counts))
+                host_read(counts.nbytes)
+                build_left, _ = self._aqe_build_side(
+                    other, build_left, est, nl, nr)
+            elif not build_left:
+                # no hint: build from the side with fewer slots
+                build_left = self.num_slots * 2 <= other.num_slots
+
+        def distinct(names, data):
+            """A column once, whatever names it has (an inner ``ON``
+            join's key carries two)."""
+            at, arrays = {}, []
+            for name in names:
+                if id(data[name]) not in at:
+                    at[id(data[name])] = len(arrays)
+                    arrays.append(data[name])
+            return [at[id(data[name])] for name in names], arrays
+
+        lat, larrays = distinct(list(ldata), ldata)
+        rat, rarrays = distinct(right_names, rdata)
+        lout, rout, mask, rows, missing = _joins.device_join(
+            how, [ldata[k] for k in keys], lmask,
+            [rdata[k] for k in keys], rmask, larrays, rarrays,
+            build_left, tuple(dtypes))
+        counters.increment("join.device")
+        sp.set(how=how, lowering="device", keys=len(keys),
+               rows_left=self.num_slots, rows_right=other.num_slots,
+               build="left" if build_left else "right", rows_out=rows)
+        if missing is not None:
+            # a left join's unmatched rows: NaN where the right side is
+            # missing (an integer column promotes to float)
+            filled = []
+            for col in rout:
+                if not np.issubdtype(np.dtype(col.dtype), np.floating):
+                    col = col.astype(float_dtype())
+                filled.append(jnp.where(
+                    missing[(...,) + (None,) * (col.ndim - 1)],
+                    jnp.asarray(np.nan, col.dtype), col))
+            rout = filled
+        if rows == mask.shape[0]:
+            mask = None                   # every slot holds a row
+        return self._join_columns(
+            keys, {name: lout[i] for name, i in zip(ldata, lat)},
+            {name: rout[i] for name, i in zip(right_names, rat)}, mask)
+
+    def _host_join(self, other, keys, how, build_left, est):
+        """The join planned on the host: both masks and every key column
+        are pulled, ``_vector_join_plan`` (numeric keys) or the dict plan
+        (string keys) pairs the rows, and the columns are gathered on the
+        device from the pair arrays. What :meth:`join` keeps here: string
+        keys, ``right`` / ``outer`` / ``cross``, sharded frames."""
+        counters.increment("join.host")
+        current_span().set(how=how, lowering="host", keys=len(keys),
+                           rows_left=self.num_slots,
+                           rows_right=other.num_slots)
         li = np.nonzero(self._host_mask())[0]
         ri = np.nonzero(other._host_mask())[0]
 
-        # Adaptive re-planning (sql/adaptive.py): the host plan already
-        # holds both sides' TRUE valid-row counts — zero extra syncs —
-        # so when either side drifted past spark.aqe.driftFactor from
-        # the optimizer's estimate, the build side re-decides from the
-        # observed counts, and an observed build side under
-        # spark.aqe.broadcastThreshold bytes skips the hash-partition
-        # shuffle entirely (the partitioned plan reproduces the
-        # unpartitioned emission order exactly, so skipping it is the
-        # identity transform). One conf read when AQE is off; a cold
-        # estimate (est None) changes nothing.
+        # Adaptive re-planning: the host plan already holds both sides'
+        # TRUE valid-row counts — zero extra syncs. One conf read when AQE
+        # is off; a cold estimate (est None) changes nothing.
         aqe_skip_shuffle = False
         if est is not None and how == "inner" and config.aqe_enabled:
-            from ..sql import adaptive as _aqe
-
-            left_est, right_est = est
-            if _aqe.drift(left_est, li.size) \
-                    or _aqe.drift(right_est, ri.size):
-                want_left = li.size * _aqe.BUILD_RATIO <= ri.size
-                if want_left != build_left and _aqe.guard("build-flip"):
-                    _aqe.record(
-                        "build-flip",
-                        f"join build={'left' if want_left else 'right'}"
-                        f" (observed {li.size} vs {ri.size} rows)",
-                        est_before=(left_est if want_left
-                                    else right_est),
-                        est_after=(int(li.size) if want_left
-                                   else int(ri.size)))
-                    build_left = want_left
-                store_hint = (self._shard if self._shard is not None
-                              else other._shard)
-                if store_hint is not None and \
-                        max(li.size, ri.size) >= int(config.shard_min_rows):
-                    b_rows = int(min(li.size, ri.size))
-                    b_frame = self if li.size <= ri.size else other
-                    b_bytes = b_rows * _aqe.row_nbytes(b_frame)
-                    if b_bytes <= int(config.aqe_broadcast_threshold) \
-                            and _aqe.guard("broadcast"):
-                        _aqe.record(
-                            "broadcast",
-                            "hash-partition Exchange skipped (observed"
-                            f" build side {b_rows} rows ~{b_bytes} B "
-                            "fits spark.aqe.broadcastThreshold)",
-                            est_before=(left_est if li.size <= ri.size
-                                        else right_est),
-                            est_after=b_rows)
-                        aqe_skip_shuffle = True
+            build_left, aqe_skip_shuffle = self._aqe_build_side(
+                other, build_left, est, li.size, ri.size)
 
         if how == "cross":
             lpairs = np.repeat(li, len(ri))
@@ -2066,11 +2233,14 @@ class Frame:
             # key columns materialize ONCE; the vector plan and the dict
             # fallback share them (a plan bail-out must not re-read).
             # Each side's device-key pull counts as one host sync batch.
-            for fr in (self, other):
+            lraw, rraw = [], []
+            for fr, rows, raw in ((self, li, lraw), (other, ri, rraw)):
+                cols = [np.asarray(fr._column_values(k)) for k in keys]
                 if any(not _is_string_col(fr._data[k]) for k in keys):
                     counters.increment("frame.host_sync")
-            lraw = [np.asarray(self._column_values(k))[li] for k in keys]
-            rraw = [np.asarray(other._column_values(k))[ri] for k in keys]
+                    host_read(sum(c.nbytes for k, c in zip(keys, cols)
+                                  if not _is_string_col(fr._data[k])))
+                raw.extend(c[rows] for c in cols)
             plan = None
             if all(not _is_string_col(self._data[k])
                    and not _is_string_col(other._data[k]) for k in keys):
@@ -2188,11 +2358,12 @@ class Frame:
                     out[name] = col
             return out
 
+        current_span().set(rows_out=int(lpairs.size))
         left_cols = gather(self, lpairs, how in ("right", "outer"))
         if how in ("left_semi", "left_anti"):
             return Frame(left_cols)
         right_cols = gather(other, rpairs, how in ("left", "outer", "left_anti"))
-        data = dict(left_cols)
+        data = left_cols
         if how in ("right", "outer") and lpairs.size and (lpairs < 0).any():
             # USING semantics: one key column, coalesced from the non-null
             # side (rows appended for unmatched right rows have lpairs == -1).
@@ -2205,12 +2376,7 @@ class Frame:
                 else:
                     data[k] = jnp.where(jnp.asarray(miss),
                                         jnp.asarray(rk).astype(lk.dtype), lk)
-        for name, col in right_cols.items():
-            if name in keys:
-                continue
-            out_name = name + "_right" if name in data else name
-            data[out_name] = col
-        return Frame(data)
+        return self._join_columns(keys, data, right_cols, None)
 
     def cross_join(self, other: "Frame") -> "Frame":
         return self.join(other, on=None, how="cross")

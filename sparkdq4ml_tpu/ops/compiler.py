@@ -110,6 +110,17 @@ def bucket_size(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
 
+def result_bucket(rows: int) -> int:
+    """Row slots for a data-dependent result of ``rows`` rows (a join's
+    pairs, a many-group GROUP BY's groups): ``rows`` rounded up to three
+    significant bits — at most an eighth more — so that results of nearly
+    one size, the same statement over another day's data, share the
+    programs behind them. The tail rides a ``False`` mask."""
+    rows = max(int(rows), 8)
+    step = 1 << max(rows.bit_length() - 3, 0)
+    return -(-rows // step) * step
+
+
 # ---------------------------------------------------------------------------
 # Compilability — the subset of Expr that traces under jit
 # ---------------------------------------------------------------------------

@@ -96,7 +96,7 @@ from ..utils import faults as _faults
 from ..utils import observability as _obs
 from ..utils.profiling import counters, host_read
 from .compiler import (_unpad_tree, bucket_size, dtype_tag, pad_rows,
-                       plan_namespace_tag)
+                       plan_namespace_tag, result_bucket)
 
 logger = logging.getLogger("sparkdq4ml_tpu.ops.segments")
 
@@ -1707,10 +1707,21 @@ def grouped_agg(frame, keys, agg_list):
     # one slice program for all k+m outputs: it retraces per distinct
     # group count, as each eager ``arr[:g]`` would (the slice length is
     # static either way), and costs one dispatch instead of k+m
-    key_outs, agg_outs = _unpad_tree((tuple(key_outs), tuple(agg_outs)), g)
+    # Past the exact-shape threshold the group count is data (4.6e5 orders
+    # one day, 4.7e5 the next): such a result keeps a bucket of slots under
+    # a mask, so that the sort or the join behind it is one program for
+    # every count in the bucket instead of one compile a count.
+    slots, out_mask = g, None
+    if g > int(config.pipeline_exact_threshold):
+        slots = min(result_bucket(g), int(key_outs[0].shape[0])
+                    if key_outs else g)
+        if slots > g:
+            out_mask = jnp.arange(slots) < g
+    key_outs, agg_outs = _unpad_tree((tuple(key_outs), tuple(agg_outs)),
+                                     slots)
     out = dict(zip(keys, key_outs))
     out.update((a.name, arr) for a, arr in zip(agg_list, agg_outs))
-    return Frame(out)
+    return Frame(out, mask=out_mask)
 
 
 # ---------------------------------------------------------------------------

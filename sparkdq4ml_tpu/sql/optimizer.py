@@ -396,6 +396,8 @@ def _needed_columns(q, rels: list, residual_where) -> bool:
     the columns the query can observe (+ every join key). Returns False
     — and leaves every ``keep`` None — when any referenced expression
     is outside the analyzable subset or any reference is ambiguous."""
+    from .parser import join_key_names
+
     refs: set = set()
     for it in q.items:
         if isinstance(it, str) and it == "*":
@@ -417,7 +419,8 @@ def _needed_columns(q, rels: list, residual_where) -> bool:
             return False
     # pushed conjuncts filter INSIDE the wrapped scan, before its
     # projection — their references need no keep slot; join keys do.
-    all_keys = {k for r in rels for k in r.keys}
+    all_keys = {k for r in rels for side in join_key_names(r.keys)
+                for k in side}
     needed = {r.idx: set() for r in rels}
     for name in refs:
         if "(" in name:
@@ -477,8 +480,9 @@ def _maybe_reorder(q, rels: list, ests: dict, flops: dict,
     if len(joins) < 2 or q.limit is not None or getattr(q, "offset", 0):
         return None
     if any(r.how != "inner" or not r.keys or r.cols is None
-           or not isinstance(r.view, str) for r in joins):
-        return None
+           or not isinstance(r.view, str)
+           or not all(isinstance(k, str) for k in r.keys) for r in joins):
+        return None                   # a key pair is turned: order stays
     base = rels[0]
     if base.cols is None:
         return None
@@ -556,8 +560,24 @@ def _clone(q):
 def _optimize_single(q, cat, rewrites: list):
     """Optimize ONE SELECT (no set-op handling); returns a rewritten
     shallow copy, or ``q`` itself when nothing applies."""
-    from .parser import DerivedTable
+    from .parser import DerivedTable, resolve_join_keys
 
+    # ON pairs and comma relations settle first, from the catalog's
+    # column lists: the rewrites below see equi-joins and a WHERE clause
+    # without the join equalities. A relation whose columns are unknown
+    # here (a derived table) leaves the query literal; the executor
+    # settles it against the frames.
+    settled = resolve_join_keys(
+        q, [_view_columns(q.view, cat)]
+        + [_view_columns(view, cat) for view, _h, _k, _a in q.joins])
+    if settled is None:
+        return q
+    if settled[0] is not q.joins:
+        q0, q = q, _clone(q)
+        q.joins, q.where = settled
+        for attr in ("join_build", "join_est"):
+            if hasattr(q0, attr):
+                setattr(q, attr, getattr(q0, attr))
     rels = _relations(q, cat)
     # recurse into derived tables first (their inner queries are full
     # SELECTs); CTE bodies are optimized by the executor at registration

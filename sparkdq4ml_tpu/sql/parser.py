@@ -263,6 +263,12 @@ class _Parser:
         if self.accept("kw", "from"):
             view, view_alias = self.parse_relation()
             while True:
+                if self.accept("op", ","):
+                    # ``FROM a, b``: an inner join whose keys are the
+                    # WHERE clause's equalities (resolve_join_keys)
+                    rel, rel_alias = self.parse_relation()
+                    joins.append((rel, "inner", [], rel_alias))
+                    continue
                 join = self.parse_join()
                 if join is None:
                     break
@@ -353,7 +359,9 @@ class _Parser:
 
     def parse_join(self):
         """``[INNER|LEFT [OUTER]|RIGHT [OUTER]|FULL [OUTER]|CROSS] JOIN view
-        (ON a = b | USING (k, ...))`` → ``(view, how, keys)``."""
+        (ON a = b | USING (k, ...))`` → ``(view, how, keys, alias)``; a key
+        is a shared column name or, from ``ON a = b`` over two names, the
+        pair ``(a, b)``."""
         how = None
         for kw in ("inner", "left", "right", "full", "cross"):
             if self.accept("kw", kw):
@@ -388,14 +396,12 @@ class _Parser:
                 self.expect("op", "=")
                 b = self._parse_maybe_dotted()
                 # qualified ON (``ON t.k = g.k``) reduces to the shared
-                # base column — the engine's joins are USING-shaped
+                # base column — the engine's joins are USING-shaped; two
+                # different names stay a pair until resolve_join_keys
+                # knows which relation holds which
                 a_col = a.rpartition(".")[2]
                 b_col = b.rpartition(".")[2]
-                if a_col != b_col:
-                    raise ValueError(
-                        f"JOIN ON supports equi-join on a shared column name; "
-                        f"got {a!r} = {b!r} (use USING or rename first)")
-                keys.append(a_col)
+                keys.append(a_col if a_col == b_col else (a, b))
         return (view, how, keys, alias)
 
     def _parse_maybe_dotted(self) -> str:
@@ -1161,6 +1167,102 @@ def _conjoin(parts):
     return out
 
 
+_ON_ERROR = ("JOIN ON supports equi-join on a shared column name or on one "
+             "column of each side; got {!r} = {!r}")
+
+
+class KeyPair(tuple):
+    """A settled join key over two names: (left column, right column).
+    The parser's unsettled ``ON a = b`` is a plain tuple."""
+
+    __slots__ = ()
+
+
+def join_key_names(keys) -> tuple:
+    """(left names, right names) of a join's resolved key list: a shared
+    name counts on both sides, a pair gives one to each."""
+    return ([k if isinstance(k, str) else k[0] for k in keys],
+            [k if isinstance(k, str) else k[1] for k in keys])
+
+
+def _orient_key(a: str, b: str, left: list, right: tuple):
+    """``a = b`` as the key of the join that adds the relation ``right`` =
+    (bind, columns) to the relations ``left`` joined before it: the shared
+    name, or the :class:`KeyPair`; None when it is not one column of each
+    side (an unqualified name that both sides carry is on neither)."""
+    def side(name):
+        qual, _, col = name.rpartition(".")
+        if qual and qual.lower() == right[0]:
+            return "r", col
+        if qual and any(qual.lower() == bind for bind, _ in left):
+            return "l", col
+        in_l = any(name in cols for _, cols in left)
+        in_r = name in right[1]
+        return ("l" if in_l else "r") if in_l != in_r else None, name
+
+    (sa, ca), (sb, cb) = side(a), side(b)
+    if {sa, sb} != {"l", "r"}:
+        return None
+    pair = (ca, cb) if sa == "l" else (cb, ca)
+    return pair[0] if pair[0] == pair[1] else KeyPair(pair)
+
+
+def _unsettled(join) -> bool:
+    _view, how, keys, _alias = join
+    return (how == "inner" and not keys) or any(
+        not isinstance(k, (str, KeyPair)) for k in keys)
+
+
+def resolve_join_keys(q: "Query", rel_cols: list):
+    """The joins and the WHERE clause of ``q`` with every join key
+    settled: an ``ON a = b`` pair turned the way its relations stand, a
+    comma relation (``inner`` with no key) given the WHERE clause's
+    equalities between it and the relations before it, or made a cross
+    join where there is none. ``rel_cols`` lists the columns of the base
+    relation and of every joined one; returns None while one is unknown
+    (a derived table before it has run), ``(joins, where)`` otherwise.
+    Settled keys pass through, so a second call changes nothing."""
+    if not any(_unsettled(j) for j in q.joins):
+        return q.joins, q.where
+    if any(c is None for c in rel_cols):
+        return None
+
+    def bind(view, alias):
+        return (alias or (view if isinstance(view, str) else "")).lower()
+
+    base = q.view.alias if isinstance(q.view, DerivedTable) else q.view
+    left = [(bind(base, q.view_alias), rel_cols[0])]
+    parts = _conjuncts(q.where) if q.where is not None else []
+    joins = []
+    for (view, how, keys, alias), cols in zip(q.joins, rel_cols[1:]):
+        right = (bind(view, alias), cols)
+        settled = []
+        for k in keys:
+            if not isinstance(k, (str, KeyPair)):
+                a, b = k
+                k = _orient_key(a, b, left, right)
+                if k is None:
+                    raise ValueError(_ON_ERROR.format(a, b))
+            settled.append(k)
+        if how == "inner" and not keys:
+            rest = []
+            for c in parts:
+                k = (_orient_key(c.left.name, c.right.name, left, right)
+                     if isinstance(c, E.BinOp) and c.op == "=="
+                     and isinstance(c.left, E.Col)
+                     and isinstance(c.right, E.Col) else None)
+                if k is None:
+                    rest.append(c)
+                else:
+                    settled.append(k)
+            parts = rest
+            if not settled:
+                how = "cross"
+        joins.append((view, how, settled, alias))
+        left.append(right)
+    return joins, _conjoin(parts)
+
+
 def _relation_aliases(q: Query) -> set:
     """The relation aliases a query's own FROM/JOIN clause binds."""
     names = set()
@@ -1605,8 +1707,8 @@ def plan_tree(q: Query) -> PlanNode:
     node = scan_node(q.view)
     hints = list(getattr(q, "join_build", ()) or ())
     hints += [None] * (len(q.joins) - len(hints))
-    for (view, how, _keys, _alias), hint in zip(reversed(q.joins),
-                                                reversed(hints)):
+    # in execution order: the first join is the innermost node
+    for (view, how, _keys, _alias), hint in zip(q.joins, hints):
         how = how if isinstance(how, str) else "inner"
         detail = f"[{how},build={hint}]" if hint else f"[{how}]"
         node = PlanNode("Join", detail, [node, scan_node(view)])
@@ -2666,9 +2768,25 @@ def _execute_single(q: Query, cat):
     # optimizer-attached (left, right) row-estimate pairs per join — the
     # drift baseline the adaptive hooks compare observed counts against
     join_ests = list(getattr(q, "join_est", ()) or ())
+    rights: dict = {}
+
+    def relation(jidx):
+        if jidx not in rights:
+            view = q.joins[jidx][0]
+            rights[jidx] = (_execute_set(view.query, cat)
+                            if isinstance(view, DerivedTable)
+                            else cat.lookup(view))
+        return rights[jidx]
+
+    if any(_unsettled(j) for j in q.joins):
+        # ON pairs and comma relations the optimizer did not settle from
+        # the catalog (it is off, or a relation is a derived table):
+        # settle them against the frames' own columns
+        q.joins, q.where = resolve_join_keys(
+            q, [list(frame.columns)]
+            + [list(relation(j).columns) for j in range(len(q.joins))])
     for jidx, (view, how, keys, jalias) in enumerate(q.joins):
-        right = (_execute_set(view.query, cat)
-                 if isinstance(view, DerivedTable) else cat.lookup(view))
+        right = relation(jidx)
         rcols = list(right.columns)
         pre = set(frame.columns)
         frame = frame.join(right, on=keys or None, how=how,
@@ -2682,7 +2800,7 @@ def _execute_single(q: Query, cat):
             if how in ("left_semi", "left_anti"):
                 # semi/anti output carries left columns only; the right
                 # side is addressable just through the join keys
-                mapping = {k: k for k in keys}
+                mapping = dict(zip(*reversed(join_key_names(keys))))
             else:
                 mapping = {c: (f"{c}_right" if c not in keys and c in pre
                                and f"{c}_right" in post else c)

@@ -185,6 +185,15 @@ METRIC_NAMES = {
     "grouped.tile": ("counter", "grouped plans reduced by the dense "
                                 "lowering's tile tier"),
     "grouped.evict": ("counter", "grouped plan-cache LRU evictions"),
+    "join.device": ("counter", "joins planned, ordered and gathered by the "
+                               "device program (ops/joins.py)"),
+    "join.host": ("counter", "joins planned on the host from pulled masks "
+                             "and key columns (string keys, right/outer/"
+                             "cross, sharded frames)"),
+    "join.rows_probed": ("counter", "probe-side row slots handed to "
+                                    "device join programs"),
+    "join.compile": ("counter", "device join programs traced"),
+    "join.hit": ("counter", "device join runs served by a built program"),
     "grouped.shard_gather": ("counter",
                              "sharded grouped/distinct programs gathered "
                              "to single-device by the shard_merge "

@@ -394,7 +394,11 @@ def test_join_counts_key_pull_syncs():
     a.count(), b.count()
     counters.clear("frame.host_sync")
     a.join(b, on="k", how="inner")
-    # two mask pulls + two key-column batches
+    # planned on the device: one scalar read, the result's row count
+    assert counters.get("frame.host_sync") == 1
+    counters.clear("frame.host_sync")
+    a.join(b, on="k", how="outer")
+    # the host plan: two mask pulls + two key-column batches
     assert counters.get("frame.host_sync") == 4
 
 

@@ -53,8 +53,14 @@ class TestQualifiedRefs:
         assert out.to_pydict()["price"].tolist() == [95.0, 120.0]
 
     def test_qualified_on_different_columns_rejected(self, session, views):
+        # g carries a ``guest`` of its own, which the join on t.guest =
+        # g.tag would lose: refused (two different names join where the
+        # right side has no column of the left key's name)
         with pytest.raises(ValueError, match="shared column name"):
             session.sql("SELECT t.price FROM t JOIN g ON t.guest = g.tag")
+        # both columns of one side: never an equi-join
+        with pytest.raises(ValueError, match="shared column name"):
+            session.sql("SELECT t.price FROM t JOIN g ON t.guest = t.price")
 
     def test_aggregates_and_post_agg(self, session, views):
         assert session.sql("SELECT max(t.price) AS mp FROM t") \
