@@ -1980,8 +1980,20 @@ class Frame:
         neither side sharded and every column to gather on the device ->
         the match plan, the pair order and the column gathers are one
         compiled program (``ops/joins.py``: a sort-merge over
-        ``lax.sort``); the host reads one scalar, the result's row count,
-        and the result is a frame of a bucket of slots under a mask.
+        ``lax.sort``). Its build step brings both sides' keys into one
+        order either by one sort of their concatenation or, where the
+        probe side arrives in key order, by a merge: the build side
+        sorted alone, the probe side in chunks sorted beside their share
+        of it. Shapes (one key column, a probe side of many chunks and
+        about twelve times the build side's slots) and the observed order of the
+        input decide; no option does, and the result is the same bit for
+        bit. The host reads one small array: the result's row count and,
+        behind a merge, whether its probe keys were in order and its
+        chunks had room — if not (``join.merge_miss``) the join runs once
+        more as the sort, and that signature's later runs start from what
+        was learnt. The span says which ``build_step`` the result came
+        from (``join.merge`` counts the merges). The result is a frame of
+        a bucket of slots under a mask.
         String keys, an integer key against a float one, ``right`` /
         ``outer`` / ``cross``, a sharded side and host (string) columns to
         gather keep the **host plan**: masks and key columns are pulled,

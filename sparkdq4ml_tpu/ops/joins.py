@@ -6,13 +6,20 @@ sort moves a row in about 3.5 ns where a gather or a scatter of one costs
 can and gathers only at the size of the result.
 
 One compiled program per (join type, key dtypes, side sizes, result
-bucket) does all of it:
+bucket, build step) does all of it:
 
-``dq.join.build``   both sides' keys in ONE sort of their concatenation;
-                    its last key, the tag, carries side, row and
-                    validity, so that inside a key group the valid build
-                    rows stand first in row order, then the valid probe
-                    rows, then the masked ones
+``dq.join.build``   both sides' keys in (key, tag) order; the tag carries
+                    side, row and validity, so that inside a key group
+                    the valid build rows stand first in row order, then
+                    the valid probe rows. Two ways to that order. The
+                    **sort**: ONE ``lax.sort`` of the two sides'
+                    concatenation. The **merge**, for a probe side that
+                    arrives in key order (a fact table stored in its
+                    parent's key order): the build side sorted alone,
+                    the probe side cut into chunks beside their share of
+                    the sorted build rows, every chunk of 2^15 sorted by
+                    itself (``_merge``) — on this chip a sort's cost
+                    follows its length steeply
 ``dq.join.probe``   per key group, by four scans, where it starts and how
                     many valid build rows it has; the probe rows the join
                     type selects, compacted by one single-operand sort of
@@ -27,11 +34,24 @@ nothing, -0.0 equals 0.0); several keys are several sort operands — no
 packing, no float detour. Memory is a constant number of n-row int32
 operands for n = left + right rows, and no ``(n, k)`` operand.
 
-The host reads one scalar a join: the size of the result, after the
-program has run. The program is built for a result ``bucket`` remembered
-per program signature from the join's last run (an estimate, like the
+The host reads one small array a join, after the program has run: the
+size of the result and, behind a merge, what the merge assumed — whether
+the probe keys were in order, and the build slots its fullest chunk
+asked for. Shapes, the key count and the observed order decide the build
+step; no option does. A join of one key column whose probe side has
+eight chunks' worth of slots and about twelve times the build side's is
+offered the merge, with a ``room`` of build slots a chunk taken from the shapes
+(``_first_room``); before such a signature's first program is built the
+probe side's order is read (one elementwise pass, one flag), so that no
+merge program is built for a probe side that arrives unordered. A merge
+that did not hold (``join.merge_miss``) runs once more as the sort, and
+its signature remembers what it learnt: the sort for a probe side out of
+order, the room the chunks asked for otherwise (``_ROOMS``). The program is likewise built for a result
+``bucket`` remembered from the join's last run (an estimate, like the
 statstore's); a result that outgrew it runs once more at the size it
-asked for. The result is a frame of ``bucket`` slots under a mask.
+asked for. So a signature's second run in a process is the program it
+keeps. The result is a frame of ``bucket`` slots under a mask, bit for
+bit the same under either build step.
 """
 
 from __future__ import annotations
@@ -44,8 +64,9 @@ import numpy as np
 from jax import lax
 
 from ..utils import observability as _obs
-from ..utils.profiling import counters, host_read
+from ..utils.profiling import counters
 from .compiler import result_bucket
+from .segments import _read_verdict
 
 #: the join types this module plans; ``right``, ``outer`` and ``cross``
 #: keep the host plan (``frame._vector_join_plan``)
@@ -55,8 +76,10 @@ _HIGH = np.uint32(1 << 31)
 _INT_MAX = np.int32(np.iinfo(np.int32).max)
 
 _LOCK = threading.Lock()
-_PROGRAMS: dict = {}      # signature + bucket -> jitted program
+_PROGRAMS: dict = {}      # signature + (bucket, room) -> jitted program
 _BUCKETS: dict = {}       # signature -> result bucket of the last run
+_ROOMS: dict = {}         # signature -> build slots a chunk of the merge
+#                           since its last miss; 0: the build step sorts
 
 
 def key_dtype(a, b):
@@ -74,7 +97,108 @@ def key_dtype(a, b):
     return None
 
 
-_CHUNK = 1 << 15       # elements a row of the chunked compaction
+_CHUNK = 1 << 15       # elements a row of the chunked sorts
+
+
+def _room_for(need: int) -> int:
+    """Build slots a chunk of the merge for chunks that ask for at most
+    ``need``: an eighth more, in steps of a 256th of the row (whole lane
+    tiles at the row's real length), or 0 — the build step sorts — where
+    that is over an eighth of the row: the chunks' padding rides every
+    later pass, and a program for 2.4e8 probe slots asked for 3.5 GB of
+    scratch more than the sort's at a room of 5,760."""
+    step = max(_CHUNK >> 8, 1)
+    room = -(-(need + need // 8) // step) * step
+    return max(room, step) if room * 8 <= _CHUNK else 0
+
+
+def _first_room(k: int, nb: int, npr: int) -> int:
+    """The room a signature's first run tries, from shapes alone, or 0
+    where they rule the merge out: one key column, a probe side of eight
+    chunks and a build side small beside it (about a twelfth). For a
+    quarter more than the build slots an evenly spread build side puts
+    into a chunk."""
+    if k != 1 or npr < 8 * _CHUNK:
+        return 0
+    even = -(-nb * _CHUNK // npr)
+    return _room_for(even + even // 4)
+
+
+def _chunks(npr: int, room: int) -> int:
+    """Chunks of the merge for ``npr`` probe slots, a multiple of eight:
+    the scans behind it then see whole tiles (a program for 2.4e8 slots
+    asked for 7.5 GB of scratch so, and 9.2 to 10.2 GB otherwise)."""
+    return -(-npr // ((_CHUNK - room) * 8)) * 8
+
+
+@jax.jit
+def _in_order(keys):
+    """Whether a key column is non-decreasing over all its slots, masked
+    ones too (a NaN key: no)."""
+    return jnp.all(keys[1:] >= keys[:-1])
+
+
+def _merge(bkey, bvalid, pkey, pvalid, room: int):
+    """The build step for a probe side that arrives in key order: the
+    pairs ``(ks, ts)`` the one sort of both sides gives (longer by the
+    chunks' padding, masked), a flag that says the result may be used,
+    and the build slots the fullest chunk asked for.
+
+    Consecutive chunks of an ordered probe side are range partitions of
+    the key space. The build side is sorted alone; chunk ``c`` of
+    ``_CHUNK - room`` probe slots takes, into its ``room`` build slots,
+    the valid build rows with ``lo[c] < key <= lo[c + 1]`` (``lo`` the
+    chunks' first keys; chunk 0 from ``lo[0]`` itself, the last chunk up
+    to the last probe key): a key's build rows stand in the chunk where
+    its probe rows begin, in front of them, even where the group runs on
+    into later chunks. Build rows no probe key reaches, masked ones and
+    NaN keys match nothing in any device join type and are left out.
+    Unused slots carry the chunk's first key and a masked tag, so they
+    split no key group. Every chunk is then sorted by itself."""
+    nb, npr = bkey.shape[0], pkey.shape[0]
+    part = _CHUNK - room
+    chunks = _chunks(npr, room)
+    floating = np.dtype(bkey.dtype).kind == "f"
+    top = jnp.asarray(np.inf if floating else np.iinfo(bkey.dtype).max,
+                      bkey.dtype)
+    if floating:
+        bvalid = bvalid & ~jnp.isnan(bkey)
+    btag = lax.iota(jnp.uint32, nb)
+    # valid rows first: a masked row carries the largest key and a tag
+    # over every valid one's
+    sk, st = lax.sort((jnp.where(bvalid, bkey, top),
+                       jnp.where(bvalid, btag, btag | _HIGH)), num_keys=2)
+    held = jnp.sum(bvalid, dtype=jnp.int32)
+
+    ptag = lax.iota(jnp.uint32, npr) + np.uint32(nb)
+    ptag = jnp.where(pvalid, ptag, ptag | _HIGH)
+    tail = chunks * part - npr
+    pk = jnp.concatenate(
+        [pkey, jnp.broadcast_to(pkey[-1], (tail,))]).reshape(chunks, part)
+    pt = jnp.concatenate(
+        [ptag, jnp.full((tail,), ~np.uint32(0))]).reshape(chunks, part)
+    lo = pk[:, 0]
+    ordered = _in_order(pkey)
+
+    def upto(keys, side):
+        return jnp.minimum(jnp.searchsorted(sk, keys, side=side), held) \
+            .astype(jnp.int32)
+
+    edges = upto(lo[1:], "right")
+    start = jnp.concatenate([upto(lo[:1], "left"), edges])
+    count = jnp.concatenate([edges, upto(pkey[-1:], "right")]) - start
+    # a chunk's build rows are one slice of the sorted build side
+    sk, st = (jnp.concatenate([x, jnp.zeros((room,), x.dtype)])
+              for x in (sk, st))
+    bk, bt = jax.vmap(lambda at: tuple(
+        lax.dynamic_slice(x, (at,), (room,)) for x in (sk, st)))(start)
+    fits = lax.broadcasted_iota(jnp.int32, (chunks, room), 1) \
+        < count[:, None]
+    ks, ts = lax.sort(
+        (jnp.concatenate([jnp.where(fits, bk, lo[:, None]), pk], axis=1),
+         jnp.concatenate([jnp.where(fits, bt, ~np.uint32(0)), pt], axis=1)),
+        dimension=1, num_keys=2)
+    return ks.reshape(-1), ts.reshape(-1), ordered, jnp.max(count)
 
 
 def _compact(sel, n: int, bucket: int):
@@ -115,12 +239,16 @@ def _compact(sel, n: int, bucket: int):
 
 
 def _build_program(how: str, dtypes: tuple, nb: int, npr: int, bucket: int,
-                   probe_is_left: bool):
+                   probe_is_left: bool, room: int):
     """The jitted join: (build keys, build mask, probe keys, probe mask,
     build columns, probe columns) -> (left rows' columns, right rows'
-    columns, slot mask, result size, missing-right flags or None)."""
-    n = nb + npr
+    columns, slot mask, verdict, missing-right flags or None). The
+    verdict is what the host reads: the result size and, where the build
+    step merges (``room`` > 0), whether the merge held and the room its
+    fullest chunk asked for."""
     k = len(dtypes)
+    # sorted (key, tag) pairs the rest of the program walks
+    n = _chunks(npr, room) * _CHUNK if room else nb + npr
 
     def canon(col, dt):
         col = col.astype(dt)
@@ -138,15 +266,22 @@ def _build_program(how: str, dtypes: tuple, nb: int, npr: int, bucket: int,
             bvalid, pvalid = bmask, pmask
 
             with _obs.scope("join.build"):
-                # ONE sort of both sides' keys; the tag is the last key:
-                # inside a key the valid build rows come first in row
-                # order, then the valid probe rows, then the masked ones
-                tag = lax.iota(jnp.uint32, n)
-                valid = jnp.concatenate([bvalid, pvalid])
-                tag = jnp.where(valid, tag, tag | _HIGH)
-                keys = [jnp.concatenate([b, p])
-                        for b, p in zip(bkeys, pkeys)]
-                *ks, ts = lax.sort((*keys, tag), num_keys=k + 1)
+                # both sides' keys in (key, tag) order; the tag is the
+                # last key: inside a key the valid build rows come first
+                # in row order, then the valid probe rows; masked ones
+                # behind the valid ones they were sorted with
+                if room:
+                    *ks, ts, ordered, need = _merge(
+                        bkeys[0], bvalid, pkeys[0], pvalid, room)
+                    verdict = [ordered.astype(jnp.int32), need]
+                else:
+                    verdict = []
+                    tag = lax.iota(jnp.uint32, n)
+                    valid = jnp.concatenate([bvalid, pvalid])
+                    tag = jnp.where(valid, tag, tag | _HIGH)
+                    keys = [jnp.concatenate([b, p])
+                            for b, p in zip(bkeys, pkeys)]
+                    *ks, ts = lax.sort((*keys, tag), num_keys=k + 1)
 
             with _obs.scope("join.probe"):
                 ok = ts < _HIGH
@@ -240,7 +375,7 @@ def _build_program(how: str, dtypes: tuple, nb: int, npr: int, bucket: int,
                         for col in pcols]
                 bout = [jnp.take(col, brow, axis=0, mode="clip")
                         for col in bcols]
-            return pout, bout, live, size, missing
+            return pout, bout, live, jnp.stack([size, *verdict]), missing
 
     return jax.jit(program)
 
@@ -251,8 +386,7 @@ def device_join(how: str, lkeys, lmask, rkeys, rmask, lcols, rcols,
     to gather from each side (none of the right for semi/anti). Returns
     ``(left columns, right columns, mask, rows, missing)``: the gathered
     columns at a bucket of slots, the slots' mask, the result's row count
-    (the one scalar read) and, for a left join, the slots whose right
-    side is missing."""
+    and, for a left join, the slots whose right side is missing."""
     nl, nr = int(lmask.shape[0]), int(rmask.shape[0])
     probe_is_left = not (how == "inner" and build_left)
     if probe_is_left:
@@ -267,35 +401,59 @@ def device_join(how: str, lkeys, lmask, rkeys, rmask, lcols, rcols,
            tuple((str(c.dtype), c.shape[1:]) for c in pcols),
            tuple(str(c.dtype) for c in list(bkeys) + list(pkeys)))
     with _LOCK:
-        bucket = _BUCKETS.get(sig)
+        bucket, room = _BUCKETS.get(sig), _ROOMS.get(sig)
     if bucket is None:
         # first run of this join: a foreign-key join gives at most one
         # row a probe row
         bucket = result_bucket(npr if how != "inner" else min(nb, npr))
+    if room is None:
+        # first run: where shapes offer the merge, the probe side's
+        # order today (one elementwise pass, one flag read) decides
+        # which program is built
+        room = _first_room(len(dtypes), nb, npr)
+        if room and not _read_verdict(_in_order(pkeys[0])):
+            counters.increment("join.merge_miss")
+            room = 0
+        with _LOCK:
+            _ROOMS[sig] = room
     while True:
         with _LOCK:
-            fn = _PROGRAMS.get(sig + (bucket,))
+            fn = _PROGRAMS.get(sig + (bucket, room))
             if fn is None:
-                fn = _PROGRAMS[sig + (bucket,)] = _build_program(
-                    how, dtypes, nb, npr, bucket, probe_is_left)
+                fn = _PROGRAMS[sig + (bucket, room)] = _build_program(
+                    how, dtypes, nb, npr, bucket, probe_is_left, room)
         before = counters.get("join.compile")
-        pout, bout, live, size, missing = fn(
+        pout, bout, live, verdict, missing = fn(
             list(bkeys), bmask, list(pkeys), pmask,
             list(bcols), list(pcols))
         if counters.get("join.compile") == before:
             counters.increment("join.hit")
         counters.increment("join.rows_probed", npr)
-        # dqlint: ok(host-sync): THE read of a device join — one scalar,
-        # the result's row count, a counted frame boundary like the
-        # grouped verdict (``segments._read_verdict``)
-        rows = int(size)
-        counters.increment("frame.host_sync")
-        host_read(size.dtype.itemsize)
+        # THE read of a device join, a counted frame boundary like the
+        # grouped verdict: the result's row count and, behind a merge,
+        # what it assumed — one to three scalars in one array
+        verdict = _read_verdict(verdict)
+        if room and not (verdict[1] and verdict[2] <= room):
+            # out of order after all, or a chunk over its room: once more,
+            # as a sort; the next run merges with the room this one asked
+            # for
+            counters.increment("join.merge_miss")
+            with _LOCK:
+                _ROOMS[sig] = _room_for(int(verdict[2])) if verdict[1] \
+                    else 0
+            room = 0
+            continue
+        rows = int(verdict[0])
         want = result_bucket(rows)
         with _LOCK:
             _BUCKETS[sig] = want
         if rows <= bucket:
             break
         bucket = want                     # outgrew its bucket: once more
+    if room:
+        counters.increment("join.merge")
+        _obs.current_span().set(build_step="merge", room=room)
+    else:
+        _obs.current_span().set(build_step="sort")
     lout, rout = (pout, bout) if probe_is_left else (bout, pout)
     return lout, rout, live, rows, missing

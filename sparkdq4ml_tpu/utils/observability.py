@@ -194,6 +194,11 @@ METRIC_NAMES = {
                                     "device join programs"),
     "join.compile": ("counter", "device join programs traced"),
     "join.hit": ("counter", "device join runs served by a built program"),
+    "join.merge": ("counter", "device joins whose build step merged (a "
+                              "probe side in key order, sorted in chunks)"),
+    "join.merge_miss": ("counter", "merge programs that found the probe "
+                                   "side out of order or a chunk over its "
+                                   "room, and ran again as a sort"),
     "grouped.shard_gather": ("counter",
                              "sharded grouped/distinct programs gathered "
                              "to single-device by the shard_merge "

@@ -125,6 +125,362 @@ def test_device_join_equals_the_host_plan(case, how, build):
                                             right.num_slots)
 
 
+
+# ---------------------------------------------------------------------------
+# The build step: the merge (a probe side in key order) against the sort
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def joins(monkeypatch):
+    """``ops/joins`` with rows of 128 elements, so that a few thousand
+    slots are many chunks, and with nothing remembered."""
+    from sparkdq4ml_tpu.ops import joins as mod
+
+    monkeypatch.setattr(mod, "_CHUNK", 128)
+    for name in ("_PROGRAMS", "_BUCKETS", "_ROOMS"):
+        monkeypatch.setattr(mod, name, {})
+    return mod
+
+
+NPR, NB = 2_000, 150
+
+
+def ordered_keys(r, distinct=700, run=1):
+    """``NPR`` non-decreasing int32 keys, multiples of 3 (so that build
+    keys fit between them), in runs of about ``run``."""
+    return np.sort(r.integers(0, distinct, -(-NPR // run)).repeat(run)[:NPR]
+                   .astype(np.int32) * 3)
+
+
+def spread(r, pk, every, rows=NB):
+    """``rows`` build keys, every ``every``-th distinct probe key from
+    the lowest, in random row order: a chunk's share of them is even."""
+    return r.permutation(np.resize(np.unique(pk)[::every], rows))
+
+
+def merge_sides(case, joins):
+    """(probe frame, build frame, the build step the probe side gets)."""
+    r = np.random.default_rng(len(case))
+    pk = ordered_keys(r)
+    bk = spread(r, pk, 4)              # a foreign key: no key twice
+    assert len(np.unique(bk)) == NB
+    pmask = bmask = None
+    step = "merge"
+    if case == "duplicate_build_keys":
+        bk = spread(r, pk, 9)          # two and three rows a key
+        assert len(np.unique(bk)) < NB / 2
+    elif case == "key_groups_over_chunk_borders":
+        # runs of 45 under chunks of under 128 probe slots: most chunks
+        # begin inside a group, and one group is longer than three chunks
+        pk = ordered_keys(r, run=45)
+        pk[600:1_000] = pk[600]
+        keys = np.unique(pk)
+        bk = r.permutation(np.concatenate(
+            [keys, keys[::2], [pk[600]] * 2,
+             -1 - np.arange(NB - 2 - len(keys) - len(keys[::2]))]))
+    elif case == "masked_rows_on_both_sides":
+        pmask, bmask = r.random(NPR) < 0.6, r.random(NB) < 0.7
+        part = joins._CHUNK - joins._first_room(1, NB, NPR)
+        pmask[::part] = False          # every chunk's first slot is masked
+        pmask[-1] = False
+    elif case == "build_keys_below_above_and_between":
+        bk = r.permutation(np.concatenate(
+            [r.integers(-50, 0, 30), pk.max() + 1 + r.integers(0, 50, 30),
+             spread(r, pk, 15, 45) + 1,                    # between
+             spread(r, pk, 15, 45)]))
+    elif case == "an_empty_build_side_after_its_mask":
+        bmask = np.zeros(NB, bool)
+    elif case == "float_keys_with_signed_zeros_and_a_build_nan":
+        pk = pk.astype(np.float32) - 300.0
+        at = np.searchsorted(pk, 0.0)
+        pk[at:at + 4] = [-0.0, 0.0, -0.0, 0.0]
+        bk = np.concatenate([spread(r, pk, 4, NB - 4),
+                             [0.0, -0.0, np.nan, np.inf]])
+    elif case == "a_nan_among_the_probe_keys":
+        pk = pk.astype(np.float32)
+        pk[1234] = np.nan              # no order holds: the sort answers
+        bk = np.concatenate([bk[:-1], [np.nan]])
+        step = "miss"
+    elif case != "foreign_keys_drawn_from_the_probe":
+        raise KeyError(case)
+    assert len(bk) == NB
+    probe = Frame({"k": pk, "a": r.normal(size=NPR).astype(np.float32)},
+                  mask=pmask)
+    build = Frame({"k": bk.astype(pk.dtype),
+                   "b": np.arange(NB, dtype=np.float32)}, mask=bmask)
+    return probe, build, step
+
+
+MERGE_CASES = (
+    "foreign_keys_drawn_from_the_probe", "duplicate_build_keys",
+    "key_groups_over_chunk_borders", "masked_rows_on_both_sides",
+    "build_keys_below_above_and_between",
+    "an_empty_build_side_after_its_mask",
+    "float_keys_with_signed_zeros_and_a_build_nan",
+    "a_nan_among_the_probe_keys",
+)
+
+
+def run_join(joins, left, right, how, sort_only=False):
+    """One join with nothing remembered: (result frame, counters moved)."""
+    for table in (joins._PROGRAMS, joins._BUCKETS, joins._ROOMS):
+        table.clear()
+    first = joins._first_room
+    if sort_only:
+        joins._first_room = lambda *shapes: 0
+    try:
+        before = counters.snapshot()
+        out = left.join(right, "k", how)
+        return out, moved(before)
+    finally:
+        joins._first_room = first
+
+
+@pytest.mark.parametrize("how, probe_is", [
+    ("inner", "left"), ("inner", "right"), ("left", "left"),
+    ("left_semi", "left"), ("left_anti", "left")])
+@pytest.mark.parametrize("case", MERGE_CASES)
+def test_the_merge_gives_the_sorts_join_bit_for_bit(joins, case, how,
+                                                     probe_is):
+    probe, build, step = merge_sides(case, joins)
+    # (an inner join builds from the side with fewer slots)
+    left, right = (probe, build) if probe_is == "left" else (build, probe)
+    want, by_sort = run_join(joins, left, right, how, sort_only=True)
+    got, by_merge = run_join(joins, left, right, how)
+    assert "join.merge" not in by_sort and "join.merge_miss" not in by_sort
+    assert by_merge.get("join.merge", 0) == (step == "merge")
+    assert by_merge.get("join.merge_miss", 0) == (step == "miss")
+    assert by_merge["join.device"] == 1 and "join.host" not in by_merge
+    # what the merge reports rides the join's one read; a signature's
+    # first run reads the probe side's order before it builds a program
+    assert by_merge["host.reads"] == by_sort["host.reads"] + 1
+    assert by_merge["join.compile"] == by_sort["join.compile"]
+    same_rows(got, want)
+    # the same slots, not only the same rows
+    assert got.num_slots == want.num_slots
+    assert np.array_equal(np.asarray(got._mask), np.asarray(want._mask))
+    if case == "foreign_keys_drawn_from_the_probe" and how != "left_anti":
+        assert got.count() >= NB                       # it joined something
+
+
+def spans_of(fn):
+    from sparkdq4ml_tpu.utils import observability as obs
+
+    obs.reset()
+    obs.enable()
+    try:
+        fn()
+    finally:
+        obs.disable()
+    found = [s for s in obs.TRACER.spans() if s.name == "frame.join"]
+    obs.reset()
+    return found
+
+
+def test_an_ordered_probe_counts_a_merge_and_its_span_says_so(joins):
+    probe, build, _ = merge_sides("foreign_keys_drawn_from_the_probe", joins)
+    before = counters.snapshot()
+    (span,) = spans_of(lambda: probe.join(build, "k", "left"))
+    delta = moved(before)
+    assert delta["join.merge"] == 1 and "join.merge_miss" not in delta
+    # the order flag before the first program; then size, order, fullest
+    # chunk in the join's one read
+    assert delta["join.compile"] == 1 and delta["host.reads"] == 2
+    assert delta["host.read_bytes"] == 1 + 12
+    assert span.attrs["build_step"] == "merge"
+    assert span.attrs["room"] == joins._first_room(1, NB, NPR) > 0
+    # settled: the second run builds nothing and reads once
+    before = counters.snapshot()
+    probe.join(build, "k", "left")
+    delta = moved(before)
+    assert delta["join.hit"] == 1 and "join.compile" not in delta
+    assert delta["join.merge"] == 1 and delta["host.reads"] == 1
+    assert delta["host.read_bytes"] == 12
+
+
+def test_an_unordered_probe_misses_once_and_then_sorts(joins):
+    probe, build, _ = merge_sides("foreign_keys_drawn_from_the_probe", joins)
+    d = probe.to_pydict()
+    shuffled = Frame({"k": d["k"][::-1].copy(), "a": d["a"][::-1].copy()})
+    want = shuffled._host_join(build, ["k"], "left", False, None)
+    before = counters.snapshot()
+    got = []
+    (span,) = spans_of(lambda: got.append(shuffled.join(build, "k", "left")))
+    delta = moved(before)
+    assert delta["join.merge_miss"] == 1 and "join.merge" not in delta
+    # no merge program is built for it: the order flag, then the sort
+    assert delta["join.compile"] == 1 and delta["host.reads"] == 2
+    assert delta["host.read_bytes"] == 1 + 4
+    assert span.attrs["build_step"] == "sort" and "room" not in span.attrs
+    same_rows(got[0], want)
+    # the signature keeps the sort: no miss, no program, one 4-byte read
+    before = counters.snapshot()
+    again = shuffled.join(build, "k", "left")
+    delta = moved(before)
+    same_rows(again, want)
+    assert "join.merge_miss" not in delta and "join.merge" not in delta
+    assert delta["join.hit"] == 1 and "join.compile" not in delta
+    assert delta["host.reads"] == 1 and delta["host.read_bytes"] == 4
+
+
+def test_a_settled_merge_that_meets_an_unordered_probe_sorts_it(joins):
+    probe, build, _ = merge_sides("foreign_keys_drawn_from_the_probe", joins)
+    probe.join(build, "k", "left")                 # settles on the merge
+    d = probe.to_pydict()
+    at = np.random.default_rng(7).permutation(NPR)
+    shuffled = Frame({"k": d["k"][at], "a": d["a"][at]})
+    want = shuffled._host_join(build, ["k"], "left", False, None)
+    before = counters.snapshot()
+    got = shuffled.join(build, "k", "left")
+    delta = moved(before)
+    same_rows(got, want)
+    # the merge program itself found it, in its one read; then the sort
+    assert delta["join.merge_miss"] == 1 and "join.merge" not in delta
+    assert delta["join.compile"] == 1 and delta["join.hit"] == 1
+    assert delta["host.reads"] == 2 and delta["host.read_bytes"] == 12 + 4
+    before = counters.snapshot()
+    same = shuffled.join(build, "k", "left")
+    delta = moved(before)
+    same_rows(same, want)
+    assert "join.merge_miss" not in delta and delta["join.hit"] == 1
+    assert delta["host.reads"] == 1 and delta["host.read_bytes"] == 4
+
+
+@pytest.mark.parametrize("crowd, then", [(14, "merge"), (40, "sort")])
+def test_a_chunk_over_its_room_runs_once_more_and_the_next_run_fits(
+        joins, crowd, then):
+    """``crowd`` build rows of one key between two probe keys, over the
+    first room of 13: 14 ask for a room the row has, 40 for over an
+    eighth of it (the signature keeps the sort)."""
+    r = np.random.default_rng(5)
+    pk = ordered_keys(r)
+    # (the others: a key every 40 distinct ones, at most two a chunk)
+    bk = np.concatenate([[pk[1000] + 1] * crowd, np.unique(pk)[::40][:10],
+                         -1 - np.arange(NB - 10 - crowd)]).astype(np.int32)
+    assert len(np.unique(bk)) == NB - crowd + 1    # a left join of NPR rows
+    probe = Frame({"k": pk, "a": np.arange(NPR, dtype=np.float32)})
+    build = Frame({"k": bk, "b": np.arange(NB, dtype=np.float32)})
+    assert 0 < joins._first_room(1, NB, NPR) < crowd
+    want = probe._host_join(build, ["k"], "left", False, None)
+
+    def run():
+        got = []
+        before = counters.snapshot()
+        (span,) = spans_of(lambda: got.append(probe.join(build, "k", "left")))
+        delta = moved(before)
+        same_rows(got[0], want)
+        return delta, span.attrs
+
+    delta, attrs = run()
+    assert delta["join.merge_miss"] == 1 and "join.merge" not in delta
+    assert delta["join.compile"] == 2 and attrs["build_step"] == "sort"
+    (room,) = joins._ROOMS.values()
+    assert (crowd <= room <= joins._CHUNK // 8) if then == "merge" \
+        else room == 0
+    # the next run fits (or sorts) at once; the one after builds nothing
+    delta, attrs = run()
+    assert "join.merge_miss" not in delta
+    assert delta.get("join.merge", 0) == (then == "merge")
+    assert delta.get("join.compile", 0) == (then == "merge")
+    assert attrs["build_step"] == then and attrs.get("room", 0) == room
+    delta, attrs = run()
+    assert delta["join.hit"] == 1 and "join.compile" not in delta
+    assert "join.merge_miss" not in delta and attrs["build_step"] == then
+
+
+@pytest.mark.parametrize("shapes, why", [
+    ((2, 150, 2_000), "two key columns"),
+    ((1, 50, 1_000), "a probe side of under eight chunks"),
+    ((1, 300, 2_000), "a build side over a twelfth of the probe side")])
+def test_shapes_that_rule_the_merge_out_pay_nothing(joins, shapes, why):
+    assert joins._first_room(*shapes) == 0, why
+    assert joins._first_room(1, 150, 2_000) > 0
+    # and the join of such shapes reads the one scalar of a sort
+    k, nb, npr = shapes
+    keys = ["k", "j"][:k]
+    probe = Frame({name: np.arange(npr, dtype=np.int32) for name in keys})
+    build = Frame({**{name: np.arange(nb, dtype=np.int32) for name in keys},
+                   "b": np.arange(nb, dtype=np.float32)})
+    before = counters.snapshot()
+    (span,) = spans_of(lambda: probe.join(build, keys, "left"))
+    delta = moved(before)
+    assert span.attrs["build_step"] == "sort"
+    assert delta["host.reads"] == 1 and delta["host.read_bytes"] == 4
+    assert "join.merge" not in delta and "join.merge_miss" not in delta
+
+
+@pytest.mark.parametrize("need, room", [
+    (0, 128), (100, 128), (114, 128), (115, 256), (865, 1024), (911, 1024),
+    (912, 1152), (3_641, 4_096), (3_642, 0)])
+def test_room_is_an_eighth_over_the_need_in_steps_of_128(need, room):
+    from sparkdq4ml_tpu.ops import joins as mod
+
+    assert mod._CHUNK == 1 << 15
+    assert mod._room_for(need) == room
+
+
+def test_the_first_room_at_the_benchmarks_shapes():
+    from sparkdq4ml_tpu.ops import joins as mod
+
+    # tpch_q3_join: the customer join's result against lineitem (859 build
+    # slots a chunk if spread evenly), and customer against orders (a
+    # tenth of them: too many)
+    assert mod._first_room(1, 6_291_456, 240_048_600) == 1_280
+    assert mod._first_room(1, 6_000_000, 60_000_000) == 0
+    # Q3 of tests/test_benchmark_cells.py: under eight chunks
+    assert mod._first_room(1, 20_000, 80_000) == 0
+
+
+@pytest.mark.parametrize("how", ["inner", "left_anti"])
+def test_the_merge_at_its_real_row_length(how):
+    """Rows of 2^15, as on the chip: 3e5 ordered probe slots (four lines
+    an order, masked ones among them) against 2e4 of their orders."""
+    from sparkdq4ml_tpu.ops import joins as mod
+
+    r = np.random.default_rng(15)
+    orders = 75_000
+    pk = np.repeat(np.arange(orders, dtype=np.int32) * 4 + 1, 4)
+    bk = r.permutation(orders)[:20_000].astype(np.int32) * 4 + 1
+    probe = Frame({"k": pk, "a": np.arange(len(pk), dtype=np.float32)},
+                  mask=r.random(len(pk)) < 0.54)
+    build = Frame({"k": bk, "b": np.arange(len(bk), dtype=np.float32)},
+                  mask=r.random(len(bk)) < 0.9)
+    room = mod._first_room(1, len(bk), len(pk))
+    assert room % 128 == 0 and 0 < room <= 1 << 12
+    want = probe._host_join(build, ["k"], how, False, None)
+    before = counters.snapshot()
+    (span,) = spans_of(lambda: same_rows(probe.join(build, "k", how), want))
+    delta = moved(before)
+    assert delta["join.merge"] == 1 and "join.merge_miss" not in delta
+    assert span.attrs["build_step"] == "merge" and span.attrs["room"] == room
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_random_tables_join_alike_under_both_build_steps(joins, seed):
+    """Whatever the spread of the build keys — the merge holds, or a
+    chunk outgrows its room and the sort answers."""
+    r = np.random.default_rng(seed)
+    npr = int(r.integers(600, 3_000))
+    nb = int(r.integers(1, npr // 16 + 1))
+    span = int(r.integers(5, 2_000))
+    probe = Frame({"k": np.sort(r.integers(0, span, npr)).astype(np.int32),
+                   "a": r.normal(size=npr).astype(np.float32)},
+                  mask=r.random(npr) < 0.7)
+    build = Frame({"k": r.integers(-20, span + 20, nb).astype(np.int32),
+                   "b": r.normal(size=nb).astype(np.float32)},
+                  mask=r.random(nb) < 0.8)
+    tried = 0
+    for how in HOWS:
+        for left, right in ((probe, build), (build, probe)):
+            want, _ = run_join(joins, left, right, how, sort_only=True)
+            got, delta = run_join(joins, left, right, how)
+            same_rows(got, want)
+            tried += delta.get("join.merge", 0) \
+                + delta.get("join.merge_miss", 0)
+    # every join whose probe side was the ordered one tried the merge
+    assert tried >= 4
+
+
 def test_emission_order_is_left_then_right_row_order():
     left = Frame({"k": np.asarray([2, 1, 2, 3], np.int32),
                   "a": np.asarray([0., 1., 2., 3.], np.float32)})
