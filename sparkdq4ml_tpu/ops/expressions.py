@@ -770,8 +770,22 @@ def _scalar_value(v):
     the scalar back out. A column-valued argument (more than one distinct
     value) is rejected rather than silently collapsed to row 0's value.
     Single base for :func:`_scalar_str` / :func:`_scalar_int`."""
-    arr = np.asarray(v, object).ravel()
-    if len(arr) > 1 and any(x != arr[0] for x in arr[1:]):
+    if isinstance(v, jax.Array) and v.size > 1:
+        # a numeric literal lies broadcast on the device: compare there and
+        # read two scalars, not every row (1.1e7 rows: 44 MB and a second
+        # of Python)
+        from ..utils.profiling import counters, host_read
+
+        flat = v.reshape(-1)
+        counters.increment("frame.host_sync")
+        head = np.asarray(jnp.stack(
+            [flat[0], jnp.all(flat == flat[0]).astype(flat.dtype)]))
+        host_read(head.nbytes)
+        arr, uniform = head[:1], bool(head[1])
+    else:
+        arr = np.asarray(v, object).ravel()
+        uniform = len(arr) <= 1 or not any(x != arr[0] for x in arr[1:])
+    if not uniform:
         raise ValueError(
             "this function argument must be a literal, not a column "
             "(per-row values are not supported)")
@@ -850,12 +864,28 @@ def _fn_array_contains(arr, value):
     return jnp.asarray(np.asarray(out, np.bool_))
 
 
+def _is_device_vector(arr) -> bool:
+    """A vector column on the device (a classifier's probability,
+    VectorAssembler's features), not a host column of list cells."""
+    return isinstance(arr, jax.Array) and arr.ndim == 2
+
+
+def _vector_element(arr, pos):
+    """Element ``pos`` (0-based) of every row of a device vector column:
+    one strided slice, nothing pulled; out of range -> null (NaN)."""
+    if not 0 <= pos < arr.shape[1]:
+        return jnp.full(arr.shape[:1], jnp.nan, arr.dtype)
+    return arr[:, pos]
+
+
 def _fn_element_at(arr, index):
     """Spark ``element_at(col, i)``: 1-based, negative counts from the
     end, out-of-bounds / null cell → null."""
     i = _scalar_int(index)
     if i == 0:
         raise ValueError("element_at index is 1-based; 0 is invalid")
+    if _is_device_vector(arr):
+        return _vector_element(arr, i - 1 if i > 0 else arr.shape[1] + i)
     out = []
     for cell in _require_array_cells(arr, "element_at"):
         if cell is None:
@@ -1042,6 +1072,8 @@ def _fn_get_item(arr, index):
     null cell) → null — Spark's GetArrayItem truth table, unlike
     ``element_at`` where negatives count from the end."""
     i = _scalar_int(index)
+    if _is_device_vector(arr):
+        return _vector_element(arr, i)
     out = []
     for cell in _require_array_cells(arr, "getItem"):
         if cell is None or i < 0 or i >= len(cell):

@@ -73,6 +73,18 @@ Wired through the framework (span names are a contract: the benchmark's
   ``fit.pack_eager``) and ``fit.solve`` (dispatch of the compiled fit to
   its result on the host); ``model.transform`` / ``model.predict`` on
   both model classes,
+* ``models/tree.py`` — one root per fit (``fit.gbt_classifier``,
+  ``fit.gbt_regressor``, ``fit.decision_tree_classifier`` / ``_regressor``,
+  ``fit.random_forest_classifier`` / ``_regressor``) holding
+  ``fit.prepare`` (children ``fit.extract``, ``fit.validate`` — the label
+  statistics and the finite-label / finite-features flags, with
+  ``host_read_bytes`` — and ``fit.tree.bin``: thresholds and bins, with
+  ``rows``, ``features``, ``bins``, ``lowering="device"``; the span waits
+  for its program) and ``fit.solve`` (``rounds``, ``levels``,
+  ``histogram`` = ``mxu`` / ``scatter``: the trees dispatched to the one
+  read of their packed arrays); ``model.transform``; counters
+  ``tree.fit_device``, ``tree.rounds``, ``tree.levels``,
+  ``tree.hist_rows``,
 * ``models/solvers.py`` — ``solver.solve``,
 * ``parallel/distributed.py`` / ``mesh.py`` — per-shard Gramian timing
   (blocks under the explicit flag only), collective/shard_map build
@@ -91,7 +103,11 @@ operations), ``dq.sketch``, ``dq.grouped``, ``dq.exchange``,
 before its passes: mask, scale, moments, the standardised design),
 ``dq.fit.gram``,
 ``dq.fit.newton.margin`` / ``.gradient`` / ``.hessian`` /
-``.line_search``, ``dq.fit.fista.loss_grad``, ``dq.fit.solve``. Metadata
+``.line_search``, ``dq.fit.fista.loss_grad``, ``dq.fit.solve``; in the
+tree programs ``dq.tree.edges`` (the sorts and the picked thresholds),
+``dq.tree.bin``, ``dq.tree.gradient``, ``dq.tree.hist`` (the Pallas kernel
+``tree_level_histogram`` or the scatters), ``dq.tree.split``,
+``dq.tree.descend``, ``dq.tree.score``. Metadata
 only: the operations' HLO names and the compiled code are unchanged. (A
 scope opened on the host around eager ``jnp`` calls does not reach their
 one-operation programs' metadata — measured on the chip, PERF.md section 3
@@ -233,6 +249,14 @@ METRIC_NAMES = {
     "fit.pack_eager": ("counter", "designs packed into Z before a fit "
                                   "(pack_design: the sharded path, "
                                   "callers that hold a Z)"),
+    # tree ensembles (models/tree.py)
+    "tree.fit_device": ("counter", "tree fits through the device entry: "
+                                   "thresholds, bins and growth on the "
+                                   "device, nothing n-sized to the host"),
+    "tree.rounds": ("counter", "trees grown (boosting rounds, forest "
+                               "members)"),
+    "tree.levels": ("counter", "histogram passes: one a level a tree"),
+    "tree.hist_rows": ("counter", "row slots handed to level histograms"),
     "jit.trace_miss": ("counter", "jit-factory cache misses (new trace)"),
     "jit.trace_hit": ("counter", "jit-factory cache hits"),
     # parallel / mesh

@@ -1,0 +1,452 @@
+"""The tree family's device entry (models/tree.py): thresholds and bins made
+on the device equal the plain host version to the bit; a level's histogram
+— the MXU one-hot contraction through the Pallas interpreter, the scatter,
+the psum'd sharded form — equals float64 numpy; a whole ``GBTClassifier``
+fit equals the benchmark's plain reference (benchmarks/configs/higgs-gbt.py)
+tree for tree; a fit reads a few KB and builds no program the second time;
+the descent without gathers equals a gather a row.
+
+CPU, seeded, small sizes. Nothing here asserts a time.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from conftest import assert_devices
+from sparkdq4ml_tpu import Frame
+from sparkdq4ml_tpu.models import (DecisionTreeRegressor, GBTClassifier,
+                                   RandomForestClassifier, VectorAssembler)
+from sparkdq4ml_tpu.models import tree as T
+from sparkdq4ml_tpu.utils.profiling import counters
+
+REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+sys.path.insert(0, REPO)
+
+from benchmarks import harness  # noqa: E402
+
+REFERENCE = harness.load_module("configs", "higgs-gbt")
+ESTIMATOR = harness.load_json(os.path.join(
+    REPO, "benchmarks", "configs", "higgs-gbt.json"))["estimator"]
+
+
+# ---------------------------------------------------------------------------
+# thresholds and bins
+# ---------------------------------------------------------------------------
+
+def _columns(case, n=700, d=4, seed=3):
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, d)).astype(np.float32)
+    mask = np.ones(n, bool)
+    if case == "masked_rows":
+        mask = rng.random(n) > 0.3
+    elif case == "nan_in_masked_slots":
+        mask = rng.random(n) > 0.3
+        X[~mask] = np.nan
+    elif case == "constant_column":
+        X[:, 1] = 2.5
+    elif case == "heavy_duplicates":
+        X[:, 0] = rng.integers(0, 5, size=n)
+        X[:, 2] = np.where(rng.random(n) < 0.9, 1.0, X[:, 2])
+    elif case == "fewer_values_than_bins":
+        X[:, 3] = rng.integers(0, 7, size=n)
+    elif case == "two_valid_rows":
+        mask[:] = False
+        mask[[5, 77]] = True
+    return X, mask
+
+
+@pytest.mark.parametrize("max_bins", [32, 8])
+@pytest.mark.parametrize("case", [
+    "dense", "masked_rows", "nan_in_masked_slots", "constant_column",
+    "heavy_duplicates", "fewer_values_than_bins", "two_valid_rows"])
+def test_device_thresholds_and_bins_equal_the_host_version(case, max_bins):
+    X, mask = _columns(case)
+    n = X.shape[0]
+    rows = T.row_layout(n)[0]
+    edges, binned, _, w, _ = T._bin_program(max_bins, rows)(
+        jnp.asarray(X), jnp.zeros(n, jnp.float32), jnp.asarray(mask), None)
+    want_edges, want_bins = T.bin_features(X.astype(np.float64), mask,
+                                           max_bins)
+    np.testing.assert_array_equal(np.asarray(edges, np.float64), want_edges)
+    got = np.asarray(binned)
+    assert got.dtype == np.int8 and got.shape == (X.shape[1], rows)
+    np.testing.assert_array_equal(got[:, :n][:, mask], want_bins.T[:, mask])
+    assert not got[:, n:].any()
+    np.testing.assert_array_equal(np.asarray(w)[:n], mask.astype(np.float32))
+    # the benchmark's plain reference states the same rule
+    ref = REFERENCE.thresholds(list(X.T), mask, max_bins)
+    np.testing.assert_array_equal(ref, want_edges)
+    np.testing.assert_array_equal(
+        REFERENCE.bin_rows(list(X.T), ref)[:, mask], want_bins.T[:, mask])
+
+
+def test_threshold_ranks_hold_for_row_counts_past_int32_products():
+    # 31 * 2e9 overflows int32; the split form does not
+    n = np.int64(2_000_000_011)
+    want = -(-np.arange(1, 32, dtype=np.int64) * n // 32) - 1
+    got = T.threshold_ranks(np.int32(n), 32)
+    np.testing.assert_array_equal(got.astype(np.int64), want)
+
+
+# ---------------------------------------------------------------------------
+# a level's histogram
+# ---------------------------------------------------------------------------
+
+def _level_case(level, n=1500, d=5, B=32, trees=2, seed=11):
+    rng = np.random.default_rng(seed + level)
+    m = 2 ** level
+    rows = T.row_layout(n)[0]
+    binned = np.zeros((d, rows), np.int8)
+    binned[:, :n] = rng.integers(0, B, size=(d, n))
+    # slot m: parked rows; masked rows carry zero statistics
+    pos = np.full((trees, rows), m, np.int32)
+    pos[:, :n] = rng.integers(0, m + 1, size=(trees, n))
+    stats = np.zeros((trees, 4, rows), np.float32)
+    stats[:, :, :n] = rng.normal(size=(trees, 4, n))
+    stats[:, :, :n] *= rng.random(n) > 0.2
+    return binned, pos, stats, m, B, _numpy_histogram(binned, pos, stats,
+                                                      m, B)
+
+
+def _numpy_histogram(binned, pos, stats, m, B):
+    want = np.zeros((pos.shape[0], binned.shape[0], m, B, stats.shape[1]))
+    for t in range(pos.shape[0]):
+        live = pos[t] < m
+        for f in range(binned.shape[0]):
+            np.add.at(want[t, f], (pos[t][live], binned[f][live]),
+                      stats[t][:, live].T.astype(np.float64))
+    return want
+
+
+@pytest.mark.parametrize("level", range(5))
+def test_mxu_histogram_equals_segment_sum_and_float64(level):
+    binned, pos, stats, m, B, want = _level_case(level)
+    args = (jnp.asarray(binned), jnp.asarray(pos), jnp.asarray(stats))
+    mxu = np.asarray(T._mxu_histogram(*args, m, B, interpret=True))
+    scatter = np.asarray(T._scatter_histogram(*args, m, B))
+    scale = np.abs(want).max()
+    assert mxu.shape == scatter.shape == want.shape
+    # three bfloat16-exact parts and float32 sums: float32's own error
+    assert np.abs(mxu - want).max() <= 2e-6 * scale
+    assert np.abs(scatter - want).max() <= 2e-5 * scale
+
+
+def test_mxu_histogram_counts_are_exact_integers():
+    binned, pos, stats, m, B, _ = _level_case(3, n=5000)
+    stats[:, 0] = (stats[:, 0] != 0)           # the weight statistic: 0 / 1
+    got = np.asarray(T._mxu_histogram(
+        jnp.asarray(binned), jnp.asarray(pos), jnp.asarray(stats), m, B,
+        interpret=True))[..., 0]
+    want = np.zeros_like(got)
+    for t in range(pos.shape[0]):
+        live = (pos[t] < m) & (stats[t, 0] > 0)
+        for f in range(binned.shape[0]):
+            np.add.at(want[t, f], (pos[t][live], binned[f][live]), 1.0)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_split_bf16x3_parts_are_exact():
+    x = jnp.asarray(np.random.default_rng(0).normal(size=4096) * 1e3,
+                    jnp.float32)
+    parts = T.split_bf16x3(x)
+    for part in parts:
+        np.testing.assert_array_equal(
+            np.asarray(part.astype(jnp.bfloat16).astype(jnp.float32)),
+            np.asarray(part))
+    np.testing.assert_array_equal(
+        np.asarray(parts[0] + parts[1] + parts[2]), np.asarray(x))
+
+
+@pytest.mark.parametrize("level", [0, 2, 4])
+def test_sharded_histogram_is_the_single_device_one(level):
+    from jax.sharding import PartitionSpec as P
+
+    from sparkdq4ml_tpu.parallel.mesh import (DATA_AXIS, make_mesh,
+                                              shard_map)
+
+    assert_devices(8)
+    binned, pos, stats, m, B, _ = _level_case(level, n=2048)
+    stats = np.round(stats * 8)                # exact in any order
+    args = (jnp.asarray(binned), jnp.asarray(pos),
+            jnp.asarray(stats, jnp.float64))
+    single = T._level_histogram(*args, m, B)
+    sharded = jax.jit(shard_map(
+        lambda b, p, t: T._level_histogram(b, p, t, m, B, DATA_AXIS),
+        mesh=make_mesh(8),
+        in_specs=(P(None, DATA_AXIS), P(None, DATA_AXIS),
+                  P(None, None, DATA_AXIS)),
+        out_specs=P()))(*args)
+    np.testing.assert_array_equal(np.asarray(sharded), np.asarray(single))
+    np.testing.assert_array_equal(
+        np.asarray(single), _numpy_histogram(binned, pos, stats, m, B))
+
+
+def test_lowering_follows_backend_and_shapes(monkeypatch):
+    assert T.hist_lowering(28, 32) == "scatter"          # the CPU of the tests
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert T.hist_lowering(28, 32) == "mxu"
+    assert T.hist_lowering(28, 32, sharded="data") == "scatter"
+    assert T.hist_lowering(600, 32) == "scatter"         # one-hot too wide
+    for rows in (1, 127, 2048, 2049, 131_071, 131_072, 11_000_000):
+        padded = T.row_layout(rows)[0]
+        again, block, partials = T.row_layout(padded)
+        # the fit pads once; the kernel finds its grid from the padded rows
+        assert again == padded >= rows and padded % (block * partials) == 0
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_mxu_histogram_compiles_for_the_chip_at_the_cell_size(one_chip):
+    """The TPU's compiler takes the kernel at 11M rows x 28 features x 32
+    bins, 16 nodes (Mosaic refuses what the interpreter lets through)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    rows = T.row_layout(11_000_000)[0]
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(
+            lambda b, p, t: T._mxu_histogram(b, p, t, 16, 32)).lower(
+            shape((28, rows), jnp.int8), shape((1, rows), jnp.int32),
+            shape((1, 4, rows), jnp.float32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert " scatter(" not in text and " gather(" not in text
+
+
+# ---------------------------------------------------------------------------
+# a whole fit against the plain reference
+# ---------------------------------------------------------------------------
+
+def _higgs_like(n, d, seed):
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, d)).astype(np.float32)
+    X[:, 0] = np.exp(0.55 * X[:, 0])
+    beta = rng.normal(size=d) * 0.6
+    y = (rng.random(n) < 1 / (1 + np.exp(-(X @ beta)))).astype(np.float32)
+    keep = X[:, 0] > 0.6
+    cols = {f"x{j}": X[:, j] for j in range(d)}
+    cols["label"] = y
+    names = [f"x{j}" for j in range(d)]
+    frame = VectorAssembler(names, "features").transform(Frame(cols))
+    return frame.filter(np.asarray(keep)), X, y, keep
+
+
+@pytest.mark.parametrize("n,d", [(3000, 5), (6000, 9)])
+@pytest.mark.parametrize("seed", range(6))
+def test_gbt_fit_equals_the_plain_reference_tree_for_tree(seed, n, d):
+    frame, X, y, keep = _higgs_like(n, d, seed)
+    # leaves of 100 rows and more: in a small node two features part the
+    # rows alike (an exact tie) and the last bit of a sum picks between them
+    est = dict(ESTIMATOR, max_iter=5, max_depth=3,
+               min_instances_per_node=100)
+    model = GBTClassifier(
+        max_iter=est["max_iter"], max_depth=est["max_depth"],
+        max_bins=est["max_bins"], step_size=est["step_size"],
+        min_instances_per_node=100).fit(frame)
+    cols = list(X.T)
+    edges = REFERENCE.thresholds(cols, keep, est["max_bins"])
+    bins = REFERENCE.bin_rows(cols, edges)
+    f0, trees, F, _ = REFERENCE.grow(
+        np.ascontiguousarray(bins[:, keep]), edges,
+        y[keep].astype(np.float64), est)
+    np.testing.assert_array_equal(np.asarray(model.split_candidates), edges)
+    assert model.f0 == pytest.approx(f0, rel=1e-12)
+    split = ~trees["is_leaf"]
+    np.testing.assert_array_equal(model.is_leaf, trees["is_leaf"])
+    np.testing.assert_array_equal(model.feature[split],
+                                  trees["feature"][split])
+    np.testing.assert_array_equal(model.threshold[split],
+                                  trees["threshold"][split])
+    np.testing.assert_array_equal(model.value[:, :, 0],
+                                  trees["value"][:, :, 0])
+    np.testing.assert_allclose(REFERENCE.leaf_values(model.value),
+                               REFERENCE.leaf_values(trees["value"]),
+                               rtol=1e-5, atol=1e-9)
+    prob = np.stack(model.transform(frame).to_pydict()["probability"])[:, 1]
+    np.testing.assert_allclose(prob, REFERENCE.probabilities(F), atol=1e-5)
+
+
+def test_replay_of_the_program_trees_reads_no_gap():
+    """The cell's comparison on a small table: the reference's replay of
+    the program's own ensemble finds its rows, leaves and splits."""
+    frame, X, y, keep = _higgs_like(2000, 6, 21)
+    est = dict(ESTIMATOR, max_iter=4, max_depth=3)
+    model = GBTClassifier(max_iter=4, max_depth=3).fit(frame)
+    cols = list(X.T)
+    edges = REFERENCE.thresholds(cols, keep, 32)
+    bins = REFERENCE.bin_rows(cols, edges)
+    trees = {k: np.asarray(getattr(model, k)) for k in
+             ("feature", "threshold", "is_leaf", "value", "gain")}
+    ref = REFERENCE.replay(np.ascontiguousarray(bins[:, keep]), edges,
+                           y[keep].astype(np.float64), trees, est,
+                           full=(0, 3))
+    np.testing.assert_array_equal(model.value[:, :, 0], ref["counts"])
+    reached = ref["counts"] > 0
+    np.testing.assert_allclose(
+        np.where(reached, REFERENCE.leaf_values(model.value), 0.0),
+        ref["leaves"], rtol=1e-9, atol=1e-12)
+    assert max(ref["regret"].values()) <= 1e-9
+
+
+# ---------------------------------------------------------------------------
+# what a fit reads, counts and builds
+# ---------------------------------------------------------------------------
+
+def _delta(before):
+    return {k: counters.get(k) - v for k, v in before.items()}
+
+
+WATCHED = ("host.reads", "host.read_bytes", "tree.fit_device",
+           "tree.rounds", "tree.levels", "tree.hist_rows", "frame.host_sync")
+
+
+@pytest.mark.parametrize("make,trees", [
+    (lambda: GBTClassifier(max_iter=10, max_depth=5), 10),
+    (lambda: RandomForestClassifier(num_trees=4, max_depth=3, seed=1), 4),
+    (lambda: DecisionTreeRegressor(max_depth=4), 1),
+], ids=["gbt", "forest", "tree"])
+def test_a_fit_reads_a_few_kilobytes_through_the_device_entry(make, trees):
+    frame, _, _, _ = _higgs_like(3000, 8, 5)
+    est = make()
+    before = {k: counters.get(k) for k in WATCHED}
+    est.fit(frame)
+    moved = _delta(before)
+    assert moved["tree.fit_device"] == 1
+    assert moved["tree.rounds"] == trees
+    assert moved["tree.levels"] == trees * est.max_depth
+    assert moved["tree.hist_rows"] == trees * est.max_depth * 3000
+    # label statistics, the two flags, the packed trees: three reads
+    assert moved["host.reads"] == 3 and moved["frame.host_sync"] == 0
+    assert 0 < moved["host.read_bytes"] < 64 * 1024
+
+
+def test_a_second_fit_of_the_same_signature_builds_no_program():
+    frame, _, _, _ = _higgs_like(900, 4, 8)
+    other, _, _, _ = _higgs_like(900, 4, 9)
+    GBTClassifier(max_iter=3, max_depth=3).fit(frame)
+    events = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, *a, **kw: events.append(name))
+    model = GBTClassifier(max_iter=3, max_depth=3).fit(other)
+    model.transform(other)                      # the first transform traces
+    events.clear()
+    GBTClassifier(max_iter=3, max_depth=3).fit(frame).transform(frame)
+    again = [e for e in events if "compile" in e or "trace" in e]
+    assert not again, again
+
+
+def test_spans_of_a_tree_fit():
+    from sparkdq4ml_tpu.utils import observability as obs
+
+    frame, _, _, _ = _higgs_like(500, 4, 2)
+    obs.enable()
+    try:
+        obs.TRACER.clear()
+        GBTClassifier(max_iter=2, max_depth=3).fit(frame)
+        spans = {s.name: s for s in obs.TRACER.spans()}
+    finally:
+        obs.disable()
+    for name in ("fit.gbt_classifier", "fit.prepare", "fit.extract",
+                 "fit.validate", "fit.tree.bin", "fit.solve"):
+        assert name in spans, sorted(spans)
+    assert spans["fit.tree.bin"].attrs["lowering"] == "device"
+    assert spans["fit.tree.bin"].attrs["bins"] == 32
+    assert spans["fit.solve"].attrs["rounds"] == 2
+    assert spans["fit.solve"].attrs["levels"] == 6
+    assert spans["fit.solve"].attrs["histogram"] == "scatter"
+    assert spans["fit.validate"].attrs["host_read_bytes"] > 0
+
+
+# ---------------------------------------------------------------------------
+# descent and scoring without a gather a row
+# ---------------------------------------------------------------------------
+
+def _gather_descent(X, feature, threshold, is_leaf, depth):
+    node = np.zeros(X.shape[0], np.int64)
+    for _ in range(depth):
+        go_left = X[np.arange(X.shape[0]), feature[node]] <= threshold[node]
+        node = np.where(is_leaf[node], node, 2 * node + 2 - go_left)
+    return node
+
+
+@pytest.mark.parametrize("depth", [1, 3, 5])
+def test_select_chain_descent_equals_a_gather_a_row(depth):
+    rng = np.random.default_rng(depth)
+    N, d, n = 2 ** (depth + 1) - 1, 6, 800
+    X = rng.normal(size=(n, d))
+    feature = rng.integers(0, d, size=N)
+    threshold = rng.normal(size=N) * 0.5
+    is_leaf = rng.random(N) < 0.25
+    want = _gather_descent(X, feature, threshold, is_leaf, depth)
+    got = T.predict_heap(jnp.asarray(X), jnp.asarray(feature),
+                         jnp.asarray(threshold), jnp.asarray(is_leaf), depth)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    table = rng.normal(size=(N, 3))
+    np.testing.assert_array_equal(
+        np.asarray(T.heap_lookup(got, jnp.asarray(table))), table[want].T)
+
+
+def test_forest_apply_sums_every_tree_leaf_payload():
+    rng = np.random.default_rng(4)
+    trees, depth, d, n = 5, 3, 4, 300
+    N = 2 ** (depth + 1) - 1
+    X = rng.normal(size=(n, d))
+    feature = rng.integers(0, d, size=(trees, N))
+    threshold = rng.normal(size=(trees, N)) * 0.5
+    is_leaf = rng.random((trees, N)) < 0.2
+    tables = rng.normal(size=(trees, N, 2))
+    want = sum(tables[t][_gather_descent(X, feature[t], threshold[t],
+                                         is_leaf[t], depth)]
+               for t in range(trees))
+    X[7, 2] = np.nan                       # NaN <= t is false: goes right
+    want = sum(tables[t][_gather_descent(X, feature[t], threshold[t],
+                                         is_leaf[t], depth)]
+               for t in range(trees))
+    edges, cut = T.score_cuts(feature, threshold, is_leaf, d)
+    assert edges.shape[1] % 8 == 0
+    got = T.forest_apply(jnp.asarray(X), jnp.asarray(edges),
+                         jnp.asarray(feature), jnp.asarray(cut),
+                         jnp.asarray(is_leaf), jnp.asarray(tables), depth)
+    np.testing.assert_allclose(np.asarray(got).T, want, rtol=1e-12)
+
+
+def test_element_at_reads_a_device_vector_column():
+    import sparkdq4ml_tpu as dq
+
+    frame, _, _, _ = _higgs_like(400, 3, 6)
+    scored = GBTClassifier(max_iter=2, max_depth=2).fit(frame) \
+        .transform(frame)
+    spark = dq.TpuSession.builder().app_name("t").master("local[*]") \
+        .get_or_create()
+    scored.create_or_replace_temp_view("scored")
+    got = spark.sql("SELECT avg(element_at(probability, 2)) AS p1, "
+                    "avg(element_at(probability, -2)) AS p0 "
+                    "FROM scored").to_pydict()
+    prob = np.stack(scored.to_pydict()["probability"])
+    assert got["p1"][0] == pytest.approx(prob[:, 1].mean(), rel=1e-9)
+    assert got["p0"][0] == pytest.approx(prob[:, 0].mean(), rel=1e-9)
