@@ -79,12 +79,15 @@ Wired through the framework (span names are a contract: the benchmark's
   ``fit.prepare`` (children ``fit.extract``, ``fit.validate`` — the label
   statistics and the finite-label / finite-features flags, with
   ``host_read_bytes`` — and ``fit.tree.bin``: thresholds and bins, with
-  ``rows``, ``features``, ``bins``, ``lowering="device"``; the span waits
-  for its program) and ``fit.solve`` (``rounds``, ``levels``,
-  ``histogram`` = ``mxu`` / ``scatter``: the trees dispatched to the one
-  read of their packed arrays); ``model.transform``; counters
-  ``tree.fit_device``, ``tree.rounds``, ``tree.levels``,
-  ``tree.hist_rows``,
+  ``rows``, ``features``, ``bins``, ``lowering="device"``, ``edges`` =
+  ``select`` / ``sort`` (how the thresholds' ranks were found) and
+  ``passes`` (the selection's passes over the table, a bit of the dtype
+  each; 0 for the sort); the span waits for its program) and
+  ``fit.solve`` (``rounds``, ``levels``, ``histogram`` = ``mxu`` /
+  ``scatter``: the trees dispatched to the one read of their packed
+  arrays); ``model.transform``; counters ``tree.fit_device``,
+  ``tree.edges_select`` / ``tree.edges_sort`` (one of the two a fit),
+  ``tree.rounds``, ``tree.levels``, ``tree.hist_rows``,
 * ``models/solvers.py`` — ``solver.solve``,
 * ``parallel/distributed.py`` / ``mesh.py`` — per-shard Gramian timing
   (blocks under the explicit flag only), collective/shard_map build
@@ -104,7 +107,8 @@ before its passes: mask, scale, moments, the standardised design),
 ``dq.fit.gram``,
 ``dq.fit.newton.margin`` / ``.gradient`` / ``.hessian`` /
 ``.line_search``, ``dq.fit.fista.loss_grad``, ``dq.fit.solve``; in the
-tree programs ``dq.tree.edges`` (the sorts and the picked thresholds),
+tree programs ``dq.tree.edges`` (the thresholds: the integer image of
+the table and the counting passes that select their ranks, or the sorts),
 ``dq.tree.bin``, ``dq.tree.gradient``, ``dq.tree.hist`` (the Pallas kernel
 ``tree_level_histogram`` or the scatters), ``dq.tree.split``,
 ``dq.tree.descend``, ``dq.tree.score``. Metadata
@@ -253,6 +257,10 @@ METRIC_NAMES = {
     "tree.fit_device": ("counter", "tree fits through the device entry: "
                                    "thresholds, bins and growth on the "
                                    "device, nothing n-sized to the host"),
+    "tree.edges_select": ("counter", "tree fits whose thresholds were "
+                                     "selected by counting passes"),
+    "tree.edges_sort": ("counter", "tree fits whose thresholds were read "
+                                   "out of a sort a feature"),
     "tree.rounds": ("counter", "trees grown (boosting rounds, forest "
                                "members)"),
     "tree.levels": ("counter", "histogram passes: one a level a tree"),

@@ -39,9 +39,13 @@ ESTIMATOR = harness.load_json(os.path.join(
 # ---------------------------------------------------------------------------
 
 def _columns(case, n=700, d=4, seed=3):
+    """(X float32, mask, held): the rows ``mask`` keeps are valid, and those
+    of them that ``held`` (None: none) marks do not vote."""
     rng = np.random.default_rng(seed)
     X = rng.normal(size=(n, d)).astype(np.float32)
     mask = np.ones(n, bool)
+    held = None
+    tiny = np.finfo(np.float32).smallest_subnormal
     if case == "masked_rows":
         mask = rng.random(n) > 0.3
     elif case == "nan_in_masked_slots":
@@ -57,32 +61,116 @@ def _columns(case, n=700, d=4, seed=3):
     elif case == "two_valid_rows":
         mask[:] = False
         mask[[5, 77]] = True
-    return X, mask
+    elif case == "negatives_only":
+        X[:, 0] = -np.abs(X[:, 0]) - 1.0
+        X[:, 2] = -np.exp(8 * X[:, 2])
+    elif case == "signed_zeros":
+        X[:, 0] = np.where(rng.random(n) < 0.5, -0.0, 0.0)
+        X[:, 1] = np.where(rng.random(n) < 0.6,
+                           np.where(rng.random(n) < 0.5, -0.0, 0.0), X[:, 1])
+    elif case == "denormals":
+        # a float compare reads every one of these as zero
+        X[:, 0] = rng.integers(-40, 40, size=n) * tiny
+        X[:, 3] = np.where(rng.random(n) < 0.5, X[:, 3],
+                           rng.integers(-9, 9, size=n) * tiny)
+    elif case == "finfo_extremes":
+        big = np.finfo(np.float32).max
+        X[:, 1] = np.where(rng.random(n) < 0.5, big, -big)
+        X[:, 2] = np.where(rng.random(n) < 0.2, big, X[:, 2])
+        X[:, 3] = np.where(rng.random(n) < 0.2, -big, X[:, 3])
+    elif case == "one_voting_row":
+        mask = rng.random(n) > 0.3
+        X[~mask] = np.nan
+        held = mask.copy()
+        held[np.flatnonzero(mask)[11]] = False
+    elif case == "no_voting_row":
+        mask = rng.random(n) > 0.3
+        held = mask.copy()
+    return X, mask, held
 
 
-@pytest.mark.parametrize("max_bins", [32, 8])
+# 127 compares a pass are past ``EDGES_SELECT_MAX_COMPARES`` for float32:
+# 128 bins take the sort, 32 and 8 the selection
+@pytest.mark.parametrize("max_bins", [32, 8, 128])
 @pytest.mark.parametrize("case", [
     "dense", "masked_rows", "nan_in_masked_slots", "constant_column",
-    "heavy_duplicates", "fewer_values_than_bins", "two_valid_rows"])
+    "heavy_duplicates", "fewer_values_than_bins", "two_valid_rows",
+    "negatives_only", "signed_zeros", "denormals", "finfo_extremes",
+    "one_voting_row", "no_voting_row"])
 def test_device_thresholds_and_bins_equal_the_host_version(case, max_bins):
-    X, mask = _columns(case)
+    X, mask, held = _columns(case)
+    assert T.edges_lowering(max_bins, X.dtype) == (
+        ("sort", 0) if max_bins == 128 else ("select", 32))
     n = X.shape[0]
+    votes = mask if held is None else mask & ~held
     rows = T.row_layout(n)[0]
-    edges, binned, _, w, _ = T._bin_program(max_bins, rows)(
-        jnp.asarray(X), jnp.zeros(n, jnp.float32), jnp.asarray(mask), None)
-    want_edges, want_bins = T.bin_features(X.astype(np.float64), mask,
+    edges, binned, _, w, w_held = T._bin_program(max_bins, rows)(
+        jnp.asarray(X), jnp.zeros(n, jnp.float32), jnp.asarray(mask),
+        None if held is None else jnp.asarray(held))
+    # every valid row is binned by the voting rows' thresholds
+    want_edges, want_bins = T.bin_features(X.astype(np.float64), votes,
                                            max_bins)
+    if not votes.any():
+        assert np.isposinf(want_edges).all()
     np.testing.assert_array_equal(np.asarray(edges, np.float64), want_edges)
     got = np.asarray(binned)
     assert got.dtype == np.int8 and got.shape == (X.shape[1], rows)
     np.testing.assert_array_equal(got[:, :n][:, mask], want_bins.T[:, mask])
     assert not got[:, n:].any()
-    np.testing.assert_array_equal(np.asarray(w)[:n], mask.astype(np.float32))
+    np.testing.assert_array_equal(np.asarray(w)[:n], votes.astype(np.float32))
+    if held is not None:
+        np.testing.assert_array_equal(np.asarray(w_held)[:n],
+                                      (mask & held).astype(np.float32))
     # the benchmark's plain reference states the same rule
-    ref = REFERENCE.thresholds(list(X.T), mask, max_bins)
+    ref = REFERENCE.thresholds(list(X.T), votes, max_bins)
     np.testing.assert_array_equal(ref, want_edges)
     np.testing.assert_array_equal(
         REFERENCE.bin_rows(list(X.T), ref)[:, mask], want_bins.T[:, mask])
+
+
+def _host_keys(x):
+    """``tree._rank_keys`` in numpy: the floats' order as unsigned ints."""
+    uint = np.dtype(f"uint{x.dtype.itemsize * 8}")
+    top = uint.type(1 << (x.dtype.itemsize * 8 - 1))
+    raw = x.view(uint)
+    raw = np.where(raw == top, uint.type(0), raw)
+    return np.where(raw >= top, ~raw, raw | top)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_selection_and_sort_agree_bit_for_bit_on_random_bit_patterns(
+        dtype, monkeypatch):
+    """Every bit pattern but the NaNs — denormals, infinities, both zeros,
+    the extremes — in columns of which some rows do not vote: the counting
+    passes select what a sort of the integer image reads at the ranks."""
+    rng = np.random.default_rng(17)
+    d, n, max_bins = 6, 5000, 32
+    uint = np.dtype(f"uint{np.dtype(dtype).itemsize * 8}")
+    raw = rng.integers(0, np.iinfo(uint).max, size=(d, n), dtype=uint,
+                       endpoint=True)
+    raw[0, :40] = raw[0, 40:80]                          # some duplicates
+    raw[1, ::7] = uint.type(1 << (uint.itemsize * 8 - 1))  # -0.0
+    raw[1, 1::7] = 0
+    Xt = raw.view(dtype)
+    Xt = np.where(np.isnan(Xt), dtype(1.5), Xt)
+    valid = rng.random(n) > 0.25
+    Xt[:, ~valid] = np.nan
+    got = {}
+    for how, limit in (("select", 10 ** 9), ("sort", 0)):
+        monkeypatch.setattr(T, "EDGES_SELECT_MAX_COMPARES", limit)
+        assert T.edges_lowering(max_bins, dtype)[0] == how
+        got[how] = np.asarray(jax.jit(
+            lambda x, v: T.device_edges(x, v, max_bins))(
+            jnp.asarray(Xt), jnp.asarray(valid)))
+        assert got[how].dtype == np.dtype(dtype)
+    np.testing.assert_array_equal(got["select"].view(uint),
+                                  got["sort"].view(uint))
+    ranks = T.threshold_ranks(int(valid.sum()), max_bins)
+    for j in range(d):
+        keys = np.unique(np.sort(_host_keys(Xt[j, valid]))[ranks])
+        np.testing.assert_array_equal(
+            _host_keys(got["select"][j, :len(keys)]), keys)
+        assert np.isposinf(got["select"][j, len(keys):]).all()
 
 
 def test_threshold_ranks_hold_for_row_counts_past_int32_products():
@@ -380,6 +468,35 @@ def test_spans_of_a_tree_fit():
     assert spans["fit.solve"].attrs["levels"] == 6
     assert spans["fit.solve"].attrs["histogram"] == "scatter"
     assert spans["fit.validate"].attrs["host_read_bytes"] > 0
+
+
+@pytest.mark.parametrize("max_bins,how", [(32, "select"), (8, "select"),
+                                          (128, "sort")])
+def test_the_bin_span_and_a_counter_say_how_the_thresholds_were_found(
+        max_bins, how):
+    from sparkdq4ml_tpu.utils import observability as obs
+
+    frame, _, _, _ = _higgs_like(600, 4, 12)
+    dtype = frame._column_values("features").dtype
+    bits = jnp.finfo(dtype).bits
+    assert T.edges_lowering(max_bins, dtype) == (
+        how, bits if how == "select" else 0)
+    watched = ("tree.edges_select", "tree.edges_sort", "tree.fit_device")
+    before = {k: counters.get(k) for k in watched}
+    obs.enable()
+    try:
+        obs.TRACER.clear()
+        DecisionTreeRegressor(max_depth=2, max_bins=max_bins).fit(frame)
+        spans = {s.name: s for s in obs.TRACER.spans()}
+    finally:
+        obs.disable()
+    assert spans["fit.tree.bin"].attrs["edges"] == how
+    assert spans["fit.tree.bin"].attrs["passes"] == (
+        bits if how == "select" else 0)
+    moved = _delta(before)
+    assert moved["tree.fit_device"] == 1
+    assert moved["tree.edges_" + how] == 1
+    assert moved["tree.edges_select"] + moved["tree.edges_sort"] == 1
 
 
 # ---------------------------------------------------------------------------
