@@ -26,9 +26,10 @@ Flagged site kinds (in the device-touching layers ``frame/``, ``ops/``,
   the program is ever cached).
 
 A site is sanctioned when its enclosing function is a **counted
-wrapper** — it increments ``frame.host_sync`` itself or delegates to one
-(``collect`` / ``to_pydict`` / ``_host_pair`` / ``_host_mask``) — or
-when it carries a reasoned ``# dqlint: ok(host-sync): ...`` pragma.
+wrapper** — it increments ``frame.host_sync`` itself, delegates to one
+(``collect`` / ``to_pydict`` / ``_host_pair`` / ``_host_mask``) or pulls
+inside ``host_reading``, the counted and timed wrapper of a blocking read
+— or when it carries a reasoned ``# dqlint: ok(host-sync): ...`` pragma.
 
 Host-data tracking (to keep numpy post-processing quiet): a receiver is
 known-host when its expression is rooted at ``np.`` / ``numpy.``, at a
@@ -47,10 +48,13 @@ _SCOPE_DIRS = ("frame/", "ops/", "models/", "sql/", "parallel/", "serve/")
 _PKG = "sparkdq4ml_tpu/"
 
 #: Functions whose call makes the *caller* a counted wrapper: each counts
-#: its one batched transfer internally.
+#: its one batched transfer internally. ``host_reading`` is THE wrapper of
+#: a blocking read (``utils.observability``): the pull runs inside its
+#: ``with``, is counted in ``host.reads`` / ``host.read_bytes`` and, while
+#: the tracer records, timed as a ``host.read`` span.
 _COUNTED_CALLS = frozenset({"collect", "to_pydict", "_host_pair",
                             "_host_mask", "host_fetch", "toPandas",
-                            "to_pandas"})
+                            "to_pandas", "host_reading"})
 _NP_ROOTS = ("np", "numpy")
 _JNP_ROOTS = ("jnp",)
 

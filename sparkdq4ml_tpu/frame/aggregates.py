@@ -537,11 +537,14 @@ def global_agg(frame, aggs: list[AggExpr]):
         # the ONE deferred device->host pull per agg call (all empty-input
         # verdicts batch into a single stacked transfer) — counted, so the
         # span layer and EXPLAIN ANALYZE see it (dqlint host-sync)
-        from ..utils.profiling import counters, host_read
+        from ..utils.observability import host_reading
+        from ..utils.profiling import counters
 
         counters.increment("frame.host_sync")
-        counts = np.asarray(jnp.stack([c for _, c, _, _ in deferred]))
-        host_read(counts.nbytes)
+        stacked = jnp.stack([c for _, c, _, _ in deferred])
+        with host_reading("agg.verdict") as rd:
+            counts = np.asarray(stacked)
+            rd.done(counts.nbytes)
         for (name, _, val, nanv), c in zip(deferred, counts):
             out[name] = val if int(c) > 0 else nanv
     return Frame(out)

@@ -37,8 +37,9 @@ import numpy as np
 
 from ..config import config, float_dtype, int_dtype
 from ..ops.expressions import Col, Expr, spark_type_name
-from ..utils.observability import current_span, op_span, span
-from ..utils.profiling import counters, host_read
+from ..utils.observability import (current_span, host_reading, op_span,
+                                   span)
+from ..utils.profiling import counters
 
 logger = logging.getLogger("sparkdq4ml_tpu.frame")
 
@@ -284,13 +285,30 @@ def _as_column(values, n: Optional[int] = None):
     return arr
 
 
-def _count_pull(device: dict, pulled: dict) -> None:
-    """One batched ``jax.device_get`` of ``device`` came back as ``pulled``:
-    a counted frame host boundary, and a host read of the bytes that were
-    on the device (host columns pass through ``device_get`` unread)."""
+def _pull(device: dict) -> dict:
+    """ONE batched ``jax.device_get`` of ``device``: a counted frame host
+    boundary, and a host read of the bytes that were on the device (host
+    columns pass through ``device_get`` unread)."""
     counters.increment("frame.host_sync")
-    host_read(sum(pulled[k].nbytes for k, v in device.items()
-                  if isinstance(v, jax.Array)))
+    with host_reading("frame.to_pydict") as rd:
+        pulled = jax.device_get(device)
+        rd.done(sum(pulled[k].nbytes for k, v in device.items()
+                    if isinstance(v, jax.Array)))
+    return pulled
+
+
+def _pull_keys(frame, keys, site: str) -> list:
+    """The key columns of ``frame`` as host arrays. The device ones come
+    in one batch: a counted frame host boundary and one host read; string
+    keys live on the host and are not read."""
+    device = [k for k in keys if not _is_string_col(frame._data[k])]
+    if not device:
+        return [np.asarray(frame._column_values(k)) for k in keys]
+    counters.increment("frame.host_sync")
+    with host_reading(site) as rd:
+        cols = [np.asarray(frame._column_values(k)) for k in keys]
+        rd.done(sum(c.nbytes for k, c in zip(keys, cols) if k in device))
+    return cols
 
 
 class Frame:
@@ -1533,16 +1551,17 @@ class Frame:
     # -- actions -----------------------------------------------------------
     def count(self) -> int:
         """Number of valid (unmasked) rows."""
-        # dqlint: ok(host-sync): deliberately NOT a counted frame host
-        # boundary — the seed contract, pinned by test_explain
-        # TestDisabledModeNoOp (count() is the no-op-path probe there;
-        # counting it would make the probe self-invalidating). It IS a
-        # blocking device->host read, so host.reads / host.read_bytes
-        # count it and the frame.count span shows how long the host waited.
+        # deliberately NOT a counted frame host boundary — the seed
+        # contract, pinned by test_explain TestDisabledModeNoOp (count()
+        # is the no-op-path probe there; counting it would make the probe
+        # self-invalidating). It IS a blocking device->host read, so
+        # host.reads / host.read_bytes count it and the host.read span
+        # under frame.count shows how long the host waited.
         with span("frame.count", cat="action", rows_in=self._n) as s:
             total = jnp.sum(self._mask)
-            n = int(total)
-            host_read(total.dtype.itemsize)
+            with host_reading("frame.count") as rd:
+                n = int(total)
+                rd.done(total.dtype.itemsize)
             s.set(host_read_bytes=total.dtype.itemsize)
         return n
 
@@ -1551,8 +1570,9 @@ class Frame:
 
     def _host_mask(self) -> np.ndarray:
         counters.increment("frame.host_sync")
-        m = np.asarray(self._mask)
-        host_read(m.nbytes)
+        with host_reading("frame.mask") as rd:
+            m = np.asarray(self._mask)
+            rd.done(m.nbytes)
         return m
 
     @op_span("frame.to_pydict", cat="action")
@@ -1580,9 +1600,7 @@ class Frame:
             device = {name: jnp.asarray(arr)[: len(m)]
                       for name, arr in self._data.items()
                       if not _is_string_col(arr)}
-            pulled = jax.device_get(device) if device else {}
-            if device:
-                _count_pull(device, pulled)
+            pulled = _pull(device) if device else {}
         else:
             mask_key = "__mask__"
             while mask_key in self._data:       # paranoid name collision
@@ -1590,8 +1608,7 @@ class Frame:
             device = {name: arr for name, arr in self._data.items()
                       if not _is_string_col(arr)}
             device[mask_key] = self._mask
-            pulled = jax.device_get(device)     # ONE batched transfer
-            _count_pull(device, pulled)
+            pulled = _pull(device)              # ONE batched transfer
             m = np.asarray(pulled.pop(mask_key), bool)
         out = {}
         for name, arr in self._data.items():
@@ -1921,9 +1938,7 @@ class Frame:
         idx = np.nonzero(self._host_mask())[0]
         seen = set()
         keep = []
-        keycols = [np.asarray(self._column_values(c)) for c in subset]
-        if any(not _is_string_col(self._data[c]) for c in subset):
-            counters.increment("frame.host_sync")  # device key-column pull
+        keycols = _pull_keys(self, subset, "distinct.keys")
 
         def cell_key(cell):
             a = np.asarray(cell)
@@ -2157,10 +2172,10 @@ class Frame:
                 # optimizer's estimates: two scalars, not two masks
                 counts = jnp.stack([jnp.sum(lmask, dtype=jnp.int32),
                                     jnp.sum(rmask, dtype=jnp.int32)])
-                # dqlint: ok(host-sync): two scalars where the host plan
-                # pulls both masks
-                nl, nr = (int(c) for c in np.asarray(counts))
-                host_read(counts.nbytes)
+                # two scalars where the host plan pulls both masks
+                with host_reading("join.count") as rd:
+                    nl, nr = (int(c) for c in np.asarray(counts))
+                    rd.done(counts.nbytes)
                 build_left, _ = self._aqe_build_side(
                     other, build_left, est, nl, nr)
             elif not build_left:
@@ -2247,11 +2262,7 @@ class Frame:
             # Each side's device-key pull counts as one host sync batch.
             lraw, rraw = [], []
             for fr, rows, raw in ((self, li, lraw), (other, ri, rraw)):
-                cols = [np.asarray(fr._column_values(k)) for k in keys]
-                if any(not _is_string_col(fr._data[k]) for k in keys):
-                    counters.increment("frame.host_sync")
-                    host_read(sum(c.nbytes for k, c in zip(keys, cols)
-                                  if not _is_string_col(fr._data[k])))
+                cols = _pull_keys(fr, keys, "join.keys")
                 raw.extend(c[rows] for c in cols)
             plan = None
             if all(not _is_string_col(self._data[k])

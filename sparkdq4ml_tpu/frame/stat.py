@@ -33,6 +33,19 @@ def _corr_cov(a, b, w):
     return corr, cov
 
 
+def _scalar(x, site: str) -> float:
+    """A device scalar as a float: a counted frame host boundary and one
+    host read."""
+    from ..utils.observability import host_reading
+    from ..utils.profiling import counters
+
+    counters.increment("frame.host_sync")
+    with host_reading(site) as rd:
+        out = float(x)
+        rd.done(x.dtype.itemsize)
+    return out
+
+
 class FrameStatFunctions:
     def __init__(self, frame):
         self._frame = frame
@@ -46,35 +59,32 @@ class FrameStatFunctions:
 
     def corr(self, col1: str, col2: str, method: str = "pearson") -> float:
         """Pearson (or Spearman rank) correlation of two numeric columns."""
-        from ..utils.profiling import counters
-
         a, b, w = self._pair(col1, col2)
         if method == "spearman":
             a, b = _rank(a, w), _rank(b, w)
         elif method != "pearson":
             raise ValueError(f"unknown correlation method {method!r}")
-        counters.increment("frame.host_sync")  # device scalar → float
-        return float(_corr_cov(a, b, w)[0])
+        return _scalar(_corr_cov(a, b, w)[0], "stat.corr")
 
     def cov(self, col1: str, col2: str) -> float:
         """Sample covariance (n−1 denominator, like Spark)."""
-        from ..utils.profiling import counters
-
         a, b, w = self._pair(col1, col2)
-        counters.increment("frame.host_sync")  # device scalar → float
-        return float(_corr_cov(a, b, w)[1])
+        return _scalar(_corr_cov(a, b, w)[1], "stat.cov")
 
     def approx_quantile(self, col: str, probabilities, relative_error=0.0):
         """Quantiles of a numeric column. Spark sketches (Greenwald-Khanna)
         to bound executor memory; here an exact device sort is both cheaper
         and exact at any size XLA can sort, so ``relative_error`` is
         accepted for API compatibility and ignored."""
+        from ..utils.observability import host_reading
         from ..utils.profiling import counters
 
         a = jnp.asarray(self._frame._column_values(col), float_dtype())
         counters.increment("frame.host_sync")  # mask + column pull, one batch
-        keep = np.asarray(self._frame.mask)
-        vals = np.sort(np.asarray(a)[keep])
+        with host_reading("stat.quantile") as rd:
+            keep, vals = jax.device_get((self._frame.mask, a))
+            rd.done(keep.nbytes + vals.nbytes)
+        vals = np.sort(vals[keep])
         if len(vals) == 0:
             return [float("nan") for _ in np.atleast_1d(probabilities)]
         qs = [float(vals[min(int(p * len(vals)), len(vals) - 1)])
@@ -113,12 +123,16 @@ class FrameStatFunctions:
                 raise ValueError(
                     f"fraction for stratum {k!r} must be in [0, 1], got {f}")
         vals = self._frame._column_values(col)
-        if vals.dtype != object:
+        if vals.dtype == object:
+            vals_h = np.asarray(vals, object)
+        else:
+            from ..utils.observability import host_reading
             from ..utils.profiling import counters
 
             counters.increment("frame.host_sync")  # device stratum pull
-        vals_h = (np.asarray(vals, object) if vals.dtype == object
-                  else np.asarray(vals))
+            with host_reading("stat.strata") as rd:
+                vals_h = np.asarray(vals)
+                rd.done(vals_h.nbytes)
         rng = np.random.default_rng(seed)
         u = rng.random(len(vals_h))
         frac = np.asarray([fractions.get(v, 0.0) for v in vals_h.tolist()])

@@ -250,6 +250,7 @@ class WindowExpr(Expr):
 
     # -- evaluation --------------------------------------------------------
     def eval(self, frame):
+        from ..utils.observability import host_reading
         from ..utils.profiling import counters
 
         func, spec = self.func, self.spec
@@ -258,14 +259,19 @@ class WindowExpr(Expr):
         # counted sync per window evaluation — the same batch convention
         # as the join key-pull — so host-boundary audits see it.
         counters.increment("frame.host_sync")
-        m = np.asarray(frame.mask)
+        with host_reading("window.mask") as rd:
+            m = np.asarray(frame.mask)
+            rd.done(m.nbytes)
         idx = np.flatnonzero(m)                      # valid slots only
         nv = len(idx)
 
         def host(name):
             arr = frame._column_values(name)
-            a = arr if (isinstance(arr, np.ndarray) and arr.dtype == object) \
-                else np.asarray(arr)
+            if isinstance(arr, np.ndarray):          # a host column
+                return arr[idx]
+            with host_reading("window.column") as rd:
+                a = np.asarray(arr)
+                rd.done(a.nbytes)
             return a[idx]
 
         # -- plan: lexsort by (partition keys, then order keys) ------------

@@ -36,11 +36,12 @@ def host_fetch(x) -> np.ndarray:
     is that it be *counted*, so EXPLAIN ANALYZE and the span layer's
     per-op sync deltas see it (dqlint's ``host-sync`` rule pins the
     discipline statically)."""
-    from ..utils.profiling import counters, host_read
+    from ..utils.profiling import counters
 
     counters.increment("frame.host_sync")
-    out = np.asarray(x)
-    host_read(out.nbytes)
+    with _obs.host_reading("model.fetch") as rd:
+        out = np.asarray(x)
+        rd.done(out.nbytes)
     return out
 
 
@@ -91,10 +92,9 @@ class LabelStats(NamedTuple):
 def read_label_stats(stats) -> LabelStats:
     """The one blocking read of a :func:`label_stats` vector, counted as a
     host read (a few scalars, whatever the row count)."""
-    from ..utils.profiling import host_read
-
-    out = np.asarray(stats)
-    host_read(out.nbytes)
+    with _obs.host_reading("fit.label_stats") as rd:
+        out = np.asarray(stats)
+        rd.done(out.nbytes)
     rows, lo, hi, label_bad, weight_bad = out.tolist()
     return LabelStats(rows, lo, hi, bool(label_bad), bool(weight_bad),
                       out.nbytes)

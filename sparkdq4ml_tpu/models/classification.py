@@ -798,10 +798,9 @@ def _pack_softmax_result(r: "SoftmaxFitResult"):
 def unpack_softmax_result(flat, num_classes: int, d: int):
     """Host-side decode of the packed softmax fit output (one counted
     blocking read of the packed buffer)."""
-    from ..utils.profiling import host_read
-
-    flat = np.asarray(flat)
-    host_read(flat.nbytes)
+    with _obs.host_reading("fit.result") as rd:
+        flat = np.asarray(flat)
+        rd.done(flat.nbytes)
     m = num_classes * d
     return SoftmaxFitResult(
         coefficient_matrix=flat[:m].reshape(num_classes, d),

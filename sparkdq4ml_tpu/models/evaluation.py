@@ -14,15 +14,18 @@ def _host_pair(labels, scores):
     helpers are public library surface — a caller handing them device
     arrays used to trigger an implicit, uncounted device→host transfer
     per numpy op, invisible to the sync audits the fused paths pin."""
-    if not isinstance(labels, np.ndarray) or not isinstance(scores,
-                                                            np.ndarray):
+    pair = (labels, scores)
+    if any(hasattr(x, "devices") for x in pair):
         import jax
 
+        from ..utils.observability import host_reading
         from ..utils.profiling import counters
 
-        if any(hasattr(x, "devices") for x in (labels, scores)):
-            counters.increment("frame.host_sync")
-        labels, scores = jax.device_get((labels, scores))
+        counters.increment("frame.host_sync")
+        with host_reading("evaluation.pair") as rd:
+            labels, scores = jax.device_get(pair)
+            rd.done(sum(h.nbytes for h, x in zip((labels, scores), pair)
+                        if hasattr(x, "devices")))
     return np.asarray(labels), np.asarray(scores)
 
 

@@ -706,12 +706,11 @@ def _shard_rows(mesh, x):
         mesh, P(*([None] * (x.ndim - 1) + [DATA_AXIS]))))
 
 
-def _read(x) -> np.ndarray:
+def _read(x, site: str) -> np.ndarray:
     """One counted blocking read of a small device array."""
-    from ..utils.profiling import host_read
-
-    out = np.asarray(x)
-    host_read(out.nbytes)
+    with _obs.host_reading(site) as rd:
+        out = np.asarray(x)
+        rd.done(out.nbytes)
     return out
 
 
@@ -792,7 +791,7 @@ class _TreeParams:
             with _obs.span("fit.validate", cat="fit") as val:
                 stats, bad = _validate(X, y, mask)
                 stats = read_label_stats(stats)
-                bad = _read(bad)
+                bad = _read(bad, "fit.finite_flags")
                 val.set(host_read_bytes=stats.nbytes + bad.nbytes)
                 if stats.rows == 0:
                     raise ValueError(f"{name}: no valid rows")
@@ -819,8 +818,11 @@ class _TreeParams:
                 counters.increment("tree.fit_device")
                 counters.increment("tree.edges_select" if how == "select"
                                    else "tree.edges_sort")
-                edges, binned, y, w, w_held = jax.block_until_ready(
-                    _bin_program(self.max_bins, rows)(X, y, mask, held))
+                out = _bin_program(self.max_bins, rows)(X, y, mask, held)
+                # the span ends with its program: a wait that brings
+                # nothing to the host, so timed and not counted as a read
+                with _obs.host_reading("tree.bin"):
+                    edges, binned, y, w, w_held = jax.block_until_ready(out)
             if mesh is not None:
                 binned, y, w = (_shard_rows(mesh, a) for a in (binned, y, w))
                 edges = jax.device_put(edges, replicated_sharding(mesh))
@@ -960,7 +962,7 @@ def _fit_forest(prep: _Prepared, *, n_trees, max_depth, max_bins, impurity,
                  else jax.device_put(fm, replicated_sharding(mesh)),)
     fn = _forest_builder(max_depth, max_bins, impurity, min_instances,
                          min_info_gain, n_feat < prep.features, mesh)
-    return _unpack_trees(_read(fn(*args)))
+    return _unpack_trees(_read(fn(*args), "tree.result"))
 
 
 # ---------------------------------------------------------------------------
@@ -1502,7 +1504,7 @@ def _gbt_fit(prep: _Prepared, *, loss, max_iter, step, max_depth, max_bins,
     hyper = np.asarray([step, subsample], dt)
     key = jax.random.key(seed)
     F, f0, held = start(prep.y, prep.w, prep.w_held)
-    best_loss = float(_read(held)) if validated else None
+    best_loss = float(_read(held, "tree.held_loss")) if validated else None
     best_k = 0
     trees = []
     for i in range(max_iter):
@@ -1512,7 +1514,7 @@ def _gbt_fit(prep: _Prepared, *, loss, max_iter, step, max_depth, max_bins,
                               if subsample < 1.0 else key, hyper)
         trees.append(tree)
         if validated:
-            cur = float(_read(held))
+            cur = float(_read(held, "tree.held_loss"))
             if cur < best_loss - validation_tol * max(abs(best_loss), 1e-12):
                 best_loss = cur
                 best_k = len(trees)
@@ -1523,7 +1525,7 @@ def _gbt_fit(prep: _Prepared, *, loss, max_iter, step, max_depth, max_bins,
         # truncate at the best round; keep at least one tree (an ensemble
         # of zero trees has no stacked arrays and MLlib keeps one too)
         trees = trees[:max(best_k, 1)]
-    flat = _read(_pack_ensemble(tuple(trees), f0))
+    flat = _read(_pack_ensemble(tuple(trees), f0), "tree.result")
     packed = flat[:-1].reshape((len(trees), 2 ** (max_depth + 1) - 1, -1))
     return float(flat[-1]), _unpack_trees(packed), rounds
 

@@ -774,13 +774,16 @@ def _scalar_value(v):
         # a numeric literal lies broadcast on the device: compare there and
         # read two scalars, not every row (1.1e7 rows: 44 MB and a second
         # of Python)
-        from ..utils.profiling import counters, host_read
+        from ..utils.observability import host_reading
+        from ..utils.profiling import counters
 
         flat = v.reshape(-1)
         counters.increment("frame.host_sync")
-        head = np.asarray(jnp.stack(
-            [flat[0], jnp.all(flat == flat[0]).astype(flat.dtype)]))
-        host_read(head.nbytes)
+        pair = jnp.stack(
+            [flat[0], jnp.all(flat == flat[0]).astype(flat.dtype)])
+        with host_reading("literal.head") as rd:
+            head = np.asarray(pair)
+            rd.done(head.nbytes)
         arr, uniform = head[:1], bool(head[1])
     else:
         arr = np.asarray(v, object).ravel()

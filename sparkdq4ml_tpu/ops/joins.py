@@ -411,7 +411,7 @@ def device_join(how: str, lkeys, lmask, rkeys, rmask, lcols, rcols,
         # order today (one elementwise pass, one flag read) decides
         # which program is built
         room = _first_room(len(dtypes), nb, npr)
-        if room and not _read_verdict(_in_order(pkeys[0])):
+        if room and not _read_verdict(_in_order(pkeys[0]), "join.order"):
             counters.increment("join.merge_miss")
             room = 0
         with _LOCK:
@@ -432,7 +432,7 @@ def device_join(how: str, lkeys, lmask, rkeys, rmask, lcols, rcols,
         # THE read of a device join, a counted frame boundary like the
         # grouped verdict: the result's row count and, behind a merge,
         # what it assumed — one to three scalars in one array
-        verdict = _read_verdict(verdict)
+        verdict = _read_verdict(verdict, "join.verdict")
         if room and not (verdict[1] and verdict[2] <= room):
             # out of order after all, or a chunk over its room: once more,
             # as a sort; the next run merges with the room this one asked

@@ -94,7 +94,7 @@ from jax import lax
 from ..config import config, float_dtype, int_dtype
 from ..utils import faults as _faults
 from ..utils import observability as _obs
-from ..utils.profiling import counters, host_read
+from ..utils.profiling import counters
 from .compiler import (_unpad_tree, bucket_size, dtype_tag, pad_rows,
                        plan_namespace_tag, result_bucket)
 
@@ -1426,12 +1426,13 @@ def _padded(tree, n: int, b: int):
     return _pad_tree(tree, b)
 
 
-def _read_verdict(tree):
+def _read_verdict(tree, site: str = "grouped.verdict"):
     """THE blocking read of a grouped/sort plan: its few scalars (group
     count, fit verdict) in one counted frame-boundary sync."""
     counters.increment("frame.host_sync")
-    host = jax.device_get(tree)
-    host_read(sum(a.nbytes for a in jax.tree_util.tree_leaves(host)))
+    with _obs.host_reading(site) as rd:
+        host = jax.device_get(tree)
+        rd.done(sum(a.nbytes for a in jax.tree_util.tree_leaves(host)))
     return host
 
 
@@ -1841,8 +1842,7 @@ def _gather_columns(data, take_dev, head: int, host_idx=None):
     for name, arr in data.items():
         if _is_host_col(arr):
             if host_idx is None:
-                counters.increment("frame.host_sync")
-                host_idx = _host_index(take_dev[:head])
+                host_idx = _read_verdict(take_dev[:head], "gather.index")
             out[name] = _host_gather(arr, host_idx)
     return {name: out[name] for name in data}
 
@@ -1947,8 +1947,7 @@ def device_unique(frame, key_names):
             "frame.grouped.flush", cat="frame", op="distinct",
             keys=len(key_arrs), rows=n, bucket=b) as sp:
         keep, groups = _run_plan(fn, args, before, sp)
-        counters.increment("frame.host_sync")
-        g = int(groups)
+        g = int(_read_verdict(groups, "distinct.groups"))
         sp.set(groups=g)
     if stats_on:
         _record_grouped_stats(
@@ -1961,11 +1960,6 @@ def device_unique(frame, key_names):
 # --- BEGIN HOST FALLBACK (numpy allowed: object-array gathers + the -------
 # CPU-backend sort permutation plan; nothing here touches device compute)
 import numpy as np  # noqa: E402  (scoped to the host-fallback region)
-
-
-def _host_index(take_dev):
-    """Device index vector → host numpy (the string-payload gather sync)."""
-    return np.asarray(take_dev)
 
 
 def _host_gather(arr, host_idx):
@@ -1981,10 +1975,11 @@ def _host_sort_plan(key_arrs, specs, mask):
     (host int array)."""
     from ..frame.frame import lexsort_keys
 
-    # dqlint: ok(host-sync): counted by the device-sort entry — the CPU
-    # branch increments frame.host_sync immediately before planning here
-    pulled = jax.device_get(tuple(key_arrs) + (mask,))
-    host_read(sum(np.asarray(a).nbytes for a in pulled))
+    # frame.host_sync is counted by the device-sort entry: its CPU branch
+    # increments it immediately before planning here
+    with _obs.host_reading("sort.keys") as rd:
+        pulled = jax.device_get(tuple(key_arrs) + (mask,))
+        rd.done(sum(np.asarray(a).nbytes for a in pulled))
     m = np.asarray(pulled[-1], bool)
     vi = np.nonzero(m)[0]
     arrays = [np.asarray(k)[vi] for k in pulled[:-1]]
@@ -2027,8 +2022,8 @@ def _sharded_unique(frame, data, key_arrs, key_kinds, store,
             shards=D) as sp:
         _faults.inject("shard_merge")
         cand, cnts, total = _run_plan(fn, (keys_in, mask_in), before, sp)
-        counters.increment("frame.host_sync")
-        cand_h, cnts_h, g = jax.device_get((cand, cnts, total))
+        cand_h, cnts_h, g = _read_verdict((cand, cnts, total),
+                                          "distinct.candidates")
         g = int(g)
         sp.set(groups=g, lowering="sharded-exchange")
     if config.costprof_enabled:

@@ -445,10 +445,9 @@ def unpack_fit_result(flat, d: int):
     """Decode the packed fit output (host side) into a ``FitResult``: the
     one blocking read of the fit's result, counted as a host read."""
     from ..models.solvers import FitResult
-    from ..utils.profiling import host_read
-
-    flat = np.asarray(flat)
-    host_read(flat.nbytes)
+    with _obs.host_reading("fit.result") as rd:
+        flat = np.asarray(flat)
+        rd.done(flat.nbytes)
     return FitResult(
         coefficients=flat[:d],
         intercept=flat[d],

@@ -43,7 +43,8 @@ Wired through the framework (span names are a contract: the benchmark's
 ``layer_metrics`` and ``benchmarks/tools/scopes.py`` read them):
 
 * ``frame/frame.py`` — op spans (:func:`op_span` decorator; rows in/out),
-  ``frame.count`` (the scalar pull, ``host_read_bytes``),
+  ``frame.count`` (the scalar pull, ``host_read_bytes``; its wait is
+  the ``host.read`` child),
 * ``frame/native_csv.py`` — ``frame.ingest``,
 * ``ops/compiler.py`` / ``ops/segments.py`` — ``frame.pipeline.flush`` and
   ``frame.grouped.flush`` around the fused programs,
@@ -117,9 +118,24 @@ scope opened on the host around eager ``jnp`` calls does not reach their
 one-operation programs' metadata — measured on the chip, PERF.md section 3
 — so none is opened there.)
 
-Where the host reads from the device the counters ``host.reads`` and
-``host.read_bytes`` count it (:func:`host_read`), beside
-``frame.host_sync``, which keeps its meaning.
+Where the host reads from the device, the blocking call runs inside
+:func:`host_reading`: the counters ``host.reads`` and ``host.read_bytes``
+count it (``profiling.host_read``; beside ``frame.host_sync``, which
+keeps its meaning) and, while the tracer records, the span ``host.read``
+(``cat="host"``; ``site``, ``bytes``) times it under whatever span is open
+— ``frame.count``, ``fit.validate``, ``fit.solve``,
+``frame.grouped.flush``, ``frame.join``, ``frame.to_pydict``, ... Sites:
+``frame.count`` / ``frame.mask`` / ``frame.to_pydict``, ``agg.verdict``,
+``literal.head``, ``grouped.verdict`` / ``sort.keys`` / ``gather.index`` /
+``distinct.groups`` / ``distinct.candidates`` / ``distinct.keys``,
+``join.order`` / ``join.verdict`` / ``join.count`` / ``join.keys``,
+``fit.label_stats`` / ``fit.finite_flags`` / ``fit.result``,
+``tree.result`` / ``tree.held_loss`` / ``tree.bin`` (a wait for the
+binning program, nothing read: no ``bytes``, not counted),
+``model.fetch``, ``stat.corr`` / ``stat.cov`` / ``stat.quantile`` /
+``stat.strata``, ``window.mask`` / ``window.column``,
+``evaluation.pair``. The benchmark's ``host_wait_ms`` is the union of a
+job's ``host.read`` spans and ``host_active_ms`` the rest of the job.
 """
 
 from __future__ import annotations
@@ -437,7 +453,6 @@ METRIC_NAME_PREFIXES = {
     "recovery.": ("counter", "resilience-layer event mirror (action and "
                              "per-site action.site keys)"),
     "faults.injected.": ("counter", "per-site injected-fault mirror"),
-    "jit.backend.": ("counter", "jax monitoring compile events"),
     "solver.": ("counter", "per-solver dispatch counters"),
     "serve.reject.": ("counter", "per-reason admission rejections"),
     "serve.e2e_ms.": ("histogram", "per-tenant end-to-end latency "
@@ -867,7 +882,6 @@ def enable(max_spans: int = 10_000, log_spans: bool = False) -> None:
     TRACER.max_spans = int(max_spans)
     TRACER.log_spans = bool(log_spans)
     TRACER.enabled = True
-    _install_jax_compile_listener()
 
 
 def disable() -> None:
@@ -917,6 +931,57 @@ def current_ids() -> tuple:
         except IndexError:
             return (None, None)
     return (s.trace_id, s.sid)
+
+
+class _CountedRead(_NoopSpan):
+    """:func:`host_reading` while nothing records: shared and stateless,
+    so a read allocates nothing; ``done`` counts and that is all."""
+
+    __slots__ = ()
+
+    def done(self, nbytes: int) -> None:
+        profiling.host_read(nbytes)
+
+
+_COUNTED_READ = _CountedRead()
+
+
+class _HostReadSpan(Span):
+    """:func:`host_reading` while the tracer records: the span
+    ``host.read``, which ``done`` gives its ``bytes``."""
+
+    __slots__ = ()
+
+    def done(self, nbytes: int) -> None:
+        self.attrs = {**self.attrs, "bytes": int(nbytes)}
+        profiling.host_read(nbytes)
+
+
+def host_reading(site: str):
+    """THE wrapper of a blocking device->host read: the blocking call
+    itself (``np.asarray(x)``, ``int(total)``, ``jax.device_get(tree)``)
+    runs inside the ``with``, and ``done(nbytes)`` — the host copy in hand,
+    its size from ``.nbytes`` or static shapes — counts the read as
+    :func:`profiling.host_read` always has (``host.reads``,
+    ``host.read_bytes``). One batched pull is one read, however many
+    arrays it brings. A wait that brings nothing to the host (a
+    ``jax.block_until_ready`` where a span has to end with its program)
+    runs inside the same ``with`` and calls no ``done``: it is timed like
+    a read, with its ``site`` and no ``bytes``, and counted nowhere.
+
+    While the tracer records, the read is also the span ``host.read``
+    (``cat="host"``), a child of whatever span is open, with ``site`` (a
+    fixed short string a call site) and ``bytes``: from before the
+    blocking call to the copy in hand, so its length is what the host
+    waited — for the transfer and for everything the device still had
+    queued before it. A parent's time outside its reads is the host's
+    own. In a profiler capture it is ``dq.host.read`` like any span. No
+    duration goes into the counters: a job's counters repeat exactly from
+    job to job, and a time would not. Off: one gate read, no allocation."""
+    t = TRACER
+    if not t.recording:
+        return _COUNTED_READ
+    return _HostReadSpan(t, "host.read", "host", {"site": site})
 
 
 # ---------------------------------------------------------------------------
@@ -1411,32 +1476,6 @@ def scope(name: str):
     the compile-cache key are unchanged. Costs nothing at run time (it
     exists while the program is traced)."""
     return jax.named_scope(TRACE_PREFIX + name)
-
-
-_jax_listener_installed = False
-
-
-def _install_jax_compile_listener() -> None:
-    """Best-effort backend compile counter: subscribe to jax's monitoring
-    events and mirror compilation-related ones into the counter registry
-    (``jit.backend.<event>``). Private-API dependent, so any failure just
-    means the deterministic lru-level ``jit.trace_*`` counters are the
-    only compile signal."""
-    global _jax_listener_installed
-    if _jax_listener_installed:
-        return
-    try:
-        from jax._src import monitoring as _mon
-
-        def _on_event(event, *a, **kw):
-            if "compil" in event:
-                tail = event.strip("/").replace("/", "_")
-                profiling.counters.increment(f"jit.backend.{tail}")
-
-        _mon.register_event_listener(_on_event)
-        _jax_listener_installed = True
-    except Exception:  # pragma: no cover - depends on jax internals
-        _jax_listener_installed = True  # don't retry every enable()
 
 
 # ---------------------------------------------------------------------------

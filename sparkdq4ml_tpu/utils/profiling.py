@@ -9,7 +9,9 @@ The reference exposes nothing beyond post-hoc ``objectiveHistory`` prints
   (``utils.recovery.RECOVERY_LOG``) mirrors every retry/fallback/breaker
   event here as ``recovery.<action>``, so resilience activity shows up in
   the same place as performance telemetry; :func:`host_read` counts the
-  blocking device->host reads and their bytes,
+  blocking device->host reads and their bytes (every site reaches it
+  through ``observability.host_reading``, which also times the read as a
+  span while the tracer records),
 * :func:`start_capture` / :func:`stop_capture` — managed ``jax.profiler``
   captures (the ``/profile/trace`` surface). While one runs the span
   tracer records by itself and writes its spans into the capture
@@ -77,7 +79,9 @@ def host_read(nbytes: int) -> None:
     ``.nbytes`` or from static shapes — never from another device op.
     Beside ``frame.host_sync``, which keeps its meaning (counted frame
     boundary pulls); this pair counts every read, ``count()`` and the
-    fit's validation-stats and result reads included, and says how large."""
+    fit's validation-stats and result reads included, and says how large.
+    A count, never a time: ``observability.host_reading`` wraps the
+    blocking call, ends in this, and holds the duration as a span."""
     counters.increment("host.reads")
     counters.increment("host.read_bytes", int(nbytes))
 

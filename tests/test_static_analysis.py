@@ -160,6 +160,31 @@ class TestHostSyncRule:
             """}, ["host-sync"])
         assert f == []
 
+    def test_the_read_wrapper_sanctions_and_a_pull_beside_it_does_not(
+            self, tmp_path):
+        f = findings_for(tmp_path, {"models/reads.py": """
+            import jax.numpy as jnp
+            import numpy as np
+            from ..utils import observability as _obs
+            from ..utils.observability import host_reading
+
+            def wrapped(x):
+                with host_reading("fit.result") as rd:
+                    out = np.asarray(jnp.abs(x))
+                    rd.done(out.nbytes)
+                return out
+
+            def wrapped_by_module(x):
+                with _obs.host_reading("fit.result") as rd:
+                    n = int(jnp.sum(x))
+                    rd.done(4)
+                return n
+
+            def bare(x):
+                return np.asarray(jnp.abs(x))
+            """}, ["host-sync"])
+        assert len(f) == 1 and f[0].line == 20
+
     def test_numpy_receivers_and_annotations_are_quiet(self, tmp_path):
         f = findings_for(tmp_path, {"frame/hosty.py": """
             import numpy as np

@@ -350,7 +350,24 @@ def _higgs_flow(session):
     model.predict(np.zeros(4))
 
 
-FLOWS = {"catering": _catering_flow, "higgs": _higgs_flow}
+def _q3_flow(session):
+    """Join, GROUP BY, ORDER BY (benchmarks/jobs/tpch_q3.py), small."""
+    rng = np.random.default_rng(7)
+    orders = session.create_data_frame(
+        {"o_key": jnp.arange(64, dtype=jnp.int32),
+         "o_pri": jnp.asarray(rng.integers(0, 4, 64).astype(np.int32))})
+    items = session.create_data_frame(
+        {"l_key": jnp.asarray(np.sort(rng.integers(0, 64, ROWS))
+                              .astype(np.int32)),
+         "l_price": jnp.asarray(rng.random(ROWS).astype(np.float32))})
+    orders.create_or_replace_temp_view("orders")
+    items.create_or_replace_temp_view("lineitem")
+    session.sql("SELECT o_pri, sum(l_price) AS revenue FROM orders JOIN "
+                "lineitem ON o_key = l_key WHERE l_price > 0.1 GROUP BY "
+                "o_pri ORDER BY revenue DESC").to_pydict()
+
+
+FLOWS = {"catering": _catering_flow, "higgs": _higgs_flow, "q3": _q3_flow}
 _FLOW_SPANS = {}
 
 
@@ -570,6 +587,221 @@ def test_host_read_counters_are_declared():
     assert profiling.counters.get("host.reads") == 2
     assert profiling.counters.get("host.read_bytes") == 15
     assert "sparkdq4ml_host_read_bytes 15" in obs.prometheus_text()
+
+
+# ---------------------------------------------------------------------------
+# 5b. host_reading: the wrapper every blocking read runs inside
+# ---------------------------------------------------------------------------
+
+
+def _read_cases():
+    """{case: (call, reads, bytes)}: the sites a small frame, fit, grouped
+    plan and join reach, with what ``host_read`` counted there before the
+    wrapper (read off the parent commit on these inputs)."""
+    from sparkdq4ml_tpu.frame import aggregates as A
+    from sparkdq4ml_tpu.frame.frame import Frame
+    from sparkdq4ml_tpu.models import LinearRegression, LogisticRegression
+
+    rng = np.random.default_rng(0)
+    n = 1000
+    f = Frame({"k": jnp.asarray(rng.integers(0, 7, n).astype(np.int32)),
+               "v": jnp.asarray(rng.normal(size=n).astype(np.float32))})
+    g = Frame({"k": jnp.arange(7, dtype=jnp.int32),
+               "w": jnp.arange(7, dtype=jnp.float32)})
+    fit = Frame({
+        "features": jnp.asarray(rng.normal(size=(n, 3)).astype(np.float32)),
+        "label": jnp.asarray((rng.random(n) < 0.5).astype(np.float32))})
+    count = jnp.sum(f.mask).dtype.itemsize
+    item = jnp.arange(1.0).dtype.itemsize       # float64 here (conftest)
+    return {
+        "frame.count": (f.count, 1, count),
+        "frame.mask": (f._host_mask, 1, n),
+        "frame.to_pydict": (f.to_pydict, 1, 4 * n + 4 * n + n),
+        # the mask, then the five-row prefixes
+        "frame.to_pydict-limit": (lambda: f.to_pydict(limit=5), 2,
+                                  n + 5 * 8),
+        "grouped.verdict": (lambda: f.group_by("k").agg(A.sum("v")), 1, 10),
+        "sort.keys": (lambda: f.filter(f.col("v") > 0).sort("v"), 1,
+                      4 * n + n),
+        "join.verdict": (lambda: f.join(g, ["k"], "inner"), 1, 4),
+        "agg.verdict": (lambda: f.agg(A.min("v")), 1, 4),
+        # the stats vector and the packed result: coef, 3 scalars, history
+        "fit.label_stats": (
+            lambda: LogisticRegression(max_iter=20).fit(fit, mesh=None), 2,
+            5 * item + (3 + 3 + 20 + 1) * item),
+        "fit.result": (
+            lambda: LinearRegression(max_iter=5, reg_param=0.1,
+                                     elastic_net_param=1.0)
+            .fit(fit, mesh=None), 1, (3 + 3 + 5 + 1) * item),
+    }
+
+
+READ_CASES = ("frame.count", "frame.mask", "frame.to_pydict",
+              "frame.to_pydict-limit", "grouped.verdict", "sort.keys",
+              "join.verdict", "agg.verdict", "fit.label_stats", "fit.result")
+
+
+@pytest.mark.parametrize("case", READ_CASES)
+def test_off_a_read_counts_as_before_and_makes_no_span(case, monkeypatch):
+    call, reads, nbytes = _read_cases()[case]
+    call()                                          # compile outside
+
+    def no_span(*a, **kw):
+        raise AssertionError("a Span was made with the tracer off")
+
+    monkeypatch.setattr(obs.Span, "__init__", no_span)
+    before = profiling.counters.snapshot()
+    call()
+    moved = _moved(before)
+    assert (moved["host.reads"], moved["host.read_bytes"]) == (reads, nbytes)
+    assert obs.TRACER.spans() == []
+
+
+def test_off_the_wrapper_is_one_shared_object_that_only_counts():
+    a, b = obs.host_reading("frame.count"), obs.host_reading("fit.result")
+    assert a is b and not isinstance(a, obs.Span)
+    with a as rd:
+        rd.done(12)
+    assert profiling.counters.get("host.reads") == 1
+    assert profiling.counters.get("host.read_bytes") == 12
+    assert obs.TRACER.spans() == []
+
+
+@pytest.mark.parametrize("case", READ_CASES)
+def test_recording_the_same_read_is_a_span_with_its_site_and_bytes(case):
+    call, reads, nbytes = _read_cases()[case]
+    call()
+    obs.enable()
+    before = profiling.counters.snapshot()
+    call()
+    obs.disable()
+    moved = _moved(before)
+    found = [s for s in obs.TRACER.spans() if s.name == "host.read"]
+    # the counters do not know that anyone recorded; a span a read
+    assert (moved["host.reads"], moved["host.read_bytes"]) == (reads, nbytes)
+    assert len(found) == reads
+    assert sum(s.attrs["bytes"] for s in found) == nbytes
+    assert case.split("-")[0] in {s.attrs["site"] for s in found}
+    assert all(s.cat == "host" and s.dur_us is not None for s in found)
+
+
+# (flow, the span that holds the read, the read's site)
+READ_PARENTS = [
+    ("catering", "frame.count", "frame.count"),
+    ("catering", "fit.solve", "fit.result"),
+    ("catering", "frame.to_pydict", "frame.to_pydict"),
+    ("higgs", "fit.validate", "fit.label_stats"),
+    ("higgs", "fit.solve", "fit.result"),
+    ("higgs", "frame.to_pydict", "frame.to_pydict"),
+    ("q3", "frame.grouped.flush", "grouped.verdict"),
+    ("q3", "frame.join", "join.verdict"),
+    ("q3", "frame.to_pydict", "frame.to_pydict"),
+]
+
+
+@pytest.mark.parametrize(
+    "flow_spans,parent,site",
+    [pytest.param(f, p, s, id=f"{f}-{p}") for f, p, s in READ_PARENTS],
+    indirect=["flow_spans"])
+def test_a_boundary_span_holds_its_read_and_its_self_time_leaves_it_out(
+        flow_spans, parent, site):
+    tree, _ = flow_spans
+    reads = [s for n, p, s in tree
+             if n == "host.read" and p == parent and s.attrs["site"] == site]
+    assert reads, [(n, p, s.attrs.get("site")) for n, p, s in tree
+                   if n == "host.read"]
+    read = reads[0]
+    assert read.attrs["bytes"] > 0 and read.cat == "host"
+    holder = next(s for _, _, s in tree if s.sid == read.parent_id)
+    lo, hi = holder.start_s, holder.start_s + holder.dur_us * 1e-6
+    assert lo <= read.start_s and \
+        read.start_s + read.dur_us * 1e-6 <= hi + 1e-5
+    # the holder's self time as the benchmark computes it: its length less
+    # what its children cover, the read among them
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from benchmarks import program_spans
+
+    timed = program_spans.with_self_time(
+        [{"name": s.name, "sid": s.sid, "parent": s.parent_id,
+          "start_s": s.start_s, "dur_s": s.dur_us * 1e-6}
+         for _, _, s in tree])
+    self_s = next(t["self_s"] for t in timed if t["sid"] == holder.sid)
+    assert -1e-5 <= self_s <= (holder.dur_us - read.dur_us) * 1e-6 + 1e-5
+
+
+@pytest.mark.parametrize("flow_spans", ["catering", "higgs", "q3"],
+                         indirect=True)
+def test_a_job_has_as_many_read_spans_as_counted_reads(flow_spans):
+    tree, counters = flow_spans
+    reads = [s for n, _, s in tree if n == "host.read"]
+    assert len(reads) == counters["host.reads"] > 0
+    assert sum(s.attrs["bytes"] for s in reads) == counters["host.read_bytes"]
+
+
+def test_the_read_span_is_an_annotation_under_its_parents(tmp_path):
+    from sparkdq4ml_tpu.frame.frame import Frame
+
+    f = Frame({"a": jnp.arange(100.0)})
+    f.count()
+    with capture(tmp_path) as cap:
+        f.count()
+    events = {name: stats for name, _, _, stats in cap.host_events()}
+    count = next(s for s in obs.TRACER.spans() if s.name == "frame.count")
+    read = next(s for s in obs.TRACER.spans() if s.name == "host.read")
+    assert events["dq.host.read"]["sid"] == read.sid
+    assert events["dq.host.read"]["parent"] == count.sid == read.parent_id
+
+
+SECOND_GROUP = ("stat.corr", "stat.cov", "stat.quantile", "stat.strata",
+                "window.mask", "evaluation.pair", "distinct.groups",
+                "distinct.keys")
+
+
+@pytest.mark.parametrize("site", SECOND_GROUP)
+def test_a_pull_that_counted_host_sync_alone_is_a_read_now(site):
+    """The stat, window, evaluation and distinct pulls: ``host.reads``
+    moves by one where ``frame.host_sync`` moves by one (a window moves
+    once more for each device column of its plan)."""
+    from sparkdq4ml_tpu.frame.frame import Frame
+    from sparkdq4ml_tpu.frame.window import Window, row_number
+    from sparkdq4ml_tpu.models import evaluation
+
+    n = 64
+    rng = np.random.default_rng(2)
+    f = Frame({"k": jnp.asarray(rng.integers(0, 5, n).astype(np.int32)),
+               "v": jnp.asarray(rng.normal(size=n).astype(np.float32))})
+    strings = Frame({"s": np.asarray(["a", "b"] * 8, object),
+                     "k": jnp.arange(16, dtype=jnp.int32) // 2})
+    calls = {
+        "stat.corr": lambda: f.stat.corr("k", "v"),
+        "stat.cov": lambda: f.stat.cov("k", "v"),
+        "stat.quantile": lambda: f.stat.approx_quantile("v", [0.5]),
+        "stat.strata": lambda: f.stat.sample_by("k", {1: 0.5}),
+        "window.mask": lambda: f.with_column(
+            "r", row_number().over(Window.partition_by("k").order_by("v"))
+        ).count(),
+        "evaluation.pair": lambda: evaluation.threshold_sweep(
+            (f._data["k"] > 2).astype(jnp.float32), f._data["v"]),
+        "distinct.groups": lambda: f.select("k").distinct(),
+        # a string key sends dropDuplicates to the host plan: the mask,
+        # then the one device key column
+        "distinct.keys": lambda: strings.drop_duplicates(["s", "k"]),
+    }
+    calls[site]()
+    obs.enable()
+    before = profiling.counters.snapshot()
+    calls[site]()
+    obs.disable()
+    moved = _moved(before)
+    sites = [s.attrs["site"] for s in obs.TRACER.spans()
+             if s.name == "host.read"]
+    assert site in sites
+    assert moved["host.reads"] == len(sites) >= 1
+    assert moved["host.read_bytes"] > 0
+    if site.startswith(("stat.", "evaluation.", "distinct.groups")):
+        assert moved["host.reads"] == 1 == moved["frame.host_sync"]
 
 
 # ---------------------------------------------------------------------------

@@ -470,6 +470,37 @@ def test_spans_of_a_tree_fit():
     assert spans["fit.validate"].attrs["host_read_bytes"] > 0
 
 
+def test_the_reads_of_a_tree_fit_and_the_wait_of_its_binning():
+    """Three counted reads, each a ``host.read`` span with its bytes, and
+    the binning's wait for its program: timed the same way, no bytes, not
+    counted."""
+    from sparkdq4ml_tpu.utils import observability as obs
+
+    frame, _, _, _ = _higgs_like(500, 4, 2)
+    GBTClassifier(max_iter=2, max_depth=3).fit(frame)
+    before = {k: counters.get(k) for k in WATCHED}
+    obs.enable()
+    try:
+        obs.TRACER.clear()
+        GBTClassifier(max_iter=2, max_depth=3).fit(frame)
+        spans = obs.TRACER.spans()
+    finally:
+        obs.disable()
+        obs.TRACER.clear()
+    moved = _delta(before)
+    by_sid = {s.sid: s.name for s in spans}
+    reads = [(by_sid[s.parent_id], s.attrs["site"], s.attrs.get("bytes"))
+             for s in spans if s.name == "host.read"]
+    assert [r[:2] for r in reads] == [
+        ("fit.validate", "fit.label_stats"),
+        ("fit.validate", "fit.finite_flags"),
+        ("fit.tree.bin", "tree.bin"), ("fit.solve", "tree.result")]
+    assert reads[2][2] is None
+    counted = [r[2] for r in reads if r[2] is not None]
+    assert moved["host.reads"] == len(counted) == 3
+    assert moved["host.read_bytes"] == sum(counted)
+
+
 @pytest.mark.parametrize("max_bins,how", [(32, "select"), (8, "select"),
                                           (128, "sort")])
 def test_the_bin_span_and_a_counter_say_how_the_thresholds_were_found(
