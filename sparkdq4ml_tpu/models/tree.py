@@ -27,8 +27,11 @@ TPU-first design — this is NOT a port of MLlib's per-partition
   ``onehot(bins)ᵀ · (onehot(node) * statistics)`` — on a TPU a Pallas
   kernel on the MXU that builds both one-hot operands in VMEM from the
   int8 bins and never stores them (:func:`_mxu_histogram`); elsewhere a
-  ``segment_sum`` a feature. Split scoring is a cumulative-sum scan over
-  bins; leaf totals come from the last split level's left and right sums.
+  ``segment_sum`` a feature. Below the root the contraction takes one
+  child of every split, the one of the smaller weight, and its sibling is
+  the parent's histogram less it (:func:`_sibling_histograms`). Split
+  scoring is a cumulative-sum scan over bins; leaf totals come from the
+  last split level's left and right sums.
 * **Static shapes, no per-row gather.** The tree is a dense heap array of
   2^(depth+1)−1 node slots; a level's descent reads, for each of its
   nodes, that node's feature row of the feature-major table (a dynamic
@@ -306,6 +309,13 @@ def _mxu_histogram(binned, node_pos, targets, n_nodes, B, interpret=False):
     the accumulators here. The grid is (tree, accumulator, block); the
     one-hot of the bins is rebuilt for every tree of a forest.
 
+    The statistic matrix has ``n_nodes * r`` columns, ``r`` = 3 s rounded
+    up to 16, and the MXU takes 128 of them a pass at the same cost
+    however many are filled (read on one v5e at 1.1e7 rows x 28 x 32, four
+    statistics: section 5 of ``PERF.md``): 1 to 8 nodes of four statistics
+    are one pass, 16 are two. :func:`build_trees` asks for half a level's
+    nodes, so a depth-5 tree's widest level is one pass.
+
     Pallas is imported here, by the one function that builds the kernel:
     at module level every importer of ``models`` would pay for it (about
     a second), tree fit or not."""
@@ -415,6 +425,12 @@ _IMPURITY = {"variance": _impurity_sse, "gini": _impurity_gini,
              "entropy": _impurity_entropy}
 
 
+def _node_weight(agg, impurity):
+    """The row weight in a node's statistics ``agg`` (..., s): statistic 0
+    for ``"variance"``, the sum of the class counts for gini and entropy."""
+    return agg[..., 0] if impurity == "variance" else jnp.sum(agg, axis=-1)
+
+
 def _find_splits(hist, edges, impurity, min_instances, min_info_gain,
                  feat_mask=None):
     """Best (feature, threshold, gain) per node from level histograms.
@@ -431,11 +447,8 @@ def _find_splits(hist, edges, impurity, min_instances, min_info_gain,
     right = total[:, :, None, :] - left
     gain = imp_fn(total)[:, :, None] - imp_fn(left) - imp_fn(right)
 
-    def weight(a):
-        return a[..., 0] if impurity == "variance" else jnp.sum(a, axis=-1)
-
-    ok = jnp.logical_and(weight(left) >= min_instances,
-                         weight(right) >= min_instances)
+    ok = jnp.logical_and(_node_weight(left, impurity) >= min_instances,
+                         _node_weight(right, impurity) >= min_instances)
     # +inf-padded edges mark bins beyond the feature's true quantiles
     real = jnp.isfinite(edges)[:, None, :]                   # (d, 1, B-1)
     ok = jnp.logical_and(ok, real)
@@ -495,6 +508,45 @@ def heap_lookup(node, table):
     return out
 
 
+def _sibling_histograms(binned, heap, base, targets, parents, split, left,
+                        right, impurity, psum_axis=None):
+    """(T, d, m, B, s) histograms of the ``m`` nodes of a level below the
+    root from ONE pass over ``m / 2`` of them: of every node of the level
+    above — ``parents`` (T, d, m/2, B, s) its histograms, ``split``,
+    ``left``, ``right`` (T, m/2[, s]) what :func:`_find_splits` made of
+    them — the child of the smaller weight (ties: the left) goes through
+    :func:`_level_histogram`, at its parent's position, and its sibling is
+    the parent's histogram less it. The MXU pays by the 128 statistic
+    columns, so half the nodes is half the passes from 16 nodes on.
+
+    No row is dropped and nothing is approximated: every row's statistics
+    are in every histogram of its path, pushed through the contraction or
+    through its parent's, in float32 as before. The derived child is the
+    larger one, at least half its parent, so it carries one subtraction of
+    numbers of its own size; weights (integers under 2^24) subtract
+    exactly. A parent that did not split has no rows below it: its derived
+    child is zero, not the parent."""
+    T, d, half, B, s = parents.shape
+    with _obs.scope("tree.hist"):
+        right_small = (_node_weight(right, impurity)
+                       < _node_weight(left, impurity))       # (T, m/2)
+        pos = heap - base                       # children of p: 2p, 2p + 1
+        parent = pos >> 1
+        picked = jax.vmap(heap_lookup)(
+            parent, right_small.astype(jnp.int32)[:, :, None])[:, 0]
+        # every other row is parked (slot m/2), as rows above the level are
+        node_pos = jnp.where((pos >= 0) & ((pos & 1) == picked), parent,
+                             half)
+    small = _level_histogram(binned, node_pos, targets, half, B, psum_axis)
+    with _obs.scope("tree.hist"):
+        other = jnp.where(split[:, None, :, None, None], parents - small,
+                          0.0)
+        right_small = right_small[:, None, :, None, None]
+        kids = jnp.stack([jnp.where(right_small, other, small),
+                          jnp.where(right_small, small, other)], axis=3)
+        return kids.reshape(T, d, 2 * half, B, s)
+
+
 def build_trees(binned, edges, targets, max_depth, max_bins, impurity,
                 min_instances, min_info_gain, feat_masks=None,
                 psum_axis=None):
@@ -504,8 +556,11 @@ def build_trees(binned, edges, targets, max_depth, max_bins, impurity,
     ([w, wy, wy²], [w, wg, wg², wh] or class one-hots) of each tree;
     ``feat_masks`` optional (T, N, d) per-heap-node feature masks. Returns
     (stacked :class:`TreeArrays` with a leading T, the rows' final heap ids
-    (T, n)). ``max_depth`` histogram passes a tree: the children's totals
-    are the split's left and right sums.
+    (T, n)). ``max_depth`` histogram passes a tree, and a pass holds half
+    of its level's nodes: the root, then one child of every node of the
+    level above (:func:`_sibling_histograms`), ``2^(max_depth - 1)`` nodes
+    a tree in all; the children's totals are the split's left and right
+    sums.
 
     ``psum_axis``: set inside ``shard_map`` when rows are sharded over a
     mesh axis. Each device histograms its row shard and the level stats
@@ -532,10 +587,13 @@ def build_trees(binned, edges, targets, max_depth, max_bins, impurity,
     for depth in range(max_depth):
         m = 2 ** depth
         base = m - 1                            # first heap id of this level
-        # rows of a node that did not split keep its id: parked (slot m)
-        node_pos = jnp.where(heap >= base, heap - base, m)
-        hist = _level_histogram(binned, node_pos, targets, m, max_bins,
-                                psum_axis)
+        if depth == 0:                          # every row sits in the root
+            hist = _level_histogram(binned, heap, targets, m, max_bins,
+                                    psum_axis)
+        else:
+            hist = _sibling_histograms(binned, heap, base, targets, hist,
+                                       split, left, right, impurity,
+                                       psum_axis)
         with _obs.scope("tree.split"):
             fm = None
             if feat_masks is not None:
@@ -838,6 +896,11 @@ class _TreeParams:
         counters.increment("tree.rounds", trees)
         counters.increment("tree.levels", levels)
         counters.increment("tree.hist_rows", levels * prep.slots)
+        # a level below the root pushes one child of every node above it
+        # through the histogram and takes the sibling by subtraction
+        pushed = trees * (2 ** self.max_depth // 2)
+        counters.increment("tree.hist_nodes", pushed)
+        counters.increment("tree.hist_derived", max(pushed - trees, 0))
         return levels
 
 

@@ -89,6 +89,9 @@ Wired through the framework (span names are a contract: the benchmark's
   arrays); ``model.transform``; counters ``tree.fit_device``,
   ``tree.edges_select`` / ``tree.edges_sort`` (one of the two a fit),
   ``tree.rounds``, ``tree.levels``, ``tree.hist_rows``,
+  ``tree.hist_nodes`` / ``tree.hist_derived`` (nodes a fit pushed through
+  a level histogram, ``2^(max_depth-1)`` a tree, and nodes it took as
+  their parent's histogram less their sibling's, one fewer a tree),
 * ``models/solvers.py`` — ``solver.solve``,
 * ``parallel/distributed.py`` / ``mesh.py`` — per-shard Gramian timing
   (blocks under the explicit flag only), collective/shard_map build
@@ -111,7 +114,9 @@ before its passes: mask, scale, moments, the standardised design),
 tree programs ``dq.tree.edges`` (the thresholds: the integer image of
 the table and the counting passes that select their ranks, or the sorts),
 ``dq.tree.bin``, ``dq.tree.gradient``, ``dq.tree.hist`` (the Pallas kernel
-``tree_level_histogram`` or the scatters), ``dq.tree.split``,
+``tree_level_histogram`` or the scatters over one child of every split —
+counter ``tree.hist_nodes`` — and the subtraction that gives the sibling,
+``tree.hist_derived``), ``dq.tree.split``,
 ``dq.tree.descend``, ``dq.tree.score``. Metadata
 only: the operations' HLO names and the compiled code are unchanged. (A
 scope opened on the host around eager ``jnp`` calls does not reach their
@@ -281,6 +286,11 @@ METRIC_NAMES = {
                                "members)"),
     "tree.levels": ("counter", "histogram passes: one a level a tree"),
     "tree.hist_rows": ("counter", "row slots handed to level histograms"),
+    "tree.hist_nodes": ("counter", "nodes pushed through a level histogram: "
+                                   "the root and one child of every split, "
+                                   "2^(max_depth-1) a tree"),
+    "tree.hist_derived": ("counter", "nodes whose histogram is their "
+                                     "parent's less their sibling's"),
     "jit.trace_miss": ("counter", "jit-factory cache misses (new trace)"),
     "jit.trace_hit": ("counter", "jit-factory cache hits"),
     # parallel / mesh

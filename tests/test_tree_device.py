@@ -287,6 +287,132 @@ def test_lowering_follows_backend_and_shapes(monkeypatch):
         assert again == padded >= rows and padded % (block * partials) == 0
 
 
+# ---------------------------------------------------------------------------
+# a level below the root: one child of every split, its sibling by subtraction
+# ---------------------------------------------------------------------------
+
+def _growth_case(case, n=4096, d=6, seed=23):
+    """(binned, edges, targets, feat_masks, kwargs of ``build_trees``) at
+    32 bins."""
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, d))
+    edges, bins = T.bin_features(X, None, 32)
+    binned = np.ascontiguousarray(bins.T).astype(np.int8)
+    w = (rng.random(n) > 0.2).astype(np.float32)         # masked rows: 0
+    fm, kw = None, dict(max_depth=5, impurity="variance", min_instances=1)
+    if case == "gini_forest":
+        trees, classes, kw = 3, 3, dict(max_depth=4, impurity="gini",
+                                        min_instances=1)
+        label = rng.integers(0, classes, size=n)
+        label[X[:, 1] > 0.3] = 0
+        boot = rng.poisson(1.0, size=(trees, n)).astype(np.float32) * w
+        targets = boot[:, None, :] * (
+            label[None, :] == np.arange(classes)[:, None])[None]
+        fm = rng.random((trees, 2 ** 5 - 1, d)) < 0.5
+        fm[:, :, 0] = True                               # never an empty set
+    else:
+        # the boosted statistics [w, wg, wg^2, wh] of a first round
+        y = (rng.random(n) < 1 / (1 + np.exp(-X[:, 0] - X[:, 2] ** 2)))
+        p = 1 / (1 + np.exp(-0.4 * X[:, 1]))
+        g, h = y - p, p * (1 - p)
+        targets = np.stack([w, w * g, w * g * g, w * h])[None]
+        if case == "some_nodes_do_not_split":
+            kw["min_instances"] = n // 9
+    return (jnp.asarray(binned), jnp.asarray(edges, jnp.float32),
+            jnp.asarray(targets, jnp.float32), fm, kw)
+
+
+def _asked_nodes(monkeypatch):
+    """The ``n_nodes`` of every ``_level_histogram`` call traced from here
+    on."""
+    direct, asked = T._level_histogram, []
+
+    def counted(binned, node_pos, targets, n_nodes, B, psum_axis=None):
+        asked.append(n_nodes)
+        return direct(binned, node_pos, targets, n_nodes, B, psum_axis)
+
+    monkeypatch.setattr(T, "_level_histogram", counted)
+    return asked
+
+
+@pytest.mark.parametrize("case", ["boosted_depth_5", "gini_forest",
+                                  "some_nodes_do_not_split", "shard_map"])
+def test_a_level_histograms_one_child_and_subtracts_its_sibling(
+        case, monkeypatch):
+    """What ``build_trees`` hands ``_find_splits`` at every level below the
+    root (``_sibling_histograms``' result, as it stands) against
+    ``_level_histogram`` over all ``m`` nodes of the same heap: the weight
+    statistic bit for bit, the rest to float32's rounding, and zeros under
+    a node that did not split."""
+    from jax.sharding import PartitionSpec as P
+
+    from sparkdq4ml_tpu.parallel.mesh import (DATA_AXIS, make_mesh,
+                                              shard_map)
+
+    binned, edges, targets, fm, kw = _growth_case(case)
+    derive, direct = T._sibling_histograms, T._level_histogram
+    seen = []
+
+    def spy(binned, heap, base, targets, parents, *rest):
+        got = derive(binned, heap, base, targets, parents, *rest)
+        m = base + 1
+        want = direct(binned, jnp.where(heap >= base, heap - base, m),
+                      targets, m, parents.shape[3], rest[-1])
+        seen.append((got, want))
+        return got
+
+    monkeypatch.setattr(T, "_sibling_histograms", spy)
+    asked = _asked_nodes(monkeypatch)          # the spy's own call apart
+
+    def grow(b, e, t, axis=None):
+        trees, _ = T.build_trees(b, e, t, kw["max_depth"], 32,
+                                 kw["impurity"], kw["min_instances"], 0.0,
+                                 fm, psum_axis=axis)
+        return trees.is_leaf, list(seen)
+
+    if case == "shard_map":
+        assert_devices(8)
+        run = jax.jit(shard_map(
+            lambda b, e, t: grow(b, e, t, DATA_AXIS), mesh=make_mesh(8),
+            in_specs=(P(None, DATA_AXIS), P(), P(None, None, DATA_AXIS)),
+            out_specs=P()))
+    else:
+        run = jax.jit(grow)
+    is_leaf, levels = run(binned, edges, targets)
+    depth = kw["max_depth"]
+    assert asked == [1] + [2 ** k for k in range(depth - 1)]
+    assert len(levels) == depth - 1
+    is_leaf = np.asarray(is_leaf)
+    for k, (got, want) in enumerate(levels, start=1):
+        got, want = np.asarray(got), np.asarray(want)
+        m = 2 ** k
+        assert got.dtype == want.dtype == np.float32
+        assert got.shape == want.shape == (
+            targets.shape[0], binned.shape[0], m, 32, targets.shape[1])
+        np.testing.assert_array_equal(
+            np.asarray(T._node_weight(got, kw["impurity"])),
+            np.asarray(T._node_weight(want, kw["impurity"])))
+        assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+        # children of a node that did not split hold nothing
+        under_leaf = np.repeat(is_leaf[:, m // 2 - 1:m - 1], 2, axis=1)
+        assert not got.transpose(0, 2, 1, 3, 4)[under_leaf].any()
+    if case == "some_nodes_do_not_split":
+        stopped = is_leaf[:, :2 ** (depth - 1) - 1]
+        assert stopped[:, 1:].any() and not stopped.all()
+
+
+def test_a_depth_5_fit_asks_the_histogram_for_1_1_2_4_8_nodes(monkeypatch):
+    frame, _, _, _ = _higgs_like(3000, 5, 4)
+    asked = _asked_nodes(monkeypatch)
+    T._gbt_programs.cache_clear()              # the round is traced anew
+    try:
+        GBTClassifier(max_iter=3, max_depth=5).fit(frame)
+    finally:
+        T._gbt_programs.cache_clear()
+    # one compiled round, whatever the number of rounds
+    assert asked == [1, 1, 2, 4, 8]
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     from jax.experimental import topologies
@@ -301,9 +427,13 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_mxu_histogram_compiles_for_the_chip_at_the_cell_size(one_chip):
+@pytest.mark.parametrize("nodes,columns", [(8, 128), (16, 256)])
+def test_mxu_histogram_compiles_for_the_chip_at_the_cell_size(
+        one_chip, nodes, columns):
     """The TPU's compiler takes the kernel at 11M rows x 28 features x 32
-    bins, 16 nodes (Mosaic refuses what the interpreter lets through)."""
+    bins (Mosaic refuses what the interpreter lets through): 8 nodes, the
+    widest pass of the cell's depth-5 fit — a statistic matrix of 128
+    columns, one pass of the MXU — and 16, which a depth-6 fit runs."""
     from jax.experimental.compilation_cache import compilation_cache
 
     rows = T.row_layout(11_000_000)[0]
@@ -315,7 +445,7 @@ def test_mxu_histogram_compiles_for_the_chip_at_the_cell_size(one_chip):
     compilation_cache.reset_cache()
     try:
         compiled = jax.jit(
-            lambda b, p, t: T._mxu_histogram(b, p, t, 16, 32)).lower(
+            lambda b, p, t: T._mxu_histogram(b, p, t, nodes, 32)).lower(
             shape((28, rows), jnp.int8), shape((1, rows), jnp.int32),
             shape((1, 4, rows), jnp.float32)).compile()
     finally:
@@ -324,6 +454,8 @@ def test_mxu_histogram_compiles_for_the_chip_at_the_cell_size(one_chip):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert " scatter(" not in text and " gather(" not in text
+    # (tree, accumulators, 28 x 32 one-hot rows, the statistic columns)
+    assert f"f32[1,8,896,{columns}]" in text
 
 
 # ---------------------------------------------------------------------------
@@ -410,7 +542,8 @@ def _delta(before):
 
 
 WATCHED = ("host.reads", "host.read_bytes", "tree.fit_device",
-           "tree.rounds", "tree.levels", "tree.hist_rows", "frame.host_sync")
+           "tree.rounds", "tree.levels", "tree.hist_rows", "tree.hist_nodes",
+           "tree.hist_derived", "frame.host_sync")
 
 
 @pytest.mark.parametrize("make,trees", [
@@ -428,6 +561,10 @@ def test_a_fit_reads_a_few_kilobytes_through_the_device_entry(make, trees):
     assert moved["tree.rounds"] == trees
     assert moved["tree.levels"] == trees * est.max_depth
     assert moved["tree.hist_rows"] == trees * est.max_depth * 3000
+    # the root and one child of every split; their siblings by subtraction
+    assert moved["tree.hist_nodes"] == trees * 2 ** (est.max_depth - 1)
+    assert moved["tree.hist_derived"] == trees * (
+        2 ** (est.max_depth - 1) - 1)
     # label statistics, the two flags, the packed trees: three reads
     assert moved["host.reads"] == 3 and moved["frame.host_sync"] == 0
     assert 0 < moved["host.read_bytes"] < 64 * 1024
