@@ -92,6 +92,17 @@ Wired through the framework (span names are a contract: the benchmark's
   ``tree.hist_nodes`` / ``tree.hist_derived`` (nodes a fit pushed through
   a level histogram, ``2^(max_depth-1)`` a tree, and nodes it took as
   their parent's histogram less their sibling's, one fewer a tree),
+* ``models/clustering.py`` — ``KMeans``'s device entry: ``fit.kmeans``
+  holding ``fit.prepare`` (``rows``, ``features``, ``lowering`` =
+  ``pallas`` / ``xla``; children ``fit.extract``, ``fit.validate`` — the
+  kept rows and the finite-features flag, ``host_read_bytes`` — and
+  ``fit.kmeans.init``: ``mode``, ``steps``, ``candidates``, ``overflow``;
+  it ends with the candidates on the host and the k centres picked among
+  them) and ``fit.solve`` (``iterations``: Lloyd's loop dispatched to the
+  one read of its history, sizes and cost); ``model.transform`` and
+  ``model.compute_cost`` (its one scalar read); counters
+  ``kmeans.fit_device``, ``kmeans.iterations``, ``kmeans.data_passes``,
+  ``kmeans.init_candidates``, ``kmeans.init_overflow``,
 * ``models/solvers.py`` — ``solver.solve``,
 * ``parallel/distributed.py`` / ``mesh.py`` — per-shard Gramian timing
   (blocks under the explicit flag only), collective/shard_map build
@@ -117,7 +128,14 @@ the table and the counting passes that select their ranks, or the sorts),
 ``tree_level_histogram`` or the scatters over one child of every split —
 counter ``tree.hist_nodes`` — and the subtraction that gives the sibling,
 ``tree.hist_derived``), ``dq.tree.split``,
-``dq.tree.descend``, ``dq.tree.score``. Metadata
+``dq.tree.descend``, ``dq.tree.score``; in k-means' programs
+``dq.kmeans.init.cost`` (a k-means‖ round's pass: the Pallas kernel
+``kmeans_pass`` or its ``jax.numpy`` form), ``dq.kmeans.init.sample`` (the
+draws and their compaction into the round's bucket),
+``dq.kmeans.init.weigh`` (the pass that counts the rows a candidate),
+``dq.kmeans.assign`` (a Lloyd pass), ``dq.kmeans.update`` (the new
+centres, their shift, the history) and ``dq.kmeans.score`` (the model's
+pass: ``transform``, ``compute_cost``). Metadata
 only: the operations' HLO names and the compiled code are unchanged. (A
 scope opened on the host around eager ``jnp`` calls does not reach their
 one-operation programs' metadata — measured on the chip, PERF.md section 3
@@ -137,6 +155,7 @@ keeps its meaning) and, while the tracer records, the span ``host.read``
 ``fit.label_stats`` / ``fit.finite_flags`` / ``fit.result``,
 ``tree.result`` / ``tree.held_loss`` / ``tree.bin`` (a wait for the
 binning program, nothing read: no ``bytes``, not counted),
+``kmeans.validate`` / ``kmeans.candidates`` / ``kmeans.result``,
 ``model.fetch``, ``stat.corr`` / ``stat.cov`` / ``stat.quantile`` /
 ``stat.strata``, ``window.mask`` / ``window.column``,
 ``evaluation.pair``. The benchmark's ``host_wait_ms`` is the union of a
@@ -291,6 +310,18 @@ METRIC_NAMES = {
                                    "2^(max_depth-1) a tree"),
     "tree.hist_derived": ("counter", "nodes whose histogram is their "
                                      "parent's less their sibling's"),
+    # k-means (models/clustering.py)
+    "kmeans.fit_device": ("counter", "KMeans fits through the device "
+                                     "entry: no row leaves the chip"),
+    "kmeans.iterations": ("counter", "Lloyd iterations run"),
+    "kmeans.data_passes": ("counter", "passes over the feature column a "
+                                      "fit dispatched: validation, the "
+                                      "seeding's, an iteration each, the "
+                                      "final cost's"),
+    "kmeans.init_candidates": ("counter", "candidates k-means|| drew"),
+    "kmeans.init_overflow": ("counter", "k-means|| rounds that drew more "
+                                        "rows than their bucket holds "
+                                        "(the rest dropped: degraded)"),
     "jit.trace_miss": ("counter", "jit-factory cache misses (new trace)"),
     "jit.trace_hit": ("counter", "jit-factory cache hits"),
     # parallel / mesh

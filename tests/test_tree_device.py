@@ -458,6 +458,53 @@ def test_mxu_histogram_compiles_for_the_chip_at_the_cell_size(
     assert f"f32[1,8,896,{columns}]" in text
 
 
+@pytest.mark.parametrize("mode", ["lloyd", "seeding_round", "weigh"])
+def test_kmeans_pass_compiles_for_the_chip_at_the_cell_size(one_chip, mode):
+    """k-means' pass kernel (``models/clustering.py``; here because one
+    file holds every compile for the described chip: its fixture owns the
+    TPU's library) at ``hibench_kmeans``' 5e7 rows x 20 features: Lloyd's
+    pass at k = 10, a seeding round against a 48-slot bucket with the
+    earlier round's costs beside it, the weighing pass. The feature column
+    arrives ``(n, 20)``; its transpose must be a bitcast (no copy of 4 GB)
+    and nothing n-sized may be built beside the row vectors asked for."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from sparkdq4ml_tpu.models import clustering as C
+
+    n, d, k = 50_000_000, 20, 10
+    slots = C.row_slots(n, "pallas")
+    bucket = C.init_bucket(k)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    X, w = shape((n, d), jnp.float32), shape((1, slots), jnp.float32)
+    rows = (shape((1, slots), jnp.float32), shape((1, slots), jnp.int32))
+    if mode == "lloyd":
+        fn = lambda X, w, c: C.device_pass(  # noqa: E731
+            X.T, w, c, sums=True, slots=k, lowering="pallas")
+        args = (X, w, shape((k, d), jnp.float32))
+    else:
+        fn = lambda X, w, c, pc, pi: C.device_pass(  # noqa: E731
+            X.T, w, c, prev=(pc, pi), base=1 + bucket,
+            rows_out=mode == "seeding_round",
+            slots=1 + 2 * bucket if mode == "weigh" else 0,
+            lowering="pallas")
+        args = (X, w, shape((bucket, d), jnp.float32)) + rows
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(fn).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kmeans_pass" in text
+    # the 4 GB operand reaches the kernel as a bitcast of the column
+    assert "f32[20,50000000]{1,0:T(8,128)} bitcast(" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
 # ---------------------------------------------------------------------------
 # a whole fit against the plain reference
 # ---------------------------------------------------------------------------
