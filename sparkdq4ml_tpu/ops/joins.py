@@ -20,8 +20,11 @@ bucket, build step) does all of it:
                     the sorted build rows, every chunk of 2^15 sorted by
                     itself (``_merge``) — on this chip a sort's cost
                     follows its length steeply
-``dq.join.probe``   per key group, by four scans, where it starts and how
-                    many valid build rows it has; the probe rows the join
+``dq.join.probe``   per key group, by scans, where it starts and how
+                    many valid build rows it has — on one TPU device and
+                    32-bit keys ONE pass of the Pallas kernel
+                    ``join_probe_scan``, elsewhere XLA's ``cumsum`` and two
+                    ``cummax`` (``scan_lowering``); the probe rows the join
                     type selects, compacted by one single-operand sort of
                     their positions
 ``dq.join.gather``  selected probe rows x their group's build rows laid
@@ -56,6 +59,7 @@ bit the same under either build step.
 
 from __future__ import annotations
 
+import functools
 import threading
 
 import jax
@@ -129,6 +133,12 @@ def _chunks(npr: int, room: int) -> int:
     the scans behind it then see whole tiles (a program for 2.4e8 slots
     asked for 7.5 GB of scratch so, and 9.2 to 10.2 GB otherwise)."""
     return -(-npr // ((_CHUNK - room) * 8)) * 8
+
+
+def _pairs(nb: int, npr: int, room: int) -> int:
+    """Sorted (key, tag) pairs the build step gives and the rest of the
+    program walks: the merge's chunks, or both sides."""
+    return _chunks(npr, room) * _CHUNK if room else nb + npr
 
 
 @jax.jit
@@ -238,8 +248,211 @@ def _compact(sel, n: int, bucket: int):
     return jnp.minimum(chunk * _CHUNK + inside.astype(jnp.int32), n - 1)
 
 
+#: pairs a grid step of ``join_probe_scan`` reads
+SCAN_BLOCK = 1 << 18
+#: rows of 128 pairs the kernel scans at once: a slab of 32 vregs, the
+#: most whose place and count a 31-bit mark holds (2^15 pairs)
+SCAN_ROWS = 256
+_VREG = 8 * 128
+# float32 bits: -0.0, the magnitude's mask, +inf (a NaN's magnitude is more)
+_NEG_ZERO, _ABS, _INF = np.int32(-(1 << 31)), np.int32(0x7FFFFFFF), \
+    np.int32(0x7F800000)
+
+
+def scan_lowering(keys, dtypes, n: int) -> str:
+    """Which lowering the probe's scans over ``n`` pairs take — from the
+    backend and the operands, never from a conf key: ``"pallas"`` (the
+    kernel ``join_probe_scan``) for keys compared in 32 bits on one TPU
+    device, ``"xla"`` everywhere else (the CPU of the tests, a mesh, 64-bit
+    keys) and for fewer pairs than a vreg holds (XLA tiles a 1-D operand
+    of up to 512 elements otherwise than the kernel's blocks). Both give
+    the same integers."""
+    if jax.default_backend() != "tpu" or n < _VREG \
+            or any(np.dtype(dt).itemsize != 4 for dt in dtypes):
+        return "xla"
+    for key in keys:
+        sharding = getattr(key, "sharding", None)
+        if sharding is None or len(sharding.device_set) != 1:
+            return "xla"
+    return "pallas"
+
+
+def _scans_xla(ks, ts, nb: int):
+    """(head, cnt) of the sorted pairs by XLA's scans: for every pair the
+    position where its key group starts (the group's valid build rows
+    stand there) and, for a valid probe row, how many valid build rows
+    its group has (0 elsewhere)."""
+    n = ts.shape[0]
+    ok = ts < _HIGH
+    row = (ts & ~_HIGH).astype(jnp.int32)
+    is_b = ok & (row < nb)
+    is_p = ok & (row >= nb)
+    one = jnp.ones((1,), jnp.bool_)
+    first = None
+    for c in ks:
+        new = jnp.concatenate([one, c[1:] != c[:-1]])
+        first = new if first is None else first | new
+    is_b32 = is_b.astype(jnp.int32)
+    cb = jnp.cumsum(is_b32)
+    head = lax.cummax(jnp.where(first, lax.iota(jnp.int32, n), 0))
+    # (a probe row stands behind its group's build rows, so the running
+    # count at it holds all of them)
+    cnt = jnp.where(is_p, cb - lax.cummax(
+        jnp.where(first, cb - is_b32, 0)), 0)
+    return head, cnt
+
+
+def _scan_kernel(*refs, floats: tuple, nb: int, block: int, rows: int):
+    """One grid step of ``join_probe_scan``: ``block`` pairs as slabs of
+    ``rows`` x 128 consecutive ones (in row order), scanned in order. A
+    slab's scans are log steps of lane rolls, then of row rolls over the
+    rows' totals, each step over all of the slab's vregs at once (a roll's
+    latency is paid once a slab, not once a vreg); what runs on from one
+    slab to the next — the last key, the open group's start and its build
+    rows so far — is carried, and kept in scratch from one grid step to
+    the next. Keys are compared as their 32 bits (``floats`` says which
+    are floats)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    k = len(floats)
+    key_refs, tag_ref = refs[:k], refs[k]
+    head_ref, cnt_ref = refs[k + 1], refs[k + 2]
+    last_refs = refs[k + 3:2 * k + 3]
+    head_c_ref, run_c_ref = refs[2 * k + 3], refs[2 * k + 4]
+    step = pl.program_id(0)
+    size = rows * 128
+    # constants as int32: a Python int would be an int64 under x64, which
+    # Mosaic does not take
+    bits_of_count = size.bit_length()   # place << them | count < 2^31
+    shift, low = np.int32(bits_of_count), np.int32((1 << bits_of_count) - 1)
+    nb, zero, none = np.int32(nb), np.int32(0), np.int32(-1)
+
+    @pl.when(step == 0)
+    def _():
+        for ref in (*last_refs, head_c_ref, run_c_ref):
+            ref[...] = jnp.zeros_like(ref)
+
+    row = lax.broadcasted_iota(jnp.int32, (rows, 128), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (rows, 128), 1)
+    flat = row * 128 + lane
+
+    def roll(v, by, axis):
+        return pltpu.roll(v, np.int32(by), axis)     # int32 under x64 too
+
+    def bits(v):
+        v = v.reshape(rows, 128)
+        return v if v.dtype == jnp.int32 else lax.bitcast_convert_type(
+            v, jnp.int32)
+
+    def lanes(v):
+        """Each row's last lane, over the row."""
+        return jnp.broadcast_to(v[:, 127:128], (rows, 128))
+
+    def last(v):
+        """The slab's last pair, over a row (Mosaic broadcasts one element
+        along one axis at a time)."""
+        return jnp.sum(jnp.where(row == rows - 1, lanes(v), zero), axis=0,
+                       keepdims=True, dtype=jnp.int32)
+
+    def over(c):
+        return jnp.broadcast_to(c, (rows, 128))
+
+    def scan(v, op, fill):
+        """Inclusive scan of a slab in pair order."""
+        s = 1
+        while s < 128:
+            v = op(v, jnp.where(lane >= s, roll(v, s, 1), fill))
+            s *= 2
+        # the rows before a row: their totals shifted by one, then a scan
+        before = jnp.where(row >= 1, roll(lanes(v), 1, 0), fill)
+        s = 1
+        while s < rows:
+            before = op(before, jnp.where(row >= s,
+                                          roll(before, s, 0), fill))
+            s *= 2
+        return op(v, before)
+
+    def slab(_, carry):
+        # the slab's offset rides in the carry: int32 under x64 too, where
+        # the loop's own index would not be
+        start, lasts, head_c, run_c = carry
+        at = pl.ds(pl.multiple_of(start, size), size)
+        origin = step * np.int32(block) + start
+        # keys as their bits, the tags as int32: a masked pair's is negative
+        tag = bits(tag_ref[at])
+        first = flat + origin == 0
+        keys = []
+        for ref, prev, floating in zip(key_refs, lasts, floats):
+            c = bits(ref[at])
+            if floating:
+                c = jnp.where(c == _NEG_ZERO, zero, c)  # -0.0 is 0.0
+            shifted = roll(c, 1, 1)            # lane l: l - 1
+            before = jnp.where(lane >= 1, shifted, jnp.where(
+                row >= 1, roll(shifted, 1, 0), over(prev)))
+            first = first | (c != before)
+            if floating:
+                # a NaN key is a group of its own
+                first = first | ((c & _ABS) > _INF)
+            keys.append(c)
+        b = ((tag >= 0) & (tag < nb)).astype(jnp.int32)
+        cs = scan(b, jnp.add, zero)
+        # the last group start at or before a pair: its place in the slab
+        # and the build rows before it, one max scan of both (each grows
+        # along the slab)
+        mark = scan(jnp.where(first, (flat << shift) + cs - b, none),
+                    jnp.maximum, none)
+        opened = mark >= 0
+        head = jnp.where(opened, origin + (mark >> shift), over(head_c))
+        run = jnp.where(opened, cs - (mark & low), over(run_c) + cs)
+        head_ref[at] = head.reshape(size)
+        cnt_ref[at] = jnp.where(tag >= nb, run, zero).reshape(size)
+        return (start + np.int32(size), [last(c) for c in keys],
+                last(head), last(run))
+
+    _, lasts, head_c, run_c = lax.fori_loop(
+        0, block // size, slab, (np.int32(0), [ref[...] for ref in last_refs],
+                                 head_c_ref[...], run_c_ref[...]))
+    for ref, v in zip(last_refs, lasts):
+        ref[...] = v
+    head_c_ref[...] = head_c
+    run_c_ref[...] = run_c
+
+
+def _scans_pallas(ks, ts, nb: int, block: int = SCAN_BLOCK,
+                  rows: int = SCAN_ROWS, interpret: bool = False):
+    """:func:`_scans_xla` as ONE pass of the Pallas kernel
+    ``join_probe_scan``: keys and tags read once, ``head`` and ``cnt``
+    written once, the pairs in grid steps of ``block`` along a sequential
+    axis. The last step may reach past the pairs' end: what it reads
+    there comes after every pair it writes, and what it writes there is
+    dropped — no operand is padded.
+
+    Pallas is imported here and in the kernel, never at module level
+    (rule 0: every cell imports this module)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = ts.shape[0]
+    size = rows * 128
+    assert rows % 8 == 0 and size <= 1 << 15, rows
+    block = -(-min(block, n) // size) * size
+    floats = tuple(np.dtype(c.dtype).kind == "f" for c in ks)
+    spec = pl.BlockSpec((block,), lambda i: (i,))
+    return tuple(pl.pallas_call(
+        functools.partial(_scan_kernel, floats=floats, nb=nb, block=block,
+                          rows=rows),
+        grid=(pl.cdiv(n, block),),
+        in_specs=[spec] * (len(ks) + 1), out_specs=[spec, spec],
+        out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)] * 2,
+        scratch_shapes=[pltpu.VMEM((1, 128), jnp.int32)] * (len(ks) + 2),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="join_probe_scan")(*ks, ts))
+
+
 def _build_program(how: str, dtypes: tuple, nb: int, npr: int, bucket: int,
-                   probe_is_left: bool, room: int):
+                   probe_is_left: bool, room: int, scan: str):
     """The jitted join: (build keys, build mask, probe keys, probe mask,
     build columns, probe columns) -> (left rows' columns, right rows'
     columns, slot mask, verdict, missing-right flags or None). The
@@ -247,8 +460,7 @@ def _build_program(how: str, dtypes: tuple, nb: int, npr: int, bucket: int,
     step merges (``room`` > 0), whether the merge held and the room its
     fullest chunk asked for."""
     k = len(dtypes)
-    # sorted (key, tag) pairs the rest of the program walks
-    n = _chunks(npr, room) * _CHUNK if room else nb + npr
+    n = _pairs(nb, npr, room)
 
     def canon(col, dt):
         col = col.astype(dt)
@@ -286,24 +498,12 @@ def _build_program(how: str, dtypes: tuple, nb: int, npr: int, bucket: int,
             with _obs.scope("join.probe"):
                 ok = ts < _HIGH
                 row = (ts & ~_HIGH).astype(jnp.int32)
-                is_b = ok & (row < nb)
                 is_p = ok & (row >= nb)
-                one = jnp.ones((1,), jnp.bool_)
-                first = None
-                for c in ks:
-                    new = jnp.concatenate([one, c[1:] != c[:-1]])
-                    first = new if first is None else first | new
-                is_b32 = is_b.astype(jnp.int32)
-                cb = jnp.cumsum(is_b32)
                 # known to every probe row: where its key group starts
                 # (the group's valid build rows stand there) and how many
                 # valid build rows it has
-                head = lax.cummax(
-                    jnp.where(first, lax.iota(jnp.int32, n), 0))
-                # (a probe row stands behind its group's build rows, so
-                # the running count at it holds all of them)
-                cnt = jnp.where(is_p, cb - lax.cummax(
-                    jnp.where(first, cb - is_b32, 0)), 0)
+                head, cnt = (_scans_pallas if scan == "pallas"
+                             else _scans_xla)(ks, ts, nb)
                 if how == "left_anti":
                     sel = is_p & (cnt == 0)
                 elif how == "left":
@@ -417,11 +617,13 @@ def device_join(how: str, lkeys, lmask, rkeys, rmask, lcols, rcols,
         with _LOCK:
             _ROOMS[sig] = room
     while True:
+        scan = scan_lowering(list(bkeys) + list(pkeys), dtypes,
+                             _pairs(nb, npr, room))
         with _LOCK:
-            fn = _PROGRAMS.get(sig + (bucket, room))
+            fn = _PROGRAMS.get(sig + (bucket, room, scan))
             if fn is None:
-                fn = _PROGRAMS[sig + (bucket, room)] = _build_program(
-                    how, dtypes, nb, npr, bucket, probe_is_left, room)
+                fn = _PROGRAMS[sig + (bucket, room, scan)] = _build_program(
+                    how, dtypes, nb, npr, bucket, probe_is_left, room, scan)
         before = counters.get("join.compile")
         pout, bout, live, verdict, missing = fn(
             list(bkeys), bmask, list(pkeys), pmask,
@@ -450,10 +652,13 @@ def device_join(how: str, lkeys, lmask, rkeys, rmask, lcols, rcols,
         if rows <= bucket:
             break
         bucket = want                     # outgrew its bucket: once more
+    if scan == "pallas":
+        counters.increment("join.scan_pallas")
     if room:
         counters.increment("join.merge")
-        _obs.current_span().set(build_step="merge", room=room)
+        _obs.current_span().set(build_step="merge", room=room,
+                                probe_scan=scan)
     else:
-        _obs.current_span().set(build_step="sort")
+        _obs.current_span().set(build_step="sort", probe_scan=scan)
     lout, rout = (pout, bout) if probe_is_left else (bout, pout)
     return lout, rout, live, rows, missing

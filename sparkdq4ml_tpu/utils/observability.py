@@ -259,6 +259,8 @@ METRIC_NAMES = {
     "join.merge_miss": ("counter", "merge programs that found the probe "
                                    "side out of order or a chunk over its "
                                    "room, and ran again as a sort"),
+    "join.scan_pallas": ("counter", "device joins whose probe scans ran in "
+                                    "the Pallas kernel join_probe_scan"),
     "grouped.shard_gather": ("counter",
                              "sharded grouped/distinct programs gathered "
                              "to single-device by the shard_merge "

@@ -735,3 +735,190 @@ def test_the_chunked_compaction_finds_what_the_full_sort_finds(share):
     got = np.asarray(joins._compact(jnp.asarray(sel), n, bucket))
     assert np.array_equal(got[:len(want)], want)
     assert got.min() >= 0 and got.max() < n
+
+
+# ---------------------------------------------------------------------------
+# The probe's scans: the Pallas kernel (through the interpreter) against
+# XLA's cumsum and two cummax, integer for integer
+# ---------------------------------------------------------------------------
+
+HIGH = np.uint32(1 << 31)
+# the kernel's grid step in these tests: two slabs of 8 x 128 pairs
+SCAN_ROWS, SCAN_BLOCK = 8, 2048
+
+
+def sorted_pairs(seed, n, nb, keys=1, groups=60, masked=0.2,
+                 floating=False, run=0):
+    """``n`` (key, tag) pairs in (key, tag) order, as the join's build step
+    leaves them: tags under ``nb`` are build rows, a masked pair's tag has
+    its high bit set; ``run`` pairs share one key (a group that long)."""
+    r = np.random.default_rng(seed)
+    cols = [r.integers(0, groups, n).astype(np.int32) for _ in range(keys)]
+    if run:
+        cols[0][r.permutation(n)[:run]] = groups
+    if floating:
+        cols = [c.astype(np.float32) - groups // 2 for c in cols]
+        cols[0][r.random(n) < 0.05] = np.nan
+        # zeros of both signs, one group: the program adds 0.0 to a float
+        # key, which leaves none negative; the kernel holds that too
+        zero = r.random(n) < 0.1
+        cols[0][zero] = np.where(r.random(zero.sum()) < 0.5, -0.0, 0.0)
+    tag = np.arange(n, dtype=np.uint32)
+    tag = np.where(r.random(n) < masked, tag | HIGH, tag)
+    order = np.lexsort([tag] + cols[::-1])
+    return [c[order] for c in cols], tag[order]
+
+
+def merge_pairs(monkeypatch):
+    """The merge's chunked pairs (``_merge``: 2^15-pair rows here cut to
+    128), flattened as the program holds them."""
+    import jax.numpy as jnp
+
+    from sparkdq4ml_tpu.ops import joins
+
+    monkeypatch.setattr(joins, "_CHUNK", 128)
+    r = np.random.default_rng(9)
+    pk = ordered_keys(r, run=3)
+    bk = spread(r, pk, 4)
+    room = joins._first_room(1, NB, NPR)
+    ks, ts, ordered, need = joins._merge(
+        jnp.asarray(bk), jnp.asarray(r.random(NB) < 0.8), jnp.asarray(pk),
+        jnp.asarray(r.random(NPR) < 0.7), room)
+    assert bool(ordered) and int(need) <= room
+    assert ks.shape[0] == joins._chunks(NPR, room) * 128
+    return [np.asarray(ks)], np.asarray(ts), NB
+
+
+SCAN_CASES = {
+    "one_int_key": dict(n=5_000, nb=700),
+    "two_int_keys": dict(n=5_000, nb=1_500, keys=2, groups=9),
+    "float_keys_nan_and_both_zeros": dict(n=4_000, nb=1_000, floating=True),
+    "two_float_keys": dict(n=3_000, nb=900, keys=2, groups=6,
+                           floating=True),
+    "masked_rows": dict(n=6_000, nb=2_000, masked=0.5),
+    "groups_over_step_borders": dict(n=7_000, nb=300, groups=4),
+    "a_group_over_three_steps": dict(n=9_000, nb=4_000, run=6_500),
+    "only_build_rows": dict(n=5_000, nb=5_000),
+    "only_probe_rows": dict(n=5_000, nb=0),
+    "under_one_step": dict(n=700, nb=200),
+    "no_step_multiple": dict(n=3 * SCAN_BLOCK + 517, nb=2_000),
+    "merge_chunked_pairs": None,
+}
+
+
+@pytest.mark.parametrize("case", sorted(SCAN_CASES))
+def test_the_scan_kernel_gives_the_xla_scans_integer_for_integer(
+        monkeypatch, case):
+    import jax.numpy as jnp
+
+    from sparkdq4ml_tpu.ops import joins
+
+    spec = SCAN_CASES[case]
+    if spec is None:
+        ks, ts, nb = merge_pairs(monkeypatch)
+    else:
+        ks, ts = sorted_pairs(len(case), **spec)
+        nb = spec["nb"]
+    ks, ts = [jnp.asarray(c) for c in ks], jnp.asarray(ts)
+    want = joins._scans_xla(ks, ts, nb)
+    got = joins._scans_pallas(ks, ts, nb, block=SCAN_BLOCK, rows=SCAN_ROWS,
+                              interpret=True)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype == jnp.int32 and g.shape == w.shape
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+    if case == "a_group_over_three_steps":
+        head = np.asarray(want[0])
+        assert np.bincount(head).max() >= 2 * SCAN_BLOCK + 1
+    if case != "only_probe_rows" and spec is not None and spec["nb"] < \
+            spec["n"]:
+        assert np.asarray(want[1]).max() > 0          # something matched
+
+
+def take_the_kernel(monkeypatch, joins):
+    """Steer ``joins`` to the kernel, run by the Pallas interpreter with
+    grid steps of two slabs of 8 x 128 pairs; returns the list of what
+    :func:`scan_lowering` would have chosen."""
+    import functools
+
+    real, taken = joins.scan_lowering, []
+
+    def pallas(*args):
+        taken.append(real(*args))
+        return "pallas"
+
+    monkeypatch.setattr(joins, "scan_lowering", pallas)
+    monkeypatch.setattr(joins, "_scans_pallas", functools.partial(
+        joins._scans_pallas, block=SCAN_BLOCK, rows=SCAN_ROWS,
+        interpret=True))
+    return taken
+
+
+@pytest.mark.parametrize("step", ["sort", "merge"])
+@pytest.mark.parametrize("how", HOWS)
+def test_a_join_with_the_kernel_is_the_join_with_xla_scans(
+        monkeypatch, joins, how, step):
+    probe, build, _ = merge_sides("key_groups_over_chunk_borders", joins)
+    want, by_xla = run_join(joins, probe, build, how,
+                            sort_only=step == "sort")
+    taken = take_the_kernel(monkeypatch, joins)
+    got, by_kernel = run_join(joins, probe, build, how,
+                              sort_only=step == "sort")
+    # the CPU's own choice was XLA's scans
+    assert taken and set(taken) == {"xla"}
+    assert by_kernel.get("join.merge", 0) == (step == "merge") \
+        == by_xla.get("join.merge", 0)
+    assert by_kernel["join.scan_pallas"] == 1
+    assert "join.scan_pallas" not in by_xla
+    same_rows(got, want)
+    assert got.num_slots == want.num_slots
+    assert np.array_equal(np.asarray(got._mask), np.asarray(want._mask))
+    if how != "left_anti":
+        assert got.count() > 0
+
+
+def test_the_join_span_says_which_scans_ran(monkeypatch, joins):
+    probe, build, _ = merge_sides("foreign_keys_drawn_from_the_probe", joins)
+    (span,) = spans_of(lambda: probe.join(build, "k", "inner"))
+    assert span.attrs["probe_scan"] == "xla"
+    take_the_kernel(monkeypatch, joins)
+    before = counters.snapshot()
+    (span,) = spans_of(lambda: probe.join(build, "k", "inner"))
+    delta = moved(before)
+    assert span.attrs["probe_scan"] == "pallas"
+    assert span.attrs["build_step"] == "merge"
+    assert delta["join.scan_pallas"] == 1 and delta["join.compile"] == 1
+
+
+def test_the_scan_lowering_follows_backend_dtype_and_devices(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from sparkdq4ml_tpu.ops import joins
+    from sparkdq4ml_tpu.parallel.mesh import DATA_AXIS, make_mesh
+
+    i32 = jnp.arange(4096, dtype=jnp.int32)
+    f32 = i32.astype(jnp.float32)
+    i64 = i32.astype(jnp.int64)          # the tests run under x64
+    assert i64.dtype == jnp.int64
+    d32, f, d64 = np.dtype(np.int32), np.dtype(np.float32), \
+        np.dtype(np.int64)
+    # the CPU of the tests
+    assert joins.scan_lowering([i32, i32], (d32,), 8192) == "xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert joins.scan_lowering([i32, i32], (d32,), 8192) == "pallas"
+    assert joins.scan_lowering([f32, f32], (f,), 8192) == "pallas"
+    assert joins.scan_lowering([i32, f32, i32, f32], (d32, f), 8192) \
+        == "pallas"
+    # 64-bit keys under x64
+    assert joins.scan_lowering([i64, i64], (d64,), 8192) == "xla"
+    assert joins.scan_lowering([i32, i64, i32, i64], (d32, d64), 8192) \
+        == "xla"
+    # fewer pairs than a vreg holds
+    assert joins.scan_lowering([i32, i32], (d32,), 1023) == "xla"
+    assert joins.scan_lowering([i32, i32], (d32,), 1024) == "pallas"
+    # a key spread over a mesh
+    mesh = make_mesh()
+    spread_key = jax.device_put(i32, NamedSharding(mesh, P(DATA_AXIS)))
+    assert len(spread_key.sharding.device_set) > 1
+    assert joins.scan_lowering([spread_key, i32], (d32,), 8192) == "xla"

@@ -36,8 +36,22 @@ print("PALLAS=" + ",".join(sorted(
 """
 
 
-@pytest.mark.parametrize("script", [IMPORTS, FILTERED_FRAME],
-                         ids=["import", "session_frame_filter"])
+DEVICE_JOIN = IMPORTS + """
+import numpy as np
+from sparkdq4ml_tpu import Frame
+from sparkdq4ml_tpu.utils.profiling import counters
+left = Frame({"k": np.arange(3000, dtype=np.int32) % 700,
+              "a": np.ones(3000, np.float32)})
+right = Frame({"k": np.arange(700, dtype=np.int32),
+               "b": np.ones(700, np.float32)})
+assert left.join(right, "k", "inner").count() == 3000
+assert counters.get("join.device") == 1
+"""
+
+
+@pytest.mark.parametrize("script", [IMPORTS, FILTERED_FRAME, DEVICE_JOIN],
+                         ids=["import", "session_frame_filter",
+                              "device_join"])
 def test_no_pallas_module_is_loaded(script):
     env = dict(os.environ, JAX_PLATFORMS="cpu")      # cwd is on sys.path
     proc = subprocess.run([sys.executable, "-c", script + TAIL], env=env,

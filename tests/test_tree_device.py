@@ -505,6 +505,43 @@ def test_kmeans_pass_compiles_for_the_chip_at_the_cell_size(one_chip, mode):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
+@pytest.mark.parametrize("n, dtype, keys", [
+    (7_624 * 32_768, jnp.int32, 1),       # the lineitem join's merged pairs
+    (66_000_000, jnp.int32, 1),           # the customer join's sorted pairs
+    (66_000_017, jnp.float32, 2),         # no step multiple, float keys
+    (1_024, jnp.uint32, 1)])              # the fewest the lowering hands it
+def test_join_probe_scan_compiles_for_the_chip_at_the_cell_size(
+        one_chip, n, dtype, keys):
+    """The join's probe-scan kernel (``ops/joins.py``; here because this
+    file holds every compile for the described chip) at ``tpch_q3_join``'s
+    two sizes: its 1-D operands are read where they lie — no copy, no
+    padded operand, not a byte of temporaries — and the ragged last step
+    is the kernel's own."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from sparkdq4ml_tpu.ops import joins as J
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(lambda ks, ts: J._scans_pallas(ks, ts, n // 10)) \
+            .lower([shape((n,), dtype)] * keys,
+                   shape((n,), jnp.uint32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "join_probe_scan" in text
+    assert " copy(" not in text and " pad(" not in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes == 0
+    # head and cnt, each tiled to whole 1,024-element tiles
+    assert memory.output_size_in_bytes <= 2 * 4 * (n + 1024) + 1024
+
+
 # ---------------------------------------------------------------------------
 # a whole fit against the plain reference
 # ---------------------------------------------------------------------------
