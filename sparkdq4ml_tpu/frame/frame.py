@@ -1864,6 +1864,8 @@ class Frame:
                     "add it with with_column first)")
             resolved.append(name)
         cols = resolved
+        if self.num_slots == 0:
+            return self          # no row to order (an empty GROUP BY result)
         # Device path (ops/segments.py): numeric sort keys compute the
         # permutation on device (jax.lax.sort) and gather payload with
         # jnp.take — one host sync (the valid-row count) instead of the

@@ -503,8 +503,11 @@ class InList(Expr):
                 np.asarray([x is not None for x in va], bool))
         else:
             v = jnp.asarray(v)
+            # an empty set (an IN subquery that selected no row) holds
+            # nothing
             hit = functools.reduce(
-                jnp.logical_or, [jnp.equal(v, jnp.asarray(x)) for x in vals])
+                jnp.logical_or, [jnp.equal(v, jnp.asarray(x)) for x in vals]
+            ) if vals else jnp.zeros(v.shape[:1], jnp.bool_)
             notnull = (jnp.logical_not(jnp.isnan(v))
                        if jnp.issubdtype(v.dtype, jnp.floating)
                        else jnp.ones(v.shape[:1], jnp.bool_))

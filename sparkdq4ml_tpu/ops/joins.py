@@ -248,6 +248,58 @@ def _compact(sel, n: int, bucket: int):
     return jnp.minimum(chunk * _CHUNK + inside.astype(jnp.int32), n - 1)
 
 
+#: slots a block of :func:`_sparse_compact` counts; a result slot per this
+#: many slots at most
+_SPARSE = 128
+
+
+def _sparse_compact(sel, bucket: int):
+    """:func:`_compact` for a selection far sparser than its slots: by the
+    counts of blocks of ``_SPARSE`` and a binary search over their running
+    totals, then a rank inside the one block each result slot falls in —
+    one pass over ``sel``, no sort."""
+    n = sel.shape[0]
+    blocks = -(-n // _SPARSE)
+    grid = jnp.concatenate(
+        [sel, jnp.zeros((blocks * _SPARSE - n,), jnp.bool_)]
+    ).reshape(blocks, _SPARSE)
+    held = jnp.sum(grid, axis=1, dtype=jnp.int32)
+    ends = jnp.cumsum(held)
+    slot = lax.iota(jnp.int32, bucket)
+    # the block that holds a slot's element: the first whose total passes it
+    at = jnp.minimum(jnp.searchsorted(ends, slot, side="right"),
+                     blocks - 1).astype(jnp.int32)
+    rows = jnp.take(grid, at, axis=0)
+    rank = jnp.cumsum(rows, axis=1, dtype=jnp.int32) \
+        + (jnp.take(ends, at) - jnp.take(held, at))[:, None] - 1
+    lane = jnp.argmax(rows & (rank == slot[:, None]), axis=1)
+    return jnp.minimum(at * _SPARSE + lane.astype(jnp.int32), n - 1)
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _take_rows(cols, mask, bucket: int):
+    n = mask.shape[0]
+    at = _sparse_compact(mask, bucket) if bucket * _SPARSE <= n \
+        else _compact(mask, n, bucket)
+    return [jnp.take(c, at, axis=0, mode="clip") for c in cols]
+
+
+def compact_rows(cols, mask):
+    """The valid rows of ``cols`` in order, in ``result_bucket(rows)``
+    slots under a mask: the build side of a join that a filter left
+    sparse (a HAVING over a many-group result), so that the join sorts its
+    rows and not its slots. One read, the row count; returns ``(columns,
+    mask, rows)``, the columns as they were where the bucket saves no
+    slot."""
+    held = _read_verdict(jnp.sum(mask, dtype=jnp.int32), "join.build_rows")
+    rows = int(held)
+    bucket = result_bucket(rows)
+    if bucket >= mask.shape[0]:
+        return list(cols), mask, rows
+    return (_take_rows(list(cols), mask, bucket),
+            jnp.arange(bucket) < rows, rows)
+
+
 #: pairs a grid step of ``join_probe_scan`` reads
 SCAN_BLOCK = 1 << 18
 #: rows of 128 pairs the kernel scans at once: a slab of 32 vregs, the
@@ -604,8 +656,10 @@ def device_join(how: str, lkeys, lmask, rkeys, rmask, lcols, rcols,
         bucket, room = _BUCKETS.get(sig), _ROOMS.get(sig)
     if bucket is None:
         # first run of this join: a foreign-key join gives at most one
-        # row a probe row
-        bucket = result_bucket(npr if how != "inner" else min(nb, npr))
+        # row a probe row, and a semi join of a key's probe rows against a
+        # few build keys (an IN subquery's) about a row a build key
+        bucket = result_bucket(min(nb, npr) if how in ("inner", "left_semi")
+                               else npr)
     if room is None:
         # first run: where shapes offer the merge, the probe side's
         # order today (one elementwise pass, one flag read) decides

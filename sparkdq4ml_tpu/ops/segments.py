@@ -7,7 +7,7 @@ module removes that boundary for the numeric surface, the same way the
 pipeline compiler (``ops/compiler.py``) removed it for expression chains:
 
 * **One jitted program per plan shape.** ``group_by(...).agg(...)`` lowers
-  to a single XLA computation. Two lowerings share one calling convention:
+  to a single XLA computation. Three lowerings share one calling convention:
 
   - the **dense** program (the common case: integer-valued keys whose
     packed range fits a bounded table) maps each row's key tuple straight
@@ -28,6 +28,14 @@ pipeline compiler (``ops/compiler.py``) removed it for expression chains:
     value key components with a row-index tiebreaker, exactly mirroring
     the host ``_group_plan`` lexsort) and reduces over the discovered
     segment boundaries.
+  - the **ordered** program, where the dense one cannot hold the key range:
+    one integer key over more rows than the exact threshold, stored in
+    key order (a fact table grouped by its parent's key, TPC-H's ``GROUP
+    BY l_orderkey``): its runs are its groups, reduced by segmented scans
+    with no sort, gather or scatter, the result left in the input's slots
+    under a mask. The order is read once a plan struct and size
+    (``grouped.order``) and checked again inside every run; keys out of
+    order take the sorted program (``grouped.order_miss``).
 
   The only dynamic quantity — the group count (plus the dense path's
   "did the range fit" verdict) — leaves the device as ONE scalar sync at
@@ -252,6 +260,8 @@ def clear_cache() -> None:
     with _CACHE_LOCK:
         _CACHE.clear()
         _PLAN_STATS.clear()
+    with _ORDER_LOCK:
+        _ORDER.clear()
 
 
 def cache_len() -> int:
@@ -1408,6 +1418,150 @@ def _build_sorted_agg_program(key_kinds, agg_ops, val_kinds):
 
 
 # ---------------------------------------------------------------------------
+# Ordered lowering (one integer key that arrives in order: runs, no sort)
+# ---------------------------------------------------------------------------
+
+#: aggregates the ordered lowering reduces by segmented scans
+_RUN_FNS = frozenset({"count", "sum", "avg", "min", "max"})
+_RUN_ROW = 128      # lanes of a row of the blocked segmented scan
+_ORDER: dict = {}   # plan struct + rows -> the key column's order, last seen
+_ORDER_LOCK = threading.Lock()
+
+
+def _seg_scan(start, members):
+    """Inclusive scans of ``members`` — ``(values, op, fill)`` with ``op``
+    associative and ``fill`` its identity — that restart wherever
+    ``start`` is set, with no sort, gather or scatter: the slots as rows
+    of ``_RUN_ROW``, each row scanned by log steps of lane shifts, then the
+    rows' totals scanned the same way (one level per 128x fewer slots) and
+    carried into the rows that hold no start before a slot."""
+    n = start.shape[0]
+    rows = -(-n // _RUN_ROW)
+    pad = rows * _RUN_ROW - n
+
+    def grid(v, fill):
+        return jnp.concatenate(
+            [v, jnp.full((pad,), fill, v.dtype)]).reshape(rows, _RUN_ROW)
+
+    def shift(v, s, fill):
+        return jnp.concatenate(
+            [jnp.full((rows, s), fill, v.dtype), v[:, :_RUN_ROW - s]], axis=1)
+
+    f = grid(start, True)
+    vs = [grid(v, fill) for v, _, fill in members]
+    s = 1
+    while s < _RUN_ROW:
+        # (f, v) over (j - 2s, j] from its two halves: a start in the
+        # nearer half cuts the farther one off
+        vs = [jnp.where(f, v, op(shift(v, s, fill), v))
+              for v, (_, op, fill) in zip(vs, members)]
+        f = f | shift(f, s, False)
+        s *= 2
+    if rows > 1:
+        totals = _seg_scan(f[:, -1], [(v[:, -1], op, fill) for v, (
+            _, op, fill) in zip(vs, members)])
+        # what runs into a row from the rows before it
+        vs = [jnp.where(f, v, op(jnp.concatenate(
+            [jnp.full((1,), fill, v.dtype), t[:-1]])[:, None], v))
+            for v, t, (_, op, fill) in zip(vs, totals, members)]
+    return [v.reshape(-1)[:n] for v in vs]
+
+
+def _build_ordered_agg_program(agg_ops, val_kinds):
+    """The ordered grouped lowering, for one integer key column that
+    arrives in order (a fact table stored in its parent's key order): a
+    group is a run of equal keys, every aggregate a segmented scan over
+    the runs (:func:`_seg_scan`), read at the run's last slot — no sort,
+    no gather, no scatter. The result keeps the input's slots under a mask
+    (a run's last slot where the run holds a valid row), in key order, and
+    the key column itself is its key (the program returns none); the
+    program reports whether the keys were in order, and where they were
+    not its result is not used. Same aggregate semantics as the sorted
+    lowering: masked rows and NaN values vote nowhere, empty -> NULL."""
+    acc = _acc_dtype()
+    wide = jax.dtypes.canonicalize_dtype(jnp.int64)
+
+    def program(keys, vals, mask):
+        k = jnp.asarray(keys[0])
+        change = k[1:] != k[:-1]
+        one = jnp.ones((1,), jnp.bool_)
+        start = jnp.concatenate([one, change])
+        end = jnp.concatenate([change, one])
+        members, at = [], {}
+
+        def member(name, v, op, fill):
+            if name not in at:
+                at[name] = len(members)
+                members.append((v, op, jnp.asarray(fill, v.dtype)))
+            return at[name]
+
+        # a run votes where it holds a valid row; counts are scanned only
+        # where an aggregate reports one (a flag scan moves a byte a slot)
+        rows_any = member("rows?", mask, jnp.logical_or, False)
+        plan = []
+        for fn, s_i, _ in agg_ops:
+            if s_i < 0:                                   # count(*)
+                plan.append((fn, s_i, member(
+                    "rows", mask.astype(jnp.int32), jnp.add, 0), None))
+                continue
+            v = jnp.asarray(vals[s_i])
+            floating = val_kinds[s_i] == "f"
+            nn = jnp.logical_and(mask, jnp.logical_not(jnp.isnan(v))) \
+                if floating else mask
+            if fn in ("count", "avg"):
+                cnt = member(f"nn{s_i}" if floating else "rows",
+                             nn.astype(jnp.int32), jnp.add, 0)
+            else:          # the empty -> NULL rule of a float column only
+                cnt = member(f"nn{s_i}?", nn, jnp.logical_or, False) \
+                    if floating else None
+            if fn == "count":
+                plan.append((fn, s_i, cnt, None))
+            elif fn in ("sum", "avg"):
+                if floating or fn == "avg":
+                    x = jnp.where(nn, v.astype(acc), jnp.zeros((), acc))
+                    plan.append((fn, s_i, cnt, member(
+                        f"sum{s_i}", x, jnp.add, 0)))
+                else:
+                    x = jnp.where(mask, v, jnp.zeros_like(v)).astype(wide)
+                    plan.append((fn, s_i, cnt, member(
+                        f"isum{s_i}", x, jnp.add, 0)))
+            else:                                         # min / max
+                lo = fn == "min"
+                vi = v.astype(jnp.int32) if v.dtype == jnp.bool_ else v
+                if floating:
+                    fill = jnp.inf if lo else -jnp.inf
+                else:
+                    info = jnp.iinfo(vi.dtype)
+                    fill = info.max if lo else info.min
+                x = jnp.where(nn, vi, jnp.asarray(fill, vi.dtype))
+                plan.append((fn, s_i, cnt, member(
+                    f"{fn}{s_i}", x, jnp.minimum if lo else jnp.maximum,
+                    fill)))
+        runs = _seg_scan(start, members)
+        live = jnp.logical_and(end, runs[rows_any])
+        nan = jnp.asarray(jnp.nan, acc)
+        outs = []
+        for fn, s_i, cnt, m in plan:
+            if fn == "count":
+                outs.append(runs[cnt].astype(int_dtype()))
+                continue
+            v = jnp.asarray(vals[s_i])
+            r = runs[m]
+            if fn == "avg":
+                outs.append((r / runs[cnt]).astype(float_dtype()))
+            elif val_kinds[s_i] != "f":
+                outs.append(r.astype(int_dtype() if fn == "sum"
+                                     else v.dtype))
+            else:
+                outs.append(jnp.where(runs[cnt], r, nan).astype(v.dtype))
+        # the key column itself is the result's: no copy leaves the program
+        return ((), tuple(outs), jnp.sum(live, dtype=jnp.int32), live,
+                jnp.all(k[1:] >= k[:-1]))
+
+    return lambda: program
+
+
+# ---------------------------------------------------------------------------
 # Grouped aggregation entry point
 # ---------------------------------------------------------------------------
 
@@ -1539,6 +1693,12 @@ def grouped_agg(frame, keys, agg_list):
         n, b)
 
     S = min(_DENSE_MAX, max(2 * b, 16))
+    # the ordered lowering: one integer key, aggregates of the run family,
+    # and more rows than the exact threshold (the bucket IS n there: the
+    # program takes the slots as they are, whose order it checks)
+    run_ok = (not sharded and len(keys) == 1 and key_kinds[0] == "i"
+              and b == n and n > int(config.pipeline_exact_threshold)
+              and all(fn in _RUN_FNS for fn, _, _ in agg_ops))
 
     # Plan-stats observatory gate (ONE flag read; disabled = nothing
     # else) — the grouped engine records HOST-KNOWN group counts, so its
@@ -1690,7 +1850,41 @@ def grouped_agg(frame, keys, agg_list):
                         _stats_store.STORE.record_miss(f"GD{S}|{struct}")
                     except Exception:
                         pass
+        live = None
+        if g < 0 and run_ok:
+            # one integer key over more slots than the exact threshold:
+            # where it arrives in order (read once a struct and size, and
+            # checked again inside every run) its runs are its groups
+            order_key = f"{struct}|{n}"
+            with _ORDER_LOCK:
+                ordered = _ORDER.get(order_key)
+            if ordered is None:
+                from .joins import _in_order
+
+                syncs += 1
+                ordered = bool(_read_verdict(_in_order(keys_in[0]),
+                                             "grouped.order"))
+                if not ordered:
+                    counters.increment("grouped.order_miss")
+            if ordered:
+                before = counters.get("grouped.compile")
+                fn = _cached_plan(f"GO|{struct}", _build_ordered_agg_program(
+                    tuple(agg_ops), tuple(val_kinds)))
+                fn.stats_key = stats_key
+                key_outs, agg_outs, groups, live, held = _run_plan(
+                    fn, args, before, sp)
+                syncs += 1
+                g_h, ordered = (int(x) for x in _read_verdict((groups, held)))
+                if ordered:
+                    g = g_h
+                    counters.increment("grouped.ordered")
+                    sp.set(groups=g, lowering="ordered")
+                else:
+                    counters.increment("grouped.order_miss")
+            with _ORDER_LOCK:
+                _ORDER[order_key] = bool(ordered)
         if g < 0:
+            live = None
             before = counters.get("grouped.compile")
             fn = _cached_plan(f"GS|{struct}", _build_sorted_agg_program(
                 tuple(key_kinds), tuple(agg_ops), tuple(val_kinds)))
@@ -1704,6 +1898,13 @@ def grouped_agg(frame, keys, agg_list):
             stats_key, n, g, (time.perf_counter() - t_stats) * 1e3,
             counters.get("grouped.compile") - c_stats, syncs,
             card_key=cardinality_history_key("g", keys, key_arrs))
+
+    if live is not None:
+        # the ordered lowering's groups stand at their runs' last slots,
+        # under the key column they were read from
+        out = dict(zip(keys, key_arrs))
+        out.update((a.name, arr) for a, arr in zip(agg_list, agg_outs))
+        return Frame(out, mask=None if g == n else live)
 
     # one slice program for all k+m outputs: it retraces per distinct
     # group count, as each eager ``arr[:g]`` would (the slice length is

@@ -364,15 +364,20 @@ def _est_rel_flops(rel: _Rel, cat) -> Optional[float]:
 def _split_where(q, rels: list, rewrites: list) -> Optional[object]:
     """Predicate pushdown: assign single-relation conjuncts to their
     relation's ``pushed`` list; returns the residual WHERE."""
-    from .parser import _conjoin, _conjuncts
+    from .parser import _conjoin, _conjuncts, semi_join_in
 
     if q.where is None or not q.joins:
         return q.where
     keep = []
     pushed_any = False
+    binds = {r.bind for r in rels if r.bind}
     for c in _conjuncts(q.where):
         refs: set = set()
-        if not _walk(c, refs) or not refs:
+        # an IN subquery the executor runs as a semi join reads only its
+        # operand from the outer relations: it moves with that operand,
+        # and the relation's scan joins it before the joins above
+        walked = c.child if semi_join_in(c, binds) else c
+        if not _walk(walked, refs) or not refs:
             keep.append(c)
             continue
         targets = [_resolve_ref(name, rels) for name in refs]

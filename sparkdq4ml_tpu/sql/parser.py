@@ -60,12 +60,14 @@ Grammar (recursive descent):
 
 from __future__ import annotations
 
+import copy
 import math
 import re
 from typing import Optional
 
 from ..ops import expressions as E
 from ..utils import observability as _obs
+from ..utils.profiling import counters
 
 _TOKEN_RE = re.compile(
     r"\s*(?:"
@@ -1012,6 +1014,10 @@ class SubqueryIn(_AliasableSubquery):
         self.query = query
         self.negated = negated
 
+    def __str__(self):
+        return f"({self.child} {'NOT IN' if self.negated else 'IN'} " \
+            "(subquery))"
+
 
 class SubqueryExists(_AliasableSubquery):
     """``EXISTS (SELECT ...)`` — uncorrelated; resolved to a boolean
@@ -1407,6 +1413,85 @@ def _decorrelate_where(where, scope: dict, cat):
     return _conjoin(keep), joins
 
 
+def semi_join_in(c, aliases) -> bool:
+    """Whether a WHERE conjunct is planned as a left-semi join: a plain
+    (not negated) ``expr IN (SELECT one_column ...)`` whose subquery names
+    none of the relations ``aliases`` binds outside it, and whose ``expr``
+    reads a column. Spark's rewrite: the subquery's frame is the build
+    side, its one column the key against ``expr``. ``NOT IN`` keeps the
+    literal path, whose NULL rule is not an anti join's."""
+    if not isinstance(c, SubqueryIn) or c.negated:
+        return False
+    cols: set = set()
+    _referenced_cols(c.child, cols)
+    sub = c.query
+    return bool(cols) and not (
+        sub.where is not None
+        and _outer_refs(sub.where, aliases, _relation_aliases(sub)))
+
+
+def _in_list(child, frame, negated):
+    """``child [NOT] IN`` the one column of a subquery's frame, as the
+    literal list it reads to the host."""
+    cols = frame.columns
+    if len(cols) != 1:
+        raise ValueError("IN (subquery) must select exactly one "
+                         f"column, got {len(cols)}: {cols}")
+    values = frame.to_pydict()[cols[0]]
+    counters.increment("subquery.literal_in")
+    _obs.current_span().set(how="literal", build_rows=len(values))
+    return E.InList(child, [E.Lit(_pyval(v)) for v in values], negated)
+
+
+def _semi_join_in(where, frame, aliases, cat):
+    """The WHERE conjuncts :func:`semi_join_in` accepts, each run as a
+    left-semi join of ``frame`` against its subquery's frame (compacted
+    to its valid rows first: a HAVING leaves a grouped result sparse);
+    returns the rest of WHERE and the joined frame. A key pair the device
+    join does not take (a string, an integer against a float) keeps the
+    literal list instead. No value of a joined subquery reaches the
+    host: the join reads its row count, the compaction the build side's."""
+    from ..frame.frame import Frame, _is_string_col
+    from ..ops import joins as _joins
+
+    parts = _conjuncts(where)
+    if not any(semi_join_in(c, aliases) for c in parts):
+        return where, frame
+    rest = []
+    for c in parts:
+        if not semi_join_in(c, aliases):
+            rest.append(c)
+            continue
+        with _obs.span("sql.subquery.in", cat="sql") as s:
+            child = _resolve_subqueries(c.child, cat)
+            sub = _execute_subquery(c.query, cat)
+            if len(sub.columns) != 1:
+                rest.append(_in_list(child, sub, False))
+                continue
+            right = sub.columns[0]
+            left = child.name if isinstance(child, E.Col) \
+                and child.name in frame.columns else None
+            probe = frame if left is not None \
+                else frame.with_column("__in_key", child)
+            lk, rk = probe._data[left or "__in_key"], sub._data[right]
+            if _is_string_col(lk) or _is_string_col(rk) \
+                    or _joins.key_dtype(lk, rk) is None:
+                rest.append(_in_list(child, sub, False))
+                continue
+            rows = sub.num_slots
+            if not sub._every_slot_valid():
+                (rk,), mask, rows = _joins.compact_rows([rk], sub._mask)
+                sub = Frame({right: rk}, mask=mask)
+            key = left or "__in_key"
+            frame = probe.join(sub, on=key if key == right
+                               else [(key, right)], how="left_semi")
+            if left is None:
+                frame = frame.drop("__in_key")
+            counters.increment("subquery.semi_join")
+            s.set(how="left_semi", build_rows=rows)
+    return _conjoin(rest), frame
+
+
 def _execute_subquery(q: Query, cat):
     """Run a subquery, converting an outer-alias reference into the
     clear diagnosis: correlation is not supported — Spark itself
@@ -1438,14 +1523,10 @@ def _resolve_subqueries(expr, cat):
             raise ValueError("scalar subquery returned more than one row")
         return E.Lit(values[0] if values else math.nan)
     if isinstance(expr, SubqueryIn):
-        frame = _execute_subquery(expr.query, cat)
-        cols = frame.columns
-        if len(cols) != 1:
-            raise ValueError("IN (subquery) must select exactly one "
-                             f"column, got {len(cols)}: {cols}")
-        values = frame.to_pydict()[cols[0]]
-        return E.InList(_resolve_subqueries(expr.child, cat),
-                        [E.Lit(_pyval(v)) for v in values], expr.negated)
+        with _obs.span("sql.subquery.in", cat="sql"):
+            frame = _execute_subquery(expr.query, cat)
+            return _in_list(_resolve_subqueries(expr.child, cat), frame,
+                            expr.negated)
     if isinstance(expr, SubqueryExists):
         return E.Lit(_execute_subquery(expr.query, cat).count() > 0)
     if isinstance(expr, E.BinOp):
@@ -1712,6 +1793,19 @@ def plan_tree(q: Query) -> PlanNode:
         how = how if isinstance(how, str) else "inner"
         detail = f"[{how},build={hint}]" if hint else f"[{how}]"
         node = PlanNode("Join", detail, [node, scan_node(view)])
+    if q.where is not None:
+        # IN subqueries the executor runs as left-semi joins, after the
+        # joins and before the rest of WHERE
+        aliases = _relation_aliases(q)
+        parts = _conjuncts(q.where)
+        semi = [c for c in parts if semi_join_in(c, aliases)]
+        for c in semi:
+            node = PlanNode("Join", "[left_semi]", [node, PlanNode(
+                "Scan", "[(subquery)]", [plan_tree(c.query)])])
+        if semi:
+            q = copy.copy(q)
+            q.where = _conjoin([c for c in parts if not any(
+                c is s for s in semi)])
     if _structurally_fusable(q):
         node = PlanNode("FusedStage",
                         f"(Project[{len(q.items)}] <- Filter)", [node])
@@ -2837,8 +2931,12 @@ def _execute_single(q: Query, cat):
         q.where, corr_joins = _decorrelate_where(q.where, scope, cat)
         for right, keys, how in corr_joins:
             frame = frame.join(right, on=keys, how=how)
-    # Uncorrelated subqueries (scalar / IN / EXISTS) resolve to literals
-    # against the same catalog before the enclosing query evaluates.
+    # Uncorrelated ``expr IN (SELECT c ...)`` conjuncts: left-semi joins
+    # against the subquery's frame. The other uncorrelated subqueries
+    # (scalar / NOT IN / EXISTS, IN under OR) resolve to literals against
+    # the same catalog before the enclosing query evaluates.
+    if q.where is not None:
+        q.where, frame = _semi_join_in(q.where, frame, scope, cat)
     if q.where is not None:
         q.where = _resolve_subqueries(q.where, cat)
     if q.having is not None:

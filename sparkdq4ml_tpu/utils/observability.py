@@ -245,6 +245,16 @@ METRIC_NAMES = {
     "grouped.tile": ("counter", "grouped plans reduced by the dense "
                                 "lowering's tile tier"),
     "grouped.evict": ("counter", "grouped plan-cache LRU evictions"),
+    "grouped.ordered": ("counter", "grouped plans reduced by the ordered "
+                                   "lowering (one integer key stored in "
+                                   "order: runs, no sort)"),
+    "grouped.order_miss": ("counter", "grouped plans offered the ordered "
+                                      "lowering whose keys were out of "
+                                      "order"),
+    "subquery.semi_join": ("counter", "IN subqueries planned as left-semi "
+                                      "joins against their frame"),
+    "subquery.literal_in": ("counter", "IN subqueries read to the host as "
+                                       "a list of literals"),
     "join.device": ("counter", "joins planned, ordered and gathered by the "
                                "device program (ops/joins.py)"),
     "join.host": ("counter", "joins planned on the host from pulled masks "
