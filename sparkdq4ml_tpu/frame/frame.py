@@ -2001,15 +2001,22 @@ class Frame:
         order either by one sort of their concatenation or, where the
         probe side arrives in key order, by a merge: the build side
         sorted alone, the probe side in chunks sorted beside their share
-        of it. Shapes (one key column, a probe side of many chunks and
-        about twelve times the build side's slots) and the observed order of the
+        of it. Where an inner or semi join's build side is a few thousand
+        keys against such a probe side, a lookup takes the merge's place:
+        each sorted build key is searched into the probe keys and the
+        pairs are laid out from the runs found, with nothing of the probe
+        side's size sorted. Shapes (one key column, a probe side of many
+        chunks and about twelve times the build side's slots, for the
+        lookup far more) and the observed order of the
         input decide; no option does, and the result is the same bit for
         bit. The host reads one small array: the result's row count and,
-        behind a merge, whether its probe keys were in order and its
-        chunks had room — if not (``join.merge_miss``) the join runs once
-        more as the sort, and that signature's later runs start from what
-        was learnt. The span says which ``build_step`` the result came
-        from (``join.merge`` counts the merges). The result is a frame of
+        behind a merge or a lookup, whether its probe keys were in order
+        and its chunks or slots had room — where the order or a merge's
+        room did not hold (``join.merge_miss``) the join runs once more as
+        the sort, and that signature's later runs start from what was
+        learnt. The span
+        says which ``build_step`` the result came from (``join.merge``
+        and ``join.lookup`` count them). The result is a frame of
         a bucket of slots under a mask.
         String keys, an integer key against a float one, ``right`` /
         ``outer`` / ``cross``, a sharded side and host (string) columns to

@@ -19,14 +19,21 @@ bucket, build step) does all of it:
                     the probe side cut into chunks beside their share of
                     the sorted build rows, every chunk of 2^15 sorted by
                     itself (``_merge``) — on this chip a sort's cost
-                    follows its length steeply
+                    follows its length steeply. The **lookup**, for an
+                    ordered probe side against a few thousand build
+                    keys, takes neither: it sorts the build side alone
+                    and searches each of its keys into the probe keys
+                    (``_lookup``), so that nothing of probe size is
+                    sorted, scanned or compacted
 ``dq.join.probe``   per key group, by scans, where it starts and how
                     many valid build rows it has — on one TPU device and
                     32-bit keys ONE pass of the Pallas kernel
                     ``join_probe_scan``, elsewhere XLA's ``cumsum`` and two
                     ``cummax`` (``scan_lowering``); the probe rows the join
                     type selects, compacted by one single-operand sort of
-                    their positions
+                    their positions. Behind the lookup: its candidate
+                    pairs laid out in slots, the masked probe rows among
+                    them dropped and the rest compacted at slot size
 ``dq.join.gather``  selected probe rows x their group's build rows laid
                     out in ``bucket`` slots, brought into (left, right)
                     order by one stable sort at result size, and every
@@ -38,23 +45,36 @@ packing, no float detour. Memory is a constant number of n-row int32
 operands for n = left + right rows, and no ``(n, k)`` operand.
 
 The host reads one small array a join, after the program has run: the
-size of the result and, behind a merge, what the merge assumed — whether
-the probe keys were in order, and the build slots its fullest chunk
-asked for. Shapes, the key count and the observed order decide the build
-step; no option does. A join of one key column whose probe side has
-eight chunks' worth of slots and about twelve times the build side's is
-offered the merge, with a ``room`` of build slots a chunk taken from the shapes
-(``_first_room``); before such a signature's first program is built the
-probe side's order is read (one elementwise pass, one flag), so that no
-merge program is built for a probe side that arrives unordered. A merge
-that did not hold (``join.merge_miss``) runs once more as the sort, and
+size of the result and, behind a merge or a lookup, what it assumed —
+whether the probe keys were in order, and the build slots the merge's
+fullest chunk asked for or the lookup's candidate count. Shapes, the key
+count and the observed order decide the build step; no option does. A
+join of one key column whose probe side has eight chunks' worth of slots
+and about twelve times the build side's is offered the merge, with a
+``room`` of build slots a chunk taken from the shapes (``_first_room``);
+before such a signature's first program is built the probe side's order
+is read (one elementwise pass, one flag), so that no merge program is
+built for a probe side that arrives unordered. Where the join's result
+is of the build side's order of size (``inner``, the probe on either
+side, and ``left_semi``) and the build side is so small that searching
+each of its keys into the probe keys costs far less than a pass of the
+chunked sort (``_takes_lookup``: build slots x ceil(log2(probe slots)) x
+16 at most the probe slots), the lookup takes the merge's place; its
+``room`` is the slots its candidate pairs are laid out in, at first the
+result's bucket. Its one pass over the probe side is the order check
+(``_in_order``) in the same program, whose flag and candidate count ride
+the join's one read. An ordered build step that did not hold
+(``join.merge_miss``: the merge's or the lookup's probe side out of
+order, or a merge chunk over its room) runs once more as the sort, and
 its signature remembers what it learnt: the sort for a probe side out of
-order, the room the chunks asked for otherwise (``_ROOMS``). The program is likewise built for a result
+order, the room the chunks asked for otherwise (``_ROOMS``); candidates
+that outgrew the lookup's slots run once more in as many as they asked
+for, remembered likewise. The program is likewise built for a result
 ``bucket`` remembered from the join's last run (an estimate, like the
 statstore's); a result that outgrew it runs once more at the size it
 asked for. So a signature's second run in a process is the program it
 keeps. The result is a frame of ``bucket`` slots under a mask, bit for
-bit the same under either build step.
+bit the same under any build step.
 """
 
 from __future__ import annotations
@@ -83,7 +103,8 @@ _LOCK = threading.Lock()
 _PROGRAMS: dict = {}      # signature + (bucket, room) -> jitted program
 _BUCKETS: dict = {}       # signature -> result bucket of the last run
 _ROOMS: dict = {}         # signature -> build slots a chunk of the merge
-#                           since its last miss; 0: the build step sorts
+#                           (candidate slots of the lookup) since its last
+#                           miss; 0: the build step sorts
 
 
 def key_dtype(a, b):
@@ -128,6 +149,17 @@ def _first_room(k: int, nb: int, npr: int) -> int:
     return _room_for(even + even // 4)
 
 
+def _takes_lookup(how: str, k: int, nb: int, npr: int) -> bool:
+    """Whether shapes that offer the merge offer the lookup in its place:
+    a join whose result is of the build side's order of size (``inner``,
+    ``left_semi``; a ``left`` or anti join gives the probe side's) and a
+    build side whose binary searches into the probe keys, a gather a
+    step, cost far less than one pass of the chunked sort over the probe
+    side: ``nb * ceil(log2(npr)) * 16 <= npr``."""
+    return how in ("inner", "left_semi") and _first_room(k, nb, npr) > 0 \
+        and nb * (npr - 1).bit_length() * 16 <= npr
+
+
 def _chunks(npr: int, room: int) -> int:
     """Chunks of the merge for ``npr`` probe slots, a multiple of eight:
     the scans behind it then see whole tiles (a program for 2.4e8 slots
@@ -146,6 +178,22 @@ def _in_order(keys):
     """Whether a key column is non-decreasing over all its slots, masked
     ones too (a NaN key: no)."""
     return jnp.all(keys[1:] >= keys[:-1])
+
+
+def _sorted_build(bkey, bvalid):
+    """The build side alone in (key, row) order, its valid rows first —
+    a masked row or a NaN key carries the largest key and a tag over
+    every valid one's — and how many are valid: ``(keys, tags, held)``."""
+    nb = bkey.shape[0]
+    floating = np.dtype(bkey.dtype).kind == "f"
+    top = jnp.asarray(np.inf if floating else np.iinfo(bkey.dtype).max,
+                      bkey.dtype)
+    if floating:
+        bvalid = bvalid & ~jnp.isnan(bkey)
+    btag = lax.iota(jnp.uint32, nb)
+    sk, st = lax.sort((jnp.where(bvalid, bkey, top),
+                       jnp.where(bvalid, btag, btag | _HIGH)), num_keys=2)
+    return sk, st, jnp.sum(bvalid, dtype=jnp.int32)
 
 
 def _merge(bkey, bvalid, pkey, pvalid, room: int):
@@ -168,17 +216,7 @@ def _merge(bkey, bvalid, pkey, pvalid, room: int):
     nb, npr = bkey.shape[0], pkey.shape[0]
     part = _CHUNK - room
     chunks = _chunks(npr, room)
-    floating = np.dtype(bkey.dtype).kind == "f"
-    top = jnp.asarray(np.inf if floating else np.iinfo(bkey.dtype).max,
-                      bkey.dtype)
-    if floating:
-        bvalid = bvalid & ~jnp.isnan(bkey)
-    btag = lax.iota(jnp.uint32, nb)
-    # valid rows first: a masked row carries the largest key and a tag
-    # over every valid one's
-    sk, st = lax.sort((jnp.where(bvalid, bkey, top),
-                       jnp.where(bvalid, btag, btag | _HIGH)), num_keys=2)
-    held = jnp.sum(bvalid, dtype=jnp.int32)
+    sk, st, held = _sorted_build(bkey, bvalid)
 
     ptag = lax.iota(jnp.uint32, npr) + np.uint32(nb)
     ptag = jnp.where(pvalid, ptag, ptag | _HIGH)
@@ -209,6 +247,55 @@ def _merge(bkey, bvalid, pkey, pvalid, room: int):
          jnp.concatenate([jnp.where(fits, bt, ~np.uint32(0)), pt], axis=1)),
         dimension=1, num_keys=2)
     return ks.reshape(-1), ts.reshape(-1), ordered, jnp.max(count)
+
+
+def _lookup(bkey, bvalid, pkey, expand: bool, room: int):
+    """The build step for a few build keys against a probe side that
+    arrives in key order: ``room`` candidate pairs ``(prow, brow, live)``
+    in (probe row, build row) order, and how many candidates there are
+    (over ``room``: the slots are too few, and the pairs incomplete).
+
+    On an ordered probe side a key's probe rows are one run. The valid
+    build rows are sorted alone in (key, row) order — masked rows and NaN
+    keys match nothing in any device join type and are left out — and
+    each key group's run ``[lo, hi)`` of probe slots found by two binary
+    searches of its key. With ``expand`` (``inner``: every probe row
+    pairs with each of its key's build rows) a group of ``m`` build rows
+    gives ``(hi - lo) * m`` candidates, else ``hi - lo``; slot ``s``
+    finds its group by a search over the groups' running totals and
+    pairs probe row ``lo + s // m`` with the group's ``s % m``-th build
+    row. Groups stand in key order, so the pairs come in probe row
+    order. Masked probe slots are candidates too: the caller drops them."""
+    nb = bkey.shape[0]
+    sk, st, held = _sorted_build(bkey, bvalid)
+    at = lax.iota(jnp.int32, nb)
+    first = (at < held) & jnp.concatenate(
+        [jnp.ones((1,), jnp.bool_), sk[1:] != sk[:-1]])
+    # a group's build rows are [at, the next group's first row)
+    after = jnp.concatenate([lax.cummin(jnp.where(first, at, held),
+                                        reverse=True)[1:], held[None]])
+    m = jnp.where(first, after - at, 0)
+    lo, hi = (jnp.searchsorted(pkey, sk, side=side, method="scan")
+              .astype(jnp.int32) for side in ("left", "right"))
+    cand = jnp.where(first, hi - lo, 0)
+    if expand:
+        cand = cand * m
+    ends = jnp.cumsum(cand, dtype=jnp.int32)
+    slot = lax.iota(jnp.int32, room)
+    group = jnp.minimum(jnp.searchsorted(ends, slot, side="right",
+                                         method="scan"), nb - 1) \
+        .astype(jnp.int32)
+    within = slot - (jnp.take(ends, group) - jnp.take(cand, group))
+    prow = jnp.take(lo, group)
+    brow = None
+    if expand:
+        each = jnp.maximum(jnp.take(m, group), 1)
+        prow = prow + within // each
+        brow = (jnp.take(st, group + within % each, mode="clip")
+                & ~_HIGH).astype(jnp.int32)
+    else:
+        prow = prow + within
+    return prow, brow, slot < ends[-1], ends[-1]
 
 
 def _compact(sel, n: int, bucket: int):
@@ -503,16 +590,28 @@ def _scans_pallas(ks, ts, nb: int, block: int = SCAN_BLOCK,
         interpret=interpret, name="join_probe_scan")(*ks, ts))
 
 
+def _build_step(how: str, k: int, nb: int, npr: int, room: int) -> str:
+    """The build step a signature's program takes at ``room``: the sort at
+    0, else the lookup where shapes offer it, else the merge."""
+    if not room:
+        return "sort"
+    return "lookup" if _takes_lookup(how, k, nb, npr) else "merge"
+
+
 def _build_program(how: str, dtypes: tuple, nb: int, npr: int, bucket: int,
                    probe_is_left: bool, room: int, scan: str):
     """The jitted join: (build keys, build mask, probe keys, probe mask,
     build columns, probe columns) -> (left rows' columns, right rows'
     columns, slot mask, verdict, missing-right flags or None). The
     verdict is what the host reads: the result size and, where the build
-    step merges (``room`` > 0), whether the merge held and the room its
-    fullest chunk asked for."""
+    step merges or looks up (``room`` > 0), whether the probe keys were in
+    order and the room its fullest chunk asked for, or the candidates the
+    lookup laid out."""
     k = len(dtypes)
-    n = _pairs(nb, npr, room)
+    step = _build_step(how, k, nb, npr, room)
+    # the pairs the sort and the merge walk; the lookup walks none, and
+    # its room is its candidates' slots, not a chunk's build slots
+    n = 0 if step == "lookup" else _pairs(nb, npr, room)
 
     def canon(col, dt):
         col = col.astype(dt)
@@ -528,6 +627,51 @@ def _build_program(how: str, dtypes: tuple, nb: int, npr: int, bucket: int,
             # a NaN key needs no flag: NaN != NaN, so it is a key group of
             # its own and matches nothing (a left or anti join keeps it)
             bvalid, pvalid = bmask, pmask
+
+            def in_order_of_rows(prow, brow, missing, live):
+                # (left, right) order: a left row's slots are one run with
+                # its right rows ascending, so a stable sort by the left
+                # row alone orders both; dead slots go last
+                lrow, rrow = (prow, brow) if probe_is_left \
+                    else (brow, prow)
+                lrow = jnp.where(live, lrow, _INT_MAX)
+                rest = [x for x in (rrow, missing) if x is not None]
+                lrow, *rest = lax.sort((lrow, *rest), num_keys=1,
+                                       is_stable=True)
+                if rrow is not None:
+                    rrow = rest[0]
+                if missing is not None:
+                    missing = rest[1]
+                prow, brow = (lrow, rrow) if probe_is_left \
+                    else (rrow, lrow)
+                pout = [jnp.take(col, prow, axis=0, mode="clip")
+                        for col in pcols]
+                bout = [jnp.take(col, brow, axis=0, mode="clip")
+                        for col in bcols]
+                return pout, bout, missing
+
+            if step == "lookup":
+                with _obs.scope("join.build"):
+                    # the build side sorted alone and its keys searched
+                    # into the probe keys: the candidate pairs in (probe
+                    # row, build row) order
+                    prow, brow, fits, total = _lookup(
+                        bkeys[0], bvalid, pkeys[0], how == "inner", room)
+                    verdict = [_in_order(pkeys[0]).astype(jnp.int32),
+                               total]
+                with _obs.scope("join.probe"):
+                    # the candidates on masked probe rows dropped, the
+                    # rest compacted at slot size
+                    keep = fits & jnp.take(pvalid, prow, mode="clip")
+                    size = jnp.sum(keep, dtype=jnp.int32)
+                    at = _compact(keep, room, bucket)
+                    prow = jnp.take(prow, at, mode="clip")
+                    if brow is not None:
+                        brow = jnp.take(brow, at, mode="clip")
+                with _obs.scope("join.gather"):
+                    live = lax.iota(jnp.int32, bucket) < size
+                    pout, bout, _ = in_order_of_rows(prow, brow, None, live)
+                return pout, bout, live, jnp.stack([size, *verdict]), None
 
             with _obs.scope("join.build"):
                 # both sides' keys in (key, tag) order; the tag is the
@@ -608,25 +752,8 @@ def _build_program(how: str, dtypes: tuple, nb: int, npr: int, bucket: int,
                     if how != "left":
                         missing = None
                 live = slot < size
-                # (left, right) order: a left row's slots are one run with
-                # its right rows ascending, so a stable sort by the left
-                # row alone orders both; dead slots go last
-                lrow, rrow = (prow, brow) if probe_is_left \
-                    else (brow, prow)
-                lrow = jnp.where(live, lrow, _INT_MAX)
-                rest = [x for x in (rrow, missing) if x is not None]
-                lrow, *rest = lax.sort((lrow, *rest), num_keys=1,
-                                       is_stable=True)
-                if rrow is not None:
-                    rrow = rest[0]
-                if missing is not None:
-                    missing = rest[1]
-                prow, brow = (lrow, rrow) if probe_is_left \
-                    else (rrow, lrow)
-                pout = [jnp.take(col, prow, axis=0, mode="clip")
-                        for col in pcols]
-                bout = [jnp.take(col, brow, axis=0, mode="clip")
-                        for col in bcols]
+                pout, bout, missing = in_order_of_rows(prow, brow, missing,
+                                                       live)
             return pout, bout, live, jnp.stack([size, *verdict]), missing
 
     return jax.jit(program)
@@ -663,16 +790,21 @@ def device_join(how: str, lkeys, lmask, rkeys, rmask, lcols, rcols,
     if room is None:
         # first run: where shapes offer the merge, the probe side's
         # order today (one elementwise pass, one flag read) decides
-        # which program is built
+        # which program is built; the lookup lays its candidates out in
+        # the result's bucket first
         room = _first_room(len(dtypes), nb, npr)
+        if room and _takes_lookup(how, len(dtypes), nb, npr):
+            room = bucket
         if room and not _read_verdict(_in_order(pkeys[0]), "join.order"):
             counters.increment("join.merge_miss")
             room = 0
         with _LOCK:
             _ROOMS[sig] = room
     while True:
-        scan = scan_lowering(list(bkeys) + list(pkeys), dtypes,
-                             _pairs(nb, npr, room))
+        step = _build_step(how, len(dtypes), nb, npr, room)
+        # (the lookup runs no probe scans)
+        scan = None if step == "lookup" else scan_lowering(
+            list(bkeys) + list(pkeys), dtypes, _pairs(nb, npr, room))
         with _LOCK:
             fn = _PROGRAMS.get(sig + (bucket, room, scan))
             if fn is None:
@@ -686,9 +818,16 @@ def device_join(how: str, lkeys, lmask, rkeys, rmask, lcols, rcols,
             counters.increment("join.hit")
         counters.increment("join.rows_probed", npr)
         # THE read of a device join, a counted frame boundary like the
-        # grouped verdict: the result's row count and, behind a merge,
-        # what it assumed — one to three scalars in one array
+        # grouped verdict: the result's row count and, behind a merge or a
+        # lookup, what it assumed — one to three scalars in one array
         verdict = _read_verdict(verdict, "join.verdict")
+        if step == "lookup" and verdict[1] and verdict[2] > room:
+            # in order, but more candidates than slots: once more, with
+            # slots for them all (the row count read is short of them)
+            room = result_bucket(int(verdict[2]))
+            with _LOCK:
+                _ROOMS[sig] = room
+            continue
         if room and not (verdict[1] and verdict[2] <= room):
             # out of order after all, or a chunk over its room: once more,
             # as a sort; the next run merges with the room this one asked
@@ -708,7 +847,10 @@ def device_join(how: str, lkeys, lmask, rkeys, rmask, lcols, rcols,
         bucket = want                     # outgrew its bucket: once more
     if scan == "pallas":
         counters.increment("join.scan_pallas")
-    if room:
+    if step == "lookup":
+        counters.increment("join.lookup")
+        _obs.current_span().set(build_step="lookup", room=room)
+    elif room:
         counters.increment("join.merge")
         _obs.current_span().set(build_step="merge", room=room,
                                 probe_scan=scan)

@@ -266,9 +266,12 @@ METRIC_NAMES = {
     "join.hit": ("counter", "device join runs served by a built program"),
     "join.merge": ("counter", "device joins whose build step merged (a "
                               "probe side in key order, sorted in chunks)"),
-    "join.merge_miss": ("counter", "merge programs that found the probe "
-                                   "side out of order or a chunk over its "
-                                   "room, and ran again as a sort"),
+    "join.merge_miss": ("counter", "ordered build steps (merge, lookup) "
+                                   "that found the probe side out of order, "
+                                   "or a merge chunk over its room, and "
+                                   "ran again as a sort"),
+    "join.lookup": ("counter", "device joins whose build step searched a "
+                               "few build keys into an ordered probe side"),
     "join.scan_pallas": ("counter", "device joins whose probe scans ran in "
                                     "the Pallas kernel join_probe_scan"),
     "grouped.shard_gather": ("counter",

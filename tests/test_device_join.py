@@ -431,6 +431,40 @@ def test_the_first_room_at_the_benchmarks_shapes():
     assert mod._first_room(1, 20_000, 80_000) == 0
 
 
+def test_the_lookup_at_the_benchmarks_shapes():
+    from sparkdq4ml_tpu.ops import joins as mod
+    from sparkdq4ml_tpu.ops.compiler import result_bucket
+
+    orders = result_bucket(2_545)            # the orders Q18's HAVING keeps
+    assert orders == 2_560
+    # tpch_q18_volume: the semi join of orders against the kept keys, the
+    # customer join's result against lineitem (probe on the right), and
+    # customer probed against the orders that passed
+    assert mod._takes_lookup("left_semi", 1, orders, 60_000_000)
+    assert mod._takes_lookup("inner", 1, orders, 240_048_600)
+    assert mod._takes_lookup("inner", 1, orders, 6_000_000)
+    for nb, npr in ((orders, 60_000_000), (orders, 240_048_600),
+                    (orders, 6_000_000)):
+        assert mod._build_step("inner", 1, nb, npr,
+                               mod._first_room(1, nb, npr)) == "lookup"
+        # a left or anti join's result is the probe side's size
+        assert mod._build_step("left", 1, nb, npr,
+                               mod._first_room(1, nb, npr)) == "merge"
+    # tpch_q3_join: the customer join's result against lineitem keeps the
+    # merge, customer against orders the sort
+    assert not mod._takes_lookup("inner", 1, 6_291_456, 240_048_600)
+    assert mod._build_step("inner", 1, 6_291_456, 240_048_600,
+                           1_280) == "merge"
+    assert not mod._takes_lookup("inner", 1, 6_000_000, 60_000_000)
+    assert mod._build_step("inner", 1, 6_000_000, 60_000_000,
+                           mod._first_room(1, 6_000_000, 60_000_000)) \
+        == "sort"
+    # the merge's own tests: NB against NPR at rows of 128, and 2e4
+    # against 3e5 at rows of 2^15
+    assert not mod._takes_lookup("inner", 1, NB, NPR)
+    assert not mod._takes_lookup("inner", 1, 20_000, 300_000)
+
+
 @pytest.mark.parametrize("how", ["inner", "left_anti"])
 def test_the_merge_at_its_real_row_length(how):
     """Rows of 2^15, as on the chip: 3e5 ordered probe slots (four lines
@@ -479,6 +513,242 @@ def test_random_tables_join_alike_under_both_build_steps(joins, seed):
                 + delta.get("join.merge_miss", 0)
     # every join whose probe side was the ordered one tried the merge
     assert tried >= 4
+
+
+# ---------------------------------------------------------------------------
+# The lookup: a few build keys searched into a probe side in key order
+# ---------------------------------------------------------------------------
+
+#: probe and build slots at which rows of 128 offer the lookup
+LNPR, LNB = 24_000, 64
+
+
+def lookup_sides(case):
+    """(probe frame, build frame, the build step the probe side gets) at
+    ``LNPR`` x ``LNB``: about three probe rows a key, multiples of 3."""
+    r = np.random.default_rng(len(case))
+    pk = np.sort(r.integers(0, 8_000, LNPR)).astype(np.int32) * 3
+    keys = np.unique(pk)
+    bk = r.permutation(keys[::100][:LNB])        # a foreign key
+    pmask = bmask = None
+    step = "lookup"
+    if case == "duplicate_build_keys":
+        bk = r.permutation(np.resize(keys[::300], LNB))
+        assert len(np.unique(bk)) < LNB / 2
+    elif case == "a_long_probe_run_against_duplicate_build_keys":
+        # 3,000 probe rows of one key x three build rows: more candidates
+        # than the first slots hold
+        pk[5_000:8_000] = pk[5_000]
+        bk = np.concatenate([bk[:-3], [pk[5_000]] * 3])
+    elif case == "masked_rows_on_both_sides":
+        pmask, bmask = r.random(LNPR) < 0.6, r.random(LNB) < 0.7
+        pmask[0] = pmask[-1] = False
+    elif case == "build_keys_below_above_and_between":
+        bk = r.permutation(np.concatenate(
+            [r.integers(-50, 0, 16), pk.max() + 1 + r.integers(0, 50, 16),
+             keys[::200][:16] + 1, keys[::200][:16]]))
+    elif case == "an_empty_build_side_after_its_mask":
+        bmask = np.zeros(LNB, bool)
+    elif case == "float_keys_with_signed_zeros_and_a_build_nan":
+        pk = pk.astype(np.float32) - 3_000.0
+        at = np.searchsorted(pk, 0.0)
+        pk[at:at + 4] = [-0.0, 0.0, -0.0, 0.0]
+        bk = np.concatenate([bk[:-4].astype(np.float32) - 3_000.0,
+                             [0.0, -0.0, np.nan, np.inf]])
+    elif case == "a_nan_among_the_probe_keys":
+        pk = pk.astype(np.float32)
+        pk[12_345] = np.nan            # no order holds: the sort answers
+        bk = np.concatenate([bk[:-1], [np.nan]])
+        step = "miss"
+    elif case != "foreign_keys_drawn_from_the_probe":
+        raise KeyError(case)
+    assert len(bk) == LNB
+    probe = Frame({"k": pk, "a": r.normal(size=LNPR).astype(np.float32)},
+                  mask=pmask)
+    build = Frame({"k": bk.astype(pk.dtype),
+                   "b": np.arange(LNB, dtype=np.float32)}, mask=bmask)
+    return probe, build, step
+
+
+LOOKUP_CASES = (
+    "foreign_keys_drawn_from_the_probe", "duplicate_build_keys",
+    "a_long_probe_run_against_duplicate_build_keys",
+    "masked_rows_on_both_sides", "build_keys_below_above_and_between",
+    "an_empty_build_side_after_its_mask",
+    "float_keys_with_signed_zeros_and_a_build_nan",
+    "a_nan_among_the_probe_keys",
+)
+
+
+@pytest.mark.parametrize("how, probe_is", [
+    ("inner", "left"), ("inner", "right"), ("left_semi", "left")])
+@pytest.mark.parametrize("case", LOOKUP_CASES)
+def test_the_lookup_gives_the_sorts_join_bit_for_bit(joins, case, how,
+                                                      probe_is):
+    probe, build, step = lookup_sides(case)
+    assert joins._takes_lookup(how, 1, LNB, LNPR)
+    # (an inner join builds from the side with fewer slots)
+    left, right = (probe, build) if probe_is == "left" else (build, probe)
+    want, by_sort = run_join(joins, left, right, how, sort_only=True)
+    got, by_lookup = run_join(joins, left, right, how)
+    assert "join.lookup" not in by_sort
+    assert by_lookup.get("join.lookup", 0) == (step == "lookup")
+    assert by_lookup.get("join.merge_miss", 0) == (step == "miss")
+    assert "join.merge" not in by_lookup
+    assert by_lookup["join.device"] == 1 and "join.host" not in by_lookup
+    same_rows(got, want)
+    # the same slots, not only the same rows
+    assert got.num_slots == want.num_slots
+    assert np.array_equal(np.asarray(got._mask), np.asarray(want._mask))
+    if case in ("foreign_keys_drawn_from_the_probe",
+                "a_long_probe_run_against_duplicate_build_keys"):
+        assert got.count() >= LNB                     # it joined something
+
+
+def unique_probe(r, n=LNPR):
+    """``n`` probe rows, one a key, in key order, and ``LNB`` build rows
+    whose keys each meet one of them: the result fits the first bucket."""
+    pk = np.arange(n, dtype=np.int32) * 3
+    probe = Frame({"k": pk, "a": r.normal(size=n).astype(np.float32)})
+    build = Frame({"k": r.permutation(pk)[:LNB],
+                   "b": np.arange(LNB, dtype=np.float32)})
+    return probe, build
+
+
+@pytest.mark.parametrize("how", ["inner", "left_semi"])
+def test_an_ordered_probe_against_few_keys_counts_a_lookup(joins, how):
+    probe, build = unique_probe(np.random.default_rng(21))
+    want = probe._host_join(build, ["k"], how, False, None)
+    got = []
+    before = counters.snapshot()
+    (span,) = spans_of(lambda: got.append(probe.join(build, "k", how)))
+    delta = moved(before)
+    same_rows(got[0], want)
+    assert delta["join.lookup"] == 1 and "join.merge" not in delta
+    assert "join.merge_miss" not in delta and "join.scan_pallas" not in delta
+    assert span.attrs["build_step"] == "lookup"
+    (room,) = joins._ROOMS.values()
+    assert span.attrs["room"] == room > 0
+    # the order flag before the first program; then size, order and the
+    # candidate count in the join's one read
+    assert delta["join.compile"] == 1 and delta["host.reads"] == 2
+    assert delta["host.read_bytes"] == 1 + 12
+    # settled: the second run builds nothing and reads once
+    before = counters.snapshot()
+    again = probe.join(build, "k", how)
+    delta = moved(before)
+    same_rows(again, want)
+    assert delta["join.hit"] == 1 and "join.compile" not in delta
+    assert delta["join.lookup"] == 1 and delta["host.reads"] == 1
+    assert delta["host.read_bytes"] == 12
+
+
+def test_a_settled_lookup_that_meets_an_unordered_probe_sorts_it(joins):
+    probe, build = unique_probe(np.random.default_rng(22))
+    probe.join(build, "k", "inner")                # settles on the lookup
+    d = probe.to_pydict()
+    at = np.random.default_rng(7).permutation(LNPR)
+    shuffled = Frame({"k": d["k"][at], "a": d["a"][at]})
+    want = shuffled._host_join(build, ["k"], "inner", False, None)
+    before = counters.snapshot()
+    got = []
+    (span,) = spans_of(lambda: got.append(
+        shuffled.join(build, "k", "inner")))
+    delta = moved(before)
+    same_rows(got[0], want)
+    # the lookup program itself found it, in its one read; then the sort
+    assert delta["join.merge_miss"] == 1 and "join.lookup" not in delta
+    assert delta["join.compile"] == 1 and delta["join.hit"] == 1
+    assert delta["host.reads"] == 2 and delta["host.read_bytes"] == 12 + 4
+    assert span.attrs["build_step"] == "sort"
+    before = counters.snapshot()
+    again = shuffled.join(build, "k", "inner")
+    delta = moved(before)
+    same_rows(again, want)
+    assert "join.merge_miss" not in delta and "join.lookup" not in delta
+    assert delta["join.hit"] == 1 and "join.compile" not in delta
+    assert delta["host.reads"] == 1 and delta["host.read_bytes"] == 4
+
+
+def test_candidates_over_their_slots_run_once_more_and_then_fit(joins):
+    probe, build, _ = lookup_sides(
+        "a_long_probe_run_against_duplicate_build_keys")
+    want = probe._host_join(build, ["k"], "inner", False, None)
+    before = counters.snapshot()
+    same_rows(probe.join(build, "k", "inner"), want)
+    delta = moved(before)
+    # the lookup at the first bucket's slots, again at the candidates'
+    # (no miss: the order held), again at the result's bucket
+    assert delta["join.lookup"] == 1 and "join.merge_miss" not in delta
+    assert delta["join.compile"] == 3
+    (room,), (bucket,) = joins._ROOMS.values(), joins._BUCKETS.values()
+    assert room >= bucket >= 9_000 > LNB
+    before = counters.snapshot()
+    again = probe.join(build, "k", "inner")
+    delta = moved(before)
+    same_rows(again, want)
+    assert delta["join.hit"] == 1 and "join.compile" not in delta
+    assert delta["join.lookup"] == 1 and delta["host.reads"] == 1
+
+
+#: (probe slots, build keys, probe rows a key) at which the lookup's room
+#: is one whole row of the chunked sorts: the first bucket of the build
+#: keys, or the bucket of the candidates a rerun asks for
+WHOLE_ROW = {
+    (128, "first_bucket"): (40_000, 120, 1),
+    (128, "rerun"): (40_000, 32, 4),
+    (1 << 15, "first_bucket"): (12_000_000, 30_000, 1),
+    (1 << 15, "rerun"): (4_000_000, 8_192, 4),
+}
+
+
+@pytest.mark.parametrize("chunk, how, probe_is", [
+    (128, "inner", "left"), (128, "inner", "right"),
+    (128, "left_semi", "left"),
+    (1 << 15, "inner", "left"), (1 << 15, "left_semi", "left")])
+@pytest.mark.parametrize("room_from", ["first_bucket", "rerun"])
+def test_a_lookup_room_of_a_whole_row_gives_the_sorts_join(
+        monkeypatch, joins, room_from, chunk, how, probe_is):
+    # the room is the lookup's slots, not a chunk's build slots: a room
+    # of a whole row builds no chunks of the merge
+    monkeypatch.setattr(joins, "_CHUNK", chunk)
+    npr, nb, run = WHOLE_ROW[chunk, room_from]
+    assert joins._takes_lookup(how, 1, nb, npr)
+    r = np.random.default_rng(nb)
+    pk = (np.arange(npr, dtype=np.int32) // run) * 3
+    probe = Frame({"k": pk})
+    build = Frame({"k": r.choice(pk[::run], nb, replace=False),
+                   "b": np.arange(nb, dtype=np.float32)})
+    left, right = (probe, build) if probe_is == "left" else (build, probe)
+    want, _ = run_join(joins, left, right, how, sort_only=True)
+    got, by_lookup = run_join(joins, left, right, how)
+    assert by_lookup["join.lookup"] == 1
+    assert "join.merge_miss" not in by_lookup
+    (room,) = joins._ROOMS.values()
+    assert room == chunk
+    # the first bucket held the candidates, or they ran once more in a
+    # row's slots and the result once more in its own bucket
+    assert by_lookup["join.compile"] == (1 if room_from == "first_bucket"
+                                         else 3)
+    same_rows(got, want)
+    assert got.num_slots == want.num_slots
+    assert np.array_equal(np.asarray(got._mask), np.asarray(want._mask))
+    assert got.count() == nb * run          # every key meets its run
+
+
+@pytest.mark.parametrize("how", ["left", "left_anti"])
+def test_left_and_anti_joins_never_take_the_lookup(joins, how):
+    probe, build, _ = lookup_sides("foreign_keys_drawn_from_the_probe")
+    assert not joins._takes_lookup(how, 1, LNB, LNPR)
+    assert joins._takes_lookup("inner", 1, LNB, LNPR)
+    want = probe._host_join(build, ["k"], how, False, None)
+    got = []
+    before = counters.snapshot()
+    (span,) = spans_of(lambda: got.append(probe.join(build, "k", how)))
+    delta = moved(before)
+    same_rows(got[0], want)
+    assert "join.lookup" not in delta and delta["join.merge"] == 1
+    assert span.attrs["build_step"] == "merge"
 
 
 def test_emission_order_is_left_then_right_row_order():

@@ -37,13 +37,11 @@ def _snapshot():
         "join.device", "grouped.fallback")}
 
 
-@pytest.fixture
-def tpch(session):
-    """The three tables at a tiny scale factor, by dbgen's shapes: sparse
+def tpch_tables(session, n_cust, n_orders, seed=18):
+    """The three tables at a small scale factor, by dbgen's shapes: sparse
     order keys in key order, 1..7 lines an order, lineitem in order-key
-    order, whole quantities 1..50, float32 prices."""
-    rng = np.random.default_rng(18)
-    n_cust, n_orders = 300, 3_000
+    order, whole quantities 1..50, float32 prices; registered as views."""
+    rng = np.random.default_rng(seed)
     index = np.arange(n_orders)
     okey = ((index // 8) * 32 + index % 8 + 1).astype(np.int32)
     counts = rng.integers(1, 8, n_orders)
@@ -63,6 +61,13 @@ def tpch(session):
     }
     for name, cols in tables.items():
         session.create_data_frame(cols).create_or_replace_temp_view(name)
+    return tables
+
+
+@pytest.fixture
+def tpch(session):
+    """The three tables at a tiny scale factor."""
+    tables = tpch_tables(session, 300, 3_000)
     yield tables
     for name in tables:
         session.catalog.drop(name)
@@ -102,6 +107,35 @@ def test_q18_through_sql_equals_numpy(session, tpch, quantity):
         assert np.array_equal(np.asarray(got[name]), want[name]), name
     if quantity < 300:
         assert len(want["o_orderkey"]) >= 24
+
+
+def test_q18_joins_a_few_thousand_orders_by_lookups(session):
+    """At 270,000 customers and orders (eight rows of 2^15 and more on
+    every probe side) the published threshold keeps a few dozen orders:
+    the semi join, the customer join and the ``lineitem`` join each
+    search those keys into a probe side stored in key order."""
+    from sparkdq4ml_tpu.ops import joins
+
+    tables = tpch_tables(session, 270_000, 270_000, seed=44)
+    try:
+        before = counters.snapshot()
+        got = session.sql(Q18.format(quantity=300, limit=100)).to_pydict()
+        now = counters.snapshot()
+    finally:
+        for name in tables:
+            session.catalog.drop(name)
+    delta = {k: now[k] - before.get(k, 0) for k in now
+             if now[k] != before.get(k, 0)}
+    assert delta["join.lookup"] == 3 and delta["join.device"] == 3
+    assert "join.merge" not in delta and "join.merge_miss" not in delta
+    assert "join.host" not in delta and "grouped.fallback" not in delta
+    want = q18_reference(tables, 300, 100)
+    assert 8 <= len(want["o_orderkey"]) <= 100
+    assert joins._takes_lookup("left_semi", 1, len(want["o_orderkey"]),
+                               270_000)
+    assert list(got) == list(want)
+    for name in want:
+        assert np.array_equal(np.asarray(got[name]), want[name]), name
 
 
 def test_q18_plan_shows_the_semi_join_and_reads_no_subquery_value(
