@@ -35,7 +35,11 @@ pipeline compiler (``ops/compiler.py``) removed it for expression chains:
     with no sort, gather or scatter, the result left in the input's slots
     under a mask. The order is read once a plan struct and size
     (``grouped.order``) and checked again inside every run; keys out of
-    order take the sorted program (``grouped.order_miss``).
+    order take the sorted program (``grouped.order_miss``). On one TPU
+    device with a 32-bit key and 32-bit members the whole reduction is
+    one pass of the Pallas kernel ``grouped_run_scan``
+    (``grouped.run_pallas``, ``scan="pallas"`` on the span); elsewhere
+    XLA's segmented scans.
 
   The only dynamic quantity — the group count (plus the dense path's
   "did the range fit" verdict) — leaves the device as ONE scalar sync at
@@ -1426,6 +1430,17 @@ _RUN_FNS = frozenset({"count", "sum", "avg", "min", "max"})
 _RUN_ROW = 128      # lanes of a row of the blocked segmented scan
 _ORDER: dict = {}   # plan struct + rows -> the key column's order, last seen
 _ORDER_LOCK = threading.Lock()
+#: slots of a vreg, which the kernel ``grouped_run_scan`` scans as 8 rows
+#: of 128
+_RUN_VREG = 8 * 128
+#: vregs a turn of the kernel's loop scans side by side
+RUN_UNROLL = 16
+#: the most slots a grid step of the kernel reads: fewer where the plan
+#: streams more columns, so that their blocks, double-buffered, keep
+#: within ``_RUN_VMEM`` of VMEM
+RUN_BLOCK = 1 << 18
+_RUN_VMEM = 12 << 20
+_INT32_MIN = -(1 << 31)
 
 
 def _seg_scan(start, members):
@@ -1467,93 +1482,439 @@ def _seg_scan(start, members):
     return [v.reshape(-1)[:n] for v in vs]
 
 
-def _build_ordered_agg_program(agg_ops, val_kinds):
+def _run_members(agg_ops, val_kinds, mask, vals):
+    """The ordered lowering's plan over ``mask`` and the value columns
+    ``vals`` (whole columns, or one vreg of them inside the kernel): the
+    members to scan, ``(values, op, fill)`` with ``op`` associative and
+    ``fill`` its identity; which member says that a run holds a valid row;
+    and for each aggregate ``(fn, slot, count member, value member)``.
+    Masked rows and NaN values vote nowhere."""
+    acc = _acc_dtype()
+    wide = jax.dtypes.canonicalize_dtype(jnp.int64)
+    members, at = [], {}
+
+    def member(name, v, op, fill):
+        if name not in at:
+            at[name] = len(members)
+            members.append((v, op, jnp.asarray(fill, v.dtype)))
+        return at[name]
+
+    # a run votes where it holds a valid row; counts are scanned only
+    # where an aggregate reports one (a flag scan moves a byte a slot)
+    rows_any = member("rows?", mask, jnp.logical_or, False)
+    plan = []
+    for fn, s_i, _ in agg_ops:
+        if s_i < 0:                                   # count(*)
+            plan.append((fn, s_i, member(
+                "rows", mask.astype(jnp.int32), jnp.add, 0), None))
+            continue
+        v = vals[s_i]
+        floating = val_kinds[s_i] == "f"
+        nn = jnp.logical_and(mask, jnp.logical_not(jnp.isnan(v))) \
+            if floating else mask
+        if fn in ("count", "avg"):
+            cnt = member(f"nn{s_i}" if floating else "rows",
+                         nn.astype(jnp.int32), jnp.add, 0)
+        else:          # the empty -> NULL rule of a float column only
+            cnt = member(f"nn{s_i}?", nn, jnp.logical_or, False) \
+                if floating else None
+        if fn == "count":
+            plan.append((fn, s_i, cnt, None))
+        elif fn in ("sum", "avg"):
+            if floating or fn == "avg":
+                x = jnp.where(nn, v.astype(acc), jnp.zeros((), acc))
+                plan.append((fn, s_i, cnt, member(
+                    f"sum{s_i}", x, jnp.add, 0)))
+            else:
+                x = jnp.where(mask, v, jnp.zeros_like(v)).astype(wide)
+                plan.append((fn, s_i, cnt, member(
+                    f"isum{s_i}", x, jnp.add, 0)))
+        else:                                         # min / max
+            lo = fn == "min"
+            vi = v.astype(jnp.int32) if v.dtype == jnp.bool_ else v
+            if floating:
+                fill = jnp.inf if lo else -jnp.inf
+            else:
+                info = jnp.iinfo(vi.dtype)
+                fill = info.max if lo else info.min
+            x = jnp.where(nn, vi, jnp.asarray(fill, vi.dtype))
+            plan.append((fn, s_i, cnt, member(
+                f"{fn}{s_i}", x, jnp.minimum if lo else jnp.maximum,
+                fill)))
+    return members, rows_any, plan
+
+
+def _run_results(plan, runs, vals, val_kinds):
+    """Every aggregate's value at each slot from the members' ``runs``
+    (a run's value stands at its last slot): empty -> NULL for a float
+    column."""
+    nan = jnp.asarray(jnp.nan, _acc_dtype())
+    outs = []
+    for fn, s_i, cnt, m in plan:
+        if fn == "count":
+            outs.append(runs[cnt].astype(int_dtype()))
+            continue
+        v = vals[s_i]
+        r = runs[m]
+        if fn == "avg":
+            outs.append((r / runs[cnt]).astype(float_dtype()))
+        elif val_kinds[s_i] != "f":
+            outs.append(r.astype(int_dtype() if fn == "sum" else v.dtype))
+        else:
+            outs.append(jnp.where(runs[cnt], r, nan).astype(v.dtype))
+    return outs
+
+
+@functools.lru_cache(maxsize=256)
+def _run_plan_specs(agg_ops, val_kinds, val_dtypes, tag):
+    """A plan's members as ``(op, dtype)`` and its outputs' dtypes, from
+    the value columns' dtypes alone (``tag``: the engine's dtype mode,
+    which the accumulators follow)."""
+    del tag
+    members, outs = [], []
+
+    def probe(mask, *vals):
+        ms, _, plan = _run_members(agg_ops, val_kinds, mask, vals)
+        members.extend((op, v.dtype) for v, op, _ in ms)
+        outs.extend(o.dtype for o in _run_results(
+            plan, [v for v, _, _ in ms], vals, val_kinds))
+        return mask
+
+    jax.eval_shape(probe, jax.ShapeDtypeStruct((_RUN_VREG,), jnp.bool_),
+                   *(jax.ShapeDtypeStruct((_RUN_VREG,), d)
+                     for d in val_dtypes))
+    return tuple(members), tuple(outs)
+
+
+def _plan_specs(agg_ops, val_kinds, vals):
+    """:func:`_run_plan_specs` of the value columns ``vals`` in the
+    engine's dtype mode now."""
+    return _run_plan_specs(
+        tuple(agg_ops), tuple(val_kinds),
+        tuple(jnp.dtype(v.dtype) for v in vals),
+        (dtype_tag(), bool(jax.config.jax_enable_x64)))
+
+
+def run_scan_lowering(keys, members, n: int) -> str:
+    """Which lowering the ordered program's segmented scans over ``n``
+    slots take — from the backend and the operands, never from a conf
+    key: ``"pallas"`` (the kernel ``grouped_run_scan``) for a 32-bit
+    integer key on one TPU device whose members (``members``: their
+    dtypes) are all of 32 bits or bool; ``"xla"`` (:func:`_seg_scan`)
+    everywhere else — the CPU of the tests, a mesh, 64-bit members under
+    x64 — and for fewer slots than a vreg holds. Both give the same
+    integers; a float sum may round otherwise (the kernel adds a run's
+    rows in another order)."""
+    if jax.default_backend() != "tpu" or n < _RUN_VREG:
+        return "xla"
+    if any(jnp.dtype(dt) != jnp.bool_ and jnp.dtype(dt).itemsize != 4
+           for dt in members):
+        return "xla"
+    for key in keys:
+        if jnp.dtype(key.dtype) not in (jnp.dtype(jnp.int32),
+                                        jnp.dtype(jnp.uint32)):
+            return "xla"
+        sharding = getattr(key, "sharding", None)
+        if sharding is None or len(sharding.device_set) != 1:
+            return "xla"
+    return "pallas"
+
+
+def _run_identity(op, dtype):
+    """``op``'s identity in ``dtype``: the carry before the first slot."""
+    if op is jnp.minimum or op is jnp.maximum:
+        low = op is jnp.maximum
+        if jnp.issubdtype(dtype, jnp.floating):
+            return -jnp.inf if low else jnp.inf
+        info = jnp.iinfo(dtype)
+        return info.min if low else info.max
+    return 0                                          # add, or
+
+
+def _run_quantities(specs):
+    """What the kernel scans for members ``specs`` (``(op, dtype)``): the
+    flag members (bool, or) as the bits of one int32, then every other
+    member, as ``(op, dtype)``."""
+    flags = [(jnp.bitwise_or, jnp.dtype(jnp.int32))] if any(
+        dt == jnp.bool_ for _, dt in specs) else []
+    return flags + [s for s in specs if s[1] != jnp.bool_]
+
+
+def _run_kernel(*refs, agg_ops, val_kinds, val_dtypes, specs, nout: int,
+                unsigned: bool, n: int, block: int, unroll: int):
+    """One grid step of ``grouped_run_scan``: ``block`` slots in turns of
+    ``unroll`` vregs, a vreg 8 rows of 128 consecutive slots. A vreg's
+    segmented scans are log steps of lane rolls, then of row rolls over
+    its rows' totals; the run open before the vreg reaches into it through
+    a carry (the run's value so far, over the whole vreg), so that the
+    chain from one vreg to the next is a few operations a member. Carries
+    and the last key stay in scratch from one grid step to the next.
+
+    A run starts and ends where the key differs from its neighbour's (the
+    first key after a turn comes from the next block where the turn ends
+    this one); the order check rides along. The plan's members, their
+    scans and every aggregate's value with its empty -> NULL rule are made
+    here, so that the columns are read once and each output written once.
+    The flag members (bool, or) are scanned as the bits of one int32."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nv = len(val_kinds)
+    key_ref, ahead_ref, mask_ref = refs[:3]
+    val_refs = refs[3:3 + nv]
+    out_refs = refs[3 + nv:3 + nv + nout]
+    live_ref, count_ref, bad_ref = refs[3 + nv + nout:6 + nv + nout]
+    prev_ref, *carry_refs = refs[6 + nv + nout:]
+    step = pl.program_id(0)
+    chunk = unroll * _RUN_VREG
+    i32 = jnp.int32
+    quants = _run_quantities(specs)
+    ops = [op for op, _ in quants]
+    flags = [i for i, (_, dt) in enumerate(specs) if dt == jnp.bool_]
+    assert len(flags) < 31, flags
+    bit = {i: b for b, i in enumerate(flags)}
+    # member i: (its quantity, its bit there or None)
+    unpack = [(0, bit[i]) if i in bit else (
+        bool(flags) + len([j for j in range(i) if j not in bit]), None)
+        for i in range(len(specs))]
+
+    row = lax.broadcasted_iota(i32, (8, 128), 0)
+    lane = lax.broadcasted_iota(i32, (8, 128), 1)
+    flat = row * 128 + lane
+    zero = jnp.zeros((8, 128), i32)
+
+    def roll(v, by, axis):
+        return pltpu.roll(v, i32(by), axis)
+
+    def lanes(v):
+        """Each row's last slot, over the row."""
+        return jnp.broadcast_to(v[:, 127:128], (8, 128))
+
+    def keys(ref, at):
+        c = ref[at].reshape(8, 128)
+        if unsigned:          # unsigned order as the signed one
+            c = lax.bitcast_convert_type(c, i32) ^ i32(_INT32_MIN)
+        return c
+
+    def tile(ref, at, dtype):
+        v = ref[at].reshape(8, 128)
+        return v != 0 if dtype == jnp.bool_ else v
+
+    def ahead(c):
+        """The key after each slot, in two parts: (r, l) <- (r, l + 1),
+        and that a row up, whose lane 127 holds (r + 1, 0) — and in row 7
+        the vreg's own first key, which the vreg before it reads there."""
+        shifted = roll(c, 127, 1)
+        return shifted, roll(shifted, 7, 0)
+
+    @pl.when(step == 0)
+    def _():
+        # slot (0, 0) of the first vreg before itself: no start, in order
+        prev_ref[...] = keys(key_ref, pl.ds(0, _RUN_VREG))
+        for ref, (op, dt) in zip(carry_refs, quants):
+            ref[...] = jnp.full((8, 128), _run_identity(op, dt), dt)
+        count_ref[...] = zero
+        bad_ref[...] = zero
+
+    def turn(_, carry):
+        off, rolled, carries, count, bad = carry
+        origin = step * i32(block) + off
+        # the first vreg after the turn: in this block, or the next one
+        inside = keys(key_ref, pl.ds(pl.multiple_of(jnp.minimum(
+            off + chunk, i32(block - _RUN_VREG)), _RUN_VREG), _RUN_VREG))
+        after_turn = jnp.where(off + chunk < block, inside,
+                               keys(ahead_ref, ...))
+
+        def vreg(u, carry):
+            # traced once, lowered ``unroll`` times side by side
+            rolled, nexts, carries, count, bad = carry
+            u = u.astype(i32)                       # int32 under x64 too
+            at = pl.ds(pl.multiple_of(off + u * i32(_RUN_VREG), _RUN_VREG),
+                       _RUN_VREG)
+            c = keys(key_ref, at)
+            following = ahead(jnp.where(u + 1 < unroll, keys(
+                key_ref, pl.ds(pl.multiple_of(jnp.minimum(
+                    off + (u + 1) * i32(_RUN_VREG), i32(block - _RUN_VREG)),
+                    _RUN_VREG), _RUN_VREG)), after_turn))
+            # (r, 0) <- (r - 1, 127); the vreg before's last key at (0, 0)
+            shifted = roll(c, 1, 1)
+            rolled, above = roll(shifted, 1, 0), rolled
+            before = jnp.where(lane >= 1, shifted, jnp.where(
+                row >= 1, rolled, above))
+            after = jnp.where(lane <= 126, nexts[0], jnp.where(
+                row <= 6, nexts[1], following[1]))
+            pos = flat + (origin + u * i32(_RUN_VREG))
+            valid = pos < n
+            bad = bad | ((c < before) & valid).astype(i32)
+            end = (c != after) | (pos == n - 1)
+
+            mask = tile(mask_ref, at, jnp.bool_)
+            vals = [tile(ref, at, dt) for ref, dt in zip(val_refs,
+                                                         val_dtypes)]
+            members, rows_any, plan = _run_members(agg_ops, val_kinds,
+                                                   mask, vals)
+            qs = [v for v, _, _ in members if v.dtype != jnp.bool_]
+            if flags:
+                packed = zero
+                for i in flags:
+                    packed = packed | (members[i][0].astype(i32) << bit[i])
+                qs.insert(0, packed)
+            # the lane of the last run start at or before a slot (-1: none
+            # in its row), then each member's scan from there
+            rs = jnp.where(c != before, lane, -1)
+            for s in (1, 2, 4, 8, 16, 32, 64):
+                rs = jnp.maximum(rs, jnp.where(lane >= s, roll(rs, s, 1), -1))
+            reach = lane - jnp.maximum(rs, 0)
+            for s in (1, 2, 4, 8, 16, 32, 64):
+                take = reach >= s
+                qs = [jnp.where(take, op(q, roll(q, s, 1)), q)
+                      for q, op in zip(qs, ops)]
+            # the rows: the last row holding a start at or before a row;
+            # each row's total scanned from there (from the carry where no
+            # row before holds one), and carried into the slots before the
+            # first start of their row
+            opened = rs >= 0
+            rstart = jnp.where(lanes(rs) >= 0, row, -1)
+            for s in (1, 2, 4):
+                rstart = jnp.maximum(rstart, jnp.where(
+                    row >= s, roll(rstart, s, 0), -1))
+            reach = row - jnp.maximum(rstart, 0)
+            unstarted = rstart < 0
+            runs = []
+            for i, (q, op) in enumerate(zip(qs, ops)):
+                total = lanes(q)
+                for s in (1, 2, 4):
+                    total = jnp.where(reach >= s,
+                                      op(total, roll(total, s, 0)), total)
+                total = jnp.where(unstarted, op(carries[i], total), total)
+                into = jnp.where(row >= 1, roll(total, 1, 0), carries[i])
+                runs.append(jnp.where(opened, q, op(into, q)))
+                carries[i] = jnp.broadcast_to(total[7:8], (8, 128))
+            runs = [(runs[qi] & i32(1 << b)) != 0 if b is not None
+                    else runs[qi] for qi, b in unpack]
+            outs = _run_results(plan, runs, vals, val_kinds)
+            live = end & runs[rows_any] & valid
+            for ref, o in zip(out_refs, outs):
+                if o.dtype == jnp.bool_:
+                    o = o.astype(i32)
+                ref[at] = o.reshape(_RUN_VREG)
+            live = live.astype(i32)
+            live_ref[at] = live.reshape(_RUN_VREG)
+            count = count + live
+            return rolled, following, carries, count, bad
+
+        first = keys(key_ref, pl.ds(pl.multiple_of(off, _RUN_VREG),
+                                    _RUN_VREG))
+        rolled, _, carries, count, bad = lax.fori_loop(
+            0, unroll, vreg,
+            (rolled, ahead(first), carries, count, bad), unroll=unroll)
+        return off + i32(chunk), rolled, carries, count, bad
+
+    _, rolled, carries, count, bad = lax.fori_loop(
+        0, block // chunk, turn, (i32(0), prev_ref[...],
+                                  [ref[...] for ref in carry_refs],
+                                  zero, zero))
+    prev_ref[...] = rolled
+    for ref, v in zip(carry_refs, carries):
+        ref[...] = v
+    count_ref[...] += count
+    bad_ref[...] |= bad
+
+
+def _run_scan_pallas(agg_ops, val_kinds, key, mask, vals,
+                     block: int = RUN_BLOCK, unroll: int = RUN_UNROLL,
+                     interpret: bool = False):
+    """The ordered program's reduction as ONE pass of the Pallas kernel
+    ``grouped_run_scan``: the key, the mask and the value columns read
+    once, every aggregate's value and the live mask written once, the
+    slots in grid steps of ``block`` along a sequential axis. The last
+    step may reach past the slots' end: what it reads there comes after
+    every slot it writes, and what it writes there is dropped — no
+    operand is padded. Returns ``(outs, live, groups, held)`` as
+    :func:`_build_ordered_agg_program`'s XLA form makes them.
+
+    Pallas is imported here and in the kernel, never at module level
+    (every cell imports this module)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = key.shape[0]
+    members, out_dtypes = _plan_specs(agg_ops, val_kinds, vals)
+    assert all(jnp.dtype(dt).itemsize == 4 or dt == jnp.bool_
+               for _, dt in members), members
+    chunk = unroll * _RUN_VREG
+    streams = 3 + len(vals) + len(out_dtypes)      # key, mask, live mask
+    block = max(chunk, min(block, _RUN_VMEM // (8 * streams)) // chunk
+                * chunk)
+    block = min(block, -(-n // chunk) * chunk)
+    last = (n - 1) // _RUN_VREG
+    spec = pl.BlockSpec((block,), lambda i: (i,))
+    ahead = pl.BlockSpec((_RUN_VREG,), lambda i: (
+        jnp.minimum((i + 1) * (block // _RUN_VREG), last),))
+    whole = pl.BlockSpec((8, 128), lambda i: (0, 0))
+
+    def as32(x):
+        return x.astype(jnp.int32) if x.dtype == jnp.bool_ else x
+
+    def stored(dt):
+        return jnp.int32 if dt == jnp.bool_ else dt
+
+    val_dtypes = tuple(jnp.dtype(v.dtype) for v in vals)
+    *outs, live, count, bad = pl.pallas_call(
+        functools.partial(
+            _run_kernel, agg_ops=tuple(agg_ops), val_kinds=tuple(val_kinds),
+            val_dtypes=val_dtypes, specs=members, nout=len(out_dtypes),
+            unsigned=jnp.dtype(key.dtype) == jnp.uint32, n=n, block=block,
+            unroll=unroll),
+        grid=(pl.cdiv(n, block),),
+        in_specs=[spec, ahead] + [spec] * (1 + len(vals)),
+        out_specs=[spec] * (len(out_dtypes) + 1) + [whole, whole],
+        out_shape=[jax.ShapeDtypeStruct((n,), stored(dt))
+                   for dt in out_dtypes]
+        + [jax.ShapeDtypeStruct((n,), jnp.int32)]
+        + [jax.ShapeDtypeStruct((8, 128), jnp.int32)] * 2,
+        scratch_shapes=[pltpu.VMEM((8, 128), jnp.int32)]
+        + [pltpu.VMEM((8, 128), dt) for _, dt in _run_quantities(members)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="grouped_run_scan")(
+            key, key, as32(mask), *(as32(v) for v in vals))
+    outs = [o != 0 if dt == jnp.bool_ else o
+            for o, dt in zip(outs, out_dtypes)]
+    return (outs, live != 0, jnp.sum(count, dtype=jnp.int32),
+            jnp.all(bad == 0))
+
+
+def _build_ordered_agg_program(agg_ops, val_kinds, scan: str = "xla"):
     """The ordered grouped lowering, for one integer key column that
     arrives in order (a fact table stored in its parent's key order): a
     group is a run of equal keys, every aggregate a segmented scan over
-    the runs (:func:`_seg_scan`), read at the run's last slot — no sort,
-    no gather, no scatter. The result keeps the input's slots under a mask
-    (a run's last slot where the run holds a valid row), in key order, and
-    the key column itself is its key (the program returns none); the
-    program reports whether the keys were in order, and where they were
-    not its result is not used. Same aggregate semantics as the sorted
-    lowering: masked rows and NaN values vote nowhere, empty -> NULL."""
-    acc = _acc_dtype()
-    wide = jax.dtypes.canonicalize_dtype(jnp.int64)
+    the runs, read at the run's last slot — no sort, no gather, no
+    scatter. ``scan`` (:func:`run_scan_lowering`) says which scans: one
+    pass of the Pallas kernel ``grouped_run_scan``
+    (:func:`_run_scan_pallas`), or XLA's (:func:`_seg_scan`). The result
+    keeps the input's slots under a mask (a run's last slot where the run
+    holds a valid row), in key order, and the key column itself is its
+    key (the program returns none); the program reports whether the keys
+    were in order, and where they were not its result is not used. Same
+    aggregate semantics as the sorted lowering: masked rows and NaN values
+    vote nowhere, empty -> NULL."""
 
     def program(keys, vals, mask):
         k = jnp.asarray(keys[0])
+        vals = [jnp.asarray(v) for v in vals]
+        if scan == "pallas":
+            outs, live, groups, held = _run_scan_pallas(
+                agg_ops, val_kinds, k, mask, vals)
+            return (), tuple(outs), groups, live, held
         change = k[1:] != k[:-1]
         one = jnp.ones((1,), jnp.bool_)
-        start = jnp.concatenate([one, change])
-        end = jnp.concatenate([change, one])
-        members, at = [], {}
-
-        def member(name, v, op, fill):
-            if name not in at:
-                at[name] = len(members)
-                members.append((v, op, jnp.asarray(fill, v.dtype)))
-            return at[name]
-
-        # a run votes where it holds a valid row; counts are scanned only
-        # where an aggregate reports one (a flag scan moves a byte a slot)
-        rows_any = member("rows?", mask, jnp.logical_or, False)
-        plan = []
-        for fn, s_i, _ in agg_ops:
-            if s_i < 0:                                   # count(*)
-                plan.append((fn, s_i, member(
-                    "rows", mask.astype(jnp.int32), jnp.add, 0), None))
-                continue
-            v = jnp.asarray(vals[s_i])
-            floating = val_kinds[s_i] == "f"
-            nn = jnp.logical_and(mask, jnp.logical_not(jnp.isnan(v))) \
-                if floating else mask
-            if fn in ("count", "avg"):
-                cnt = member(f"nn{s_i}" if floating else "rows",
-                             nn.astype(jnp.int32), jnp.add, 0)
-            else:          # the empty -> NULL rule of a float column only
-                cnt = member(f"nn{s_i}?", nn, jnp.logical_or, False) \
-                    if floating else None
-            if fn == "count":
-                plan.append((fn, s_i, cnt, None))
-            elif fn in ("sum", "avg"):
-                if floating or fn == "avg":
-                    x = jnp.where(nn, v.astype(acc), jnp.zeros((), acc))
-                    plan.append((fn, s_i, cnt, member(
-                        f"sum{s_i}", x, jnp.add, 0)))
-                else:
-                    x = jnp.where(mask, v, jnp.zeros_like(v)).astype(wide)
-                    plan.append((fn, s_i, cnt, member(
-                        f"isum{s_i}", x, jnp.add, 0)))
-            else:                                         # min / max
-                lo = fn == "min"
-                vi = v.astype(jnp.int32) if v.dtype == jnp.bool_ else v
-                if floating:
-                    fill = jnp.inf if lo else -jnp.inf
-                else:
-                    info = jnp.iinfo(vi.dtype)
-                    fill = info.max if lo else info.min
-                x = jnp.where(nn, vi, jnp.asarray(fill, vi.dtype))
-                plan.append((fn, s_i, cnt, member(
-                    f"{fn}{s_i}", x, jnp.minimum if lo else jnp.maximum,
-                    fill)))
-        runs = _seg_scan(start, members)
-        live = jnp.logical_and(end, runs[rows_any])
-        nan = jnp.asarray(jnp.nan, acc)
-        outs = []
-        for fn, s_i, cnt, m in plan:
-            if fn == "count":
-                outs.append(runs[cnt].astype(int_dtype()))
-                continue
-            v = jnp.asarray(vals[s_i])
-            r = runs[m]
-            if fn == "avg":
-                outs.append((r / runs[cnt]).astype(float_dtype()))
-            elif val_kinds[s_i] != "f":
-                outs.append(r.astype(int_dtype() if fn == "sum"
-                                     else v.dtype))
-            else:
-                outs.append(jnp.where(runs[cnt], r, nan).astype(v.dtype))
+        members, rows_any, plan = _run_members(agg_ops, val_kinds, mask,
+                                                vals)
+        runs = _seg_scan(jnp.concatenate([one, change]), members)
+        live = jnp.logical_and(jnp.concatenate([change, one]),
+                               runs[rows_any])
+        outs = _run_results(plan, runs, vals, val_kinds)
         # the key column itself is the result's: no copy leaves the program
         return ((), tuple(outs), jnp.sum(live, dtype=jnp.int32), live,
                 jnp.all(k[1:] >= k[:-1]))
@@ -1867,18 +2228,25 @@ def grouped_agg(frame, keys, agg_list):
                 if not ordered:
                     counters.increment("grouped.order_miss")
             if ordered:
+                scan = run_scan_lowering(keys_in, [
+                    dt for _, dt in _plan_specs(agg_ops, val_kinds,
+                                                vals_in)[0]], n)
                 before = counters.get("grouped.compile")
-                fn = _cached_plan(f"GO|{struct}", _build_ordered_agg_program(
-                    tuple(agg_ops), tuple(val_kinds)))
+                fn = _cached_plan(f"GO|{scan}|{struct}",
+                                  _build_ordered_agg_program(
+                                      tuple(agg_ops), tuple(val_kinds),
+                                      scan))
                 fn.stats_key = stats_key
                 key_outs, agg_outs, groups, live, held = _run_plan(
                     fn, args, before, sp)
+                if scan == "pallas":
+                    counters.increment("grouped.run_pallas")
                 syncs += 1
                 g_h, ordered = (int(x) for x in _read_verdict((groups, held)))
                 if ordered:
                     g = g_h
                     counters.increment("grouped.ordered")
-                    sp.set(groups=g, lowering="ordered")
+                    sp.set(groups=g, lowering="ordered", scan=scan)
                 else:
                     counters.increment("grouped.order_miss")
             with _ORDER_LOCK:

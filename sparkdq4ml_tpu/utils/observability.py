@@ -251,6 +251,9 @@ METRIC_NAMES = {
     "grouped.order_miss": ("counter", "grouped plans offered the ordered "
                                       "lowering whose keys were out of "
                                       "order"),
+    "grouped.run_pallas": ("counter", "ordered grouped programs whose "
+                                      "segmented scans ran in the Pallas "
+                                      "kernel grouped_run_scan"),
     "subquery.semi_join": ("counter", "IN subqueries planned as left-semi "
                                       "joins against their frame"),
     "subquery.literal_in": ("counter", "IN subqueries read to the host as "

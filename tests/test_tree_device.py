@@ -542,6 +542,59 @@ def test_join_probe_scan_compiles_for_the_chip_at_the_cell_size(
     assert memory.output_size_in_bytes <= 2 * 4 * (n + 1024) + 1024
 
 
+@pytest.mark.parametrize("n, plan", [
+    (240_048_600, "q18"),                 # the cell's lineitem
+    (66_000_017, "family"),               # no step multiple, every member
+    (1_024, "q18")])                      # the fewest the lowering hands it
+def test_grouped_run_scan_compiles_for_the_chip_at_the_cell_size(
+        one_chip, n, plan):
+    """The ordered GROUP BY's kernel (``ops/segments.py``; here because this
+    file holds every compile for the described chip) in the chip's 32-bit
+    mode: ``tpch_q18_volume``'s ``sum(l_quantity)`` over 2.4e8 lines, and
+    the whole aggregate family over a float, an int and a bool column. No
+    operand is padded or concatenated; the only temporaries are the int32
+    forms of the bools that Mosaic takes and gives (the mask in, the live
+    mask out, a bool column and a bool column's max)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from sparkdq4ml_tpu.config import config
+    from sparkdq4ml_tpu.ops import segments as S
+
+    if plan == "q18":
+        ops, kinds, dts = (("sum", 0, False),), ("f",), (jnp.float32,)
+    else:
+        ops = (("count", -1, False), ("sum", 0, False), ("avg", 0, False),
+               ("min", 0, False), ("max", 1, False), ("sum", 1, False),
+               ("count", 0, False), ("max", 2, False))
+        kinds, dts = ("f", "i", "b"), (jnp.float32, jnp.int32, jnp.bool_)
+
+    def shape(dt):
+        return jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+
+    saved = (config.default_float_dtype, config.default_int_dtype)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        config.default_float_dtype, config.default_int_dtype = \
+            jnp.float32, jnp.int32
+        with jax.enable_x64(False):
+            compiled = jax.jit(S._build_ordered_agg_program(
+                ops, kinds, "pallas")()).lower(
+                (shape(jnp.int32),), tuple(shape(dt) for dt in dts),
+                shape(jnp.bool_)).compile()
+    finally:
+        config.default_float_dtype, config.default_int_dtype = saved
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "grouped_run_scan" in text
+    assert " pad(" not in text and " concatenate(" not in text
+    memory = compiled.memory_analysis()
+    # the mask and the live mask; the family's bool column and its max
+    bools = 4 if plan == "family" else 2
+    assert memory.temp_size_in_bytes <= bools * (4 * n + (1 << 18))
+
+
 # ---------------------------------------------------------------------------
 # a whole fit against the plain reference
 # ---------------------------------------------------------------------------
