@@ -19,10 +19,11 @@ Stages (``run``): A the reference app on ``data/dataset-full.csv``; B the
 same path on a seed-generated 4,000,000-row CSV (streaming native ingest,
 both rules, both SQL filters, GROUP BY, ORDER BY, Lasso fit) plus a
 1,000,000 x 16 logistic fit, with the flushes run twice; C eight requests
-through the socket front end; D the three Pallas kernels, compiled; E
-nothing degraded on the way (device placement, recovery log, fallback
-counters, dq profile, ``block_until_ready``, the compile cache); F, on more
-than one device, the sharded frame path and the sharded gradient.
+through the socket front end; E nothing degraded on the way (device
+placement, recovery log, fallback counters, dq profile,
+``block_until_ready``, the compile cache); F, on more than one device, the
+sharded frame path and the sharded gradient. There is no stage D: the
+kernels it compiled are gone, and the other stages keep their letters.
 
 ``tests/test_chip_smoke.py`` imports ``run`` and drives it at tiny sizes
 with the platform it expects there.
@@ -430,56 +431,6 @@ def stage_c_serve(ctx: Smoke, spark) -> None:
                healthz=200, drained=True)
 
 
-def stage_d_kernels(ctx: Smoke, rows: int, logit_rows: int, logit_cols: int,
-                    pallas_mode: str, seed: int) -> None:
-    import jax
-    import jax.numpy as jnp
-
-    from sparkdq4ml_tpu.config import config
-    from sparkdq4ml_tpu.models.solvers import augmented_gram
-    from sparkdq4ml_tpu.ops import pallas_kernels, rules
-    from sparkdq4ml_tpu.parallel.distributed import pack_design
-
-    ctx.stage = "D:kernels"
-    kx, ky, km, kg, kp = jax.random.split(jax.random.PRNGKey(seed), 5)
-    X = jax.random.normal(kx, (logit_rows, logit_cols), jnp.float32)
-    y = jax.random.normal(ky, (logit_rows,), jnp.float32)
-    mask = jax.random.uniform(km, (logit_rows,)) < 0.9
-    Z = pack_design(X, y, mask)
-    guest = jnp.floor(jax.random.uniform(kg, (rows,), jnp.float32, 1, 41))
-    price = jax.random.uniform(kp, (rows,), jnp.float32, 1.0, 250.0)
-
-    saved = config.pallas
-    ctx.check(saved == "off", f"config.pallas is {saved!r} before stage D")
-    A_xla = jax.block_until_ready(augmented_gram(X, y, mask))
-    rules_xla = jax.block_until_ready(rules.dq_rules_fused(price, guest))
-    config.pallas = pallas_mode
-    try:
-        ctx.check(pallas_kernels.dispatch_to_pallas(X),
-                  f"config.pallas={pallas_mode!r} does not select Pallas")
-        A_masked = jax.block_until_ready(
-            pallas_kernels.masked_gram_pallas(X, y, mask))
-        A_packed = jax.block_until_ready(
-            pallas_kernels.packed_gram_pallas(Z))
-        rules_pl = jax.block_until_ready(
-            pallas_kernels.dq_rules_pallas(price, guest))
-    finally:
-        config.pallas = saved
-    diffs = {"masked_gram": ctx.approx(A_masked, A_xla, 1e-4,
-                                      "masked_gram_pallas vs XLA"),
-             "packed_gram": ctx.approx(A_packed, A_xla, 1e-4,
-                                      "packed_gram_pallas vs XLA")}
-    for name, got, want in zip(("price_no_min", "price_correct_correl",
-                                "keep"), rules_pl, rules_xla):
-        ctx.check(bool(jnp.array_equal(got, want)),
-                  f"dq_rules_pallas {name} differs from the XLA expression")
-    ctx.report(mode=pallas_mode, compiled=["masked_gram_pallas",
-                                           "packed_gram_pallas",
-                                           "dq_rules_pallas"],
-               rel_diff_vs_xla={k: float(f"{v:.3g}")
-                                for k, v in diffs.items()})
-
-
 def stage_e_nothing_degraded(ctx: Smoke, spark, b: dict, marks: dict,
                              matmul_n: int) -> None:
     import jax
@@ -648,7 +599,7 @@ def stage_f_several_devices(ctx: Smoke, spark, b: dict,
 def run(expect_platform: str, rows: int = 4_000_000,
         logit_rows: int = 1_000_000, logit_cols: int = 16,
         ingest_chunk_bytes: int = 8 << 20, shard_min_rows: int = 65536,
-        pallas_mode: str = "on", matmul_n: int = 8192,
+        matmul_n: int = 8192,
         seed: int = 20260926) -> dict:
     """Drive every stage once; raise :class:`SmokeFailure` on the first
     check that does not hold. Returns the device identity."""
@@ -696,7 +647,6 @@ def run(expect_platform: str, rows: int = 4_000_000,
         b = stage_b_chip_width(ctx, spark, workdir, rows, logit_rows,
                                logit_cols, seed)
         stage_c_serve(ctx, spark)
-        stage_d_kernels(ctx, rows, logit_rows, logit_cols, pallas_mode, seed)
         stage_e_nothing_degraded(ctx, spark, b, marks, matmul_n)
         if ctx.device["count"] > 1:
             stage_f_several_devices(ctx, spark, b, shard_min_rows)

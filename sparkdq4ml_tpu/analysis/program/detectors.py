@@ -266,8 +266,8 @@ class RetraceHazardDetector(Detector):
             # themselves. The list form is what real producers declare
             # (bucket x2 vs x4): jax serves the base avals from its
             # internal trace cache, which may predate a config flip
-            # (e.g. the pallas dispatch mode) — two FRESH traces under
-            # the current config are the apples-to-apples comparison.
+            # (the float dtype, say) — two FRESH traces under the
+            # current config are the apples-to-apples comparison.
             pairs = spec if isinstance(spec, list) else [spec]
             ref_sig, ref_name = base_sig, "base"
             for i, (vargs, vkwargs) in enumerate(pairs):

@@ -392,14 +392,6 @@ class _Config:
     # drained profile; "persisted" only ever adopts; "off" disables
     # drift scoring.
     dq_baseline_mode: str = "first"
-    # Pallas fast-path selection for the hot ops (ops/pallas_kernels.py):
-    # the single-device Gramian in solvers.augmented_gram and the fused DQ
-    # chain entry point ops/rules.py:dq_rules_fused. "off" = plain XLA
-    # (default; XLA fuses these well), "on" = compiled Pallas kernels,
-    # "auto" = Pallas when the backend is TPU, "interpret" = Pallas
-    # interpreter (CPU tests/CI of the kernel code). shard_map/vmap traces
-    # always use XLA (see pallas_kernels.dispatch_to_pallas).
-    pallas: str = "off"
 
 
 config = _Config()

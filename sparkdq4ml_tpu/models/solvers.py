@@ -64,14 +64,8 @@ def augmented_gram(X: jnp.ndarray, y: jnp.ndarray, mask: jnp.ndarray) -> jnp.nda
     """One-pass masked statistics: ``A = ZᵀZ``, ``Z = [X, y, 1]·mask``.
 
     Shape ``(d+2, d+2)``. This is the entire data touch of a linear fit — the
-    ``treeAggregate`` analogue, as one MXU matmul per shard. With
-    ``config.pallas`` enabled, dispatches to the row-streaming Pallas kernel
-    (``ops/pallas_kernels.py``); default is the XLA expression below.
+    ``treeAggregate`` analogue, as one MXU matmul per shard.
     """
-    from ..ops import pallas_kernels
-
-    if pallas_kernels.dispatch_to_pallas(X, y, mask):
-        return pallas_kernels.masked_gram_pallas(X, y, mask)
     w = mask.astype(X.dtype)
     ones = jnp.ones_like(y)
     Z = jnp.concatenate([X, y[:, None], ones[:, None]], axis=1) * w[:, None]

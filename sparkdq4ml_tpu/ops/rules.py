@@ -21,7 +21,6 @@ asymmetry (SURVEY.md §2.1):
 from __future__ import annotations
 
 import jax.numpy as jnp
-import numpy as np
 
 from ..config import float_dtype
 
@@ -55,29 +54,6 @@ def price_correlation_rule(price, guest):
     null = jnp.logical_or(jnp.isnan(price), jnp.isnan(guest_f))
     sentinel = jnp.asarray(BAD_ROW_SENTINEL, price.dtype)
     return jnp.where(jnp.logical_or(bad, null), sentinel, price)
-
-
-def dq_rules_fused(price, guest):
-    """One-pass fused DQ chain: ``(price_no_min, price_correct_correl, keep)``.
-
-    Collapses the reference's four stages — rule 1, ``WHERE > 0``, rule 2,
-    ``WHERE > 0`` (`DataQuality4MachineLearningApp.java:68-95`) — into a
-    single elementwise pass; the two filters commute into one conjunction
-    because filtering is mask composition. Dispatches to the Pallas kernel
-    (``ops/pallas_kernels.py``) when ``config.pallas`` selects it, else runs
-    the fused XLA expression below (identical semantics, incl. the NaN
-    asymmetry of the two rules).
-    """
-    from . import pallas_kernels
-
-    price = jnp.asarray(price, float_dtype())
-    guest = jnp.asarray(guest, float_dtype())
-    if pallas_kernels.dispatch_to_pallas(price, guest):
-        return pallas_kernels.dq_rules_pallas(price, guest)
-    pnm = minimum_price_rule(price)
-    pcc = price_correlation_rule(price, guest)
-    keep = jnp.logical_and(pnm > 0, pcc > 0)
-    return pnm, pcc, keep
 
 
 def register_builtin_rules(registry=None) -> None:

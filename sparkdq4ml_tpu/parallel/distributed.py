@@ -75,7 +75,7 @@ class _RecordedProgram:
 class _EnumerableFactory:
     """Memoizing decorator for the jit factories with the
     ``cache_info()``/``cache_clear()`` surface of ``functools.lru_cache``
-    (the observability trace-probe and the pallas tests use both) PLUS
+    (the observability trace-probe and the tests use both) PLUS
     entry enumeration — ``entries()`` yields ``(key, product)`` pairs so
     the program auditor can re-trace every cached fit program without a
     private import. Builds serialize on one lock (factory builds are
@@ -293,16 +293,6 @@ def fused_linear_fit_packed(mesh: Optional[Mesh], solver: str, max_iter: int,
     solve_A = _resolve_solve_A(solver, max_iter, tol, fit_intercept,
                                standardization)
 
-    def local_gram(Z):
-        # Honors config.pallas like the unpacked augmented_gram; inside
-        # shard_map the dispatch gate sees the varying mesh axes and falls
-        # back to the XLA matmul.
-        from ..ops import pallas_kernels
-
-        if pallas_kernels.dispatch_to_pallas(Z):
-            return pallas_kernels.packed_gram_pallas(Z)
-        return Z.T @ Z
-
     if mesh is None or mesh.devices.size <= 1:
         def gram(design):
             if isinstance(design, DesignColumns):
@@ -311,10 +301,10 @@ def fused_linear_fit_packed(mesh: Optional[Mesh], solver: str, max_iter: int,
                 with _obs.scope("fit.pack"):
                     X, y, mask, w = design
                     design = _pack(jnp, X, y, row_scale(mask, w), None)
-            return local_gram(design)
+            return design.T @ design
     else:
         gram = shard_map(
-            lambda Zs: jax.lax.psum(local_gram(Zs), DATA_AXIS),
+            lambda Zs: jax.lax.psum(Zs.T @ Zs, DATA_AXIS),
             mesh=mesh, in_specs=(P(DATA_AXIS),), out_specs=P())
 
     def fit(design, hyper):
@@ -386,7 +376,7 @@ def _fit_handle(name, key, rec, entry, example):
     # small fixed-shape args keep their calling convention.
     # Two factors (x2/x4) give the retrace detector a pair of
     # FRESH traces — jax may serve the recorded shape from a
-    # trace cache predating a config flip (pallas mode).
+    # trace cache predating a config flip (the float dtype).
     leaves = [s for s in jax.tree_util.tree_leaves(example)
               if hasattr(s, "shape") and s.shape]
     rows = max((s.shape[0] for s in leaves), default=0)
